@@ -70,6 +70,10 @@ def initialize(
 
     configure_from_raw(raw_cfg)
 
+    from .utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     if dist_init_required is None or dist_init_required:
         comm.init_distributed(distributed_port=distributed_port)
 

@@ -26,8 +26,17 @@ def _probe() -> Accelerator:
     if name:
         raise ValueError(f"DS_ACCELERATOR={name!r} is not supported (tpu|cpu)")
     tpu = TPUAccelerator()
-    if tpu.is_available():
-        return tpu
+    try:
+        if tpu.is_available():
+            return tpu
+        why = "jax.devices() lists no TPU"
+    except RuntimeError as exc:   # jax's backend-initialization failure
+        why = f"the TPU backend did not start: {exc}"
+    # the library may run on the CPU (tests, host-side tools) — but it says
+    # so, once, with the reason
+    from ..utils.logging import logger
+
+    logger.info(f"accelerator: using the CPU accelerator ({why})")
     return CPUAccelerator()
 
 

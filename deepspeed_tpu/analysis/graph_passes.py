@@ -156,7 +156,7 @@ class ReplicaGroupGatherPass(GraphPass):
             if _is_leaf_eqn(eqn):
                 continue
             inner_sm = in_shard_map or name == "shard_map"
-            if name == "pjit":
+            if name == "jit":      # the jit-call primitive (was "pjit")
                 cj = eqn.params.get("jaxpr")
                 inner = getattr(cj, "jaxpr", cj)
                 if inner is not None and hasattr(inner, "invars"):
@@ -253,7 +253,7 @@ class MaskedNaNPass(GraphPass):
 
 
 @register_pass
-class FusedWireLayoutPass(GraphPass):
+class FusedWirePass(GraphPass):
     """Quantized-collective wire contract (generalizes PR 9's
     ``assert_fused_pack``): every int8-operand collective must consume the
     output of a Pallas quantize+pack kernel through layout-only ops —

@@ -91,7 +91,7 @@ def describe_eqn(eqn) -> str:
 #: (scan: consts+carry+xs in / carry+ys out — positional either side;
 #: cond/while have multiple bodies or split signatures and are excluded)
 _ALIASING_CONTAINERS = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
+    "jit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "shard_map", "scan",
 })

@@ -1,7 +1,7 @@
 """Pallas ragged/paged serving attention — the FastGen ``blocked_flash``
 equivalent on TPU.
 
-Round-4 redesign (VERDICT r3 #1): the round-3 kernel walked ``max_blocks``
+Round-4 redesign: the round-3 kernel walked ``max_blocks``
 grid steps per (atom, kv-head) with one tiny ``[rows, block_size]`` tile
 each — grid-step overhead swamped decode (measured: paged 11.8 tok/s vs its
 own dense-gather oracle at 16.9, 8k ctx on v5e).  This kernel moves the

@@ -544,9 +544,8 @@ def build_decode_loop(cfg, *, max_q: int, max_seqs: int, max_blocks: int,
     advanced on device between iterations.
 
     Why: the host-driven put()/argmax loop pays a host↔device round trip per
-    token — over a remote TPU link that latency (not compute) caps decode
-    throughput; even colocated it is the kernel-launch overhead the reference
-    kills with CUDA graphs (engine.py:494).  Here the whole decode window is
+    token — the kernel-launch overhead the reference kills with CUDA graphs
+    (engine.py:494).  Here the whole decode window is
     device-resident: token i+1's embedding lookup consumes the sampled token
     of step i without ever leaving HBM, selection (argmax / temperature /
     top-k — :func:`sample_tokens`) runs on device, and the advanced metadata

@@ -296,19 +296,63 @@ def _rmsnorm_matmul_kernel(eps, x_ref, s_ref, w_ref, o_ref):
                        ).astype(o_ref.dtype)
 
 
+#: Mosaic's default scoped-VMEM limit on v4/v5e/v5p (v6e allows 32 MiB); the
+#: kernel's tiles are sized to the smallest so one choice compiles everywhere
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+
+def rmsnorm_vmem_bytes(bm: int, bn: int, D: int, in_bytes: int,
+                       out_bytes: int) -> int:
+    """Upper bound on the kernel's VMEM need for one ``(bm, D) x (D, bn)``
+    grid step: double-buffered x / w / out tiles plus the norm's
+    intermediates (the f32 copy of the row tile and the normalized tile in
+    the input dtype).  Checked against the chip's compiler at D = 4096 and
+    8192 (tests/unit/test_chip_compile.py): every tiling this admits
+    compiles; it over-counts by 1-4 MiB where Mosaic reuses a buffer."""
+    tiles = 2 * (bm * D + D * bn) * in_bytes + 2 * bm * bn * out_bytes
+    return tiles + bm * D * (4 + in_bytes)
+
+
+def rmsnorm_blocks(M: int, D: int, F: int, in_dtype, out_dtype,
+                   block_m: int = 256, block_n: int = 512
+                   ) -> Optional[Tuple[int, int]]:
+    """``(bm, bn)`` for the fused kernel, or ``None`` when no legal tiling
+    fits VMEM — the caller then runs the unfused composition.
+
+    Legal on the chip: ``bn`` a multiple of the 128-lane width (or all of
+    ``F``), ``bm`` a multiple of the dtype's sublane packing (8 rows of
+    f32, 16 of bf16; or all of ``M``), both dividing their dimension (a
+    partial block would pad the walk).  The row tile is taken as large as
+    it goes first: the weight is re-streamed once per row tile, so ``bm``
+    sets the kernel's HBM traffic; ``bn`` only sets how often the norm is
+    recomputed."""
+    in_bytes = jnp.dtype(in_dtype).itemsize
+    out_bytes = jnp.dtype(out_dtype).itemsize
+    sublane = 32 // in_bytes
+    rows = [d for d in range(min(M, block_m), 0, -1)
+            if M % d == 0 and (d % sublane == 0 or d == M)]
+    cols = [d for d in range(min(F, block_n), 0, -1)
+            if F % d == 0 and (d % 128 == 0 or d == F)]
+    for bm in rows:
+        for bn in cols:
+            if rmsnorm_vmem_bytes(bm, bn, D, in_bytes,
+                                  out_bytes) <= _VMEM_LIMIT_BYTES:
+                return bm, bn
+    return None
+
+
 @_partial(jax.custom_vjp, nondiff_argnums=(0, 4, 5))
-def _rmsnorm_matmul_pallas(eps, x2, scale, w, block_m, block_n):
-    """Fused kernel over ``x2 [M, D] @ w [D, F]`` with a custom VJP: the
-    forward is the Pallas kernel, the backward differentiates the
-    reference composition (same math — the forward is bitwise against it,
-    test-asserted — so the cotangents are the unfused path's).  Without
-    this, ``jax.grad`` through the ``pallas_call`` raises and the
-    ``fused_rmsnorm="auto"`` default would break TPU *training* (the same
-    reason ``flash_attention`` carries a custom VJP)."""
+def _rmsnorm_matmul_pallas(eps, x2, scale, w, bm, bn):
+    """Fused kernel over ``x2 [M, D] @ w [D, F]`` in ``(bm, bn)`` tiles
+    (from :func:`rmsnorm_blocks`) with a custom VJP: the forward is the
+    Pallas kernel, the backward differentiates the reference composition
+    (same math — the forward is bitwise against it, test-asserted — so the
+    cotangents are the unfused path's).  Without this, ``jax.grad``
+    through the ``pallas_call`` raises and the ``fused_rmsnorm="auto"``
+    default would break TPU *training* (the same reason
+    ``flash_attention`` carries a custom VJP)."""
     M, D = x2.shape
     F = w.shape[1]
-    bm = _largest_divisor(M, block_m)
-    bn = _largest_divisor(F, block_n)
     out_dtype = jnp.promote_types(x2.dtype, w.dtype)
     return pl.pallas_call(
         _partial(_rmsnorm_matmul_kernel, eps),
@@ -322,12 +366,12 @@ def _rmsnorm_matmul_pallas(eps, x2, scale, w, block_m, block_n):
     )(x2, scale, w)
 
 
-def _rmsnorm_matmul_fwd(eps, x2, scale, w, block_m, block_n):
-    return _rmsnorm_matmul_pallas(eps, x2, scale, w, block_m, block_n), \
+def _rmsnorm_matmul_fwd(eps, x2, scale, w, bm, bn):
+    return _rmsnorm_matmul_pallas(eps, x2, scale, w, bm, bn), \
         (x2, scale, w)
 
 
-def _rmsnorm_matmul_bwd(eps, _block_m, _block_n, res, g):
+def _rmsnorm_matmul_bwd(eps, _bm, _bn, res, g):
     x2, scale, w = res
     _, vjp = jax.vjp(
         lambda x, s, ww: rmsnorm_matmul_reference(x, s.reshape(-1), ww,
@@ -352,15 +396,34 @@ def rmsnorm_matmul(x: jnp.ndarray, scale: jnp.ndarray, w: jnp.ndarray,
     :func:`rmsnorm_matmul_reference` — test-asserted.  Differentiable:
     the Pallas path carries a custom VJP whose backward is the reference
     composition's (training through the fused model works).
+
+    ``block_m``/``block_n`` cap the tiles :func:`rmsnorm_blocks` picks from
+    the shapes.  Where no tiling fits VMEM (very wide ``D``, rows that do
+    not tile), ``"auto"`` runs the unfused composition and an explicit
+    ``"pallas"`` raises — the kernel is never asked for what the chip's
+    compiler would refuse.
     """
-    impl = resolve_impl(impl)
-    if impl == "dense":
+    explicit = impl != "auto"
+    if resolve_impl(impl) == "dense":
         return rmsnorm_matmul_reference(x, scale, w, eps)
     lead = x.shape[:-1]
     D = x.shape[-1]
     x2 = x.reshape(-1, D)
+    blocks = rmsnorm_blocks(x2.shape[0], D, w.shape[1], x2.dtype,
+                            jnp.promote_types(x2.dtype, w.dtype),
+                            block_m, block_n)
+    if blocks is None:
+        what = (f"rmsnorm_matmul: no tiling of [{x2.shape[0]}, {D}] x "
+                f"[{D}, {w.shape[1]}] {x2.dtype} fits "
+                f"{_VMEM_LIMIT_BYTES >> 20} MiB of VMEM")
+        if explicit:
+            raise ValueError(what)
+        from ..utils.logging import warning_once
+
+        warning_once(what + "; running the unfused composition")
+        return rmsnorm_matmul_reference(x, scale, w, eps)
     out = _rmsnorm_matmul_pallas(float(eps), x2, scale.reshape(1, D), w,
-                                 block_m, block_n)
+                                 *blocks)
     return out.reshape(lead + (w.shape[1],))
 
 
@@ -369,12 +432,9 @@ def supports_fused_rmsnorm() -> bool:
     TPU only (the CPU sim keeps the unfused jaxpr so tier-1 numerics and
     compile behavior are unchanged; parity is asserted through the
     interpreter seam in the kernel tests)."""
-    try:
-        from ..accelerator import get_accelerator
+    from ..accelerator import get_accelerator
 
-        return bool(get_accelerator().supports_pallas())
-    except Exception:  # noqa: BLE001 — conservative off
-        return False
+    return bool(get_accelerator().supports_pallas())
 
 
 # --------------------------------------------------------------------- #

@@ -119,7 +119,7 @@ def main(args=None):
     resource_pool = fetch_hostfile(args.hostfile)
 
     if args.launcher == "popen":
-        # Localhost pod rehearsal (VERDICT r3 #10): N distinct processes +
+        # Localhost pod rehearsal: N distinct processes +
         # a real jax.distributed coordinator on 127.0.0.1 — the same
         # per-rank env contract a physical pod launch uses, so a real
         # slice becomes a hostfile change, not new code.  One process per

@@ -29,7 +29,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..runtime.topology import DATA, DATA_OUTER, EXPERT, SEQ, TENSOR
+from ..runtime.topology import (DATA, DATA_OUTER, EXPERT, SEQ, TENSOR,
+                                shard_kernel)
+
+#: mesh axes the batch dimension of activations is laid out over
+_BATCH_AXES = (DATA_OUTER, DATA, EXPERT)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +57,8 @@ class TransformerConfig:
     remat_policy: str = "nothing_saveable"
     use_flash: bool = True          # pallas flash attention on TPU
     attn_impl: str = "auto"         # auto | flash | xla | ring | ulysses
-    #: flash kernel tile sizes; defaults from the on-chip sweep table
-    #: (bench_logs r3: block_q=256/block_k=512 best on v5e at seq 2048)
+    #: flash kernel tile sizes.  The defaults have no valid on-chip
+    #: measurement behind them (ROADMAP S4): re-derive from the trace
     flash_block_q: int = 256
     flash_block_k: int = 512
     #: fold rms_norm into the consuming projections' Pallas kernels
@@ -269,18 +273,33 @@ def attention(q, k, v, cfg: TransformerConfig, causal=True):
         from ..ops.transformer.flash_attention import flash_attention
 
         # the kernel clamps blocks to the (128-aligned) sequence itself —
-        # pre-clamping here would feed it non-lane-aligned tiles
-        return flash_attention(q, k, v, causal=causal,
-                               block_q=cfg.flash_block_q,
-                               block_k=cfg.flash_block_k)
+        # pre-clamping here would feed it non-lane-aligned tiles.  Each
+        # device runs it on its own batch rows and (tensor-parallel) heads.
+        heads = P(_BATCH_AXES, None, TENSOR, None)
+        return shard_kernel(
+            partial(flash_attention, causal=causal,
+                    block_q=cfg.flash_block_q, block_k=cfg.flash_block_k),
+            in_specs=(heads, heads, heads), out_specs=heads)(q, k, v)
     return _xla_attention(q, k, v, causal=causal)
+
+
+def _norm_matmul(x, scale, w, eps):
+    """``rms_norm(x) @ w`` through the fused kernel, on each device's batch
+    rows and its column block of ``w`` (q/k/v/gate/up are column-parallel
+    over "tensor", see :func:`partition_specs`)."""
+    from ..kernels.fused_collective_matmul import rmsnorm_matmul
+
+    return shard_kernel(
+        lambda x, s, w: rmsnorm_matmul(x, s, w, eps),
+        in_specs=(P(_BATCH_AXES, None, None), P(None), P(None, TENSOR)),
+        out_specs=P(_BATCH_AXES, None, TENSOR))(x, scale, w)
 
 
 # --------------------------------------------------------------------- #
 # Forward
 # --------------------------------------------------------------------- #
 def _activation_spec():
-    return P((DATA_OUTER, DATA, EXPERT), SEQ, None)
+    return P(_BATCH_AXES, SEQ, None)
 
 
 def _constrain(x, spec):
@@ -321,12 +340,10 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             # fused path: h is the UN-normalized residual; the norm is
             # folded into the gate/up projection kernels (down has no
             # norm in front and stays a plain matmul)
-            from ..kernels.fused_collective_matmul import rmsnorm_matmul
-
-            gate = jax.nn.silu(rmsnorm_matmul(
+            gate = jax.nn.silu(_norm_matmul(
                 h, fused_scale, lp["gate_proj"]["kernel"], cfg.norm_eps))
-            up = rmsnorm_matmul(h, fused_scale, lp["up_proj"]["kernel"],
-                                cfg.norm_eps)
+            up = _norm_matmul(h, fused_scale, lp["up_proj"]["kernel"],
+                              cfg.norm_eps)
         else:
             gate = jax.nn.silu(h @ lp["gate_proj"]["kernel"])
             up = h @ lp["up_proj"]["kernel"]
@@ -344,9 +361,7 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         """rms_norm folded into the projection kernel (the fused path's
         per-tile recompute of the norm is free VPU work; the normalized
         activations never hit HBM)."""
-        from ..kernels.fused_collective_matmul import rmsnorm_matmul
-
-        y = rmsnorm_matmul(x, norm_scale, p["kernel"], cfg.norm_eps)
+        y = _norm_matmul(x, norm_scale, p["kernel"], cfg.norm_eps)
         if "bias" in p:
             y = y + p["bias"]
         return y.reshape(B, S, n_heads, cfg.head_dim)
@@ -397,7 +412,7 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
         if ac.active():
             # DS-config activation_checkpointing (partition_activations /
             # cpu_checkpointing) overrides the model's own remat policy —
-            # the config toggle must change execution (VERDICT r3 #5/#6)
+            # the config toggle must change execution
             policy = ac.get_policy()
         else:
             policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)

@@ -131,7 +131,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, Sk - S), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, Sk - S), (0, 0)))
 
-    # Causal DMA skip (VERDICT round-1 weak #3): compute for masked blocks
+    # Causal DMA skip: compute for masked blocks
     # is pl.when-gated; the clamped index maps remove their DMA too.
     kv_index = _causal_kv_index(causal, block_q, block_k)
 

@@ -14,7 +14,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
-from ..utils.logging import logger
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,31 +45,54 @@ class DeviceSpec:
         return self.peak_flops / max(self.hbm_bandwidth, 1.0)
 
 
-#: ordered: first substring match against device_kind wins
+#: THE peaks table — bench.py, the engine's roofline gauges and the
+#: offline summaries all read it; there is no second copy.  Ordered: first
+#: substring match against ``device_kind`` wins.  Sources: bf16 peak, HBM
+#: bandwidth and ICI are the per-chip figures of the Google Cloud TPU
+#: documentation's system-architecture pages ("TPU v6e", "TPU v5p",
+#: "TPU v5e", "TPU v4", "TPU v3"; ICI converted from Gbit/s to bytes/s).
+#: DCN and host (PCIe) bandwidths are not published per chip: they are
+#: order-of-magnitude planning figures for the collective selector and
+#: the offload planner, not roofline denominators.
 DEVICE_SPECS = (
-    DeviceSpec("TPU v6 lite", 918e12, 1640e9, 448e9, 25e9, 64e9),  # Trillium
+    DeviceSpec("TPU v6 lite", 918e12, 1640e9, 448e9, 25e9, 64e9),  # v6e
     DeviceSpec("TPU v6", 918e12, 1640e9, 448e9, 25e9, 64e9),
     DeviceSpec("TPU v5p", 459e12, 2765e9, 600e9, 25e9, 32e9),
-    DeviceSpec("TPU v5 lite", 197e12, 819e9, 200e9, 12.5e9, 32e9),
+    DeviceSpec("TPU v5 lite", 197e12, 819e9, 200e9, 12.5e9, 32e9),  # v5e
     DeviceSpec("TPU v5e", 197e12, 819e9, 200e9, 12.5e9, 32e9),
     DeviceSpec("TPU v4", 275e12, 1228e9, 300e9, 12.5e9, 16e9),
     DeviceSpec("TPU v3", 123e12, 900e9, 82e9, 6e9, 16e9),
 )
 
-#: conservative stand-in so CPU smoke runs produce finite (clearly labelled)
-#: utilization numbers instead of dividing by zero
-CPU_FALLBACK = DeviceSpec("cpu", 1e12, 100e9, 10e9, 1e9, 10e9)
+#: stand-in for CPU runs ONLY (tests, host-side tools), so they produce
+#: finite numbers instead of dividing by zero.  Its ``kind`` always carries
+#: the words "cpu fallback peaks" wherever it is printed; no TPU ever
+#: resolves to it.
+CPU_FALLBACK = DeviceSpec("cpu (cpu fallback peaks)", 1e12, 100e9, 10e9,
+                          1e9, 10e9)
+
+
+def _resolve(kind: str, is_tpu: bool) -> DeviceSpec:
+    """Table row for ``kind``; a TPU that is not in the table raises (a
+    device without published peaks is an error, not a default); anything
+    else is a CPU run and gets the labelled CPU fallback."""
+    for spec in DEVICE_SPECS:
+        if spec.kind.lower() in kind.lower():
+            return dataclasses.replace(spec, kind=kind)
+    if is_tpu:
+        raise KeyError(
+            f"no peaks for TPU device kind {kind!r} in "
+            f"profiling/roofline.py DEVICE_SPECS — add the chip's published "
+            f"figures with their source")
+    return dataclasses.replace(CPU_FALLBACK,
+                               kind=f"{kind} (cpu fallback peaks)")
 
 
 def spec_for_kind(kind: str) -> DeviceSpec:
     """Spec from a ``device_kind`` string alone — no backend probe, so the
     offline tools (``dstpu-telemetry``'s comm table) can resolve peaks from
-    a recorded run's metadata.  Unknown kinds get the CPU fallback numbers
-    under the given name."""
-    for spec in DEVICE_SPECS:
-        if spec.kind.lower() in str(kind).lower():
-            return dataclasses.replace(spec, kind=str(kind))
-    return dataclasses.replace(CPU_FALLBACK, kind=str(kind))
+    a recorded run's metadata.  See :func:`_resolve` for unknown kinds."""
+    return _resolve(str(kind), "tpu" in str(kind).lower())
 
 
 def interconnect_peak(kind: str) -> float:
@@ -88,26 +110,19 @@ def host_transfer_seconds(nbytes: float,
 
 
 def device_spec(device: Any = None) -> DeviceSpec:
-    """Spec for ``device`` (default: first visible device).  Unknown TPU
-    kinds get the v5e numbers (the most common fleet chip) with a warning;
-    non-TPU backends get the CPU fallback."""
+    """Spec for ``device`` (default: first visible device).  An unknown TPU
+    kind raises; non-TPU backends get the labelled CPU fallback."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
     kind = str(getattr(device, "device_kind", "cpu"))
-    for spec in DEVICE_SPECS:
-        if spec.kind.lower() in kind.lower():
-            return dataclasses.replace(spec, kind=kind)
-    if getattr(device, "platform", "cpu") == "tpu":
-        logger.warning(f"no roofline spec for device kind {kind!r}; "
-                       f"assuming TPU v5e peaks")
-        return DeviceSpec(kind, 197e12, 819e9, 200e9, 12.5e9, 32e9)
-    return dataclasses.replace(CPU_FALLBACK, kind=kind)
+    return _resolve(kind, getattr(device, "platform", "cpu") == "tpu"
+                    or "tpu" in kind.lower())
 
 
 def peak_flops_per_chip(device: Any = None) -> float:
-    """bf16 peak FLOP/s for one chip (bench.py's MFU denominator)."""
+    """bf16 peak FLOP/s for one chip (the MFU denominator)."""
     return device_spec(device).peak_flops
 
 

@@ -213,11 +213,11 @@ def assert_fused_pack(traced) -> None:
     the wires-must-exist check, which the general pass deliberately lacks:
     a program with no quantized collectives is not a wire regression)."""
     from ...analysis.core import ERROR, PassContext
-    from ...analysis.graph_passes import FusedWireLayoutPass
+    from ...analysis.graph_passes import FusedWirePass
 
     if not any("int8" in o["dtypes"] for o in wire_ops(traced)):
         raise AssertionError("no int8-wire collectives found")
-    findings = FusedWireLayoutPass().run(
+    findings = FusedWirePass().run(
         traced, PassContext(artifact="assert_fused_pack"))
     errors = [f for f in findings if f.severity == ERROR]
     if errors:

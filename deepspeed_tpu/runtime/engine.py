@@ -280,15 +280,21 @@ class DeepSpeedEngine:
                 out_shardings=NamedSharding(self.mesh, err_spec),
             )(params)
 
+        # The counters, the scaler and the RNG key are placed replicated on
+        # the mesh, as the step returns them: left on one device they make
+        # the SECOND train_batch call see new input shardings and compile
+        # the whole step again (seen on four chips: two compiles per engine).
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        on_mesh = lambda tree: jax.device_put(tree, replicated)  # noqa: E731
         self.state = EngineState(
-            global_step=jnp.zeros((), jnp.int32),
-            micro_step=jnp.zeros((), jnp.int32),
-            skipped_steps=jnp.zeros((), jnp.int32),
+            global_step=on_mesh(jnp.zeros((), jnp.int32)),
+            micro_step=on_mesh(jnp.zeros((), jnp.int32)),
+            skipped_steps=on_mesh(jnp.zeros((), jnp.int32)),
             params=params,
             opt_state=opt_state,
-            scaler=self.loss_scaler.init(),
+            scaler=on_mesh(self.loss_scaler.init()),
             grad_acc=grad_acc,
-            rng=jax.random.PRNGKey(seed),
+            rng=on_mesh(jax.random.PRNGKey(seed)),
             comm_error=comm_error,
         )
 

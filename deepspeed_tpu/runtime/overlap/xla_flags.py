@@ -17,8 +17,10 @@ itself initialize the backend and defeat the wiring.
 """
 from __future__ import annotations
 
+import os
 from typing import List, Optional, Sequence
 
+from ...accelerator.tpu_accelerator import LIBTPU_ENV
 from ...utils.logging import logger
 
 #: the overlap flag set (libtpu spellings): LHS + async collectives +
@@ -77,9 +79,12 @@ def configure_xla_overlap_flags(overlap_cfg=None,
 
         accelerator = peek_accelerator()
     flags = overlap_flag_set(overlap_cfg)
+    before = os.environ.get(LIBTPU_ENV)
     applied = accelerator.apply_xla_flags(flags)
     if applied:
-        if backend_initialized():
+        # a repeat call (a second engine in one process) adds nothing and
+        # is not late; only flags that landed AFTER client creation are
+        if backend_initialized() and os.environ.get(LIBTPU_ENV) != before:
             logger.warning(
                 "overlap.xla_flags: JAX backend already initialized — the "
                 "latency-hiding scheduler flags are recorded in the "

@@ -736,7 +736,7 @@ class PipelineEngine(DeepSpeedEngine):
 
         if isinstance(model, PipelineModule):
             # arbitrary LayerSpec lists with a user loss (no hard-wired
-            # CausalLM recipe — VERDICT round-1 weak #6)
+            # CausalLM recipe)
             def fn(params, batch, rng):
                 return pipeline_module_loss(
                     model, params, batch, rng, self.num_micro,

@@ -334,49 +334,21 @@ class MeshTopology:
 
 
 def compat_shard_map(f, mesh, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` across jax versions.
+    """``jax.shard_map`` with this repo's conventions: ``check_vma`` off and
+    ``manual_axes`` (``None`` = fully manual) spelled as ``axis_names``.
 
-    Newer jax exposes ``jax.shard_map`` with partial-manual ``axis_names``
-    and ``check_vma``; 0.4.x only has ``jax.experimental.shard_map`` where
-    the same partial-manual region is spelled as the complement set
-    (``auto=``) and the varying-manual check is ``check_rep``.  One seam so
-    every sharded step builder keeps working on both (``manual_axes=None``
-    = fully manual).
+    A partial-manual map (``manual_axes`` a strict subset of the mesh axes)
+    must be called under ``jax.jit``: jax 0.9's eager implementation
+    rejects it (its internal un-match names every mesh axis in
+    ``out_specs``).  Every step builder already jits; tests wrap theirs.
     """
     import jax
 
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        if manual_axes is not None:
-            kwargs["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=False)
     if manual_axes is not None:
-        auto = frozenset(mesh.axis_names) - set(manual_axes)
-        # 0.4.x's auto= support miscompiles some partial-manual programs
-        # when an Auto axis is nontrivial (observed: XLA hard-abort on the
-        # quantized-wire step under tensor parallelism).  A process abort
-        # mid-suite is far worse than a clean refusal, so degrade exactly
-        # the unreliable combination.
-        try:
-            sizes = dict(getattr(mesh, "shape", {}) or {})
-        except TypeError:
-            sizes = {}
-        live_auto = sorted(a for a in auto if int(sizes.get(a, 1)) > 1)
-        if live_auto:
-            raise NotImplementedError(
-                f"partial-manual shard_map with nontrivial Auto axes "
-                f"{live_auto} needs jax.shard_map (newer jax); this jax's "
-                f"experimental shard_map miscompiles that combination — "
-                f"use the fused path on model-parallel meshes")
-    mapped = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False, auto=auto)
-    # 0.4.x partial-manual shard_map has no eager impl (NotImplementedError
-    # outside jit); wrapping is a no-op for callers already under jit
-    return jax.jit(mapped) if auto else mapped
+        kwargs["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, **kwargs)
 
 
 def shard_map_context(topo: "MeshTopology"):
@@ -401,6 +373,69 @@ def shard_map_context(topo: "MeshTopology"):
     except Exception:  # noqa: BLE001 - introspection is best-effort
         pass
     return topo.mesh, set()
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """``fn`` (a Pallas kernel call) made safe inside a multi-device jitted
+    program.
+
+    Mosaic kernels cannot be partitioned by GSPMD — lowering a multi-chip
+    step that calls one raises "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" — so the call must sit
+    in a ``shard_map`` that is manual over every mesh axis and hands the
+    kernel its device-local block.  ``in_specs``/``out_specs`` (one
+    ``PartitionSpec`` per operand / for the single output) say how the
+    caller lays the operands out; GSPMD reshards to them at the boundary
+    (e.g. the ZeRO-3 gather of a weight stored sharded over ``data``).
+    An axis named in ``out_specs`` must sit on an input dimension of the
+    same size (batch rows, heads, weight columns), so divisibility is
+    decided from the inputs alone.
+
+    Adapts to what it can observe at trace time:
+
+      * no global topology, or a one-device mesh: ``fn`` runs as is;
+      * an axis that does not divide a dimension it is put on (batch 1 on
+        four data shards) is dropped from the specs — that operand is
+        replicated over the axis and every shard computes it whole;
+      * inside a region already manual over some axes (the explicit-comm
+        step), those axes are dropped and the map covers the rest.
+    """
+    from jax.sharding import PartitionSpec
+
+    def names_of(entry):
+        if entry is None:
+            return ()
+        return tuple(entry) if isinstance(entry, (tuple, list)) else (entry,)
+
+    def mapped(*args):
+        topo = _TOPOLOGY
+        if topo is None or topo.mesh.size <= 1:
+            return fn(*args)
+        mesh, already = shard_map_context(topo)
+        manual = {a for a in mesh.axis_names if a not in already}
+        if not manual:
+            return fn(*args)
+        unusable = {a for a in manual if topo.dims[a] <= 1} | set(already)
+        for spec, x in zip(in_specs, args):
+            for dim, entry in zip(x.shape, spec):
+                names = [a for a in names_of(entry) if a not in unusable]
+                if dim % int(np.prod([topo.dims[a] for a in names] or [1])):
+                    unusable.update(names)
+
+        def fit(spec):
+            dims = []
+            for entry in spec:
+                names = tuple(a for a in names_of(entry)
+                              if a not in unusable)
+                dims.append(names if len(names) > 1
+                            else (names[0] if names else None))
+            return PartitionSpec(*dims)
+
+        return compat_shard_map(
+            fn, mesh, tuple(fit(s) for s in in_specs), fit(out_specs),
+            manual_axes=manual)(*args)
+
+    return mapped
 
 
 def mesh_shape_str(dims: Dict[str, int]) -> str:

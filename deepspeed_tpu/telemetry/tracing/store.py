@@ -50,7 +50,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .. import get_telemetry
 
-#: canonical span taxonomy (attrs may refine; kinds stay closed so the
+#: canonical span kinds (attrs may refine; kinds stay closed so the
 #: decomposition tables and the waterfall renderer have a stable axis)
 SPAN_KINDS = (
     "queue_wait",      # submit → admission (per admission; resets on preempt)

@@ -12,7 +12,7 @@ setup(
     package_data={"deepspeed_tpu": ["csrc/*.cpp"]},
     python_requires=">=3.10",
     install_requires=[
-        "jax>=0.5",
+        "jax>=0.9",
         "optax",
         "orbax-checkpoint",
         "pydantic>=2",

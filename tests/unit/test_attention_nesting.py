@@ -34,7 +34,6 @@ def _qkv():
 
 
 class TestUlyssesNesting:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
     def test_eager_toplevel(self, sp_mesh):
         q, k, v = _qkv()
         ua = UlyssesAttention()
@@ -42,7 +41,6 @@ class TestUlyssesNesting:
         np.testing.assert_allclose(np.asarray(ua(q, k, v, causal=True)),
                                    np.asarray(ref), rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_nested_inside_manual_over_data(self, sp_mesh):
         q, k, v = _qkv()
@@ -55,7 +53,6 @@ class TestUlyssesNesting:
         np.testing.assert_allclose(np.asarray(f(q, k, v)), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_inside_already_manual_seq_region(self, sp_mesh):
         """When seq is already manual the layer must call its body directly
@@ -71,7 +68,6 @@ class TestUlyssesNesting:
         np.testing.assert_allclose(np.asarray(f(q, k, v)), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_context_detection(self, sp_mesh):
         """shard_map_context reports the already-manual axes from inside a
@@ -93,7 +89,6 @@ class TestUlyssesNesting:
 
 
 class TestRingNesting:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
     def test_eager_and_nested(self, sp_mesh):
         q, k, v = _qkv()
         ref = ring_attention(q, k, v, causal=True, sp_axis="tensor")  # sp=1

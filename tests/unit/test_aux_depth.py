@@ -1,4 +1,4 @@
-"""Aux-subsystem depth (VERDICT missing #9/#10/#11 + weak #10): DataAnalyzer,
+"""Aux-subsystem depth: DataAnalyzer,
 autotuner experiment scheduler/persistence, compression scheduler +
 head/channel pruning + layer reduction, flops per-module tree."""
 import json

@@ -1,0 +1,298 @@
+"""What the CPU sim cannot see: does the main path COMPILE for the chip?
+
+The TPU's compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (``jax.experimental.topologies``, ``v5e:2x2``).
+Every kernel of ``chip_smoke.py``'s two phases is compiled here at the
+smoke's widths (Mistral-7B: hidden 4096, intermediate 14336, 32/8 heads of
+128, vocab 32000), plus the default training step of a hidden-4096 model on
+one chip and ZeRO-3 across the four.  Interpret-mode tests pass kernels the
+chip refuses — more VMEM than a kernel may use, a block not aligned to the
+tiling, a Mosaic call GSPMD cannot partition: each of those was live at the
+parent of this file.  A compile that passes is not a chip run; nothing here
+is timed.
+
+Plus three quick tests of the bring-up plumbing: ``chip_smoke.py`` itself at
+its CPU-rehearsal size, and the compile-cache placement.
+"""
+import importlib.util
+import json
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+pytestmark = pytest.mark.kernels
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+# the smoke's widths
+D, F, H, KV, HD, V = 4096, 14336, 32, 8, 128, 32000
+PAGE, CTX = 64, 8192
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it cannot
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc!r}")
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """Steer the seams that read ``jax.default_backend()`` (cpu here) onto
+    their device branch — in the test, not through an option of the
+    program — and keep these compiles out of the persistent cache (a
+    described-device entry cannot be read back without the chip)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
+    from deepspeed_tpu.inference.v2.kernels import ragged_ops
+    from deepspeed_tpu.kernels import fused_collective_matmul as fcm
+    from deepspeed_tpu.ops.adam import fused_adam
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+
+    for mod in (fa, fcm, ragged_ops, fused_adam):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(fcm, "resolve_impl",
+                        lambda impl="auto": "pallas" if impl == "auto"
+                        else impl)
+    monkeypatch.setattr(real_accelerator, "_ACCELERATOR", TPUAccelerator())
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(sharding, shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# ---- one builder per case: (fn, abstract args) ---------------------------
+def _flash(grad):
+    def build(dev):
+        from deepspeed_tpu.ops.transformer.flash_attention import \
+            flash_attention
+
+        q = _on(dev, (2, 2048, H, HD))
+        kv = _on(dev, (2, 2048, KV, HD))
+        if not grad:
+            return flash_attention, (q, kv, kv)
+        loss = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv)
+    return build
+
+
+def _paged(decode):
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
+            decode_paged_attention, ragged_paged_attention)
+
+        seqs, blocks = 16, CTX // PAGE
+        pool = _on(dev, (2 * seqs * blocks + 1, PAGE, 2 * KV, HD))
+        lens = _on(dev, (seqs,), jnp.int32)
+        table = _on(dev, (seqs, blocks), jnp.int32)
+        if decode:
+            return (lambda q, p, n, t: decode_paged_attention(
+                q, p, n, t, num_kv_heads=KV)), \
+                (_on(dev, (seqs, H, HD)), pool, lens, table)
+        return (lambda q, p, n, t, cu: ragged_paged_attention(
+            q, p, n, t, cu, num_kv_heads=KV)), \
+            (_on(dev, (512, H, HD)), pool, lens, table,
+             _on(dev, (seqs + 1,), jnp.int32))
+    return build
+
+
+def _rmsnorm(d, f):
+    def build(dev):
+        from deepspeed_tpu.kernels.fused_collective_matmul import \
+            rmsnorm_matmul
+
+        return (lambda x, s, w: rmsnorm_matmul(x, s, w, 1e-5,
+                                               impl="pallas")), \
+            (_on(dev, (4, 2048, d)), _on(dev, (d,)), _on(dev, (d, f)))
+    return build
+
+
+def _adam(dev):
+    from deepspeed_tpu.ops.adam.fused_adam import fused_adam_update
+
+    t = _on(dev, (2, D, F), jnp.float32)
+    return (lambda p, g, m, v, step: fused_adam_update(
+        p, g, m, v, step, lr=3e-4, weight_decay=0.1)), \
+        (t, t, t, t, _on(dev, (), jnp.int32))
+
+
+def _train_step(zero_stage):
+    """The default model config (flash + fused RMSNorm both "auto" = on for
+    a TPU, remat) at hidden 4096 under the engine's step recipe: bf16 cast
+    of fp32 masters, ``value_and_grad`` of the LM loss, AdamW.  Depth 1 and
+    a short batch keep the compile quick; the kernels' tiles are the real
+    ones (rows >= 256, full widths).  ``zero_stage`` 3 lays the state out
+    with the engine's own ZeRO-3 plan over all four chips."""
+    def build(topology):
+        import optax
+
+        from deepspeed_tpu.models.transformer import (CausalLM,
+                                                      TransformerConfig)
+        from deepspeed_tpu.runtime.topology import (TopologyConfig,
+                                                    initialize_mesh)
+        from deepspeed_tpu.runtime.zero.sharding import ZeroShardingPlan
+
+        n = 4 if zero_stage else 1
+        topo = initialize_mesh(TopologyConfig(),
+                               devices=list(topology.devices[:n]),
+                               force=True)
+        cfg = TransformerConfig(
+            vocab_size=V, hidden_size=D, intermediate_size=F, num_layers=1,
+            num_heads=H, num_kv_heads=KV, max_seq_len=512, remat=True)
+        assert cfg.use_flash and cfg.fused_rmsnorm == "auto"   # defaults
+        model = CausalLM(cfg)
+        tx = optax.adamw(3e-4, weight_decay=0.1)
+        p_abs = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+        plan = ZeroShardingPlan(topo, zero_stage,
+                                base_specs=model.partition_specs)
+        p_sh = plan.param_shardings(p_abs)
+        o_sh = plan.opt_state_shardings(jax.eval_shape(tx.init, p_abs),
+                                        p_abs)
+        place = lambda t, sh: jax.tree.map(  # noqa: E731
+            lambda x, s: _on(s, x.shape, x.dtype), t, sh)
+
+        def step(params, opt, tokens):
+            def loss_fn(p32):
+                p = jax.tree.map(lambda x: x.astype(BF16), p32)
+                return model.loss_fn(p, {"input_ids": tokens}, None)
+
+            loss, grads = jax.value_and_grad(loss_fn)(params)
+            updates, opt = tx.update(grads, opt, params)
+            return optax.apply_updates(params, updates), opt, loss
+
+        tokens = _on(NamedSharding(topo.mesh, topo.batch_spec()),
+                     (n, 512), jnp.int32)
+        return step, (place(p_abs, p_sh),
+                      place(jax.eval_shape(tx.init, p_abs), o_sh), tokens)
+    build.whole_topology = True
+    return build
+
+
+CASES = {
+    "flash_fwd": _flash(grad=False),
+    "flash_bwd": _flash(grad=True),
+    "decode_paged_attention": _paged(decode=True),
+    "ragged_paged_attention": _paged(decode=False),
+    "rmsnorm_matmul[4096x14336]": _rmsnorm(D, F),      # gate / up
+    "rmsnorm_matmul[4096x6144]": _rmsnorm(D, 6144),    # fused qkv width
+    "rmsnorm_matmul[4096x1024]": _rmsnorm(D, 1024),    # k / v
+    "rmsnorm_matmul[4096x32000]": _rmsnorm(D, V),      # lm head
+    "fused_adam_update": _adam,
+    "train_step[1 chip]": _train_step(zero_stage=0),
+    "train_step[zero3 x 4 chips]": _train_step(zero_stage=3),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compiles_for_v5e(v5e, for_the_chip, case):
+    build = CASES[case]
+    if getattr(build, "whole_topology", False):
+        fn, args = build(v5e)
+    else:
+        fn, args = build(SingleDeviceSharding(v5e.devices[0]))
+    lowered = jax.jit(fn).lower(*args)
+    # the device kernel is IN the program (an interpret-mode or XLA
+    # fall-back would compile too, and prove nothing)
+    assert "tpu_custom_call" in lowered.as_text(), \
+        f"{case}: no Mosaic kernel in the lowered program"
+    lowered.compile()                   # raises what the chip would raise
+
+
+def test_rmsnorm_blocks_follow_the_shapes():
+    """The fused kernel's tiles come from D, the dtype and the VMEM limit:
+    aligned to the chip's tiling, inside the limit, and ``None`` (the
+    caller runs the unfused composition) where nothing fits."""
+    from deepspeed_tpu.kernels.fused_collective_matmul import (
+        _VMEM_LIMIT_BYTES, rmsnorm_blocks, rmsnorm_vmem_bytes)
+
+    for d, f in ((4096, 14336), (4096, 32000), (8192, 28672),
+                 (16384, 53248)):
+        bm, bn = rmsnorm_blocks(8192, d, f, BF16, BF16)
+        assert bn % 128 == 0 and f % bn == 0 and bm % 16 == 0
+        assert rmsnorm_vmem_bytes(bm, bn, d, 2, 2) <= _VMEM_LIMIT_BYTES
+    # at the parent: block_n = 500 for F = 32000 (not a lane multiple) and
+    # 16.6 MiB of VMEM at D = 4096 with the default 256 x 512 tiles
+    assert rmsnorm_blocks(8192, 4096, 32000, BF16, BF16) == (256, 256)
+    assert rmsnorm_blocks(8192, 16384, 53248, jnp.float32,
+                          jnp.float32) is None
+    assert rmsnorm_blocks(7, 64, 96, jnp.float32, jnp.float32) == (7, 96)
+
+
+# ---- chip_smoke.py and the compile cache ---------------------------------
+def _load_chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO_ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_needs_a_chip_or_its_rehearsal_option(capsys):
+    """No chip, no rehearsal option: non-zero exit and NO result on stdout.
+    With ``--cpu-rehearsal`` the same phases run at toy widths and the last
+    line is the contract's."""
+    smoke = _load_chip_smoke()
+    assert jax.devices()[0].platform == "cpu"
+    assert smoke.main([]) != 0
+    assert capsys.readouterr().out == ""
+
+    assert smoke.main(["--cpu-rehearsal"]) == 0
+    lines = [json.loads(ln) for ln in
+             capsys.readouterr().out.strip().splitlines()]
+    assert lines[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices())}}
+    phases = {ln["phase"]: ln for ln in lines[:-1] if "phase" in ln}
+    assert phases["train"]["ok"] and phases["train"]["global_steps"] == 5
+    assert phases["train"]["losses"][-1] < phases["train"]["losses"][0]
+    assert phases["serve"]["ok"] and all(
+        r["status"] == 200 and r["tokens"] == 4
+        for r in phases["serve"]["requests"])
+    assert "32->2" in phases["config"]["reduced"]
+
+
+@pytest.mark.parametrize("case", ["from_env", "checkout", "held_to_cpu"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, case):
+    """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code.  Unset:
+    the one fixed path inside the checkout — except in a process held to
+    the CPU (this one), which gets no cache."""
+    from deepspeed_tpu.utils import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    assert os.environ["JAX_PLATFORMS"] == "cpu"        # tests/conftest.py
+    if case == "from_env":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert compile_cache.configure_compile_cache() == "/some/dir"
+        assert calls == []
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    if case == "held_to_cpu":
+        assert compile_cache.configure_compile_cache() is None
+        assert calls == []
+        return
+    monkeypatch.delenv("JAX_PLATFORMS")                # as on the chip
+    fixed = os.path.join(REPO_ROOT, ".jax_cache")
+    assert compile_cache.configure_compile_cache() == fixed
+    assert calls == [("jax_compilation_cache_dir", fixed)]

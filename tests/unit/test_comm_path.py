@@ -1,7 +1,7 @@
 """Explicit-comm train path: ZeRO++ quantized wires + sparse gradients
 (reference: runtime/comm/coalesced_collectives.py:31, engine.py:2636).
 
-Covers VERDICT round-1 weak #5: the zero_quantized_* / sparse_gradients
+The zero_quantized_* / sparse_gradients
 config keys must actually change the wire, verified both by numerics and by
 inspecting the compiled step for int8 collectives.
 """
@@ -135,14 +135,13 @@ def _engine_on(stage, zero_extra=None, top_extra=None, **tdims):
 
 
 class TestExplicitCommModelParallel:
-    """VERDICT r2 item 5: ZeRO++ wires under Megatron TP (reference
+    """ZeRO++ wires under Megatron TP (reference
     docs/_tutorials/zeropp.md:13 — ZeRO++ runs under model parallelism).
 
     The step is a PARTIAL-manual shard_map: manual over the data axes only,
     tensor/seq stay Auto so XLA keeps inserting the model-parallel
     collectives inside the per-shard compute."""
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
 
     def test_qgz_loco_converges_on_dp_tp_mesh(self):
         batch = _batch(n=8)
@@ -154,7 +153,6 @@ class TestExplicitCommModelParallel:
         assert abs(lb[-1] - lq[-1]) < 0.3
         assert lq[-1] < lq[0] - 1.0
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
 
     def test_qgz_wire_is_int8_and_tp_allreduce_remains(self):
         batch = _batch(n=8)
@@ -170,7 +168,6 @@ class TestExplicitCommModelParallel:
         assert "all-reduce" in low.compile().as_text(), \
             "TP all-reduce missing — tensor axis no longer Auto?"
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
 
     def test_stage3_qwz_trains_under_tp(self):
         batch = _batch(n=8)
@@ -222,7 +219,7 @@ class TestExplicitCommModelParallel:
 
 
 class TestImperativeWireParity:
-    """VERDICT r2 item 8 (reference engine.py:2048-2085): the explicit-comm
+    """Reference engine.py:2048-2085: the explicit-comm
     wires must also apply on the imperative backward()/step() API —
     local-grad accumulation per data shard, ONE exchange at the boundary."""
 

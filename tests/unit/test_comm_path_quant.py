@@ -19,8 +19,9 @@ N_DEV = 8
 
 
 def _sharded(fn, mesh8, in_specs, out_specs):
-    return compat_shard_map(fn, mesh8.mesh, in_specs, out_specs,
-                            manual_axes={DATA})
+    # partial-manual (DATA only) maps must run under jit on jax 0.9
+    return jax.jit(compat_shard_map(fn, mesh8.mesh, in_specs, out_specs,
+                                    manual_axes={DATA}))
 
 
 class TestQuantizedAllGatherShard:

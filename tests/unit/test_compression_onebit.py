@@ -14,7 +14,6 @@ pytestmark = pytest.mark.comm
 
 
 class TestCompressedAllreduce:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_signs_and_error_feedback(self):
         topo = initialize_mesh(TopologyConfig(), force=True)
         from deepspeed_tpu.runtime.comm.compressed import compressed_allreduce
@@ -39,7 +38,6 @@ class TestCompressedAllreduce:
         err = np.asarray(err)
         np.testing.assert_allclose(np.asarray(g), out * 0 + (np.asarray(g) - err) + err)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_convergence_vs_exact(self):
         """1-bit compression converges on a quadratic (per-rank noisy grads);

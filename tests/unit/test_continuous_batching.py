@@ -1,4 +1,4 @@
-"""Continuous-batching scheduler at the operating point (VERDICT r3 #7):
+"""Continuous-batching scheduler at the operating point:
 64-sequence churn (admission, eviction, block recycling) and O(batch)
 scheduling cost independent of queue depth.
 

@@ -1,5 +1,4 @@
-"""Determinism / NaN-check debug mode (SURVEY §5's explicit TPU ask;
-VERDICT round-1 component #74)."""
+"""Determinism / NaN-check debug mode (SURVEY §5's explicit TPU ask)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -1,4 +1,4 @@
-"""FPDT backward memory proof (VERDICT round-1 weak #7; reference:
+"""FPDT backward memory proof (reference:
 sequence/fpdt_layer.py:510 — offloaded KV must stay off-device through the
 BACKWARD pass too)."""
 import jax

@@ -110,7 +110,6 @@ class TestEpilogue:
         assert jnp.all(out == base), "fp epilogue must be BITWISE"
 
     @pytest.mark.slow
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
     @pytest.mark.parametrize("impl", ["pallas", "dense"])
     def test_fp_bitwise_dp_tp(self, mesh_dp_tp, impl):
         out, base, *_ = _run_epilogue(mesh_dp_tp, impl, 0)
@@ -122,7 +121,6 @@ class TestEpilogue:
             "int8 epilogue must be bitwise vs unfused-matmul→fused-wire"
 
     @pytest.mark.slow
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
     def test_int8_bitwise_dp_tp(self, mesh_dp_tp):
         out, base, *_ = _run_epilogue(mesh_dp_tp, "pallas", 8)
         assert jnp.all(out == base)
@@ -188,7 +186,6 @@ class TestPrologue:
         assert jnp.all(out == base), "fp prologue must be BITWISE"
 
     @pytest.mark.slow
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x: compat_shard_map refuses partial-manual shard_map with a nontrivial Auto axis (0.4.x experimental shard_map miscompiles it)")
     @pytest.mark.parametrize("impl", ["pallas", "dense"])
     def test_fp_bitwise_dp_tp(self, mesh_dp_tp, impl):
         out, base, *_ = _run_prologue(mesh_dp_tp, impl, 0)
@@ -549,7 +546,9 @@ class TestKernelRooflineTelemetry:
         row = rows["fused_gemm"]
         assert row["pct_peak_flops"] == pytest.approx(
             100.0 * (2e9 / 1e-2) / CPU_FALLBACK.peak_flops)
-        assert row["device_kind"] == "cpu"
+        # the stand-in peaks are labelled as such wherever they surface
+        assert row["device_kind"] == CPU_FALLBACK.kind
+        assert "cpu fallback peaks" in row["device_kind"]
 
     def test_summary_renders_kernels_section(self):
         from deepspeed_tpu.telemetry.summary import (format_summary,

@@ -71,7 +71,7 @@ class TestUniversalFamilyEngine:
 
     def test_universal_family_serves_ragged(self):
         """UniversalCausalLM models serve through the ragged engine (the
-        round-2 guard is gone — VERDICT r2 missing #3)."""
+        round-2 guard is gone)."""
         from deepspeed_tpu.inference.v2.engine_v2 import (
             InferenceEngineV2,
             RaggedInferenceEngineConfig,

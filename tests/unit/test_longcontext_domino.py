@@ -60,7 +60,6 @@ class TestChunkedAttention:
 
 
 class TestDomino:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_matches_plain_layer_tp2(self):
         from deepspeed_tpu.models.transformer import (
             TransformerConfig,
@@ -103,7 +102,6 @@ class TestDomino:
         np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                    atol=2e-4, rtol=2e-3)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_micro_batches_are_independent(self):
         """The property Domino contributes — and the one the overlap needs:

@@ -44,7 +44,6 @@ def _converge(tx, steps=150, lr_note=""):
 
 
 class TestOnebitLamb:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_convergence_with_compression(self):
         from deepspeed_tpu.runtime.fp16.onebit.lamb import onebit_lamb
 
@@ -72,7 +71,6 @@ class TestOnebitLamb:
 
 
 class TestZeroOneAdam:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_convergence_with_sync_intervals(self):
         from deepspeed_tpu.runtime.fp16.onebit.zoadam import zero_one_adam
 

@@ -77,10 +77,10 @@ class TestBucketedExchangeExact:
             return tuple(jax.lax.psum(x, DATA) / n for x in ls)
 
         specs = tuple(P(DATA) for _ in leaves)
-        out_b = compat_shard_map(bucketed, mesh8.mesh, specs, specs,
-                                 manual_axes={DATA})(*leaves)
-        out_p = compat_shard_map(per_leaf, mesh8.mesh, specs, specs,
-                                 manual_axes={DATA})(*leaves)
+        out_b = jax.jit(compat_shard_map(bucketed, mesh8.mesh, specs, specs,
+                                         manual_axes={DATA}))(*leaves)
+        out_p = jax.jit(compat_shard_map(per_leaf, mesh8.mesh, specs, specs,
+                                         manual_axes={DATA}))(*leaves)
         for b, p in zip(out_b, out_p):
             np.testing.assert_array_equal(np.asarray(b), np.asarray(p))
 
@@ -95,8 +95,8 @@ class TestBucketedExchangeExact:
             return tuple(outs)
 
         specs = tuple(P(DATA) for _ in leaves)
-        outs = compat_shard_map(fn, mesh8.mesh, specs, specs,
-                                manual_axes={DATA})(*leaves)
+        outs = jax.jit(compat_shard_map(fn, mesh8.mesh, specs, specs,
+                                        manual_axes={DATA}))(*leaves)
         for o, l in zip(outs, leaves):
             assert o.shape == l.shape and o.dtype == l.dtype
             np.testing.assert_array_equal(np.asarray(o), np.asarray(l))

@@ -56,10 +56,12 @@ class TestPrefetchedLayerScan:
 
         specs = ({"w": P(None, DATA)}, P())
         out_specs = (P(), P())
-        got = compat_shard_map(prefetched, mesh8.mesh, specs, out_specs,
-                               manual_axes={DATA})(stacked, x0)
-        want = compat_shard_map(plain, mesh8.mesh, specs, out_specs,
-                                manual_axes={DATA})(stacked, x0)
+        got = jax.jit(compat_shard_map(prefetched, mesh8.mesh, specs,
+                                       out_specs, manual_axes={DATA})
+                      )(stacked, x0)
+        want = jax.jit(compat_shard_map(plain, mesh8.mesh, specs,
+                                        out_specs, manual_axes={DATA})
+                       )(stacked, x0)
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),

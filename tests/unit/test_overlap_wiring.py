@@ -240,9 +240,7 @@ class TestTooling:
         assert "overlap:" in content
 
     def test_bench_has_overlap_sweep_mode(self):
-        """bench.py must dispatch DSTPU_BENCH_MODE=overlap_sweep and map
-        its failure metric (the full subprocess run is exercised by
-        test_bench_integrity's slow path)."""
+        """bench.py must dispatch DSTPU_BENCH_MODE=overlap_sweep."""
         src = open(os.path.join(REPO_ROOT, "bench.py")).read()
         assert "def run_overlap_sweep" in src
-        assert '"overlap_sweep": ("overlap_step_ms", "ms/step")' in src
+        assert '"overlap_sweep": run_overlap_sweep' in src

@@ -1,4 +1,4 @@
-"""Pipeline generality (VERDICT round-1 weak #6): heterogeneous LayerSpec
+"""Pipeline generality: heterogeneous LayerSpec
 stage lists under pp>1, SP×PP composition, and the remat memory profile
 (reference: runtime/pipe/schedule.py:189 TrainSchedule, module.py:393)."""
 import jax

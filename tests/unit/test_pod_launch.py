@@ -1,4 +1,4 @@
-"""Localhost pod-launch rehearsal (VERDICT r3 #10): the real ``bin/dstpu``
+"""Localhost pod-launch rehearsal: the real ``bin/dstpu``
 CLI fans out N distinct processes with the per-rank env contract, each
 process runs ``deepspeed_tpu.init_distributed`` against a real
 ``jax.distributed`` coordinator, and a cross-process collective agrees —
@@ -56,7 +56,6 @@ class TestPodLaunchRehearsal:
             s.bind(("127.0.0.1", 0))
             return s.getsockname()[1]
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_dstpu_popen_two_process_coordinator(self, tmp_path):
         script = tmp_path / "worker.py"

@@ -6,7 +6,8 @@ from deepspeed_tpu.profiling.roofline import (CPU_FALLBACK, DeviceSpec,
                                               device_spec,
                                               format_roofline_line,
                                               peak_flops_per_chip,
-                                              publish_gauges, roofline_report)
+                                              publish_gauges, roofline_report,
+                                              spec_for_kind)
 from deepspeed_tpu.telemetry.metrics import MetricsRegistry
 
 pytestmark = pytest.mark.profiling
@@ -31,11 +32,19 @@ class TestDeviceSpec:
     def test_cpu_fallback(self):
         spec = device_spec(FakeDevice("Zen9", platform="cpu"))
         assert spec.peak_flops == CPU_FALLBACK.peak_flops
-        assert spec.kind == "Zen9"
+        # labelled as what it is wherever it is printed
+        assert spec.kind == "Zen9 (cpu fallback peaks)"
 
-    def test_unknown_tpu_assumes_v5e(self):
-        spec = device_spec(FakeDevice("TPU v99"))
-        assert spec.peak_flops == 197e12
+    @pytest.mark.parametrize("resolve", [
+        lambda: device_spec(FakeDevice("TPU v99")),
+        lambda: device_spec(FakeDevice("v99 lite")),   # tpu by platform
+        lambda: spec_for_kind("TPU v99"),
+    ])
+    def test_unknown_tpu_kind_raises(self, resolve):
+        """A device that is not in the table is an error, not a default —
+        neither "assume v5e" nor the CPU numbers under its name."""
+        with pytest.raises(KeyError, match="no peaks for TPU device kind"):
+            resolve()
 
     def test_local_device_resolves(self):
         # conftest pins the cpu backend — must hit the CPU fallback

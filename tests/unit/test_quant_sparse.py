@@ -46,7 +46,6 @@ class TestQuantizerKernels:
 
 
 class TestQuantizedCollectives:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_quantized_reduce_scatter_close_to_exact(self):
         topo = initialize_mesh(TopologyConfig(), force=True)
         from deepspeed_tpu.runtime.comm.coalesced_collectives import (
@@ -64,7 +63,6 @@ class TestQuantizedCollectives:
         exact = np.asarray(jnp.mean(g, axis=0)).reshape(8, 256)
         np.testing.assert_allclose(np.asarray(out), exact, atol=0.05)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_quantized_allgather(self):
         topo = initialize_mesh(TopologyConfig(), force=True)
@@ -84,7 +82,6 @@ class TestQuantizedCollectives:
         for r in range(8):
             np.testing.assert_allclose(np.asarray(out[r]), full, atol=0.05)
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_reduce_scatter_coalesced(self):
         topo = initialize_mesh(TopologyConfig(), force=True)

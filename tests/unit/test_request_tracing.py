@@ -225,7 +225,7 @@ class TestStoreSampling:
 # Scheduler span production (one shared engine)
 # --------------------------------------------------------------------- #
 class TestSchedulerSpans:
-    def test_full_lifecycle_span_taxonomy(self, shared_eng, fresh_store):
+    def test_full_lifecycle_span_kinds(self, shared_eng, fresh_store):
         s = LifecycleScheduler(shared_eng, window_steps=4)
         ctx = TraceContext.mint()
         t0 = time.time()

@@ -112,7 +112,6 @@ class TestRingAttention:
 
 
 class TestSPCrossEntropy:
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
     def test_matches_plain(self):
         topo = initialize_mesh(TopologyConfig(seq=4), force=True)
         key = jax.random.PRNGKey(0)

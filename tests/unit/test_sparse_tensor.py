@@ -22,7 +22,6 @@ class TestSparseTensor:
         sp = SparseTensor.from_dense(dense, max_nnz=2)
         assert set(np.asarray(sp.indices).tolist()) == {3, 5}
 
-    @pytest.mark.xfail(strict=False, reason="jax 0.4.x has no jax.shard_map (exercises the newer partial-manual API)")
 
     def test_sparse_allreduce_matches_dense(self):
         topo = initialize_mesh(TopologyConfig(), force=True)

@@ -75,13 +75,13 @@ class TestExtraction:
         assert m["step_time_s"] == pytest.approx(0.25)
 
     def test_real_repo_history_loads(self):
-        """The actual BENCH_r*.json archive at the repo root must parse —
-        the tracker exists to consume it."""
+        """Whatever BENCH_r*.json archive sits at the repo root must parse.
+        (The pre-growth-phase archive was deleted in PR 21 — CPU runs under
+        a device metric's name — so today this is the empty-history path;
+        the driver's ledger will repopulate it.)"""
         entries = load_history(REPO_ROOT)
-        assert len(entries) >= 5
-        usable = [e for e in entries if e["metrics"]]
-        assert usable, "no usable bench history at repo root"
-        assert all("step_time_s" in e["metrics"] for e in usable)
+        assert isinstance(entries, list)
+        assert all(isinstance(e["metrics"], dict) for e in entries)
 
 
 class TestVerdicts:

@@ -95,6 +95,48 @@ class TestCausalLM:
         l0 = float(engine.train_batch(batch))
         assert np.isfinite(l0)
 
+    @pytest.mark.parametrize("topo_cfg,batch", [
+        (TopologyConfig(tensor=2), 4),     # data=4 x tensor=2
+        (TopologyConfig(), 3),             # 3 rows do not divide data=8
+    ])
+    def test_kernels_on_a_mesh_match_xla(self, monkeypatch, topo_cfg, batch):
+        """The Pallas kernels inside a multi-device jitted step: Mosaic
+        kernels cannot be partitioned by GSPMD (the chip's compiler refuses
+        the whole step), so flash attention and the fused RMSNorm-matmul run
+        under ``shard_kernel`` on each device's batch rows / tensor-parallel
+        heads.  Loss and grads must equal the kernels-off model's; a batch
+        the data axis does not divide is computed whole on every shard."""
+        import dataclasses
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from deepspeed_tpu.kernels import fused_collective_matmul as fcm
+        from deepspeed_tpu.models.transformer import lm_loss, partition_specs
+
+        # resolve_impl("auto") reads jax.default_backend(): steer the fused
+        # kernel onto its (interpreted) Pallas path for this test
+        monkeypatch.setattr(fcm, "resolve_impl", lambda impl="auto": "pallas")
+        topo = initialize_mesh(topo_cfg, force=True)
+        on = TransformerConfig.tiny(attn_impl="flash", fused_rmsnorm="on",
+                                    remat=True)
+        off = dataclasses.replace(on, attn_impl="xla", fused_rmsnorm="off")
+        params = jax.device_put(
+            CausalLM(on).init_params(jax.random.PRNGKey(0)),
+            jax.tree.map(lambda s: NamedSharding(topo.mesh, s),
+                         partition_specs(on),
+                         is_leaf=lambda x: isinstance(x, P)))
+        toks = tiny_batch(batch, seq=128)["input_ids"]
+        if batch % topo.dims["data"] == 0:
+            toks = jax.device_put(toks, NamedSharding(topo.mesh,
+                                                      topo.batch_spec()))
+        grad = lambda cfg: jax.jit(jax.value_and_grad(  # noqa: E731
+            lambda p: lm_loss(p, {"input_ids": toks}, cfg)))(params)
+        (l_on, g_on), (l_off, g_off) = grad(on), grad(off)
+        np.testing.assert_allclose(float(l_on), float(l_off), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(g_on), jax.tree.leaves(g_off)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-5)
+
     def test_num_params_and_flops(self):
         model = CausalLM(TransformerConfig.tiny())
         assert model.num_params() > 0

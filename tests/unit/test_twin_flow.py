@@ -1,4 +1,4 @@
-"""Twin-Flow fractional optimizer-state offload (VERDICT r2 item 6).
+"""Twin-Flow fractional optimizer-state offload.
 
 Reference: offload_config.py ``ratio`` + blogs/deepspeed-offloadpp — a
 ``ratio`` fraction of optimizer-state BYTES lives on the host, the rest in

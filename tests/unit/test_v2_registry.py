@@ -77,8 +77,8 @@ class TestModelImplementations:
         assert logits.shape == (1, 3, 64)
 
     def test_factory_serves_universal_archs_ragged(self):
-        """gpt2 & co now serve ragged through put/query/flush (VERDICT r2
-        missing #3: the engine_factory rejection is gone)."""
+        """gpt2 & co now serve ragged through put/query/flush (the
+        engine_factory rejection is gone)."""
         from transformers import GPT2Config
 
         from deepspeed_tpu.inference.v2.engine_factory import build_hf_engine
