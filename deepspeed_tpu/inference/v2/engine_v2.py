@@ -26,11 +26,16 @@ import numpy as np
 
 from ...models.transformer import CausalLM, TransformerConfig
 from ...runtime.fault.injection import InjectedNaN, inject
+from ...telemetry.trace import get_tracer
 from ...utils.logging import log_dist, logger
 from .model_runner import build_ragged_step
 from .ragged.kv_cache import BlockedKVCache, KVCacheConfig
 from .ragged.ragged_wrapper import RaggedBatchWrapper
 from .ragged.sequence_descriptor import DSStateManager
+
+#: every call into the engine is a span on the process-global tracer (and a
+#: host event in a profiler trace): telemetry/trace.py says what one costs
+_TRACER = get_tracer()
 
 
 class SchedulingResult(Enum):
@@ -209,7 +214,11 @@ class InferenceEngineV2:
         self._param_bytes = sum(
             x.size * jnp.dtype(x.dtype).itemsize
             for x in jax.tree_util.tree_leaves(self.params))
-        self.last_decode_roofline: Optional[Dict] = None
+        #: (n_seqs, steps, mean_ctx, duration_s, resumed, compiled) of the
+        #: last drained decode window: ``last_decode_roofline`` is computed
+        #: from it when somebody asks
+        self._last_window_facts: Optional[Tuple] = None
+        self._last_roofline: Optional[Tuple[Tuple, Dict]] = None
         #: dstpu-check findings accumulated by ``config.graph_lint`` (one
         #: lint per freshly-built bucket program; see _graph_lint_bucket)
         self.graph_lint_findings: List = []
@@ -384,29 +393,37 @@ class InferenceEngineV2:
             tokens_list: Sequence[Sequence[int]]) -> jnp.ndarray:
         """One forward over the given sequence chunks → last-token logits
         [n_seqs, vocab] in input order."""
-        verdict = self.can_schedule(uids, [len(t) for t in tokens_list])
-        if verdict != SchedulingResult.Success:
-            raise RuntimeError(f"cannot schedule batch: {verdict}")
-        self._decode_state = None      # host forward invalidates device meta
-        bucket = self.bucket_for(sum(len(t) for t in tokens_list), len(uids))
-        wrapper = self._wrapper_for(bucket)
-        wrapper.clear()
-        for uid, toks in zip(uids, tokens_list):
-            seq = self.state_manager.get_or_create_sequence(uid)
-            ok = self.state_manager.maybe_allocate_kv(seq, len(toks))
-            assert ok, "allocator raced"  # can_schedule checked
-            wrapper.insert_sequence(seq, list(toks))
-        batch = wrapper.finalize()
-        # ONE metadata transfer per forward: ~15 small H2D copies per
-        # decode step cost more than the step itself
-        dev = jnp.asarray(batch.pack())
-        logits, new_pages = self._step_for(bucket)(self.params,
-                                                   self.kv.pages, dev)
-        self.kv.update(new_pages)
-        for uid in batch.uids:
-            self.state_manager.get_sequence(uid).post_forward()
-        self._touch_heat(batch.uids)
-        return logits[:batch.n_seqs]
+        n_tokens = sum(len(t) for t in tokens_list)
+        with _TRACER.span("engine/put", tokens=n_tokens,
+                          n_seqs=len(uids)) as sp:
+            with _TRACER.span("engine/put_pack"):
+                verdict = self.can_schedule(uids,
+                                            [len(t) for t in tokens_list])
+                if verdict != SchedulingResult.Success:
+                    raise RuntimeError(f"cannot schedule batch: {verdict}")
+                self._decode_state = None  # host forward invalidates meta
+                bucket = self.bucket_for(n_tokens, len(uids))
+                sp.set(bucket=bucket[0])
+                wrapper = self._wrapper_for(bucket)
+                wrapper.clear()
+                for uid, toks in zip(uids, tokens_list):
+                    seq = self.state_manager.get_or_create_sequence(uid)
+                    ok = self.state_manager.maybe_allocate_kv(seq, len(toks))
+                    assert ok, "allocator raced"  # can_schedule checked
+                    wrapper.insert_sequence(seq, list(toks))
+                batch = wrapper.finalize()
+                packed = batch.pack()
+            with _TRACER.span("engine/put_dispatch"):
+                # ONE metadata transfer per forward: ~15 small H2D copies
+                # per decode step cost more than the step itself
+                dev = jnp.asarray(packed)
+                logits, new_pages = self._step_for(bucket)(
+                    self.params, self.kv.pages, dev)
+                self.kv.update(new_pages)
+                for uid in batch.uids:
+                    self.state_manager.get_sequence(uid).post_forward()
+                self._touch_heat(batch.uids)
+                return logits[:batch.n_seqs]
 
     def flush(self, uids: Sequence[int]) -> None:
         self._decode_state = None
@@ -673,31 +690,43 @@ class InferenceEngineV2:
             raise RuntimeError(f"cannot schedule verify window: {verdict}")
         self._decode_state = None      # host forward invalidates device meta
         bucket = self.bucket_for(sum(lens), n)
-        wrapper = self._wrapper_for(bucket)
-        wrapper.clear()
-        ctx_before = []
-        for uid, seed, draft in zip(uids, seed_tokens, drafts):
-            seq = self.state_manager.get_or_create_sequence(uid)
-            ctx_before.append(seq.seen_tokens)
-            ok = self.state_manager.maybe_allocate_kv(seq, 1 + len(draft))
-            assert ok, "allocator raced"  # can_schedule checked
-            wrapper.insert_sequence(seq, [int(seed)] + [int(t) for t in draft])
-        batch = wrapper.finalize()
-        dev = jnp.asarray(batch.pack())
-        step, first_compile = self._verify_step_for(bucket)
+        with _TRACER.span("engine/decode_dispatch", n_seqs=n, steps=1,
+                          key=f"verify{bucket[0]}", resumed=False) as sp:
+            with _TRACER.span("engine/decode_pack"):
+                wrapper = self._wrapper_for(bucket)
+                wrapper.clear()
+                ctx_before = []
+                for uid, seed, draft in zip(uids, seed_tokens, drafts):
+                    seq = self.state_manager.get_or_create_sequence(uid)
+                    ctx_before.append(seq.seen_tokens)
+                    ok = self.state_manager.maybe_allocate_kv(
+                        seq, 1 + len(draft))
+                    assert ok, "allocator raced"  # can_schedule checked
+                    wrapper.insert_sequence(
+                        seq, [int(seed)] + [int(t) for t in draft])
+                batch = wrapper.finalize()
+                dev = jnp.asarray(batch.pack())
+            with _TRACER.span("engine/decode_launch"):
+                step, first_compile = self._verify_step_for(bucket)
+                sp.set(compiled=first_compile)
 
-        t0 = time.perf_counter()
-        self.decode_windows_dispatched += 1
-        poisoned = False
-        try:
-            inject("decode_window", step=self.decode_windows_dispatched)
-        except InjectedNaN:
-            poisoned = True
-            self._poison_kv(uids[0])
-        greedy_dev, bad_dev, new_pages = step(self.params, self.kv.pages, dev)
-        self.kv.update(new_pages)
-        greedy = np.asarray(greedy_dev)
-        bad = np.asarray(bad_dev)
+                t0 = time.perf_counter()
+                self.decode_windows_dispatched += 1
+                poisoned = False
+                try:
+                    inject("decode_window",
+                           step=self.decode_windows_dispatched)
+                except InjectedNaN:
+                    poisoned = True
+                    self._poison_kv(uids[0])
+                greedy_dev, bad_dev, new_pages = step(self.params,
+                                                      self.kv.pages, dev)
+                self.kv.update(new_pages)
+        with _TRACER.span("engine/window_wait", steps=1):
+            jax.block_until_ready(greedy_dev)
+        with _TRACER.span("engine/window_fetch"):
+            greedy = np.asarray(greedy_dev)
+            bad = np.asarray(bad_dev)
         duration_s = time.perf_counter() - t0
 
         accepted: List[List[int]] = []
@@ -737,7 +766,8 @@ class InferenceEngineV2:
             accepted_draft=accepted_draft, emitted=emitted,
             duration_s=duration_s, draft_s=float(draft_wall_s),
             compiled=first_compile, poisoned=poisoned)
-        self._record_verify_window(result)
+        with _TRACER.span("engine/window_account"):
+            self._record_verify_window(result)
         return result
 
     def _record_verify_window(self, result: "VerifyResult") -> None:
@@ -820,37 +850,48 @@ class InferenceEngineV2:
         still executing: dispatch the next window first, THEN drain the
         previous handle's ``tokens()``.
         """
+        with _TRACER.span("engine/decode_dispatch", n_seqs=len(uids),
+                          steps=steps) as sp:
+            return self._dispatch_decode_window(
+                sp, uids, seed_tokens, steps, temperature, rng, top_k)
+
+    def _dispatch_decode_window(self, sp, uids, seed_tokens, steps,
+                                temperature, rng, top_k) -> "DecodeWindow":
         c = self.config
         n = len(uids)
-        verdict = self.can_schedule(uids, [steps] * n)
-        if verdict != SchedulingResult.Success:
-            raise RuntimeError(f"cannot schedule decode window: {verdict}")
-        # decode bucket: one flat token per sequence — the compiled program
-        # carries n-ish tokens of work, not the full max_tokens budget
-        s_b = self._seq_bucket(n)
-        bucket = (s_b, s_b)
-        ctx_before = []
-        grew = False
-        for uid in uids:
-            seq = self.state_manager.get_or_create_sequence(uid)
-            ctx_before.append(seq.seen_tokens)
-            prev = seq.cur_allocated_blocks
-            ok = self.state_manager.maybe_allocate_kv(seq, steps)
-            assert ok, "allocator raced"
-            grew |= seq.cur_allocated_blocks != prev
+        with _TRACER.span("engine/decode_alloc"):
+            verdict = self.can_schedule(uids, [steps] * n)
+            if verdict != SchedulingResult.Success:
+                raise RuntimeError(
+                    f"cannot schedule decode window: {verdict}")
+            # decode bucket: one flat token per sequence — the compiled
+            # program carries n-ish tokens of work, not the full max_tokens
+            # budget
+            s_b = self._seq_bucket(n)
+            bucket = (s_b, s_b)
+            ctx_before = []
+            grew = False
+            for uid in uids:
+                seq = self.state_manager.get_or_create_sequence(uid)
+                ctx_before.append(seq.seen_tokens)
+                prev = seq.cur_allocated_blocks
+                ok = self.state_manager.maybe_allocate_kv(seq, steps)
+                assert ok, "allocator raced"
+                grew |= seq.cur_allocated_blocks != prev
 
-        st = self._decode_state
-        uids_t = tuple(uids)
-        resume = (not grew and st is not None
-                  and st["uids"] == uids_t and st["bucket"] == bucket
-                  and all(st["seen"][u] ==
-                          self.state_manager.get_sequence(u).seen_tokens
-                          for u in uids))
-        if resume and "last_tokens" in st:
-            # the previous window was drained, so the caller KNOWS the
-            # stream — a seed differing from the cached on-device token
-            # (stop-token rewrite, guided decoding) must win over resume
-            resume = tuple(int(t) for t in seed_tokens) == st["last_tokens"]
+            st = self._decode_state
+            uids_t = tuple(uids)
+            resume = (not grew and st is not None
+                      and st["uids"] == uids_t and st["bucket"] == bucket
+                      and all(st["seen"][u] ==
+                              self.state_manager.get_sequence(u).seen_tokens
+                              for u in uids))
+            if resume and "last_tokens" in st:
+                # the previous window was drained, so the caller KNOWS the
+                # stream — a seed differing from the cached on-device token
+                # (stop-token rewrite, guided decoding) must win over resume
+                resume = tuple(int(t) for t in seed_tokens) \
+                    == st["last_tokens"]
         if resume:
             self.decode_resume_hits += 1
             meta_dev = st["meta"]
@@ -869,16 +910,30 @@ class InferenceEngineV2:
                 # repack.  (Same uids ⟹ same n ⟹ same bucket, so the
                 # slice below is the previous window's seq rows.)
                 seed_tokens = [int(t) for t in np.asarray(st["meta"][:n])]
-            wrapper = self._wrapper_for(bucket)
-            wrapper.clear()
-            for uid, tok in zip(uids, seed_tokens):
-                wrapper.insert_sequence(
-                    self.state_manager.get_sequence(uid), [int(tok)])
-            meta_dev = jnp.asarray(wrapper.finalize().pack())
+            with _TRACER.span("engine/decode_pack"):
+                wrapper = self._wrapper_for(bucket)
+                wrapper.clear()
+                for uid, tok in zip(uids, seed_tokens):
+                    wrapper.insert_sequence(
+                        self.state_manager.get_sequence(uid), [int(tok)])
+                meta_dev = jnp.asarray(wrapper.finalize().pack())
 
+        with _TRACER.span("engine/decode_launch"):
+            return self._launch_decode_window(
+                sp, uids, seed_tokens, steps, temperature, rng, top_k,
+                bucket, meta_dev, resume, ctx_before)
+
+    def _launch_decode_window(self, sp, uids, seed_tokens, steps,
+                              temperature, rng, top_k, bucket, meta_dev,
+                              resume, ctx_before) -> "DecodeWindow":
+        c = self.config
+        n = len(uids)
+        uids_t = tuple(uids)
         top_k = c.top_k if top_k is None else int(top_k)
         key = (bucket, steps, float(temperature), top_k)
         first_compile = key not in self._decode_loops
+        sp.set(key=f"{bucket[0]}x{steps}", resumed=resume,
+               compiled=first_compile)
         if first_compile:
             from .model_runner import build_decode_loop
 
@@ -975,35 +1030,43 @@ class InferenceEngineV2:
                 for layer in range(self.cfg.num_layers) for b in own]
         self.kv.update(self.kv.pages.at[jnp.asarray(phys)].set(jnp.nan))
 
-    def _record_decode_roofline(self, window: "DecodeWindow") -> None:
-        """Feed a drained decode window into the analytic HBM roofline
+    @property
+    def last_decode_roofline(self) -> Optional[Dict]:
+        """The analytic HBM roofline of the last drained decode window
         (decode is bandwidth-bound, so %-of-peak HBM — not MFU — is its
-        utilization number).  Stores the per-kernel report on
-        ``last_decode_roofline`` and mirrors it into ``serving/*`` gauges
-        when the process-global telemetry hub is installed, so
-        ``dstpu-telemetry`` renders the serving section."""
+        utilization number), computed when asked for.  A window that
+        compiled its loop is flagged ``compile_polluted``: its wall time
+        measures XLA, not decode."""
+        facts = self._last_window_facts
+        if facts is None:
+            return None
+        if self._last_roofline is None or self._last_roofline[0] is not facts:
+            from ...profiling.serving_roofline import (
+                decode_roofline_report, decode_window_bytes)
+
+            n_seqs, steps, mean_ctx, duration_s, resumed, compiled = facts
+            cfg = self.cfg
+            report = decode_roofline_report(decode_window_bytes(
+                num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim,
+                kv_dtype_bytes=jnp.dtype(self.kv.config.dtype).itemsize,
+                param_bytes=self._param_bytes, n_seqs=n_seqs, steps=steps,
+                mean_ctx=mean_ctx), duration_s, n_seqs, steps)
+            report["resumed"] = resumed
+            report["compile_polluted"] = compiled
+            self._last_roofline = (facts, report)
+        return self._last_roofline[1]
+
+    def _account_decode_window(self, window: "DecodeWindow") -> None:
+        """Keep a drained window's facts for ``last_decode_roofline`` and,
+        when the process-global telemetry hub is installed, mirror the
+        report into ``serving/*`` gauges so ``dstpu-telemetry`` renders the
+        serving section.  With no hub this is one tuple."""
         if not window.n_seqs or not window.duration_s:
             return
-        from ...profiling.serving_roofline import (
-            decode_roofline_report,
-            decode_window_bytes,
-            format_decode_roofline,
-            publish_decode_gauges,
-        )
-
-        cfg = self.cfg
-        kv_cfg = self.kv.config
-        bytes_by_kernel = decode_window_bytes(
-            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim,
-            kv_dtype_bytes=jnp.dtype(kv_cfg.dtype).itemsize,
-            param_bytes=self._param_bytes, n_seqs=window.n_seqs,
-            steps=window.steps, mean_ctx=window.mean_ctx)
-        report = decode_roofline_report(bytes_by_kernel, window.duration_s,
-                                        window.n_seqs, window.steps)
-        report["resumed"] = window.resumed
-        report["compile_polluted"] = window.compiled
-        self.last_decode_roofline = report
+        self._last_window_facts = (
+            window.n_seqs, window.steps, window.mean_ctx, window.duration_s,
+            window.resumed, window.compiled)
         if window.compiled:
             # first window per loop key times trace+XLA-compile inside its
             # wall clock; publishing that as tok/s or HBM %-of-peak would
@@ -1013,29 +1076,32 @@ class InferenceEngineV2:
         from ...telemetry import get_telemetry
 
         tel = get_telemetry()
-        if tel is not None:
-            publish_decode_gauges(tel.metrics, report)
-            # per-kernel %-of-peak roofline (kernels/* gauges, the
-            # dstpu-telemetry "kernels" section) for the decode attention
-            # kernel: its analytic page-walk bytes over the window wall,
-            # plus the QK+PV flops (decode is memory-bound — pct_peak_hbm
-            # is the number that matters; flops ride along for the AI)
-            from ...profiling.roofline import (kernel_roofline_report,
-                                               publish_kernel_gauges)
+        if tel is None:
+            return
+        from ...profiling.roofline import (kernel_roofline_report,
+                                           publish_kernel_gauges)
+        from ...profiling.serving_roofline import publish_decode_gauges
 
-            attn_bytes = bytes_by_kernel.get("decode_attention", 0.0)
-            attn_flops = (4.0 * cfg.num_heads * cfg.head_dim
-                          * window.mean_ctx * window.n_seqs * window.steps
-                          * cfg.num_layers)
-            kname = "decode_paged" if self.config.attn_impl == "paged" \
-                else "decode_dense"
-            publish_kernel_gauges(tel.metrics, kernel_roofline_report(
-                kname, attn_flops, attn_bytes, window.duration_s))
-            tel.event("decode_window", tok_per_s=report["decode_tok_per_s"],
-                      hbm_pct_peak=report["hbm_pct_peak"],
-                      n_seqs=window.n_seqs, steps=window.steps,
-                      resumed=window.resumed)
-        logger.debug(format_decode_roofline(report))
+        report = self.last_decode_roofline
+        publish_decode_gauges(tel.metrics, report)
+        # per-kernel %-of-peak roofline (kernels/* gauges, the
+        # dstpu-telemetry "kernels" section) for the decode attention
+        # kernel: its analytic page-walk bytes over the window wall, plus
+        # the QK+PV flops (decode is memory-bound — pct_peak_hbm is the
+        # number that matters; flops ride along for the AI)
+        cfg = self.cfg
+        attn_bytes = report["kernels"]["decode_attention"]["bytes"]
+        attn_flops = (4.0 * cfg.num_heads * cfg.head_dim
+                      * window.mean_ctx * window.n_seqs * window.steps
+                      * cfg.num_layers)
+        kname = "decode_paged" if self.config.attn_impl == "paged" \
+            else "decode_dense"
+        publish_kernel_gauges(tel.metrics, kernel_roofline_report(
+            kname, attn_flops, attn_bytes, window.duration_s))
+        tel.event("decode_window", tok_per_s=report["decode_tok_per_s"],
+                  hbm_pct_peak=report["hbm_pct_peak"],
+                  n_seqs=window.n_seqs, steps=window.steps,
+                  resumed=window.resumed)
 
     # ------------------------------------------------------------------ #
     # Dynamic SplitFuse scheduling (MII-layer policy, host-only logic)
@@ -1178,22 +1244,31 @@ class DecodeWindow:
     def tokens(self) -> np.ndarray:
         """Block for the generated tokens [steps, n_seqs]."""
         if self._toks is None:
-            self._toks = np.asarray(self._toks_dev[:, :self.n_seqs])
-            if self._nonfinite_dev is not None:
-                self.nonfinite = np.asarray(
-                    self._nonfinite_dev[:self.n_seqs])
-            else:
-                self.nonfinite = np.zeros(self.n_seqs, bool)
+            # the sync the copies below would make anyway, timed apart: the
+            # wait is the device's, the fetch is the host's.  The slices
+            # are queued behind the window BEFORE the wait, so the device
+            # never idles between the window and them.
+            with _TRACER.span("engine/window_wait", steps=self.steps):
+                toks_dev = self._toks_dev[:, :self.n_seqs]
+                bad_dev = None if self._nonfinite_dev is None \
+                    else self._nonfinite_dev[:self.n_seqs]
+                jax.block_until_ready((toks_dev, bad_dev))
+            with _TRACER.span("engine/window_fetch"):
+                self._toks = np.asarray(toks_dev)
+                self.nonfinite = np.zeros(self.n_seqs, bool) \
+                    if bad_dev is None else np.asarray(bad_dev)
             self.duration_s = time.perf_counter() - self._t0
             self._toks_dev = None
             self._nonfinite_dev = None
-            if self._state is not None and \
-                    self.engine._decode_state is self._state:
-                # the last sampled token is the next window's seed: once it
-                # is host-known, resume can honor caller-supplied seeds
-                self._state["last_tokens"] = tuple(
-                    int(t) for t in self._toks[-1])
-            self.engine._record_decode_roofline(self)
+            with _TRACER.span("engine/window_account"):
+                if self._state is not None and \
+                        self.engine._decode_state is self._state:
+                    # the last sampled token is the next window's seed: once
+                    # it is host-known, resume can honor caller-supplied
+                    # seeds
+                    self._state["last_tokens"] = tuple(
+                        int(t) for t in self._toks[-1])
+                self.engine._account_decode_window(self)
         return self._toks
 
     def nonfinite_uids(self) -> List[int]:

@@ -370,6 +370,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((T_pad, H, hd), q.dtype),
         interpret=interp,
+        name="ragged_prefill",
     )(kv_lens.astype(jnp.int32), page_table.astype(jnp.int32),
       cu_q_lens.astype(jnp.int32), q, kv_pages)
     return out[:T]
@@ -572,6 +573,7 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="paged_decode",
     )(kv_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, kv_pages)
 
 

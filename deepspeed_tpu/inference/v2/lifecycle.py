@@ -60,10 +60,16 @@ import numpy as np
 
 from ...telemetry.goodput import (get_goodput_ledger, goodput_residual,
                                   record_goodput)
-from ...telemetry.tracing import (FLAG_BY_REASON, get_trace_store,
-                                  record_span, trace_id_of)
+from ...telemetry.hub import get_telemetry
+from ...telemetry.trace import get_tracer
+from ...telemetry.tracing import (FLAG_BY_REASON, flag_trace,
+                                  get_trace_store, record_span, trace_id_of)
 from ...utils.logging import logger
 from .engine_v2 import InferenceEngineV2
+
+#: ``serve/*`` spans: the process-global ring and a profiler session's host
+#: events; a request with a ``trace`` feeds the store from the same reads
+_TRACER = get_tracer()
 
 
 class RequestState(Enum):
@@ -100,11 +106,10 @@ class ServeRequest:
     deadline_s: Optional[float] = None
     ttft_timeout_s: Optional[float] = None
     on_event: Optional[Callable[[str, "ServeRequest"], None]] = None
-    #: per-request speculative-decoding override (`/v1/generate` grows
-    #: ``speculative: {mode, k}``): ``spec_mode`` None inherits the
-    #: scheduler default; "off" disables; any other mode enables the
-    #: scheduler's configured drafter.  ``spec_k`` overrides the draft
-    #: length for this request only.
+    #: per-request speculative-decoding override (``speculative: {mode,
+    #: k}`` on `/v1/generate`): ``spec_mode`` None inherits the scheduler
+    #: default, "off" disables, any other mode enables the scheduler's
+    #: drafter; ``spec_k`` is this request's draft length.
     spec_mode: Optional[str] = None
     spec_k: Optional[int] = None
     #: QoS attribution (serving/fleet/qos): the admission class the
@@ -120,10 +125,9 @@ class ServeRequest:
     prefill_only: bool = False
     kv_import: Optional[object] = None
     #: fleet-wide request-trace context (telemetry/tracing): when set, the
-    #: scheduler appends typed spans (queue_wait, admission, prefill,
-    #: decode_window, preempt/resume, draft/verify, kv_ship_*) under this
-    #: trace id to the process-global store, and ``trace_result`` carries
-    #: the finished local trace for in-band return to the router
+    #: scheduler appends typed spans (``store.SPAN_KINDS``) under this trace
+    #: id to the process-global store, and ``trace_result`` carries the
+    #: finished local trace for in-band return to the router
     trace: Optional[object] = None
     trace_result: Optional[dict] = None
 
@@ -143,11 +147,11 @@ class ServeRequest:
     _prefill_pos: int = 0
     _resume_seed: Optional[int] = None       # set while resuming a preempt
     _prefix_counted: bool = False            # hit/miss recorded once
-    #: wall-clock (time.time) marks for span timestamps — kept separate
-    #: from the scheduler's injectable ``clock`` so fake-clock tests still
-    #: produce mergeable cross-process timelines
-    _twall_submit: float = 0.0
-    _twall_queue: float = 0.0                # reset on preemption re-queue
+    #: on the scheduler's ``clock``: queued (again after a preemption),
+    #: first admitted; and the prompt chunks run so far
+    _queue_t: float = 0.0
+    _admit_t: Optional[float] = None
+    _chunks: int = 0
     _import_s: float = 0.0                   # kv_ship_import wall inside
     #                                          the last _reserve_for call
 
@@ -254,18 +258,15 @@ class LifecycleScheduler:
         self.last_incident_kind: Optional[str] = None
         self.last_shed_t: Optional[float] = None
 
-    # ------------------------------------------------------------------ #
-    # Request tracing (telemetry/tracing): span + finish helpers.  A
-    # ``None`` store or an un-traced request is the disabled fast path —
-    # one global read + one attribute check per site, no host syncs.
-    # ------------------------------------------------------------------ #
+    # Request tracing: un-traced request / no store = one check per site
     def _tspan(self, req: ServeRequest, kind: str, t0: float, dur_s: float,
                **attrs) -> None:
-        record_span(req.trace, kind, t0=t0, dur_s=dur_s,
-                    component=self.trace_component, uid=req.uid, **attrs)
+        """``t0``: a span's own (``perf_counter``); the store keeps unix."""
+        if req.trace is not None:
+            record_span(req.trace, kind, t0=_TRACER.wall(t0), dur_s=dur_s,
+                        component=self.trace_component, uid=req.uid, **attrs)
 
-    def _trace_finish(self, req: ServeRequest,
-                      flag: Optional[str] = None) -> None:
+    def _trace_finish(self, req: ServeRequest, flag=None) -> None:
         store = get_trace_store()
         if store is None or req.trace is None:
             return
@@ -273,11 +274,7 @@ class LifecycleScheduler:
             store.flag(req.trace.trace_id, "preempted")
         req.trace_result = store.finish(
             req.trace.trace_id, flag=flag,
-            wall_s=max(time.time() - req._twall_submit, 0.0)
-            if req._twall_submit else None)
-
-    def _trace_id(self, req: ServeRequest) -> Optional[str]:
-        return trace_id_of(req.trace)
+            wall_s=max(self.clock() - req.arrival_t, 0.0))
 
     # ------------------------------------------------------------------ #
     # Ingress (HTTP handler threads)
@@ -287,8 +284,7 @@ class LifecycleScheduler:
         with self._lock:
             t_shed0 = time.perf_counter()
             now = self.clock()
-            req.arrival_t = now
-            req._twall_submit = req._twall_queue = time.time()
+            req.arrival_t = req._queue_t = now
             if req.deadline_s is not None:
                 req.deadline_t = now + req.deadline_s
             if req.ttft_timeout_s is not None:
@@ -304,40 +300,22 @@ class LifecycleScheduler:
                 self._trace_finish(req)
                 req._fire("finished")
                 return AdmissionVerdict(True)
-            if self.draining:
-                req.state = RequestState.SHED
-                req.finish_reason = "draining"
-                self._count("serving/shed")
-                self._event("serving_shed", uid=req.uid, reason="draining",
-                            tenant=req.tenant or "default",
-                            trace=self._trace_id(req))
-                self._tspan(req, "admission", t0=req._twall_submit,
-                            dur_s=0.0, shed="draining",
-                            tenant=req.tenant or "default")
-                self._trace_finish(req,
-                                   flag=FLAG_BY_REASON.get(req.finish_reason))
+            full = len(self._waiting) >= self.max_queue
+            if self.draining or full:
+                reason = "draining" if self.draining else "queue_full"
+                tenant = req.tenant or "default"
+                if not self.draining:
+                    self.last_shed_t = now
+                self._tspan(req, "admission", t0=t_shed0, dur_s=0.0,
+                            shed=reason, tenant=tenant)
+                self._retire(req, RequestState.SHED, reason, "serving_shed",
+                             "serving/shed", tenant=tenant,
+                             queue_depth=len(self._waiting))
                 record_goodput("shed", time.perf_counter() - t_shed0,
-                               tenant=req.tenant or "default")
-                return AdmissionVerdict(False, "draining",
-                                        self.predicted_drain_s())
-            if len(self._waiting) >= self.max_queue:
-                req.state = RequestState.SHED
-                req.finish_reason = "queue_full"
-                self.last_shed_t = now
-                self._count("serving/shed")
-                self._event("serving_shed", uid=req.uid, reason="queue_full",
-                            tenant=req.tenant or "default",
-                            queue_depth=len(self._waiting),
-                            trace=self._trace_id(req))
-                self._tspan(req, "admission", t0=req._twall_submit,
-                            dur_s=0.0, shed="queue_full",
-                            tenant=req.tenant or "default")
-                self._trace_finish(req,
-                                   flag=FLAG_BY_REASON.get(req.finish_reason))
-                record_goodput("shed", time.perf_counter() - t_shed0,
-                               tenant=req.tenant or "default")
-                return AdmissionVerdict(False, "queue_full",
-                                        self.retry_after_s())
+                               tenant=tenant)
+                return AdmissionVerdict(
+                    False, reason, self.predicted_drain_s()
+                    if self.draining else self.retry_after_s())
             self._reqs[req.uid] = req
             self._waiting.append(req.uid)
             self._count("serving/requests")
@@ -405,31 +383,54 @@ class LifecycleScheduler:
     # Lifecycle passes
     # ------------------------------------------------------------------ #
     def _retire(self, req: ServeRequest, state: RequestState, reason: str,
-                event: str, counter: Optional[str] = None) -> None:
-        """Move a request to a terminal state, reclaiming its KV blocks."""
+                event: str, counter: Optional[str] = None,
+                holds_blocks: bool = False, **fields) -> None:
+        """Move a request to a terminal state, reclaiming its KV blocks: the
+        one way out, for ``submit``'s sheds too (never queued, nothing to
+        reclaim, no callback).  Any end but ``finished`` says why."""
         uid = req.uid
-        holds_blocks = uid in self._prefilling or uid in self._decodes
+        if state is not RequestState.FINISHED:
+            self._say_why(uid, state.value, reason, len(req.produced))
+        holds_blocks |= uid in self._prefilling or uid in self._decodes
         self._waiting = collections.deque(
             u for u in self._waiting if u != uid)
         self._prefilling.pop(uid, None)
         self._decodes.pop(uid, None)
-        if holds_blocks:
+        self._release(uid, flush=holds_blocks)
+        req.state = state
+        req.finish_reason = reason
+        if req.finished_t is None:
+            req.finished_t = self.clock()
+        if counter:
+            self._count(counter)
+        self._event(event, uid=uid, reason=reason, produced=len(req.produced),
+                    trace=trace_id_of(req.trace), **fields)
+        self._trace_finish(req, flag=FLAG_BY_REASON.get(reason))
+        if state is not RequestState.SHED:
+            req._fire(event.replace("serving_", ""))
+        self._publish_gauges()
+
+    def _say_why(self, uid, state: str, reason: str, produced: int,
+                 **attrs) -> None:
+        """A request that does not finish leaves its reason in the ring (a
+        ``serve/retire`` span) and in one warning line, hub or no hub."""
+        attrs.update(waiting=len(self._waiting),
+                     kv_used=round(self.eng.kv_used_fraction(), 4))
+        with _TRACER.span("serve/retire", uid=uid, state=state, reason=reason,
+                          produced=produced, **attrs):
+            logger.warning(f"serve/retire uid={uid} state={state} "
+                           f"reason={reason} produced={produced} " + " ".join(
+                               f"{k}={v}" for k, v in attrs.items()))
+
+    def _release(self, uid: int, flush: bool = True) -> None:
+        """A request is over: its KV blocks, drafter state, parked rows."""
+        if flush:
             self.eng.flush([uid])
         if self.drafter is not None:
             self.drafter.flush(uid)
         ksw = getattr(self.eng, "kv_swap", None)
         if ksw is not None:
-            ksw.drop(uid)       # parked rows die with the request
-        req.state = state
-        req.finish_reason = reason
-        req.finished_t = self.clock()
-        if counter:
-            self._count(counter)
-        self._event(event, uid=uid, reason=reason,
-                    produced=len(req.produced), trace=self._trace_id(req))
-        self._trace_finish(req, flag=FLAG_BY_REASON.get(reason))
-        req._fire(event.replace("serving_", ""))
-        self._publish_gauges()
+            ksw.drop(uid)
 
     def _process_cancellations(self) -> List[int]:
         done = []
@@ -449,15 +450,16 @@ class LifecycleScheduler:
             if req.state in TERMINAL_STATES:
                 continue
             if req.deadline_t is not None and now >= req.deadline_t:
-                self._retire(req, RequestState.EXPIRED, "deadline",
-                             "serving_expired", "serving/deadline_expired")
-                done.append(req.uid)
+                reason, counter = "deadline", "serving/deadline_expired"
             elif (req.ttft_deadline_t is not None
                     and req.first_token_t is None
                     and now >= req.ttft_deadline_t):
-                self._retire(req, RequestState.EXPIRED, "ttft_timeout",
-                             "serving_expired", "serving/ttft_timeout")
-                done.append(req.uid)
+                reason, counter = "ttft_timeout", "serving/ttft_timeout"
+            else:
+                continue
+            self._retire(req, RequestState.EXPIRED, reason,
+                         "serving_expired", counter)
+            done.append(req.uid)
         return done
 
     # ------------------------------------------------------------------ #
@@ -472,11 +474,10 @@ class LifecycleScheduler:
         if self.eng.kv_used_fraction() < self.kv_high_watermark:
             return False
         victims = [self._reqs[u] for u in self._decodes]
-        # anti-ping-pong: among equal priorities, a head that has itself
-        # been preempted N times may only evict victims preempted >= N
-        # times — two requests can then never evict each other in a cycle
-        # (observed livelock: a 3-block and an 8-block request alternately
-        # preempting each other forever on a 10-block pool)
+        # anti-ping-pong: among equal priorities, a head preempted N times
+        # may only evict victims preempted >= N times, so two requests never
+        # evict each other in a cycle (observed livelock: a 3-block and an
+        # 8-block request alternating forever on a 10-block pool)
         victims = [v for v in victims
                    if v.priority < head.priority
                    or (v.priority == head.priority
@@ -488,9 +489,8 @@ class LifecycleScheduler:
         victim = min(victims, key=lambda r: (r.priority, -r._admit_order))
         uid = victim.uid
         # host tier on: park the victim's coldest contiguous page-prefix
-        # BEFORE the flush (the export is a pure read of still-live pages)
-        # so resume is a swap-in instead of a prefill recompute; 0 tokens
-        # spilled degrades to the pre-tier evict+recompute path
+        # BEFORE the flush (a pure read of still-live pages), so resume is a
+        # swap-in, not a prefill recompute; 0 tokens spilled = recompute
         swapped = 0
         ksw = getattr(self.eng, "kv_swap", None)
         if ksw is not None and victim.produced:
@@ -510,10 +510,10 @@ class LifecycleScheduler:
         self._event("serving_preempted", uid=uid, for_uid=head.uid,
                     produced=len(victim.produced), swapped=swapped,
                     kv_used=round(self.eng.kv_used_fraction(), 4),
-                    trace=self._trace_id(victim))
-        self._tspan(victim, "preempt", t0=time.time(), dur_s=0.0,
+                    trace=trace_id_of(victim.trace))
+        victim._queue_t = self.clock()        # the next queue_wait span
+        self._tspan(victim, "preempt", t0=victim._queue_t, dur_s=0.0,
                     for_uid=head.uid, produced=len(victim.produced))
-        victim._twall_queue = time.time()     # the next queue_wait span
         victim._fire("preempted")
         logger.info(f"KV pressure: preempted uid {uid} "
                     f"({len(victim.produced)} tokens spilled) to admit "
@@ -523,6 +523,14 @@ class LifecycleScheduler:
     # ------------------------------------------------------------------ #
     # Scheduling
     # ------------------------------------------------------------------ #
+    def _room_for(self, need_blocks: int) -> bool:
+        """Evict prefix-cache slack; are ``need_blocks`` free then?"""
+        sm = self.eng.state_manager
+        if need_blocks > sm.allocator.free_blocks and \
+                sm.prefix_cache is not None:
+            sm.prefix_cache.evict(need_blocks - sm.allocator.free_blocks)
+        return need_blocks <= sm.allocator.free_blocks
+
     def _reserve_for(self, req: ServeRequest) -> Optional[bool]:
         """Whole-lifetime KV reservation for admission.  Returns True on
         success, False on transient exhaustion (backpressure), None when
@@ -563,30 +571,22 @@ class LifecycleScheduler:
                 # swap-in resume: the preempt path parked this uid's rows
                 # host-side, and they cover MORE than any original
                 # kv_import shipment (prompt + produced so far), so this
-                # branch wins.  Same cheap feasibility gate as kv_import:
-                # evict cache slack, then bail before touching the device.
-                if need_blocks > sm.allocator.free_blocks and \
-                        sm.prefix_cache is not None:
-                    sm.prefix_cache.evict(
-                        need_blocks - sm.allocator.free_blocks)
-                if need_blocks > sm.allocator.free_blocks:
+                # branch wins.  Same cheap feasibility gate as kv_import.
+                if not self._room_for(need_blocks):
                     return False
-                t0w, t0p = time.time(), time.perf_counter()
-                n = ksw.restore(req.uid, req.resume_prompt)
+                with _TRACER.span("serve/kv_swap_in", uid=req.uid) as sp:
+                    n = ksw.restore(req.uid, req.resume_prompt)
                 if n:
-                    req._import_s = time.perf_counter() - t0p
-                    self._tspan(req, "kv_swap_in", t0=t0w,
-                                dur_s=req._import_s, tokens=n)
+                    req._import_s = sp.dur_s
+                    self._tspan(req, "kv_swap_in", t0=sp.t0,
+                                dur_s=sp.dur_s, tokens=n)
                     req._prefill_pos = n
                     swapped_in = True
                     self._count("serving/swap_in")
                     self._count("serving/swap_in_tokens", n)
                 elif ksw.entry(req.uid) is not None:
-                    return False    # transient exhaustion: rows stay
-                                    # parked, the queue head retries
-                else:
-                    # rows were LRU-evicted / failed re-attestation /
-                    # fault-injected away: recompute (bit-exact, slower)
+                    return False    # transient: rows stay parked, retry
+                else:   # rows evicted / failed re-attestation: recompute
                     self._count("serving/swap_miss")
             elif req.kv_import is not None:
                 ship = req.kv_import
@@ -596,25 +596,20 @@ class LifecycleScheduler:
                         or list(ship.tokens) != attested):
                     # wrong conversation's KV: no retry can fix this
                     return None
-                # feasibility gate BEFORE the device write: a blocked
-                # queue head retries every pass, and importing (pages
-                # scatter + decode-state invalidation) only to flush on a
-                # failed reservation would repeat that work per window.
-                # Evict cache slack first, then bail cheaply.
-                if need_blocks > sm.allocator.free_blocks and \
-                        sm.prefix_cache is not None:
-                    sm.prefix_cache.evict(
-                        need_blocks - sm.allocator.free_blocks)
-                if need_blocks > sm.allocator.free_blocks:
+                # feasibility gate BEFORE the device write: a blocked head
+                # retries every pass, and importing (pages scatter + decode-
+                # state invalidation) only to flush would repeat per window
+                if not self._room_for(need_blocks):
                     return False
                 from .kv_ship import import_kv
 
-                t0w, t0p = time.time(), time.perf_counter()
-                if not import_kv(self.eng, ship, req.uid):
+                with _TRACER.span("serve/kv_import", uid=req.uid) as sp:
+                    imported = import_kv(self.eng, ship, req.uid)
+                if not imported:
                     return False           # transient exhaustion
-                req._import_s = time.perf_counter() - t0p
-                self._tspan(req, "kv_ship_import", t0=t0w,
-                            dur_s=req._import_s, tokens=ship.n_tokens)
+                req._import_s = sp.dur_s
+                self._tspan(req, "kv_ship_import", t0=sp.t0,
+                            dur_s=sp.dur_s, tokens=ship.n_tokens)
                 req._prefill_pos = ship.n_tokens
             elif self.eng.prefix_cache is not None:
                 matched = self.eng.graft_prefix(req.uid, req.resume_prompt)
@@ -625,19 +620,17 @@ class LifecycleScheduler:
         # attribute this uid's KV pages fractionally per tenant
         self.eng.set_tenant(req.uid, req.tenant or "default")
         if not sm.maybe_allocate_kv(seq, need - seq.seen_tokens):
-            # roll back so a shed/preempted retry starts clean: grafted /
-            # imported blocks are released (shared pages survive in the
-            # trie), an empty descriptor is popped
+            # roll back so a retry starts clean: grafted / imported blocks
+            # go (shared pages survive in the trie), an empty descriptor too
             if seq.blocks or seq.seen_tokens:
                 sm.flush_sequence(req.uid)
             else:
                 sm._seqs.pop(req.uid, None)
             req._prefill_pos = 0
             return False
-        # count the graft ONLY on a successful reservation: a blocked
-        # head releases and re-grafts every pass, and counting those
-        # retries would inflate the hit stats (cache.note_hit/note_miss
-        # exist for the same reason — match() itself is a pure lookup)
+        # count the graft ONLY on a successful reservation: a blocked head
+        # re-grafts every pass, and those retries would inflate the hit
+        # stats (why cache.note_hit/note_miss exist; match() only looks up)
         cache = self.eng.prefix_cache
         if swapped_in:
             pass    # swap-in counters were recorded in the branch above
@@ -656,12 +649,13 @@ class LifecycleScheduler:
                 cache.note_miss()
         return True
 
-    def _build_prefill_batch(self) -> List[Tuple[int, List[int]]]:
-        """Chunks for one ``put``: in-flight prefills first, then admit
-        from the queue head (with preemption when starved under
-        pressure)."""
+    def _build_prefill_batch(self, sp) -> List[Tuple[int, List[int]]]:
+        """Chunks for one ``put``: in-flight prefills first, then admit from
+        the queue head (with preemption when starved under pressure).  The
+        counts go on ``sp``, the ``serve/admit`` span."""
         c = self.eng.config
         budget = c.max_tokens
+        admitted = 0
         picked: List[Tuple[int, List[int]]] = []
         for uid in list(self._prefilling):
             if budget <= 0 or len(picked) >= c.max_seqs:
@@ -674,23 +668,28 @@ class LifecycleScheduler:
         preempted_this_pass = False
         while self._waiting and budget > 0 and len(picked) < c.max_seqs:
             head = self._reqs[self._waiting[0]]
-            t0w, t0p = time.time(), time.perf_counter()
+            t_try = self.clock()
             head._import_s = 0.0
-            verdict = self._reserve_for(head)
+            with _TRACER.span("serve/reserve", uid=head.uid) as rsp:
+                verdict = self._reserve_for(head)
             if verdict is True:
-                # admission succeeded: close the queue_wait segment
-                # (re-opened by preemption) and record the reservation /
-                # graft work as the admission segment — MINUS the KV
-                # import, which has its own kv_ship_import span (segments
-                # must stay disjoint or the decomposition sums lie)
-                self._tspan(head, "queue_wait", t0=head._twall_queue,
-                            dur_s=max(t0w - head._twall_queue, 0.0))
+                # admitted: close the queue_wait segment (re-opened by a
+                # preemption); the reservation / graft work is the admission
+                # segment MINUS the KV import, which has its own span
+                # (segments stay disjoint or the decomposition sums lie)
+                waited = max(t_try - head._queue_t, 0.0)
+                _TRACER.record("serve/queue_wait", head._queue_t, waited,
+                               uid=head.uid, prompt_tokens=len(head.prompt),
+                               preempted=head.preempt_count)
+                self._tspan(head, "queue_wait", t0=head._queue_t,
+                            dur_s=waited)
+                if head._admit_t is None:
+                    head._admit_t = t_try
                 # tenant rides the admission span so a recorded
                 # traces.jsonl stays convertible into a replayable
                 # workload even without a router in front
-                self._tspan(head, "admission", t0=t0w,
-                            dur_s=max(time.perf_counter() - t0p
-                                      - head._import_s, 0.0),
+                self._tspan(head, "admission", t0=rsp.t0,
+                            dur_s=max(rsp.dur_s - head._import_s, 0.0),
                             prefix_hit=head._prefill_pos
                             if head.kv_import is None else 0,
                             tenant=head.tenant or "default")
@@ -710,6 +709,7 @@ class LifecycleScheduler:
             self._waiting.popleft()
             head.state = RequestState.PREFILL
             self._prefilling[head.uid] = None
+            admitted += 1
             self._admit_seq += 1
             head._admit_order = self._admit_seq
             # _prefill_pos may start past 0: grafted prefix / imported KV
@@ -718,36 +718,56 @@ class LifecycleScheduler:
                                        head._prefill_pos + budget]
             picked.append((head.uid, chunk))
             budget -= len(chunk)
+        # a head still waiting with budget left is one the pool cannot host
+        sp.set(admitted=admitted, preempted=int(preempted_this_pass),
+               blocked=int(bool(self._waiting) and budget > 0
+                           and len(picked) < c.max_seqs),
+               tokens=c.max_tokens - budget)
         return picked
 
     def _run_prefill(self, batch: List[Tuple[int, List[int]]]) -> List[int]:
-        t0w, t0p = time.time(), time.perf_counter()
-        logits = self.eng.put([u for u, _ in batch], [t for _, t in batch])
-        put_s = time.perf_counter() - t0p
-        ledger = get_goodput_ledger()
-        if ledger is not None and put_s > 0.0:
-            # the forward's wall splits across riders by chunk size; the
-            # share replaying a preemption victim's already-produced KV is
-            # waste the ledger must see (``preempt_recompute``), the rest
-            # is useful prefill
-            total_toks = sum(len(t) for _, t in batch) or 1
-            redo_toks = sum(len(t) for u, t in batch
-                            if self._reqs[u]._resume_seed is not None)
-            if redo_toks:
-                ledger.add("preempt_recompute",
-                           put_s * redo_toks / total_toks)
-            ledger.add("compute", put_s * (total_toks - redo_toks)
-                       / total_toks)
+        with _TRACER.span("serve/prefill", n_seqs=len(batch)) as sp:
+            logits = self.eng.put([u for u, _ in batch],
+                                  [t for _, t in batch])
+            put_s = time.perf_counter() - sp.t0
+            ledger = get_goodput_ledger()
+            if ledger is not None and put_s > 0.0:
+                # split by chunk size: replaying a preemption victim's KV
+                # is waste (``preempt_recompute``), the rest useful prefill
+                total_toks = sum(len(t) for _, t in batch) or 1
+                redo = put_s / total_toks * sum(
+                    len(t) for u, t in batch
+                    if self._reqs[u]._resume_seed is not None)
+                if redo:
+                    ledger.add("preempt_recompute", redo)
+                ledger.add("compute", put_s - redo)
+            # rows whose prompt ends here and that go on to decode need
+            # their next token now: the one host sync of a prefill step
+            seeds: Dict[int, int] = {}
+            need = [row for row, (r, chunk) in enumerate(
+                (self._reqs[u], t) for u, t in batch)
+                if r._prefill_pos + len(chunk) >= len(r.resume_prompt)
+                and not r.prefill_only and r._resume_seed is None]
+            if need:
+                with _TRACER.span("serve/logits_fetch", rows=len(need)):
+                    for row in need:
+                        seeds[row] = int(np.argmax(np.asarray(logits[row])))
+            with _TRACER.span("serve/prefill_apply"):
+                return self._apply_prefill(batch, seeds, sp.t0, put_s)
+
+    def _apply_prefill(self, batch, seeds: Dict[int, int], t0: float,
+                       put_s: float) -> List[int]:
         finished: List[int] = []
         now = self.clock()
         for row, (uid, chunk) in enumerate(batch):
             req = self._reqs[uid]
             # the whole forward's wall is attributed to every rider: the
             # request really did spend that time inside this batch
-            self._tspan(req, "prefill", t0=t0w, dur_s=put_s,
+            self._tspan(req, "prefill", t0=t0, dur_s=put_s,
                         tokens=len(chunk), batch=len(batch),
                         resume=req._resume_seed is not None)
             req._prefill_pos += len(chunk)
+            req._chunks += 1
             if req._prefill_pos < len(req.resume_prompt):
                 continue                       # mid-prompt; logits unused
             # prefill complete: commit the full prompt pages to the radix
@@ -755,17 +775,16 @@ class LifecycleScheduler:
             # requests sharing the prefix hit while this one still decodes
             self.eng.commit_prefix(uid, req.resume_prompt)
             if req.prefill_only:
-                # disaggregated-prefill producer: export the rows, finish
-                # without decoding a single token (_retire pops the
-                # prefilling entry and reclaims the blocks — the export
-                # above it is a pure read)
+                # disaggregated-prefill producer: export the rows (a pure
+                # read), finish without decoding (_retire pops the
+                # prefilling entry and reclaims the blocks)
                 from .kv_ship import export_kv
 
-                te_w, te_p = time.time(), time.perf_counter()
-                req.kv_shipment = export_kv(self.eng, uid,
-                                            req.resume_prompt)
-                self._tspan(req, "kv_ship_encode", t0=te_w,
-                            dur_s=time.perf_counter() - te_p,
+                with _TRACER.span("serve/kv_export", uid=uid) as esp:
+                    req.kv_shipment = export_kv(self.eng, uid,
+                                                req.resume_prompt)
+                self._tspan(req, "kv_ship_encode", t0=esp.t0,
+                            dur_s=esp.dur_s,
                             tokens=req.kv_shipment.n_tokens)
                 self._count("serving/completed")
                 self._retire(req, RequestState.FINISHED, "prefill_done",
@@ -776,22 +795,21 @@ class LifecycleScheduler:
             req.state = RequestState.DECODE
             if req._resume_seed is not None:
                 # preemption resume: KV is rebuilt, the next decode seed is
-                # the spilled stream's last token — NOT a fresh argmax
-                # (which would re-derive the token it already produced)
+                # the spilled stream's last token, NOT a fresh argmax
                 seed = int(req._resume_seed)
                 req._resume_seed = None
-                self._tspan(req, "resume", t0=time.time(), dur_s=0.0,
+                self._tspan(req, "resume", t0=t0 + put_s, dur_s=0.0,
                             produced=len(req.produced))
             else:
-                seed = int(np.argmax(np.asarray(logits[row])))
+                seed = seeds[row]
                 req.produced.append(seed)
                 req.first_token_t = now
-                self._observe("serving/ttft_s", req.ttft_s())
-                store = get_trace_store()
-                if store is not None and req.trace is not None \
-                        and req.ttft_s() is not None:
-                    store.note_exemplar("ttft_s", req.ttft_s(),
-                                        req.trace.trace_id)
+                _TRACER.record("serve/first_token", req._admit_t,
+                               now - req._admit_t, uid=uid,
+                               prompt_tokens=len(req.prompt),
+                               chunks=req._chunks,
+                               preempted=req.preempt_count)
+                self._observe("serving/ttft_s", req.ttft_s(), req)
                 req._fire("tokens")
                 if self._finished_by(req, seed):
                     self._finish(req)
@@ -806,40 +824,22 @@ class LifecycleScheduler:
                 or req.remaining <= 0)
 
     def _finish(self, req: ServeRequest) -> None:
-        self._decodes.pop(req.uid, None)
         # the tail prompt page goes quiet forever now — commit it too
         # (allow_partial), so sub-page prefixes become reusable; full pages
         # were committed at prefill completion
         self.eng.commit_prefix(req.uid, req.prompt, allow_partial=True)
-        self.eng.flush([req.uid])
-        if self.drafter is not None:
-            self.drafter.flush(req.uid)
-        ksw = getattr(self.eng, "kv_swap", None)
-        if ksw is not None:
-            ksw.drop(req.uid)
-        req.state = RequestState.FINISHED
-        req.finish_reason = "eos" if (
-            self.eos_token_id is not None and req.produced
-            and req.produced[-1] == self.eos_token_id) else "length"
         req.finished_t = self.clock()
-        self._count("serving/completed")
-        self._observe("serving/tpot_s", req.tpot_s())
-        store = get_trace_store()
-        if store is not None and req.trace is not None \
-                and req.tpot_s() is not None:
-            store.note_exemplar("tpot_s", req.tpot_s(),
-                                req.trace.trace_id)
-        self._event("serving_finished", uid=req.uid,
-                    produced=len(req.produced), reason=req.finish_reason,
-                    trace=self._trace_id(req))
-        self._trace_finish(req)
-        req._fire("finished")
-        self._publish_gauges()
+        self._observe("serving/tpot_s", req.tpot_s(), req)
+        eos = self.eos_token_id is not None and req.produced \
+            and req.produced[-1] == self.eos_token_id
+        self._retire(req, RequestState.FINISHED, "eos" if eos else "length",
+                     "serving_finished", "serving/completed",
+                     holds_blocks=True)
 
-    def _run_decode_window(self) -> List[int]:
-        """One bounded fused decode window over up to max_seqs decoding
-        requests (round-robin rotated), with watchdog + NaN isolation at
-        drain."""
+    def _run_decode_window(self, sp) -> List[int]:
+        """One bounded fused decode window (``sp``: its ``serve/window`` span)
+        over up to max_seqs decoding requests (round-robin rotated), with
+        watchdog + NaN isolation at drain."""
         c = self.eng.config
         n = min(len(self._decodes), c.max_seqs, c.max_tokens)
         uids = []
@@ -863,25 +863,27 @@ class LifecycleScheduler:
             return []
         if self.drafter is not None and \
                 any(self._spec_k_for(self._reqs[u]) > 0 for u in uids):
+            sp.set(n_seqs=len(uids), steps=1, verify=True)
             return self._run_verify_window(uids, room)
         steps = min(self.window_steps,
                     min(self._reqs[u].remaining for u in uids),
                     min(room[u] for u in uids))
         if steps > 2:       # pow2 quantize: one compiled loop per window size
             steps = 1 << (steps.bit_length() - 1)
+        sp.set(n_seqs=len(uids), steps=steps)
         seeds = [self._decodes[u] for u in uids]
         window = self.eng.decode_batch_async(uids, seeds, steps)
         toks = window.tokens()
-        streams = [[int(t) for t in toks[:, col]]
-                   for col in range(len(uids))]
-        return self._apply_window_results(
-            uids, streams, set(window.nonfinite_uids()),
-            wall_s=window.duration_s, compiled=window.compiled)
+        with _TRACER.span("serve/window_apply"):
+            streams = [[int(t) for t in toks[:, col]]
+                       for col in range(len(uids))]
+            return self._apply_window_results(
+                uids, streams, set(window.nonfinite_uids()),
+                wall_s=window.duration_s, compiled=window.compiled)
 
     def _apply_window_results(self, uids: List[int],
                               streams: List[List[int]], poisoned: set,
-                              wall_s: Optional[float],
-                              compiled: bool,
+                              wall_s: float, compiled: bool,
                               span_kind: str = "decode_window",
                               span_wall_s: Optional[float] = None
                               ) -> List[int]:
@@ -893,26 +895,20 @@ class LifecycleScheduler:
         attributed elsewhere (verify windows: drafting has its own
         span)."""
         finished: List[int] = []
-        # goodput: the window wall is attributed ONCE (not per rider) —
-        # first-use windows are XLA compilation, drained windows are
-        # useful decode work (verify windows include their draft host
-        # time: speculative work that produced accepted tokens is compute)
-        if wall_s is not None:
-            record_goodput("compile" if compiled else "compute", wall_s)
-        # window span per rider — a first-use (compiled) window's wall is
-        # XLA compilation, so it is typed ``compile``, keeping the
-        # decode_window decomposition clean of compile pollution exactly
-        # like the roofline gauges
-        if wall_s is not None:
-            span_s = wall_s if span_wall_s is None else span_wall_s
-            t0w = time.time() - span_s
-            kind = "compile" if compiled else span_kind
-            for uid, stream in zip(uids, streams):
-                self._tspan(self._reqs[uid], kind, t0=t0w, dur_s=span_s,
-                            n_seqs=len(uids), tokens=len(stream),
-                            window=self.eng.decode_windows_dispatched)
-        if not compiled and wall_s is not None \
-                and wall_s > self.hang_deadline_s:
+        # goodput: the window wall is attributed ONCE (not per rider) — a
+        # first-use window is XLA compilation, a drained one useful decode
+        # (a verify window's draft time included: its tokens were accepted)
+        record_goodput("compile" if compiled else "compute", wall_s)
+        # window span per rider; a first-use window is typed ``compile``, so
+        # the decode_window decomposition stays clean like the roofline's
+        span_s = wall_s if span_wall_s is None else span_wall_s
+        t0 = time.perf_counter() - span_s
+        kind = "compile" if compiled else span_kind
+        for uid, stream in zip(uids, streams):
+            self._tspan(self._reqs[uid], kind, t0=t0, dur_s=span_s,
+                        n_seqs=len(uids), tokens=len(stream),
+                        window=self.eng.decode_windows_dispatched)
+        if not compiled and wall_s > self.hang_deadline_s:
             # post-hoc hang detection: the window drained, but took longer
             # than the deadline — a stuck DMA / pathological host stall.
             self.last_incident_t = self.clock()
@@ -921,14 +917,10 @@ class LifecycleScheduler:
             self._event("serving_window_hang", uids=list(uids),
                         duration_s=round(wall_s, 3),
                         deadline_s=self.hang_deadline_s,
-                        traces=[self._trace_id(self._reqs[u])
+                        traces=[trace_id_of(self._reqs[u].trace)
                                 for u in uids])
-            store = get_trace_store()
-            if store is not None:
-                for u in uids:
-                    if self._reqs[u].trace is not None:
-                        store.flag(self._reqs[u].trace.trace_id,
-                                   "window_hang")
+            for u in uids:
+                flag_trace(self._reqs[u].trace, "window_hang")
 
         if poisoned:
             self.last_incident_t = self.clock()
@@ -983,69 +975,78 @@ class LifecycleScheduler:
 
         Per stream the drafter proposes up to ``spec_k`` candidates —
         capped at ``remaining - 1`` and ``room - 1`` so the speculative
-        append can never outgrow the whole-lifetime block reservation or
-        the context cap (the admission invariant that live requests never
-        allocate KV mid-flight survives speculation: verify-window allocs
-        are always no-ops under a reservation), and at the engine's flat
-        token budget: the window packs ``sum(1 + k_i)`` tokens into one
-        ragged batch, so with every stream drafting the wide batch could
-        exceed ``max_tokens`` and fail the pack — the leftover budget
-        after the mandatory one-token-per-stream rows is dealt out in
-        rotation order instead (late streams draft less this window, and
-        the rotation moves the full allowance around).  Streams whose
-        drafter has nothing to say ride along with an empty draft (a
-        1-token verify is exactly one vanilla decode step).  Greedy
-        bit-exactness, watchdog/NaN isolation, eos handling and
-        preemption bookkeeping all mirror the fused-decode path."""
-        t_d0w, t_d0 = time.time(), time.perf_counter()
+        append never outgrows the whole-lifetime block reservation or the
+        context cap (live requests still never allocate KV mid-flight), and
+        at the engine's flat token budget: the window packs ``sum(1 + k_i)``
+        tokens into one ragged batch, so what ``max_tokens`` leaves after
+        the one-token-per-stream rows is dealt out in rotation order (late
+        streams draft less this window; the rotation moves the allowance
+        around).  A stream with nothing to draft rides along (a 1-token
+        verify is one vanilla decode step).  Greedy bit-exactness, watchdog/
+        NaN isolation, eos handling and preemption mirror the fused path."""
         budget = self.eng.config.max_tokens - len(uids)   # draft allowance
         seeds, drafts = [], []
-        for u in uids:
-            req = self._reqs[u]
-            cap = max(0, min(self._spec_k_for(req), req.remaining - 1,
-                             room[u] - 1, budget))
-            d = []
-            if cap > 0:
-                d = [int(t) for t in self.drafter.draft(
-                    u, req.prompt + req.produced, cap)][:cap]
-            budget -= len(d)
-            drafts.append(d)
-            seeds.append(self._decodes[u])
-        draft_s = time.perf_counter() - t_d0
+        with _TRACER.span("serve/draft") as dsp:
+            for u in uids:
+                req = self._reqs[u]
+                cap = max(0, min(self._spec_k_for(req), req.remaining - 1,
+                                 room[u] - 1, budget))
+                d = []
+                if cap > 0:
+                    d = [int(t) for t in self.drafter.draft(
+                        u, req.prompt + req.produced, cap)][:cap]
+                budget -= len(d)
+                drafts.append(d)
+                seeds.append(self._decodes[u])
+        draft_s = dsp.dur_s
         for u, d in zip(uids, drafts):
-            self._tspan(self._reqs[u], "draft", t0=t_d0w, dur_s=draft_s,
+            self._tspan(self._reqs[u], "draft", t0=dsp.t0, dur_s=draft_s,
                         k=len(d))
         result = self.eng.verify_decode(uids, seeds, drafts,
                                         draft_wall_s=draft_s)
-        self._count("serving/spec_windows")
-        if result.drafted:
-            self._count("serving/spec_drafted", result.drafted)
-        if result.accepted_draft:
-            self._count("serving/spec_accepted", result.accepted_draft)
-        return self._apply_window_results(
-            uids, result.accepted, set(result.nonfinite_uids),
-            wall_s=result.duration_s + draft_s, compiled=result.compiled,
-            span_kind="verify", span_wall_s=result.duration_s)
+        with _TRACER.span("serve/window_apply"):
+            self._count("serving/spec_windows")
+            if result.drafted:
+                self._count("serving/spec_drafted", result.drafted)
+            if result.accepted_draft:
+                self._count("serving/spec_accepted", result.accepted_draft)
+            return self._apply_window_results(
+                uids, result.accepted, set(result.nonfinite_uids),
+                wall_s=result.duration_s + draft_s,
+                compiled=result.compiled,
+                span_kind="verify", span_wall_s=result.duration_s)
 
     def step(self) -> List[int]:
         """One scheduler iteration; returns uids that reached a terminal
         state.  Lifecycle passes (cancel, expiry) run FIRST, so no request
         outlives its deadline by more than one bounded window."""
-        with self._lock:
-            done = self._process_cancellations()
-            done += self._process_expiries()
+        with self._lock, _TRACER.span(
+                "serve/step", waiting=len(self._waiting),
+                prefilling=len(self._prefilling),
+                decoding=len(self._decodes)) as sp:
+            with _TRACER.span("serve/lifecycle"):
+                done = self._process_cancellations()
+                done += self._process_expiries()
             # prefill/admission first — finishing prefills frees the decode
             # path to run fused windows over the full live set.  A BLOCKED
-            # queue head (reservation failed, no eligible preemption
-            # victim) yields an empty batch: fall through to the decode
-            # window so the live set keeps draining toward the capacity
-            # the head is waiting for.
-            batch = self._build_prefill_batch() \
-                if (self._prefilling or self._waiting) else []
-            if batch:
-                done += self._run_prefill(batch)
-            elif self._decodes:
-                done += self._run_decode_window()
+            # queue head (no reservation, no preemption victim) yields an
+            # empty batch: the decode window runs, draining the live set
+            # toward the capacity the head is waiting for.
+            batch = []
+            if self._prefilling or self._waiting:
+                with _TRACER.span("serve/admit") as asp:
+                    batch = self._build_prefill_batch(asp)
+            kind = "prefill" if batch else "decode" if self._decodes else "idle"
+            sp.set(kind=kind)                       # what the step DID
+            try:
+                if batch:
+                    done += self._run_prefill(batch)
+                elif self._decodes:
+                    with _TRACER.span("serve/window") as wsp:
+                        done += self._run_decode_window(wsp)
+            except Exception as e:  # said, then the caller's: requests stay
+                self._say_why(None, "error", kind, 0, error=type(e).__name__)
+                raise
             return done
 
     def run_until_idle(self, max_iters: int = 10_000) -> None:
@@ -1144,31 +1145,28 @@ class LifecycleScheduler:
 
     def _count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if tel is not None:
             tel.metrics.counter(name).inc(n)
 
-    def _observe(self, name: str, value: Optional[float]) -> None:
+    def _observe(self, name: str, value, req: ServeRequest) -> None:
+        """A latency into its histogram and the store's exemplar slots."""
         if value is None:
             return
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if tel is not None:
             tel.metrics.histogram(name).observe(float(value))
+        store = get_trace_store()
+        if store is not None and req.trace is not None:
+            store.note_exemplar(name.rsplit("/", 1)[1], value,
+                                req.trace.trace_id)
 
     def _event(self, kind: str, **fields) -> None:
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if tel is not None:
             tel.event(kind, **fields)
 
     def _publish_gauges(self) -> None:
-        from ...telemetry import get_telemetry
-
         tel = get_telemetry()
         if tel is None:
             return

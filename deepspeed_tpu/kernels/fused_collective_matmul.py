@@ -363,6 +363,7 @@ def _rmsnorm_matmul_pallas(eps, x2, scale, w, bm, bn):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, F), out_dtype),
         interpret=_interpret(),
+        name="rmsnorm_matmul",
     )(x2, scale, w)
 
 

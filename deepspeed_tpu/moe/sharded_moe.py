@@ -365,29 +365,40 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
     routing decisions).
     """
     assert dispatch_impl in ("sparse", "dense"), dispatch_impl
-    logits_r = tokens.astype(jnp.float32) @ lp["router"]["kernel"].astype(jnp.float32)
+    sparse = dispatch_impl == "sparse"
     dtype = lp["gate_proj"]["kernel"].dtype
-    if dispatch_impl == "sparse":
-        gate_out = topkgating_sparse(logits_r, k=k,
-                                     capacity_factor=capacity_factor, rng=rng,
-                                     valid=valid,
-                                     num_experts_logical=num_experts_logical)
-        dispatched = dispatch_sparse(gate_out.slot, tokens,
-                                     logits_r.shape[1], gate_out.capacity, dtype)
-    else:
-        assert valid is None, "ragged validity masks need dispatch_impl='sparse'"
-        gate_out = topkgating(logits_r, k=k, capacity_factor=capacity_factor,
-                              rng=rng,
-                              num_experts_logical=num_experts_logical)
-        dispatched = dispatch_to_experts(gate_out.dispatch, tokens, dtype)
-    act = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched,
-                                 lp["gate_proj"]["kernel"]))
-    up = jnp.einsum("ecd,edf->ecf", dispatched, lp["up_proj"]["kernel"])
-    eo = jnp.einsum("ecf,efd->ecd", act * up, lp["down_proj"]["kernel"])
-    if dispatch_impl == "sparse":
-        out = combine_sparse(gate_out.slot, gate_out.gate_val, eo, dtype)
-    else:
-        out = combine_from_experts(gate_out.combine, eo, dtype)
+    # one name scope per phase: device time (and every collective GSPMD puts
+    # in) shows up under moe/route, moe/dispatch, moe/experts, moe/combine
+    with jax.named_scope("moe/route"):
+        logits_r = tokens.astype(jnp.float32) \
+            @ lp["router"]["kernel"].astype(jnp.float32)
+        if sparse:
+            gate_out = topkgating_sparse(
+                logits_r, k=k, capacity_factor=capacity_factor, rng=rng,
+                valid=valid, num_experts_logical=num_experts_logical)
+        else:
+            assert valid is None, \
+                "ragged validity masks need dispatch_impl='sparse'"
+            gate_out = topkgating(
+                logits_r, k=k, capacity_factor=capacity_factor, rng=rng,
+                num_experts_logical=num_experts_logical)
+    with jax.named_scope("moe/dispatch"):
+        if sparse:
+            dispatched = dispatch_sparse(gate_out.slot, tokens,
+                                         logits_r.shape[1],
+                                         gate_out.capacity, dtype)
+        else:
+            dispatched = dispatch_to_experts(gate_out.dispatch, tokens, dtype)
+    with jax.named_scope("moe/experts"):
+        act = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched,
+                                     lp["gate_proj"]["kernel"]))
+        up = jnp.einsum("ecd,edf->ecf", dispatched, lp["up_proj"]["kernel"])
+        eo = jnp.einsum("ecf,efd->ecd", act * up, lp["down_proj"]["kernel"])
+    with jax.named_scope("moe/combine"):
+        if sparse:
+            out = combine_sparse(gate_out.slot, gate_out.gate_val, eo, dtype)
+        else:
+            out = combine_from_experts(gate_out.combine, eo, dtype)
     return out, gate_out.l_aux
 
 

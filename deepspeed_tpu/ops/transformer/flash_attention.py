@@ -161,6 +161,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qp, kp, vp)
     return out[:, :, :S], lse[:, :, :S, 0]
 
@@ -278,6 +279,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     # dkv: kv-blocks outer, q-blocks inner; below-diagonal q blocks are the
@@ -298,6 +300,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
     return dq[:, :, :S], dk[:, :, :S], dv[:, :, :S]
 

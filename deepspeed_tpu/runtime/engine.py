@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import os
 import time
+import weakref
 from functools import partial
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -38,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..accelerator import get_accelerator
 from ..telemetry import emit_event
 from ..telemetry.goodput import get_goodput_ledger, record_goodput
-from ..telemetry.trace import NULL_SPAN
+from ..telemetry.trace import NULL_SPAN, get_tracer
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from .config import DeepSpeedConfig
@@ -328,6 +329,7 @@ class DeepSpeedEngine:
         self._step_cost: Optional[Tuple[Any, Dict[str, float]]] = None
         self._step_jaxpr: Optional[Tuple[Any, Any]] = None  # (shape key, jaxpr)
         self._last_batch_struct = None
+        self._last_batch_shardings = None
         self._roofline_spec = None
         pcfg = getattr(config, "profiling", None)
         self._profiling_on = bool(pcfg is not None and (
@@ -669,6 +671,25 @@ class DeepSpeedEngine:
         self._step_cost = (key, stats)
         return stats
 
+    def compiled_step_text(self) -> Optional[str]:
+        """The compiled train step as the device runs it (``as_text()`` of
+        the same lowering, so the executable comes from XLA's cache): what
+        ``profiling/xprof_parse.parse_hlo_scopes`` reads instruction scopes
+        from.  None before the first ``train_batch``."""
+        if "train_batch" not in self._compiled or \
+                self._last_batch_struct is None:
+            return None
+        placed = lambda x, sh: jax.ShapeDtypeStruct(  # noqa: E731
+            x.shape, x.dtype, sharding=sh)
+        state = jax.tree.map(
+            lambda x: placed(x, getattr(x, "sharding", None)), self.state)
+        leaves, treedef = jax.tree.flatten(self._last_batch_struct)
+        batch = jax.tree.unflatten(treedef, [
+            placed(x, sh) for x, sh in zip(leaves,
+                                           self._last_batch_shardings)])
+        return self._compiled["train_batch"].lower(
+            state, batch).compile().as_text()
+
     def _publish_roofline(self, step: int) -> None:
         """Roofline/MFU gauges for the current steady state (``roofline/*``
         in the metrics registry; surfaced by ``bin/dstpu-telemetry``)."""
@@ -721,7 +742,9 @@ class DeepSpeedEngine:
         """
 
         def scaled_loss(p32):
-            p = jax.tree.map(lambda x: x.astype(self.compute_dtype), p32)
+            # the masters' cast: under ZeRO-3 what follows it is the gather
+            with jax.named_scope("zero/gather_params"):
+                p = jax.tree.map(lambda x: x.astype(self.compute_dtype), p32)
             out = self.loss_fn(p, batch, rng)
             loss = out[0] if isinstance(out, tuple) else out
             return self.loss_scaler.scale_loss(loss.astype(jnp.float32), scaler_state), loss
@@ -736,9 +759,10 @@ class DeepSpeedEngine:
         """Apply ZeRO-2/3 grad sharding (XLA lowers the psum into reduce-scatter)."""
         if self.zero_stage >= 2:
             specs = self.plan.grad_specs(grads)
-            grads = jax.tree.map(
-                lambda g, s: jax.lax.with_sharding_constraint(g, NamedSharding(self.mesh, s)),
-                grads, specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
+            with jax.named_scope("zero/reduce_grads"):
+                grads = jax.tree.map(
+                    lambda g, s: jax.lax.with_sharding_constraint(g, NamedSharding(self.mesh, s)),
+                    grads, specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
         return grads
 
     def _apply_update(self, state: EngineState, grads, grad_norm_scale=None,
@@ -748,42 +772,46 @@ class DeepSpeedEngine:
         ``unscale=False`` when the caller already unscaled (the explicit-comm
         path unscales before the wire so LoCo residuals live in true units).
         """
-        if unscale:
-            grads = self.loss_scaler.unscale_grads(grads, state.scaler)
-        if grad_norm_scale is not None:
-            grads = jax.tree.map(lambda g: g * grad_norm_scale, grads)
-        # prescale_gradients / gradient_predivide_factor (reference
-        # engine.py:2501-2508): in DeepSpeed these only reorder the divide
-        # around the allreduce and always net out to the exact DP mean.
-        # Sharded autodiff already yields that exact mean, so both knobs are
-        # numerical no-ops here — applying 1/f permanently would silently
-        # shrink the effective LR for any ported config.
-        overflow = self.loss_scaler.check_overflow(grads) \
-            if self.loss_scaler.dynamic else jnp.zeros((), bool)
+        # one scope for everything after the backward pass, so a profile
+        # can tell the update's device time from the model's
+        with jax.named_scope("optimizer"):
+            if unscale:
+                grads = self.loss_scaler.unscale_grads(grads, state.scaler)
+            if grad_norm_scale is not None:
+                grads = jax.tree.map(lambda g: g * grad_norm_scale, grads)
+            # prescale_gradients / gradient_predivide_factor (reference
+            # engine.py:2501-2508): in DeepSpeed these only reorder the divide
+            # around the allreduce and always net out to the exact DP mean.
+            # Sharded autodiff already yields that exact mean, so both knobs are
+            # numerical no-ops here — applying 1/f permanently would silently
+            # shrink the effective LR for any ported config.
+            overflow = self.loss_scaler.check_overflow(grads) \
+                if self.loss_scaler.dynamic else jnp.zeros((), bool)
 
-        clip = self.config.gradient_clipping
-        if clip and clip > 0:
-            gnorm = _global_norm(grads)
-            scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        safe_grads = jax.tree.map(lambda g: jnp.where(jnp.isfinite(g), g, 0.0), grads)
-        updates, new_opt = self.optimizer.update(safe_grads, state.opt_state, state.params)
-        import optax
+            clip = self.config.gradient_clipping
+            if clip and clip > 0:
+                with jax.named_scope("clip"):
+                    gnorm = _global_norm(grads)
+                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * scale, grads)
+            safe_grads = jax.tree.map(lambda g: jnp.where(jnp.isfinite(g), g, 0.0), grads)
+            updates, new_opt = self.optimizer.update(safe_grads, state.opt_state, state.params)
+            import optax
 
-        new_params = optax.apply_updates(state.params, updates)
-        # On overflow: keep old params/opt state, bump skipped counter.
-        keep = lambda new, old: jax.tree.map(
-            lambda n, o: jnp.where(overflow, o, n), new, old)
-        new_params = keep(new_params, state.params)
-        new_opt = keep(new_opt, state.opt_state)
-        new_scaler = self.loss_scaler.update(state.scaler, overflow)
-        return state.replace(
-            params=new_params,
-            opt_state=new_opt,
-            scaler=new_scaler,
-            global_step=state.global_step + jnp.where(overflow, 0, 1),
-            skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
-        )
+            new_params = optax.apply_updates(state.params, updates)
+            # On overflow: keep old params/opt state, bump skipped counter.
+            keep = lambda new, old: jax.tree.map(
+                lambda n, o: jnp.where(overflow, o, n), new, old)
+            new_params = keep(new_params, state.params)
+            new_opt = keep(new_opt, state.opt_state)
+            new_scaler = self.loss_scaler.update(state.scaler, overflow)
+            return state.replace(
+                params=new_params,
+                opt_state=new_opt,
+                scaler=new_scaler,
+                global_step=state.global_step + jnp.where(overflow, 0, 1),
+                skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
+            )
 
     # ------------------------------------------------------------------ #
     # Fused path
@@ -934,6 +962,15 @@ class DeepSpeedEngine:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
         if "train_batch" not in self._compiled:
             self._compiled["train_batch"] = self._build_train_batch_fn()
+        if self._last_batch_shardings is None:
+            # a profile of this step can be read by name scope: the text
+            # is made (from the compile cache) only if somebody asks
+            from ..profiling.xprof_parse import register_step_text
+
+            self._last_batch_shardings = [
+                getattr(x, "sharding", None) for x in jax.tree.leaves(batch)]
+            ref = weakref.WeakMethod(self.compiled_step_text)
+            register_step_text("train_batch", lambda: ref() and ref()())
         if self._graph_lint_mode and not self._graph_lint_done:
             self._run_graph_lint()
         self._heartbeat("train_batch")
@@ -966,16 +1003,17 @@ class DeepSpeedEngine:
         # skips warmup steps, so its last_step_time can't cover step 1 —
         # the compile step is exactly the one the ledger must not lose)
         self._goodput_step_t0 = time.perf_counter()
-        tel = self.telemetry
-        step_span = tel.tracer.step_span(
-            self._host_step_calls, name="engine/train_batch") \
-            if tel is not None else contextlib.nullcontext()
+        # the step's boundaries go on the process-global tracer with or
+        # without a hub: a profiler session then groups the device
+        # operations by step and shows the host beside them
+        tracer = get_tracer()
         self.tput_timer.start()
         if self.config.wall_clock_breakdown:
             self._timers("step").start()
-        with step_span:
+        with tracer.step_span(self._host_step_calls,
+                              name="engine/train_batch"):
             with ctx:
-                with self._span("engine/dispatch") as sp:
+                with tracer.span("engine/dispatch") as sp:
                     self.state, loss = self._compiled["train_batch"](self.state, batch)
                     self._fence_span(sp, loss)
                 if trace_now:
@@ -1001,7 +1039,8 @@ class DeepSpeedEngine:
                 f"debug.nan_check: non-finite loss {float(loss)} at step "
                 f"{self.global_steps} (note: fp16 dynamic loss scaling "
                 f"intentionally overflows — use nan_check with bf16)")
-        self._post_step_logging(loss, batch)
+        with tracer.span("engine/post_step"):
+            self._post_step_logging(loss, batch)
         return loss
 
     def _post_step_logging(self, loss, batch):

@@ -18,13 +18,14 @@ from .hub import (Telemetry, emit_event, get_telemetry, set_telemetry, span,
 from .memory import (MEM_BUCKETS, MemoryLedger, MemorySampler,
                      get_memory_ledger, install_memory_ledger, rollup_memory)
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import NULL_SPAN, SpanRecord, Tracer
+from .trace import NULL_SPAN, SpanRecord, Tracer, get_tracer
 
 __all__ = [
     "Counter", "EventLog", "GOODPUT_CATEGORIES", "Gauge", "GoodputLedger",
     "Histogram", "MEM_BUCKETS", "MemoryLedger", "MemorySampler",
     "MetricsRegistry", "NULL_SPAN", "SpanRecord", "Telemetry", "Tracer",
     "emit_event", "get_goodput_ledger", "get_memory_ledger", "get_telemetry",
+    "get_tracer",
     "goodput_residual", "install_goodput_ledger", "install_memory_ledger",
     "read_event_segments", "read_jsonl",
     "record_goodput", "rollup_goodput", "rollup_memory", "set_telemetry",

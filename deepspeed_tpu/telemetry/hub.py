@@ -22,7 +22,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from .events import EventLog
 from .memory import MemorySampler
 from .metrics import MetricsRegistry
-from .trace import NULL_SPAN, Tracer
+from .trace import DEFAULT_MAX_SPANS, NULL_SPAN, get_tracer
 
 EVENTS_FILE = "events.jsonl"
 TRACE_FILE = "trace.json"
@@ -41,8 +41,13 @@ class Telemetry:
         self.prometheus = bool(prometheus)
         #: fence spans with block_until_ready on the value handed to span(sync=)
         self.fence = bool(fence)
-        self.tracer = Tracer(enabled=True, max_spans=max_spans,
-                             jax_annotations=jax_annotations)
+        # the process-global tracer, which the serve and train paths record
+        # on with or without a hub: the hub sizes its ring, starts it empty
+        # (what came before is not this run's) and exports it
+        self.tracer = get_tracer()
+        self.tracer.configure(max_spans=max_spans,
+                              jax_annotations=jax_annotations,
+                              drop_recorded=True)
         self.metrics = MetricsRegistry(
             histogram_max_samples=histogram_max_samples)
         self.events = EventLog(
@@ -52,7 +57,8 @@ class Telemetry:
         self.memory = MemorySampler(self.metrics, self.events,
                                     interval=memory_interval)
         self._flush_lock = threading.Lock()
-        self._spans_flushed = 0
+        # spans recorded before this hub existed are not its run's
+        self._spans_flushed = self.tracer.total_recorded
         self._closed = False
         # Run delimiter: events.jsonl is append-mode, so re-using an
         # output_dir accumulates runs — this marker lets the summarizer
@@ -146,7 +152,7 @@ class Telemetry:
             # the ring buffer length — ring eviction must not re-export old
             # spans or silently skip new ones.
             records, total = self.tracer.snapshot()
-            unseen = total - self._spans_flushed
+            unseen = max(total - self._spans_flushed, 0)
             missed = max(unseen - len(records), 0)
             if missed:   # evicted before this flush could export them
                 self.events.emit("spans_dropped", count=missed,
@@ -177,6 +183,9 @@ class Telemetry:
         out = self.flush()
         self.events.close()
         self._closed = True
+        # the tracer outlives the hub: back to what nobody configured
+        self.tracer.configure(max_spans=DEFAULT_MAX_SPANS,
+                              jax_annotations=True)
         return out
 
 

@@ -261,7 +261,7 @@ SERVING_LATENCY_HISTOGRAMS = ("serving/ttft_s", "serving/tpot_s")
 
 def serving_summary(metrics: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     """The ``serving/*`` series: decode-HBM-roofline gauges (published per
-    drained decode window by ``InferenceEngineV2._record_decode_roofline``)
+    drained decode window by ``InferenceEngineV2._account_decode_window``)
     with the per-kernel %-of-peak breakdown, plus the request-lifecycle
     layer — shed/preempt/cancel/expiry counters and TTFT/TPOT percentiles
     (published by ``LifecycleScheduler``)."""

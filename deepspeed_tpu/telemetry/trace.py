@@ -15,19 +15,33 @@ https://ui.perfetto.dev with no conversion step.
 Disabled cost: a disabled tracer hands back one shared no-op span object —
 no allocation, no locking — so instrumentation can stay in the hot path
 unconditionally.
+
+One process-global tracer always exists (:func:`get_tracer`).  The layer
+boundaries of the served and the trained path (``serve/*``, ``engine/*``)
+record on it whether or not a ``Telemetry`` hub is installed: two clock
+reads, one ``TraceAnnotation`` (a flag check while no profiler session
+runs, a host event on the device trace's clock while one does) and one
+append to the bounded ring — no file, socket, thread or sync.  A hub adopts
+this tracer and adds its exporters; a profiler session sees the spans in
+its xplane.
 """
 from __future__ import annotations
 
 import collections
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+#: ring capacity of a tracer nobody configured (the hub's ``max_spans``
+#: default is the same number)
+DEFAULT_MAX_SPANS = 100_000
 
 
 class _NullSpan:
     """Shared do-nothing span for disabled telemetry (zero per-call cost)."""
 
     __slots__ = ()
+    t0 = dur_s = 0.0
 
     def __enter__(self):
         return self
@@ -72,9 +86,27 @@ class SpanRecord:
         return d
 
 
+_ANNOTATIONS: Optional[tuple] = None
+
+
+def _annotations() -> tuple:
+    """(TraceAnnotation, StepTraceAnnotation), looked up once; (None, None)
+    where jax cannot be imported."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        try:
+            import jax
+
+            _ANNOTATIONS = (jax.profiler.TraceAnnotation,
+                            jax.profiler.StepTraceAnnotation)
+        except Exception:
+            _ANNOTATIONS = (None, None)
+    return _ANNOTATIONS
+
+
 class _Span:
     __slots__ = ("_tracer", "name", "_attrs", "_sync", "_t0", "_annotation",
-                 "_step_num")
+                 "_step_num", "dur_s", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, sync: Any,
                  attrs: Optional[Dict[str, Any]], step_num: Optional[int] = None):
@@ -85,6 +117,13 @@ class _Span:
         self._t0 = 0.0
         self._annotation = None
         self._step_num = step_num
+        self.dur_s = 0.0           # set at exit
+
+    @property
+    def t0(self) -> float:
+        """``time.perf_counter()`` at entry: with ``dur_s`` (after exit) a
+        second sink can be fed from the same two clock reads."""
+        return self._t0
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes after entry (e.g. values known only mid-span)."""
@@ -101,20 +140,20 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         tracer = self._tracer
-        stack = tracer._stack()
+        stack = self._stack = tracer._stack()
         stack.append(self)
         if tracer.jax_annotations:
-            try:
-                import jax
-
-                if self._step_num is not None:
-                    self._annotation = jax.profiler.StepTraceAnnotation(
-                        self.name, step_num=self._step_num)
-                else:
-                    self._annotation = jax.profiler.TraceAnnotation(self.name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+            trace_ann, step_ann = _annotations()
+            if trace_ann is not None:
+                try:
+                    if self._step_num is not None:
+                        self._annotation = step_ann(
+                            self.name, step_num=self._step_num)
+                    else:
+                        self._annotation = trace_ann(self.name)
+                    self._annotation.__enter__()
+                except Exception:
+                    self._annotation = None
         self._t0 = time.perf_counter()
         return self
 
@@ -136,7 +175,7 @@ class _Span:
                 except Exception:
                     pass
         finally:
-            stack = tracer._stack()
+            stack = self._stack
             depth = len(stack) - 1
             if stack and stack[-1] is self:
                 stack.pop()
@@ -145,15 +184,11 @@ class _Span:
                     if stack.pop() is self:
                         break
             parent = stack[-1].name if stack else None
+            self.dur_s = end - self._t0
             tracer._record(SpanRecord(
-                name=self.name,
-                start_s=self._t0 - tracer._epoch,
-                dur_s=end - self._t0,
-                depth=max(depth, 0),
-                parent=parent,
-                tid=threading.get_ident(),
-                attrs=self._attrs,
-                error=exc_type.__name__ if exc_type is not None else None))
+                self.name, self._t0 - tracer._epoch, self.dur_s,
+                max(depth, 0), parent, threading.get_ident(), self._attrs,
+                exc_type.__name__ if exc_type is not None else None))
         return False  # never swallow the exception
 
 
@@ -168,7 +203,8 @@ class Tracer:
     jax_annotations: mirror spans into ``jax.profiler.TraceAnnotation``.
     """
 
-    def __init__(self, enabled: bool = True, max_spans: int = 100_000,
+    def __init__(self, enabled: bool = True,
+                 max_spans: int = DEFAULT_MAX_SPANS,
                  jax_annotations: bool = True):
         self.enabled = enabled
         self.max_spans = max(int(max_spans), 1)
@@ -183,6 +219,34 @@ class Tracer:
         self._tls = threading.local()
 
     # ---------------------------------------------------------------- #
+    @property
+    def epoch(self) -> float:
+        """``time.perf_counter()`` at ``start_s`` = 0: a record's start on
+        the host's clock is ``epoch + start_s``."""
+        return self._epoch
+
+    def wall(self, t: float) -> float:
+        """Unix seconds of ``t``, a ``perf_counter``/``monotonic`` reading
+        (spans from several processes merge on that clock)."""
+        return self._epoch_unix + (t - self._epoch)
+
+    def configure(self, max_spans: Optional[int] = None,
+                  jax_annotations: Optional[bool] = None,
+                  drop_recorded: bool = False) -> None:
+        """Resize the ring (the newest spans are kept, or none with
+        ``drop_recorded``) and/or switch the profiler mirror;
+        ``total_recorded`` stays monotonic."""
+        with self._lock:
+            if max_spans is not None:
+                self.max_spans = max(int(max_spans), 1)
+            if drop_recorded or self._spans.maxlen != self.max_spans:
+                self._spans = collections.deque(
+                    () if drop_recorded else self._spans,
+                    maxlen=self.max_spans)
+                self.dropped = 0
+            if jax_annotations is not None:
+                self.jax_annotations = bool(jax_annotations)
+
     def _stack(self) -> list:
         stack = getattr(self._tls, "stack", None)
         if stack is None:
@@ -215,6 +279,19 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, sync, {"step": int(step_num)},
                      step_num=int(step_num))
+
+    def record(self, name: str, start: float, dur_s: float,
+               **attrs) -> None:
+        """A span measured elsewhere (``start`` on the ``perf_counter`` /
+        ``monotonic`` clock): the ring only, no profiler event — it is
+        already over.  Its parent is whatever span is open on this thread."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        self._record(SpanRecord(
+            name=name, start_s=start - self._epoch, dur_s=dur_s,
+            depth=len(stack), parent=stack[-1].name if stack else None,
+            tid=threading.get_ident(), attrs=attrs or None, error=None))
 
     def current_span(self) -> Optional[str]:
         stack = self._stack()
@@ -275,3 +352,32 @@ class Tracer:
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         atomic_write_text(path, json.dumps(self.to_chrome_trace()))
         return path
+
+
+def own_times(records: Iterable[SpanRecord]) -> Dict[str, float]:
+    """Seconds by span name less the time of the spans directly inside
+    (same thread, one level deeper, within the interval)."""
+    out: Dict[str, float] = {}
+    open_: Dict[int, List[SpanRecord]] = {}
+    for rec in sorted(records, key=lambda r: (r.tid, r.start_s, -r.dur_s)):
+        out[rec.name] = out.get(rec.name, 0.0) + rec.dur_s
+        stack = open_.setdefault(rec.tid, [])
+        while stack and (stack[-1].depth >= rec.depth or stack[-1].start_s
+                         + stack[-1].dur_s < rec.start_s + rec.dur_s):
+            stack.pop()
+        if stack and stack[-1].depth == rec.depth - 1:
+            out[stack[-1].name] -= rec.dur_s
+        stack.append(rec)
+    return out
+
+
+# --------------------------------------------------------------------- #
+# The process-global tracer
+# --------------------------------------------------------------------- #
+_TRACER = Tracer()
+
+
+def get_tracer() -> Tracer:
+    """The tracer every layer boundary records on; a ``Telemetry`` hub
+    adopts it rather than building its own."""
+    return _TRACER

@@ -109,3 +109,56 @@ class TestDiscoveryAndFormats:
         assert rep["files"] == []
         assert rep["top_ops"] == []
         assert "no duration events" in "\n".join(format_device_table(rep))
+
+
+class TestOverlappingLanes:
+    """A TPU device process has a "Steps", an "XLA Modules" and an "XLA Ops"
+    lane over the same time, and a ``while`` on the ops lane covers its
+    body's operations: device time is a union, an operation's time its own."""
+
+    def _write(self, tmp_path, lanes=("Steps", "XLA Modules", "XLA Ops")):
+        events = [
+            {"ph": "M", "pid": 1, "name": "process_name",
+             "args": {"name": "/device:TPU:0"}},
+            {"ph": "M", "pid": 2, "name": "process_name",
+             "args": {"name": "/host:CPU"}},
+        ]
+        for tid, lane in enumerate(lanes, start=10):
+            events.append({"ph": "M", "pid": 1, "tid": tid,
+                           "name": "thread_name", "args": {"name": lane}})
+        tid = {lane: t for t, lane in enumerate(lanes, start=10)}
+        X = lambda lane, name, ts, dur: {  # noqa: E731
+            "ph": "X", "pid": 1, "tid": tid[lane], "ts": ts, "dur": dur,
+            "name": name}
+        if "Steps" in tid:
+            events.append(X("Steps", "3", 0, 10000))
+        if "XLA Modules" in tid:
+            events.append(X("XLA Modules", "jit_step_fn(1)", 0, 10000))
+        ops = lanes[-1]
+        events += [X(ops, "while.1", 0, 6000),          # covers its body
+                   X(ops, "fusion.1", 0, 2500), X(ops, "fusion.1", 3000, 2500),
+                   X(ops, "all-reduce.7", 6000, 1500),
+                   X(ops, "fusion.2", 8000, 2000)]       # idle 7500-8000
+        p = tmp_path / "lanes.trace.json"
+        p.write_text(json.dumps({"traceEvents": events}))
+        return str(p)
+
+    def test_device_time_is_the_ops_lane_union(self, tmp_path):
+        rep = attribute_device_time(self._write(tmp_path))
+        assert rep["device_time_s"] == pytest.approx(9500e-6)   # not 29500
+        assert rep["categories"]["communication"] == pytest.approx(1500e-6)
+        assert rep["categories"]["compute"] == pytest.approx(8000e-6)
+
+    def test_an_operation_keeps_its_own_time(self, tmp_path):
+        rep = attribute_device_time(self._write(tmp_path))
+        by_op = {r["op"]: r for r in rep["top_ops"]}
+        assert by_op["fusion.1"]["total_s"] == pytest.approx(5000e-6)
+        assert by_op["while.1"]["total_s"] == pytest.approx(1000e-6)
+        assert "3" not in by_op and "jit_step_fn(1)" not in by_op
+        assert sum(r["total_s"] for r in rep["top_ops"]) \
+            == pytest.approx(rep["device_time_s"])
+
+    def test_without_an_ops_lane_lanes_are_united(self, tmp_path):
+        rep = attribute_device_time(
+            self._write(tmp_path, lanes=("Steps", "TensorCore")))
+        assert rep["device_time_s"] == pytest.approx(10000e-6)  # not 19500
