@@ -1,26 +1,59 @@
-"""Mixture-of-Experts layer with expert parallelism.
+"""Mixture-of-Experts layer: gating, dispatch, stacked expert FFN, combine.
 
 Reference analogue: ``deepspeed/moe/sharded_moe.py`` — top1/top2/topk gating
 (:183,:290,:374), ``MOELayer`` einsum dispatch → all-to-all → experts →
 all-to-all → combine (:533,:586), capacity/drop logic, load-balance aux loss.
 
-TPU-native formulation (GShard-style): gating produces dense one-hot
-dispatch/combine tensors [S, E, C]; the dispatch/collect are einsums over
-stacked expert weights [E, ...] sharded on the "expert" mesh axis, so XLA
-lowers the token exchange to an all-to-all over ICI — no hand-written NCCL
-all_to_all_single as in the reference (:96 _AllToAll).  Shapes are static
-(capacity padding), which keeps everything jit-compatible.
+Gating produces either dense one-hot dispatch/combine tensors [S, E, C] (the
+GShard einsums, kept as the numerics oracle) or flat slot ids (scatter-add /
+gather, linear in tokens); shapes are static (capacity padding), so
+everything is jit-compatible.  :func:`moe_mlp_block` routes the WHOLE batch
+as one group on every mesh — one capacity, slots filled in token order, the
+balance term over all tokens — and what it lowers to depends on the mesh it
+observes at trace time:
+
+  * no mesh, one device, or inside a region that is manual already (the
+    explicit-comm step, manual over the batch axes): plain local code on the
+    tokens the call sees, no collective of its own;
+  * a mesh whose devices all lie on the batch axes ``data_outer``/``data``
+    (G shards; the ZeRO cells): one ``shard_map`` over the whole mesh.  Each
+    shard routes its own tokens into their GLOBAL slots (an all-gather of
+    G×E counts gives the offsets), the shards' dispatch buffers are
+    reduce-scattered so that each keeps C/G slots of every expert, the
+    expert matmuls run on those, and the outputs are all-gathered for a
+    local combine: two collectives of the [E, C, D] buffer a pass.  The
+    expert weights enter replicated, so under ZeRO-3 GSPMD all-gathers each
+    bf16 weight at the region's boundary and reduce-scatters its gradient
+    on the way back, like every other parameter.  (Capacity per SHARD, the
+    reference's ``TopKGate`` semantics, would save the two buffer
+    collectives but drops pairs the global capacity keeps: a sequence's
+    tokens lean to the same few experts, so a shard's fullest expert runs
+    over 2 × its mean long before the batch's does.  The same layout asked
+    of GSPMD by sharding constraints alone costs an all-reduce of the whole
+    scatter buffer per top-k choice: PERF.md section 6, PR 26.)
+  * any other axis of more than one device (``expert``: expert-parallel
+    weights, :func:`moe_partition_specs`; ``tensor``, ``seq``, ``pipe``), or
+    a token count or capacity that G does not divide: the same function as
+    one program under GSPMD.  The partitioner then chooses the exchange
+    itself — on a data-only mesh it was an all-reduce of the [E, C, F]
+    expert activations, not the reference's all-to-all (:96 ``_AllToAll``);
+    an all-to-all over ``expert`` is a different mechanism that no cell
+    runs yet and is not attempted here, and neither is a region manual over
+    ``data`` alone beside a tensor-parallel axis.
 """
 from __future__ import annotations
 
 import math
+import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..runtime.topology import EXPERT, get_topology
+from ..runtime import topology as _topo
+from ..runtime.topology import DATA, DATA_OUTER, EXPERT, get_topology
+from ..telemetry import get_tracer
 
 
 class GateOutput(NamedTuple):
@@ -30,10 +63,23 @@ class GateOutput(NamedTuple):
     exp_counts: jnp.ndarray     # [E] tokens routed per expert (pre-drop)
 
 
+#: floor of every gate's per-expert capacity
+MIN_CAPACITY = 4
+
+
 def _capacity(num_tokens: int, num_experts: int, capacity_factor: float,
               min_capacity: int) -> int:
     cap = math.ceil(num_tokens / num_experts * capacity_factor)
     return max(cap, min_capacity)
+
+
+def _group_count(group_axes) -> int:
+    """Shards of the batch under ``group_axes`` (static; 1 outside)."""
+    return jax.lax.psum(1, group_axes) if group_axes else 1
+
+
+def _group_sum(x, group_axes):
+    return jax.lax.psum(x, group_axes) if group_axes else x
 
 
 def _one_hot(idx, n):
@@ -57,8 +103,35 @@ def _mask_padded_experts(logits: jnp.ndarray,
     return logits + mask[None, :], int(num_experts_logical)
 
 
+def _slot_positions(mask, counts, group_axes):
+    """One choice's one-hot [S, E] → each token's position among its
+    expert's slots [S, E] and the experts' loads over the whole batch [E].
+    Tokens fill slots in order, after the ``counts`` slots earlier choices
+    took.  Inside a region manual over ``group_axes`` the call sees one
+    shard of the batch: the shards' tokens follow each other in mesh order,
+    so positions, capacity and drops are those of one global routing."""
+    load = jnp.sum(mask, axis=0)
+    pos = jnp.cumsum(mask, axis=0) - mask + counts[None, :]
+    if group_axes:
+        loads = jax.lax.all_gather(load, group_axes)              # [G, E]
+        before = jnp.arange(loads.shape[0]) < jax.lax.axis_index(group_axes)
+        pos = pos + jnp.sum(loads * before[:, None], axis=0)[None, :]
+        load = jnp.sum(loads, axis=0)
+    return pos, load
+
+
+def _topk_balance_loss(gates, ce_total, n_log: int, group_axes):
+    """Switch balance term of a top-k gate from the whole batch's loads."""
+    me = jnp.mean(gates, axis=0)
+    if group_axes:
+        me = jax.lax.pmean(me, group_axes)
+    ce = ce_total / jnp.maximum(jnp.sum(ce_total), 1.0)
+    return jnp.sum(me * ce) * n_log
+
+
 def top1gating(logits: jnp.ndarray, capacity_factor: float = 1.0,
-               min_capacity: int = 4, noisy_gate_policy: Optional[str] = None,
+               min_capacity: int = MIN_CAPACITY,
+               noisy_gate_policy: Optional[str] = None,
                rng: Optional[jax.Array] = None, drop_tokens: bool = True,
                used_capacity: Any = None,
                num_experts_logical: Optional[int] = None) -> GateOutput:
@@ -93,14 +166,20 @@ def top1gating(logits: jnp.ndarray, capacity_factor: float = 1.0,
 
 
 def topkgating(logits: jnp.ndarray, k: int = 2, capacity_factor: float = 1.0,
-               min_capacity: int = 4, drop_tokens: bool = True,
+               min_capacity: int = MIN_CAPACITY, drop_tokens: bool = True,
                rng: Optional[jax.Array] = None,
                normalize_weights: bool = True,
-               num_experts_logical: Optional[int] = None) -> GateOutput:
-    """Top-k gating (reference: sharded_moe.py:374; k=2 ≡ top2gating :290)."""
+               num_experts_logical: Optional[int] = None,
+               group_axes: Tuple[str, ...] = ()) -> GateOutput:
+    """Top-k gating (reference: sharded_moe.py:374; k=2 ≡ top2gating :290).
+
+    ``group_axes``: the manual mesh axes over which the batch is split, when
+    the call sees one shard of it (:func:`_slot_positions`); S then counts
+    the shard's tokens, C and every output the whole batch's."""
     S, E = logits.shape
     logits, n_log = _mask_padded_experts(logits, num_experts_logical)
-    C = _capacity(S * k, n_log, capacity_factor, min_capacity)
+    C = _capacity(S * _group_count(group_axes) * k, n_log, capacity_factor,
+                  min_capacity)
     gates = jax.nn.softmax(logits, axis=1)
 
     topk_val, topk_idx = jax.lax.top_k(gates, k)                  # [S, k]
@@ -115,24 +194,22 @@ def topkgating(logits: jnp.ndarray, k: int = 2, capacity_factor: float = 1.0,
     for choice in range(k):
         idx = topk_idx[:, choice]
         mask = _one_hot(idx, E)                                   # [S, E]
-        ce_total = ce_total + jnp.sum(mask, axis=0)
-        pos = jnp.cumsum(mask, axis=0) - mask + counts[None, :]
+        pos, load = _slot_positions(mask, counts, group_axes)
+        ce_total = ce_total + load
         if drop_tokens:
             mask = mask * (pos < C)
-        counts = counts + jnp.sum(mask, axis=0)
+        counts = counts + _group_sum(jnp.sum(mask, axis=0), group_axes)
         pos_in_expert = jnp.sum(pos * mask, axis=1).astype(jnp.int32)
         d = mask[:, :, None] * _one_hot(pos_in_expert, C)[:, None, :]
         dispatch = jnp.logical_or(dispatch, d.astype(bool))
         combine = combine + d * topk_val[:, choice][:, None, None]
 
-    me = jnp.mean(gates, axis=0)
-    ce = ce_total / jnp.maximum(jnp.sum(ce_total), 1.0)
-    l_aux = jnp.sum(me * ce) * n_log
+    l_aux = _topk_balance_loss(gates, ce_total, n_log, group_axes)
     return GateOutput(l_aux, combine, dispatch, ce_total.astype(jnp.int32))
 
 
-def top2gating(logits, capacity_factor: float = 1.0, min_capacity: int = 4,
-               **kw) -> GateOutput:
+def top2gating(logits, capacity_factor: float = 1.0,
+               min_capacity: int = MIN_CAPACITY, **kw) -> GateOutput:
     return topkgating(logits, k=2, capacity_factor=capacity_factor,
                       min_capacity=min_capacity, **kw)
 
@@ -159,7 +236,7 @@ class SparseGateOutput(NamedTuple):
 
 
 def top1gating_sparse(logits: jnp.ndarray, capacity_factor: float = 1.0,
-                      min_capacity: int = 4,
+                      min_capacity: int = MIN_CAPACITY,
                       noisy_gate_policy: Optional[str] = None,
                       rng: Optional[jax.Array] = None,
                       drop_tokens: bool = True,
@@ -196,20 +273,24 @@ def top1gating_sparse(logits: jnp.ndarray, capacity_factor: float = 1.0,
 
 
 def topkgating_sparse(logits: jnp.ndarray, k: int = 2,
-                      capacity_factor: float = 1.0, min_capacity: int = 4,
+                      capacity_factor: float = 1.0,
+                      min_capacity: int = MIN_CAPACITY,
                       drop_tokens: bool = True,
                       rng: Optional[jax.Array] = None,
                       normalize_weights: bool = True,
                       valid: Optional[jnp.ndarray] = None,
-                      num_experts_logical: Optional[int] = None) -> SparseGateOutput:
+                      num_experts_logical: Optional[int] = None,
+                      group_axes: Tuple[str, ...] = ()) -> SparseGateOutput:
     """Sparse-form top-k gating; routing decisions identical to topkgating.
 
     ``valid`` [S] bool: tokens marked invalid (ragged-batch padding) are
     routed to the trash slot and consume no expert capacity.
+    ``group_axes``: as in :func:`topkgating`.
     """
     S, E = logits.shape
     logits, n_log = _mask_padded_experts(logits, num_experts_logical)
-    C = _capacity(S * k, n_log, capacity_factor, min_capacity)
+    C = _capacity(S * _group_count(group_axes) * k, n_log, capacity_factor,
+                  min_capacity)
     gates = jax.nn.softmax(logits, axis=1)
 
     topk_val, topk_idx = jax.lax.top_k(gates, k)
@@ -224,11 +305,11 @@ def topkgating_sparse(logits: jnp.ndarray, k: int = 2,
         mask = _one_hot(idx, E)
         if valid is not None:
             mask = mask * valid.astype(jnp.float32)[:, None]
-        ce_total = ce_total + jnp.sum(mask, axis=0)
-        pos = jnp.cumsum(mask, axis=0) - mask + counts[None, :]
+        pos, load = _slot_positions(mask, counts, group_axes)
+        ce_total = ce_total + load
         if drop_tokens:
             mask = mask * (pos < C)
-        counts = counts + jnp.sum(mask, axis=0)
+        counts = counts + _group_sum(jnp.sum(mask, axis=0), group_axes)
         kept = jnp.sum(mask, axis=1) > 0
         pos_in_expert = jnp.sum(pos * mask, axis=1).astype(jnp.int32)
         # beyond-capacity → trash row (dense one_hot(pos>=C) is all-zeros)
@@ -237,9 +318,7 @@ def topkgating_sparse(logits: jnp.ndarray, k: int = 2,
                                E * C))
         vals.append(jnp.where(kept, topk_val[:, choice], 0.0))
 
-    me = jnp.mean(gates, axis=0)
-    ce = ce_total / jnp.maximum(jnp.sum(ce_total), 1.0)
-    l_aux = jnp.sum(me * ce) * n_log
+    l_aux = _topk_balance_loss(gates, ce_total, n_log, group_axes)
     return SparseGateOutput(l_aux, jnp.stack(slots, axis=1),
                             jnp.stack(vals, axis=1),
                             ce_total.astype(jnp.int32), C)
@@ -271,8 +350,6 @@ def _pin_replicated(x: jnp.ndarray) -> jnp.ndarray:
     row-scatter).  Replicating the gather operand first makes the gather
     local and keeps the cross-expert exchange as one explicit all-gather.
     """
-    from ..runtime import topology as _topo
-
     topo = _topo._TOPOLOGY
     if topo is None or topo.mesh.size <= 1:
         return x
@@ -350,6 +427,28 @@ def combine_from_experts(combine: jnp.ndarray, expert_out: jnp.ndarray,
     return jnp.einsum("sec,ecd->sd", combine.astype(dtype), expert_out)
 
 
+def _routing_groups(num_tokens: int, capacity: int):
+    """``(mesh, batch axes)`` when the block spreads its expert slots over
+    the data shards: the mesh's batch axes (what ``models/transformer.py``
+    lays every activation's batch dimension over) hold all of its G > 1
+    devices — no expert, tensor, sequence or pipeline parallelism, whose
+    exchanges are other mechanisms that no cell runs — no axis is manual
+    already, and G divides the token count and the capacity.  The region is
+    then manual over the whole mesh (one manual over some axes only aborts
+    jax 0.9's CPU compiler on the bf16 ``psum_scatter``).  ``None`` = one
+    program under GSPMD."""
+    topo = _topo._TOPOLOGY
+    if topo is None or topo.mesh.size <= 1:
+        return None
+    mesh, manual = _topo.shard_map_context(topo)
+    axes = tuple(a for a in (DATA_OUTER, DATA) if topo.dims[a] > 1)
+    groups = math.prod(topo.dims[a] for a in axes)
+    if manual or groups != mesh.size \
+            or num_tokens % groups or capacity % groups:
+        return None
+    return mesh, axes
+
+
 def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
                   capacity_factor: float = 2.0, dispatch_impl: str = "sparse",
                   rng: Optional[jax.Array] = None,
@@ -363,48 +462,91 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
     train and serve route identically.  Router always runs in f32 (the
     reference keeps the gate fp32; under bf16 compute we re-cast to preserve
     routing decisions).
+
+    The routing is one function of the whole batch on every mesh: capacity
+    ``ceil(T × k / E × capacity_factor)``, slots filled in token order.
+    Where the tokens are sharded over the batch axes (module docstring,
+    :func:`_routing_groups`) each shard computes C/G slots of every expert.
+    Every traced call leaves one ``moe/layout`` record on the tracer saying
+    which program it became.
     """
     assert dispatch_impl in ("sparse", "dense"), dispatch_impl
     sparse = dispatch_impl == "sparse"
+    assert sparse or valid is None, \
+        "ragged validity masks need dispatch_impl='sparse'"
     dtype = lp["gate_proj"]["kernel"].dtype
-    # one name scope per phase: device time (and every collective GSPMD puts
-    # in) shows up under moe/route, moe/dispatch, moe/experts, moe/combine
-    with jax.named_scope("moe/route"):
-        logits_r = tokens.astype(jnp.float32) \
-            @ lp["router"]["kernel"].astype(jnp.float32)
-        if sparse:
-            gate_out = topkgating_sparse(
-                logits_r, k=k, capacity_factor=capacity_factor, rng=rng,
-                valid=valid, num_experts_logical=num_experts_logical)
-        else:
-            assert valid is None, \
-                "ragged validity masks need dispatch_impl='sparse'"
-            gate_out = topkgating(
-                logits_r, k=k, capacity_factor=capacity_factor, rng=rng,
-                num_experts_logical=num_experts_logical)
-    with jax.named_scope("moe/dispatch"):
-        if sparse:
-            dispatched = dispatch_sparse(gate_out.slot, tokens,
-                                         logits_r.shape[1],
-                                         gate_out.capacity, dtype)
-        else:
-            dispatched = dispatch_to_experts(gate_out.dispatch, tokens, dtype)
-    with jax.named_scope("moe/experts"):
-        act = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched,
-                                     lp["gate_proj"]["kernel"]))
-        up = jnp.einsum("ecd,edf->ecf", dispatched, lp["up_proj"]["kernel"])
-        eo = jnp.einsum("ecf,efd->ecd", act * up, lp["down_proj"]["kernel"])
-    with jax.named_scope("moe/combine"):
-        if sparse:
-            out = combine_sparse(gate_out.slot, gate_out.gate_val, eo, dtype)
-        else:
-            out = combine_from_experts(gate_out.combine, eo, dtype)
-    return out, gate_out.l_aux
+    T, E = tokens.shape[0], lp["router"]["kernel"].shape[1]
+    capacity = _capacity(T * k, min(num_experts_logical or E, E),
+                         capacity_factor, MIN_CAPACITY)
+    grouped = _routing_groups(T, capacity)
+    mesh, group_axes = grouped or (None, ())
+    groups = math.prod(mesh.shape[a] for a in group_axes)
+    # trace time only: what a run says about the program it compiled
+    get_tracer().record(
+        "moe/layout", time.perf_counter(), 0.0, groups=groups,
+        tokens_per_group=T // groups, capacity=capacity,
+        slots_per_group=capacity // groups, experts=E,
+        local=grouped is not None)
+
+    def routed(tokens, valid, rng, router, w_gate, w_up, w_down):
+        # one name scope per phase: device time (and every collective) shows
+        # up under moe/route, moe/dispatch, moe/experts, moe/combine
+        with jax.named_scope("moe/route"):
+            logits_r = tokens.astype(jnp.float32) @ router.astype(jnp.float32)
+            gating = dict(k=k, capacity_factor=capacity_factor, rng=rng,
+                          num_experts_logical=num_experts_logical,
+                          group_axes=group_axes)
+            if sparse:
+                gate_out = topkgating_sparse(logits_r, valid=valid, **gating)
+            else:
+                gate_out = topkgating(logits_r, **gating)
+            assert capacity == (gate_out.capacity if sparse
+                                else gate_out.dispatch.shape[2])
+        with jax.named_scope("moe/dispatch"):
+            if sparse:
+                dispatched = dispatch_sparse(gate_out.slot, tokens, E,
+                                             capacity, dtype)
+            else:
+                dispatched = dispatch_to_experts(gate_out.dispatch, tokens,
+                                                 dtype)
+            if group_axes:
+                # a slot holds one token of one shard: the sum of the shards'
+                # buffers is the global one, of which each keeps C/G slots
+                # of every expert
+                dispatched = jax.lax.psum_scatter(
+                    dispatched, group_axes, scatter_dimension=1, tiled=True)
+        with jax.named_scope("moe/experts"):
+            act = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched, w_gate))
+            up = jnp.einsum("ecd,edf->ecf", dispatched, w_up)
+            eo = jnp.einsum("ecf,efd->ecd", act * up, w_down)
+        with jax.named_scope("moe/combine"):
+            if group_axes:
+                eo = jax.lax.all_gather(eo, group_axes, axis=1, tiled=True)
+            if sparse:
+                out = combine_sparse(gate_out.slot, gate_out.gate_val, eo,
+                                     dtype)
+            else:
+                out = combine_from_experts(gate_out.combine, eo, dtype)
+        return out, gate_out.l_aux
+
+    if grouped is not None:
+        # the weights come in whole: ZeRO-3's gather lands at this boundary,
+        # outside moe/*
+        rows, whole = P(group_axes), P()
+        routed = _topo.compat_shard_map(
+            routed, mesh,
+            in_specs=(rows, None if valid is None else rows,
+                      None if rng is None else whole,
+                      whole, whole, whole, whole),
+            out_specs=(rows, whole))
+    return routed(tokens, valid, rng, lp["router"]["kernel"],
+                  lp["gate_proj"]["kernel"], lp["up_proj"]["kernel"],
+                  lp["down_proj"]["kernel"])
 
 
 def moe_layer(params: Dict, x: jnp.ndarray, k: int = 1,
               capacity_factor: float = 1.0, eval_capacity_factor: float = 1.0,
-              min_capacity: int = 4, drop_tokens: bool = True,
+              min_capacity: int = MIN_CAPACITY, drop_tokens: bool = True,
               noisy_gate_policy: Optional[str] = None,
               rng: Optional[jax.Array] = None, training: bool = True,
               activation=jax.nn.gelu,

@@ -4,18 +4,24 @@ The ROADMAP flags the MoE layer as needing hardening; these tests pin the
 gating contracts the elastic-resharding work relies on: capacity-factor
 edge cases, zero-token experts, deterministic tie-breaks, and the uneven
 expert÷ep padding path (bit-identical routing through a padded stack)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from deepspeed_tpu.moe import sharded_moe
 from deepspeed_tpu.moe.sharded_moe import (
     _capacity, combine_sparse, dispatch_sparse, expert_shard_ranges,
-    init_moe_params, moe_layer, pad_experts_for_ep, padded_expert_count,
-    placed_expert_ranges, reshard_expert_params, top1gating,
-    top1gating_sparse, topkgating, topkgating_sparse)
-from deepspeed_tpu.runtime.topology import (EXPERT, TopologyConfig,
-                                            initialize_mesh)
+    init_moe_params, moe_layer, moe_mlp_block, pad_experts_for_ep,
+    padded_expert_count, placed_expert_ranges, reshard_expert_params,
+    top1gating, top1gating_sparse, topkgating, topkgating_sparse)
+from deepspeed_tpu.runtime import topology as topo_mod
+from deepspeed_tpu.runtime.topology import (DATA, DATA_OUTER, EXPERT,
+                                            TopologyConfig, initialize_mesh)
+from deepspeed_tpu.telemetry import get_tracer
 
 pytestmark = pytest.mark.moe
 
@@ -165,11 +171,33 @@ class TestExpertResharding:
         out, aux, counts = moe_layer(padded, x, k=2, capacity_factor=2.0,
                                      dispatch_impl=impl,
                                      num_experts_logical=e_logical)
-        np.testing.assert_array_equal(np.asarray(ref_out), np.asarray(out))
+        if impl == "sparse":
+            np.testing.assert_array_equal(np.asarray(ref_out), np.asarray(out))
+        else:
+            # the routing is the same bit for bit (next test); the dense
+            # combine einsum sums over the expert axis, and two more zero
+            # terms regroup XLA's float32 sum by an ulp (ROADMAP D1)
+            np.testing.assert_allclose(np.asarray(ref_out), np.asarray(out),
+                                       rtol=0, atol=2e-7)
         assert float(ref_aux) == float(aux)
         np.testing.assert_array_equal(np.asarray(ref_counts),
                                       np.asarray(counts)[:E])
         assert np.asarray(counts)[E:].sum() == 0   # padding never routed
+
+    def test_padded_stack_gates_bit_identically(self):
+        """The dense gate of a padded stack: the same (token, expert, slot)
+        assignments and combine weights, zero in every padding column."""
+        E = 6
+        logits = jax.random.normal(jax.random.PRNGKey(5), (16, E), jnp.float32)
+        ref = topkgating(logits, k=2, capacity_factor=2.0)
+        got = topkgating(jnp.pad(logits, ((0, 0), (0, 2))), k=2,
+                         capacity_factor=2.0, num_experts_logical=E)
+        np.testing.assert_array_equal(np.asarray(ref.combine),
+                                      np.asarray(got.combine)[:, :E])
+        np.testing.assert_array_equal(np.asarray(ref.dispatch),
+                                      np.asarray(got.dispatch)[:, :E])
+        assert not np.asarray(got.dispatch)[:, E:].any()
+        assert float(ref.l_aux) == float(got.l_aux)
 
     def test_reshard_divisible_places_on_expert_axis(self):
         topo = initialize_mesh(TopologyConfig(expert=4), force=True)
@@ -213,3 +241,232 @@ class TestSparseDispatchCombine:
         np.testing.assert_array_equal(np.asarray(back)[kept],
                                       np.asarray(tokens)[kept])
         assert np.all(np.asarray(back)[~kept] == 0.0)      # dropped → zeros
+
+
+# --------------------------------------------------------------------- #
+# The block on a data mesh: global routing, expert slots spread (ISSUE 26)
+# --------------------------------------------------------------------- #
+#: sizes chosen so that no two dimensions coincide: a shape in the compiled
+#: text names its tensor (at capacity factor 2.0, C = 128 and C/G = 32)
+N_EXP, D_MODEL, D_FFN, N_TOK = 8, 16, 48, 256
+QUANTITIES = ("out", "l_aux", "d_tokens", "d_gate_proj", "d_up_proj",
+              "d_down_proj")
+COLLECTIVE = re.compile(
+    r"= .*\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(-start)?\(")
+#: (dispatch, mesh, capacity factor): at 0.5 most pairs are over capacity,
+#: and which ones are dropped must not depend on the mesh either
+CASES = [("sparse", "data4", 2.0), ("dense", "data4", 2.0),
+         ("sparse", "data4", 0.5), ("sparse", "mics2x2", 2.0)]
+
+
+def _block_params():
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    lp = {"router": {"kernel": jax.random.normal(ks[0], (D_MODEL, N_EXP))},
+          "gate_proj": {"kernel": 0.2 * jax.random.normal(
+              ks[1], (N_EXP, D_MODEL, D_FFN))},
+          "up_proj": {"kernel": 0.2 * jax.random.normal(
+              ks[2], (N_EXP, D_MODEL, D_FFN))},
+          "down_proj": {"kernel": 0.2 * jax.random.normal(
+              ks[3], (N_EXP, D_FFN, D_MODEL))}}
+    return lp, jax.random.normal(ks[4], (N_TOK, D_MODEL))
+
+
+def _value_and_grads(impl, capacity_factor):
+    def objective(lp, x):
+        out, l_aux = moe_mlp_block(lp, x, k=2, dispatch_impl=impl,
+                                   capacity_factor=capacity_factor)
+        return jnp.sum(out ** 2) + l_aux, (out, l_aux)
+
+    return jax.jit(jax.value_and_grad(objective, argnums=(0, 1),
+                                      has_aux=True))
+
+
+def _named(result):
+    (_, (out, l_aux)), (d_lp, d_x) = result
+    named = {"out": out, "l_aux": l_aux, "d_tokens": d_x}
+    named.update({f"d_{k}": d_lp[k]["kernel"]
+                  for k in ("gate_proj", "up_proj", "down_proj")})
+    return {k: np.asarray(v) for k, v in named.items()}
+
+
+def _on_data_mesh(lp, x, mesh="data4"):
+    """Four data shards (``mics2x2``: as data_outer 2 × data 2), the tokens
+    batch-sharded and the expert weights stored as ZeRO-3 stores them (their
+    last dimension over ``data``)."""
+    topo = initialize_mesh(
+        TopologyConfig(data=4, zero_shard_size=2 if mesh == "mics2x2" else -1),
+        devices=jax.devices()[:4], force=True)
+    x = jax.device_put(x, NamedSharding(topo.mesh, P((DATA_OUTER, DATA))))
+    lp = jax.tree.map(
+        lambda w: jax.device_put(w, NamedSharding(
+            topo.mesh, P(None, None, DATA) if w.ndim == 3 else P())), lp)
+    return topo, lp, x
+
+
+def _layouts():
+    return [r.attrs for r in get_tracer().records() if r.name == "moe/layout"]
+
+
+@pytest.fixture(scope="module", params=CASES,
+                ids=lambda case: "-".join(map(str, case)))
+def both_programs(request):
+    """One block on one device and on four data shards, with the compiled
+    text of the second."""
+    impl, mesh, capacity_factor = request.param
+    lp, x = _block_params()
+    topo_mod.reset_topology()
+    get_tracer().clear()
+    one = _named(_value_and_grads(impl, capacity_factor)(lp, x))
+    one_layout = _layouts()
+    _, lp4, x4 = _on_data_mesh(lp, x, mesh)
+    fn = _value_and_grads(impl, capacity_factor)
+    get_tracer().clear()
+    four = _named(fn(lp4, x4))
+    four_layout = _layouts()
+    text = fn.lower(lp4, x4).compile().as_text()
+    topo_mod.reset_topology()
+    return dict(one=one, four=four, text=text, one_layout=one_layout,
+                four_layout=four_layout,
+                capacity=int(np.ceil(N_TOK * 2 / N_EXP * capacity_factor)))
+
+
+class TestBlockOnDataShards:
+    """Routing stays one function of the whole batch (capacity, slots in
+    token order, drops, balance term); only where the slots are computed
+    changes, so a data mesh gives the one-device result."""
+
+    @pytest.mark.parametrize("quantity", QUANTITIES)
+    def test_equals_one_device(self, both_programs, quantity):
+        want = both_programs["one"][quantity]
+        got = both_programs["four"][quantity]
+        assert got.shape == want.shape
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+    def test_each_shard_computes_its_part_of_every_experts_slots(
+            self, both_programs):
+        (one,), (four,) = (both_programs["one_layout"],
+                           both_programs["four_layout"])
+        cap = both_programs["capacity"]
+        assert one == dict(groups=1, tokens_per_group=N_TOK, capacity=cap,
+                           slots_per_group=cap, experts=N_EXP, local=False)
+        assert four == dict(groups=4, tokens_per_group=N_TOK // 4,
+                            capacity=cap, slots_per_group=cap // 4,
+                            experts=N_EXP, local=True)
+
+    def test_collectives_move_no_expert_activation(self, both_programs):
+        """What has the F dimension and crosses chips is a WEIGHT (ZeRO-3's
+        gather, the gradient's reduction): no [E, C, F] product, and nothing
+        at all under ``moe/experts``; dispatch and combine exchange the
+        [E, C, D] buffer."""
+        cap = both_programs["capacity"]
+        lines = [ln for ln in both_programs["text"].splitlines()
+                 if COLLECTIVE.search(ln)]
+        assert any("moe/dispatch" in ln for ln in lines)
+        assert any("moe/combine" in ln for ln in lines)
+        for line in lines:
+            assert "moe/experts" not in line, line
+            for shape in re.findall(r"\[([0-9,]+)\]", line.split("(")[0]):
+                dims = [int(d) for d in shape.split(",")]
+                assert dims not in ([N_EXP, cap, D_FFN],
+                                    [N_EXP, cap // 4, D_FFN]), line
+
+
+def _block_jaxpr(lp, x, manual_mesh=None):
+    """The jaxpr of one fresh trace of the block (a jitted function would
+    hand back its cached one); inside a region manual over ``data`` when a
+    mesh is given."""
+    fn = lambda lp, x: moe_mlp_block(lp, x)  # noqa: E731
+    if manual_mesh is not None:
+        fn = jax.jit(topo_mod.compat_shard_map(
+            fn, manual_mesh, in_specs=(P(), P(DATA)),
+            out_specs=(P(DATA), P()), manual_axes={DATA}))
+    return str(jax.make_jaxpr(fn)(lp, x))
+
+
+def _global_jaxpr(monkeypatch, *args):
+    """The block's program with the grouped path switched off: the one
+    program it hands to GSPMD (or runs locally)."""
+    with monkeypatch.context() as m:
+        m.setattr(sharded_moe, "_routing_groups", lambda *sizes: None)
+        return _block_jaxpr(*args)
+
+
+class TestBlockFallBacks:
+    """Where the block cannot observe a mesh of data shards alone it does
+    not engage: its jaxpr is the one with the grouped path switched off,
+    with no ``shard_map`` of its own."""
+
+    @pytest.mark.parametrize("case", ["one_device", "indivisible_tokens",
+                                      "indivisible_capacity",
+                                      "expert_axis_2", "tensor_axis_2",
+                                      "already_manual"])
+    def test_falls_back_to_the_global_program(self, case, monkeypatch):
+        lp, x = _block_params()
+        mesh = None
+        if case == "one_device":
+            initialize_mesh(TopologyConfig(), devices=jax.devices()[:1],
+                            force=True)
+        elif case == "expert_axis_2":
+            initialize_mesh(TopologyConfig(data=4, expert=2), force=True)
+        elif case == "tensor_axis_2":
+            initialize_mesh(TopologyConfig(data=2, tensor=2),
+                            devices=jax.devices()[:4], force=True)
+        else:
+            topo = initialize_mesh(TopologyConfig(data=4),
+                                   devices=jax.devices()[:4], force=True)
+            if case == "indivisible_tokens":
+                x = x[:N_TOK - 2]
+            elif case == "indivisible_capacity":
+                x = x[:N_TOK - 4]       # 252 tokens: C = 126
+            else:
+                mesh = topo.mesh
+        try:
+            get_tracer().clear()
+            text = _block_jaxpr(lp, x, mesh)
+            (layout,) = _layouts()
+            assert layout["local"] is False and layout["groups"] == 1
+            assert text == _global_jaxpr(monkeypatch, lp, x, mesh)
+            assert text.count("shard_map") == (case == "already_manual")
+        finally:
+            topo_mod.reset_topology()
+
+    @pytest.mark.parametrize("dims", [dict(data=2, tensor=2),
+                                      dict(data=2, seq=2),
+                                      dict(data=2, expert=2)],
+                             ids=lambda d: "x".join(f"{k}{v}"
+                                                    for k, v in d.items()))
+    def test_other_parallel_axes_compile_and_run_in_bf16(self, dims):
+        """A mesh with another axis of more than one device keeps the GSPMD
+        program: compiled and run in bf16 (a region manual over ``data``
+        alone would abort jax 0.9's CPU compiler there), it gives the
+        one-device result."""
+        lp, x = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                             _block_params())
+        fn = jax.jit(lambda lp, x: moe_mlp_block(lp, x))  # noqa: E731
+        topo_mod.reset_topology()
+        want, want_aux = fn(lp, x)
+        topo = initialize_mesh(TopologyConfig(**dims),
+                               devices=jax.devices()[:4], force=True)
+        try:
+            get_tracer().clear()
+            got, got_aux = jax.jit(lambda lp, x: moe_mlp_block(lp, x))(
+                lp, jax.device_put(x, NamedSharding(topo.mesh, P(DATA))))
+            (layout,) = _layouts()
+            assert layout["local"] is False
+            want, got = (np.asarray(a, np.float32) for a in (want, got))
+            assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+            assert abs(float(got_aux) - float(want_aux)) <= 1e-5
+        finally:
+            topo_mod.reset_topology()
+
+    def test_engages_on_the_data_mesh(self, monkeypatch):
+        """The control of the cases above: same comparison, other verdict."""
+        lp, x = _block_params()
+        _on_data_mesh(lp, x)
+        try:
+            text = _block_jaxpr(lp, x)
+            assert "shard_map" in text
+            assert text != _global_jaxpr(monkeypatch, lp, x)
+        finally:
+            topo_mod.reset_topology()

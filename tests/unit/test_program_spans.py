@@ -420,6 +420,24 @@ def _has_scope(text, scope):
     return re.search(r"[/(\"]" + re.escape(scope) + r"[)/]", text) is not None
 
 
+def _lowered_moe_block(shards):
+    """``moe_mlp_block`` over 32 tokens lowered on a ``data=shards`` mesh."""
+    from deepspeed_tpu.moe.sharded_moe import moe_mlp_block
+    from deepspeed_tpu.runtime import topology
+
+    lp = {"router": {"kernel": jnp.zeros((8, 4))},
+          "gate_proj": {"kernel": jnp.zeros((4, 8, 16))},
+          "up_proj": {"kernel": jnp.zeros((4, 8, 16))},
+          "down_proj": {"kernel": jnp.zeros((4, 16, 8))}}
+    topology.initialize_mesh(topology.TopologyConfig(data=shards),
+                             devices=jax.devices()[:shards], force=True)
+    try:
+        return _lowered_text(lambda x: moe_mlp_block(lp, x)[0],
+                             jnp.zeros((32, 8)))
+    finally:
+        topology.reset_topology()
+
+
 # ---- the train engine ------------------------------------------------------
 def _train_engine(zero_stage=0, num_experts=1):
     import deepspeed_tpu
@@ -474,18 +492,27 @@ class TestTrainSpans:
             debug_info=True)
         assert _has_scope(text, scope)
 
+    @pytest.mark.parametrize("shards", [1, 4])
     @pytest.mark.parametrize("scope", ["moe/route", "moe/dispatch",
                                        "moe/experts", "moe/combine"])
-    def test_moe_scopes(self, scope):
-        from deepspeed_tpu.moe.sharded_moe import moe_mlp_block
-
-        lp = {"router": {"kernel": jnp.zeros((8, 4))},
-              "gate_proj": {"kernel": jnp.zeros((4, 8, 16))},
-              "up_proj": {"kernel": jnp.zeros((4, 8, 16))},
-              "down_proj": {"kernel": jnp.zeros((4, 16, 8))}}
-        text = jax.jit(lambda x: moe_mlp_block(lp, x)[0]).lower(
-            jnp.zeros((32, 8))).as_text(debug_info=True)
+    def test_moe_scopes(self, scope, shards):
+        """The four phases keep their scopes where the block spreads the
+        expert slots over the data shards, inside its ``shard_map``."""
+        text = _lowered_moe_block(shards)
         assert _has_scope(text, scope)
+        assert ("shard_map" in text) == (shards > 1)
+
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_moe_layout_record(self, shards, tracer):
+        """Every traced call of the block says which program it became."""
+        _lowered_moe_block(shards)
+        (layout,) = [r.attrs for r in tracer.records()
+                     if r.name == "moe/layout"]
+        # 32 tokens, top-2 of 4 experts, capacity factor 2.0: 32 slots an
+        # expert, of which every data shard computes its part
+        assert layout == dict(groups=shards, tokens_per_group=32 // shards,
+                              capacity=32, slots_per_group=32 // shards,
+                              experts=4, local=shards > 1)
 
     def test_compiled_text_gives_scopes(self, dense_step):
         _, _, text = dense_step
