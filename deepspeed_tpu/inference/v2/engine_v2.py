@@ -110,18 +110,30 @@ class InferenceEngineV2:
     def __init__(self, model: CausalLM, params,
                  config: Optional[RaggedInferenceEngineConfig] = None):
         from ...models.families import ArchConfig
+        from ...models.xing4 import Xing4Config
         from ...utils.compile_cache import configure_compile_cache
 
         configure_compile_cache()
         self.model = model
         self.cfg = model.config
-        if not isinstance(self.cfg, (TransformerConfig, ArchConfig)):
+        if not isinstance(self.cfg, (TransformerConfig, ArchConfig,
+                                     Xing4Config)):
             raise NotImplementedError(
                 f"ragged serving needs a TransformerConfig (native llama "
-                f"families) or ArchConfig (universal gpt2/gptj/opt/bloom/"
-                f"falcon/phi families) model; got {type(self.cfg).__name__}")
+                f"families), ArchConfig (universal gpt2/gptj/opt/bloom/"
+                f"falcon/phi families) or Xing4Config model; got "
+                f"{type(self.cfg).__name__}")
         self.config = config or RaggedInferenceEngineConfig()
         c = self.config
+        #: a latent (MLA) page pool: one row a token, no K/V pair, no heads
+        self.latent_kv = isinstance(self.cfg, Xing4Config)
+        if self.latent_kv and c.host_tier_mb > 0:
+            # kv_swap parks pages as kv_ship rows [L, n, 2*KV, hd]: a
+            # latent page has no such shape, and a reshaped copy would be
+            # silently wrong
+            raise ValueError(
+                "host_tier_mb > 0 (the host KV tier, ragged/kv_swap.py) is "
+                "not supported with latent (MLA) pages: set host_tier_mb=0")
         num_blocks = c.num_blocks or (c.max_seqs * -(-c.max_ctx // c.block_size))
         self.state_manager = DSStateManager(num_blocks=num_blocks,
                                             block_size=c.block_size)
@@ -130,10 +142,20 @@ class InferenceEngineV2:
 
             self.state_manager.prefix_cache = RadixPrefixCache(
                 self.state_manager.allocator, c.block_size)
-        self.kv = BlockedKVCache(KVCacheConfig(
-            num_layers=self.cfg.num_layers, num_blocks=num_blocks,
-            block_size=c.block_size, num_kv_heads=self.cfg.num_kv_heads,
-            head_dim=self.cfg.head_dim, dtype=c.dtype))
+        if self.latent_kv:
+            # decode_window_bytes counts 2*KV*hd values a token a layer:
+            # 2 * 1 * latent_dim/2 is the latent row
+            self._kv_row_heads = (1, self.cfg.latent_dim // 2)
+            self.kv = BlockedKVCache(KVCacheConfig(
+                num_layers=self.cfg.num_layers, num_blocks=num_blocks,
+                block_size=c.block_size, num_kv_heads=0, head_dim=0,
+                dtype=c.dtype, latent_row=self.cfg.latent_row))
+        else:
+            self._kv_row_heads = (self.cfg.num_kv_heads, self.cfg.head_dim)
+            self.kv = BlockedKVCache(KVCacheConfig(
+                num_layers=self.cfg.num_layers, num_blocks=num_blocks,
+                block_size=c.block_size, num_kv_heads=self.cfg.num_kv_heads,
+                head_dim=self.cfg.head_dim, dtype=c.dtype))
         #: page-heat tracker (None = tracking off): observes the allocator
         #: so its live set mirrors the free list, ticked per forward below
         self.heat = None
@@ -163,8 +185,11 @@ class InferenceEngineV2:
         # Cast to serving dtype, EXCEPT router kernels: routing must run in
         # f32 so serving picks the same experts as the training forward — a
         # bf16 round-trip flips top-k selection on near-tie tokens.
+        # The hyper-connection maps (keys ``hc_*``) are float32 too: they
+        # are a few hundred thousand values and feed a Sinkhorn iteration.
         def _cast(path, x):
-            if any("router" in str(getattr(k, "key", "")) for k in path):
+            keys = [str(getattr(k, "key", "")) for k in path]
+            if any("router" in k or k.startswith("hc_") for k in keys):
                 return jnp.asarray(x, jnp.float32)
             return jnp.asarray(x, c.dtype)
 
@@ -264,14 +289,52 @@ class InferenceEngineV2:
                 pad_page=self.kv.config.pad_page_flag)
         return self._wrappers[key]
 
-    def _counted(self, key, fn):
+    def _counted(self, key, fn, name: str):
         """Wrap a traceable fn so each XLA trace bumps ``trace_counts[key]``
-        (the Python body only runs while tracing — cache hits skip it)."""
+        (the Python body only runs while tracing — cache hits skip it).
+        ``name`` becomes the compiled module's (``jit_<name>``), one per
+        program, so a device profile's operations can be looked up in the
+        program they belong to (:meth:`_register_program`)."""
         def wrapped(*args):
             self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
             return fn(*args)
 
+        wrapped.__name__ = wrapped.__qualname__ = name
         return wrapped
+
+    def _register_program(self, name: str, store: str, key,
+                          bucket: Tuple[int, int],
+                          with_rng: bool = False) -> None:
+        """Register the compiled program ``getattr(self, store)[key]``'s
+        text for reading a profile by name scope
+        (``profiling/xprof_parse``), as ``runtime/engine.py`` does for the
+        train step.  The text is made (from the compile cache) only if
+        somebody asks.  The registry outlives the engine, so the callable
+        holds a weak reference and nothing else of it: the jitted program's
+        closure holds the engine, its parameters and its page pool."""
+        import weakref
+
+        from ...profiling.xprof_parse import register_step_text
+        from .ragged.ragged_wrapper import pack_layout
+
+        ref = weakref.ref(self)
+        n_meta = pack_layout(bucket[0], bucket[1], self._wrapper_for(
+            bucket).max_blocks)["_total"][0]
+
+        def text():
+            eng = ref()
+            if eng is None:
+                return None
+            jitted = getattr(eng, store)[key]
+            struct = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+                x.shape, x.dtype, sharding=getattr(x, "sharding", None))
+            args = [jax.tree.map(struct, eng.params), struct(eng.kv.pages),
+                    jax.ShapeDtypeStruct((n_meta,), jnp.int32)]
+            if with_rng:
+                args.append(struct(eng._rng))
+            return jitted.lower(*args).compile().as_text()
+
+        register_step_text(f"serve/{name}@{id(self):x}", text)
 
     def _graph_lint_bucket(self, kind: str, key: Tuple[int, int], raw_fn,
                            with_rng: bool = False) -> None:
@@ -336,8 +399,10 @@ class InferenceEngineV2:
                 block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
                 jit=False, kv_replicate=self._kv_replicate)
             self._graph_lint_bucket("prefill", key, fn)
-            self._steps[key] = jax.jit(self._counted(key, fn),
+            name = f"serve_prefill_t{key[0]}"
+            self._steps[key] = jax.jit(self._counted(key, fn, name),
                                        donate_argnums=(1,))
+            self._register_program(name, "_steps", key, key)
         return self._steps[key]
 
     def _verify_step_for(self, key: Tuple[int, int]):
@@ -358,7 +423,9 @@ class InferenceEngineV2:
                 jit=False, kv_replicate=self._kv_replicate)
             self._graph_lint_bucket("verify", key, fn)
             self._verify_steps[key] = jax.jit(
-                self._counted(("verify",) + key, fn), donate_argnums=(1,))
+                self._counted(("verify",) + key, fn,
+                              f"serve_verify_t{key[0]}"),
+                donate_argnums=(1,))
         return self._verify_steps[key], first
 
     # ------------------------------------------------------------------ #
@@ -417,7 +484,9 @@ class InferenceEngineV2:
                 # ONE metadata transfer per forward: ~15 small H2D copies
                 # per decode step cost more than the step itself
                 dev = jnp.asarray(packed)
-                logits, new_pages = self._step_for(bucket)(
+                # (a routed family's step also returns its pairs per
+                # expert; a prefill leaves them on the device)
+                logits, new_pages, *_ = self._step_for(bucket)(
                     self.params, self.kv.pages, dev)
                 self.kv.update(new_pages)
                 for uid in batch.uids:
@@ -675,6 +744,10 @@ class InferenceEngineV2:
         windows."""
         n = len(uids)
         assert n == len(seed_tokens) == len(drafts)
+        if self.latent_kv:
+            raise NotImplementedError(
+                "verify_decode (speculative decoding) is not supported with "
+                "latent (MLA) pages: serve this model without a drafter")
         lens = [1 + len(d) for d in drafts]
         if sum(lens) > self.config.max_tokens:
             # fail BEFORE touching allocator/descriptor state: the ragged
@@ -946,8 +1019,12 @@ class InferenceEngineV2:
                 top_k=top_k, jit=False, kv_replicate=self._kv_replicate)
             self._graph_lint_bucket("decode_loop", bucket, loop,
                                     with_rng=True)
+            name = f"serve_decode_s{bucket[0]}x{steps}"
             self._decode_loops[key] = jax.jit(
-                self._counted(("decode",) + key, loop), donate_argnums=(1,))
+                self._counted(("decode",) + key, loop, name),
+                donate_argnums=(1,))
+            self._register_program(name, "_decode_loops", key, bucket,
+                                   with_rng=True)
         if rng is None:
             # persistent engine key: re-seeding each window with a constant
             # would repeat the identical sample stream every call
@@ -979,8 +1056,8 @@ class InferenceEngineV2:
                 meta_dev = jnp.asarray(wrapper.finalize().pack())
                 resume = False
             self._poison_kv(uids[0])
-        toks, new_pages, meta_out, nonfinite = self._decode_loops[key](
-            self.params, self.kv.pages, meta_dev, rng)
+        toks, new_pages, meta_out, nonfinite, *extra = \
+            self._decode_loops[key](self.params, self.kv.pages, meta_dev, rng)
         self.kv.update(new_pages)
         seen = {}
         for uid in uids:
@@ -999,7 +1076,8 @@ class InferenceEngineV2:
         mean_ctx = float(np.mean(ctx_before)) + steps / 2.0 if n else 0.0
         window = DecodeWindow(self, toks, n, steps, mean_ctx, t0,
                               resumed=resume, compiled=first_compile,
-                              uids=list(uids), nonfinite_dev=nonfinite)
+                              uids=list(uids), nonfinite_dev=nonfinite,
+                              moe_pairs_dev=extra[0] if extra else None)
         window._state = self._decode_state
         return window
 
@@ -1047,8 +1125,9 @@ class InferenceEngineV2:
             n_seqs, steps, mean_ctx, duration_s, resumed, compiled = facts
             cfg = self.cfg
             report = decode_roofline_report(decode_window_bytes(
-                num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.head_dim,
+                num_layers=cfg.num_layers,
+                num_kv_heads=self._kv_row_heads[0],
+                head_dim=self._kv_row_heads[1],
                 kv_dtype_bytes=jnp.dtype(self.kv.config.dtype).itemsize,
                 param_bytes=self._param_bytes, n_seqs=n_seqs, steps=steps,
                 mean_ctx=mean_ctx), duration_s, n_seqs, steps)
@@ -1091,7 +1170,11 @@ class InferenceEngineV2:
         # number that matters; flops ride along for the AI)
         cfg = self.cfg
         attn_bytes = report["kernels"]["decode_attention"]["bytes"]
-        attn_flops = (4.0 * cfg.num_heads * cfg.head_dim
+        # QK + PV per cached token per head: 2*hd each, or in the absorbed
+        # latent form 2*(latent_dim + kv_lora_rank)
+        per_ctx = 2.0 * (cfg.latent_dim + cfg.kv_lora_rank) \
+            if self.latent_kv else 4.0 * cfg.head_dim
+        attn_flops = (per_ctx * cfg.num_heads
                       * window.mean_ctx * window.n_seqs * window.steps
                       * cfg.num_layers)
         kname = "decode_paged" if self.config.attn_impl == "paged" \
@@ -1220,7 +1303,8 @@ class DecodeWindow:
     def __init__(self, engine: "InferenceEngineV2", toks_dev, n_seqs: int,
                  steps: int, mean_ctx: float, t0: float,
                  resumed: bool = False, compiled: bool = False,
-                 uids: Optional[List[int]] = None, nonfinite_dev=None):
+                 uids: Optional[List[int]] = None, nonfinite_dev=None,
+                 moe_pairs_dev=None):
         self.engine = engine
         self.n_seqs = n_seqs
         self.steps = steps
@@ -1233,6 +1317,10 @@ class DecodeWindow:
         self.uids = list(uids) if uids is not None else []
         self._toks_dev = toks_dev
         self._nonfinite_dev = nonfinite_dev
+        #: routed families: (token, choice) pairs per expert, summed over
+        #: the window's steps and expert layers; host numpy after the drain
+        self._moe_pairs_dev = moe_pairs_dev
+        self.moe_pairs: Optional[np.ndarray] = None
         self._t0 = t0
         self._toks: Optional[np.ndarray] = None
         #: per-sequence poison flags [n_seqs], populated at drain: True
@@ -1252,15 +1340,21 @@ class DecodeWindow:
                 toks_dev = self._toks_dev[:, :self.n_seqs]
                 bad_dev = None if self._nonfinite_dev is None \
                     else self._nonfinite_dev[:self.n_seqs]
-                jax.block_until_ready((toks_dev, bad_dev))
+                jax.block_until_ready((toks_dev, bad_dev,
+                                       self._moe_pairs_dev))
             with _TRACER.span("engine/window_fetch"):
                 self._toks = np.asarray(toks_dev)
                 self.nonfinite = np.zeros(self.n_seqs, bool) \
                     if bad_dev is None else np.asarray(bad_dev)
+                if self._moe_pairs_dev is not None:
+                    self.moe_pairs = np.asarray(self._moe_pairs_dev)
             self.duration_s = time.perf_counter() - self._t0
             self._toks_dev = None
             self._nonfinite_dev = None
-            with _TRACER.span("engine/window_account"):
+            self._moe_pairs_dev = None
+            with _TRACER.span("engine/window_account") as asp:
+                if self.moe_pairs is not None:
+                    self._account_moe(asp)
                 if self._state is not None and \
                         self.engine._decode_state is self._state:
                     # the last sampled token is the next window's seed: once
@@ -1270,6 +1364,22 @@ class DecodeWindow:
                         int(t) for t in self._toks[-1])
                 self.engine._account_decode_window(self)
         return self._toks
+
+    def _account_moe(self, sp) -> None:
+        """Dropless by construction: every (token, choice) pair of the
+        window's live rows reached an expert.  ``moe_pairs_dropped`` is
+        what the routing says it should have computed less what the
+        experts' groups held: 0, asserted."""
+        cfg = self.engine.cfg
+        pairs = int(self.moe_pairs.sum())
+        expected = (self.n_seqs * self.steps * cfg.num_moe_layers
+                    * cfg.num_experts_per_tok)
+        dropped = expected - pairs
+        assert dropped == 0, \
+            f"dropless expert layer lost pairs: {expected} routed, " \
+            f"{pairs} computed"
+        sp.set(moe_pairs=pairs, moe_pairs_dropped=dropped,
+               moe_load_max_share=float(self.moe_pairs.max()) / max(pairs, 1))
 
     def nonfinite_uids(self) -> List[int]:
         """uids whose logits went non-finite during this window (drains

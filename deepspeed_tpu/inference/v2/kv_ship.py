@@ -59,6 +59,15 @@ class KVShipment:
         return len(self.tokens)
 
 
+def _refuse_latent(engine, what: str) -> None:
+    """A shipment is canonical K/V rows ``[L, n, 2*KV, hd]``; a latent (MLA)
+    page has no such shape, and a reshaped copy would be silently wrong."""
+    if getattr(engine, "latent_kv", False):
+        raise NotImplementedError(
+            f"{what} is not supported with latent (MLA) pages: the shipment "
+            f"format holds K/V rows per head")
+
+
 def export_kv(engine, uid: int, tokens: List[int],
               n_tokens: Optional[int] = None) -> KVShipment:
     """Snapshot the first ``n_tokens`` cached rows of ``uid`` (default:
@@ -68,6 +77,7 @@ def export_kv(engine, uid: int, tokens: List[int],
     conversation's cache."""
     import jax.numpy as jnp
 
+    _refuse_latent(engine, "export_kv (prefill_only requests)")
     seq = engine.state_manager.get_sequence(uid)
     assert seq is not None, f"export of unknown uid {uid}"
     n = seq.seen_tokens if n_tokens is None else min(int(n_tokens),
@@ -100,6 +110,7 @@ def import_kv(engine, shipment: KVShipment, uid: int) -> bool:
     (wrong model), which no retry can fix."""
     import jax.numpy as jnp
 
+    _refuse_latent(engine, "import_kv (kv_import requests)")
     c = engine.kv.config
     if (shipment.num_layers != engine.cfg.num_layers
             or shipment.num_kv_heads != c.num_kv_heads

@@ -234,6 +234,11 @@ class LifecycleScheduler:
         #: otherwise it is built from the config.
         self.spec = speculative
         self.drafter = drafter
+        if (speculative is not None or drafter is not None) \
+                and getattr(engine, "latent_kv", False):
+            raise ValueError(
+                "speculative= / drafter= (verify windows) are not supported "
+                "with latent (MLA) pages: build the scheduler without them")
         if self.spec is not None and self.drafter is None:
             from .speculative import make_drafter
 
@@ -656,6 +661,7 @@ class LifecycleScheduler:
         c = self.eng.config
         budget = c.max_tokens
         admitted = 0
+        prefix_tokens = prompt_tokens = 0   # of the requests admitted here
         picked: List[Tuple[int, List[int]]] = []
         for uid in list(self._prefilling):
             if budget <= 0 or len(picked) >= c.max_seqs:
@@ -710,6 +716,9 @@ class LifecycleScheduler:
             head.state = RequestState.PREFILL
             self._prefilling[head.uid] = None
             admitted += 1
+            prompt_tokens += len(head.resume_prompt)
+            if head.kv_import is None:
+                prefix_tokens += head._prefill_pos      # grafted, not run
             self._admit_seq += 1
             head._admit_order = self._admit_seq
             # _prefill_pos may start past 0: grafted prefix / imported KV
@@ -722,7 +731,8 @@ class LifecycleScheduler:
         sp.set(admitted=admitted, preempted=int(preempted_this_pass),
                blocked=int(bool(self._waiting) and budget > 0
                            and len(picked) < c.max_seqs),
-               tokens=c.max_tokens - budget)
+               tokens=c.max_tokens - budget, prompt_tokens=prompt_tokens,
+               prefix_tokens=prefix_tokens)
         return picked
 
     def _run_prefill(self, batch: List[Tuple[int, List[int]]]) -> List[int]:

@@ -438,6 +438,152 @@ def ragged_forward_universal(params: Dict, kv_pages: jnp.ndarray, batch, cfg,
     return logits.astype(jnp.float32), new_pages
 
 
+def ragged_forward_xing(params: Dict, kv_pages: jnp.ndarray, batch, cfg,
+                        max_q: int, num_blocks: int,
+                        attn_impl: str = "paged", max_seqs: int = 0,
+                        max_blocks: int = 0, block_q: int = 128,
+                        pages_per_chunk: int = 8, decode_mode: bool = False,
+                        verify_mode: bool = False, kv_replicate=None):
+    """Paged ragged serving for the Xing4 family (models/xing4.py): latent
+    (MLA) pages, sigmoid-routed experts beside a shared expert, and
+    hyper-connection residual streams.  → (last-token logits [max_seqs, V],
+    new pages, pairs per expert [E] int32 summed over the expert layers).
+
+    Two scans, because the layers are not all alike: the leading dense
+    stack, then the expert stack.  The carry is ``[T, hc_mult, D]`` float32.  The
+    pool is ``[L·num_blocks + 1, page_size, latent_row]``; attention runs in
+    the absorbed form against it (kernels/mla_ops.py) for prefill chunks and
+    decode alike."""
+    from ...models import xing4 as X
+    from ...moe.dropless import sigmoid_moe_block
+    from .kernels import mla_ops
+
+    if verify_mode or kv_replicate is not None:
+        raise NotImplementedError(
+            "xing4 serving: speculative verify windows and tensor-parallel "
+            "params are not supported with latent pages")
+    batch = _unpack_batch(batch, max_q, max_seqs, max_blocks)
+    tokens = batch["tokens"]
+    page_of = batch["page_of_token"]
+    off_of = batch["off_of_token"]
+    pos = batch["pos_of_token"]
+    q_len, ctx_len = batch["q_len"], batch["ctx_len"]
+    T = tokens.shape[0]
+    R, scale = cfg.kv_lora_rank, cfg.softmax_scale
+    dtype = params["embed"]["embedding"].dtype
+    trash_page = kv_pages.shape[0] - 1
+    batch_valid = page_of < num_blocks
+    E = cfg.n_routed_experts
+
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0
+                     ).astype(jnp.float32)
+        # the embedding is copied into every residual stream; the carry
+        # is float32 (models/xing4.hc_sublayer)
+        x = jnp.broadcast_to(x[:, None, :], (T, cfg.hc_mult, x.shape[-1]))
+    cos, sin = X.rope_at(pos, cfg)
+
+    def attend(q_abs, pages, l_idx):
+        pt_l = batch["block_table"] + l_idx * num_blocks
+        if attn_impl == "paged" and decode_mode:
+            SW = min(q_len.shape[0], T)
+            out = mla_ops.mla_decode_attention(
+                q_abs[:SW], pages, ctx_len[:SW], pt_l[:SW], rank=R,
+                scale=scale, pages_per_chunk=pages_per_chunk)
+            return jnp.pad(out, ((0, T - SW), (0, 0), (0, 0)))
+        if attn_impl == "paged":
+            return mla_ops.mla_ragged_prefill(
+                q_abs, pages, ctx_len, pt_l, batch["cu_q_lens"], rank=R,
+                scale=scale, block_q=min(block_q, 16),
+                pages_per_chunk=pages_per_chunk)
+        q_idx = jnp.clip(batch["q_offset"][:, None]
+                         + jnp.arange(max_q)[None, :], 0, T - 1)
+        q_seq = jnp.take(q_abs, q_idx.reshape(-1), axis=0).reshape(
+            (-1, max_q) + q_abs.shape[1:])
+        o_seq = mla_ops.mla_attend_dense(q_seq, pages, pt_l, q_len, ctx_len,
+                                         rank=R, scale=scale).astype(dtype)
+        within = jnp.clip(
+            jnp.arange(T) - jnp.take(batch["q_offset"],
+                                     batch["seq_of_token"]), 0, max_q - 1)
+        return o_seq[batch["seq_of_token"], within]
+
+    def layer_step(moe):
+        def step(carry, inputs):
+            xs, pages, pairs = carry
+            lp, l_idx = inputs
+
+            def attention(h):
+                with jax.named_scope("attention/mla_q"):
+                    q_nope, q_rope = X.mla_query(h, lp, cos, sin, cfg)
+                    q_abs = X.mla_absorb_query(q_nope, q_rope, lp, cfg)
+                with jax.named_scope("attention/mla_kv"):
+                    rows = X.mla_latent(h, lp, cos, sin, cfg)
+                    new_pages = mla_ops.latent_append(
+                        pages, rows,
+                        _layer_pages(page_of, l_idx, num_blocks, trash_page),
+                        off_of)
+                with jax.named_scope("attention/mla_core"):
+                    o_lat = attend(q_abs, new_pages, l_idx).astype(dtype)
+                    return X.mla_output(o_lat, lp, cfg), new_pages
+
+            xs, pages = X.hc_sublayer(xs, lp["hc_attn"],
+                                      lp["attn_norm"]["scale"], attention,
+                                      cfg, dtype)
+
+            def mlp(h):
+                if not moe:
+                    with jax.named_scope("mlp"):
+                        return X.dense_mlp(
+                            h, lp["gate_proj"]["kernel"],
+                            lp["up_proj"]["kernel"],
+                            lp["down_proj"]["kernel"]), None
+                # the experts' stack rides the closure, not the scan: see
+                # moe/dropless.dropless_experts
+                return sigmoid_moe_block(
+                    h, lp, k=cfg.num_experts_per_tok,
+                    scaling=cfg.routed_scaling_factor,
+                    renormalise=cfg.norm_topk_prob, valid=batch_valid,
+                    experts=params["moe_layers"]["experts"],
+                    layer=l_idx - cfg.num_dense_layers)
+
+            xs, layer_pairs = X.hc_sublayer(xs, lp["hc_mlp"],
+                                            lp["mlp_norm"]["scale"], mlp,
+                                            cfg, dtype)
+            if moe:
+                pairs = pairs + layer_pairs
+            return (xs, pages, pairs), None
+
+        return step
+
+    carry = (x, kv_pages, jnp.zeros((E,), jnp.int32))
+    with jax.named_scope("layers"):
+        Ld = cfg.num_dense_layers
+        if Ld:
+            carry, _ = jax.lax.scan(
+                layer_step(False), carry,
+                (params["dense_layers"], jnp.arange(Ld, dtype=jnp.int32)))
+        if cfg.num_moe_layers:
+            carry, _ = jax.lax.scan(
+                layer_step(True), carry,
+                ({k: v for k, v in params["moe_layers"].items()
+                  if k != "experts"},
+                 jnp.arange(Ld, cfg.num_layers, dtype=jnp.int32)))
+    x, new_pages, pairs = carry
+
+    with jax.named_scope("final_norm"):
+        # the streams are summed before the final norm
+        x = rms_norm(jnp.sum(x, axis=1),
+                     params["norm_f"]["scale"].astype(jnp.float32),
+                     cfg.norm_eps).astype(dtype)
+    with jax.named_scope("lm_head"):
+        last = jnp.take(x, batch["logit_idx"], axis=0)
+        if cfg.tie_embeddings:
+            logits = last @ params["embed"]["embedding"].T
+        else:
+            logits = last @ params["lm_head"]["kernel"]
+    return logits.astype(jnp.float32), new_pages, pairs
+
+
 def build_ragged_step(cfg, max_q: int, num_blocks: int,
                       attn_impl: str = "paged", max_seqs: int = 0,
                       max_blocks: int = 0, block_q: int = 128,
@@ -457,10 +603,12 @@ def build_ragged_step(cfg, max_q: int, num_blocks: int,
     NamedSharding) must be passed when params are TP-sharded — see
     :func:`paged_kv_append`."""
     from ...models.families import ArchConfig
+    from ...models.xing4 import Xing4Config
 
     assert attn_impl in ("paged", "gather"), \
         f"attn_impl must be 'paged' or 'gather', got {attn_impl!r}"
     body = ragged_forward_universal if isinstance(cfg, ArchConfig) \
+        else ragged_forward_xing if isinstance(cfg, Xing4Config) \
         else ragged_forward
     fn = partial(body, cfg=cfg, max_q=max_q, num_blocks=num_blocks,
                  attn_impl=attn_impl, max_seqs=max_seqs,
@@ -610,10 +758,18 @@ def build_decode_loop(cfg, *, max_q: int, max_seqs: int, max_blocks: int,
         meta = set_field(meta, "ctx_len", ctx)
         return meta
 
+    #: per-expert pair counts ride the window's carry for a family that
+    #: routes (they come back with the window's tokens: no new sync)
+    n_experts = getattr(cfg, "n_routed_experts", 0)
+
     def loop(params, kv_pages, meta, rng):
+        stats0 = jnp.zeros((n_experts,), jnp.int32) if n_experts else None
+
         def body(carry, _):
-            pages, meta, rng, bad = carry
-            logits, pages = step_fn(params, pages, meta)
+            pages, meta, rng, bad, stats = carry
+            logits, pages, *extra = step_fn(params, pages, meta)
+            if extra:       # xing4: (token, choice) pairs per expert
+                stats = stats + extra[0]
             # per-sequence poison flag: a NaN/Inf logit row marks ONLY its
             # own sequence (sticky across the window's steps)
             bad = bad | ~jnp.all(jnp.isfinite(logits), axis=-1)
@@ -624,11 +780,13 @@ def build_decode_loop(cfg, *, max_q: int, max_seqs: int, max_blocks: int,
             toks = sample_tokens(logits, sub, temperature=temperature,
                                  top_k=top_k)
             meta = advance(meta, toks)
-            return (pages, meta, rng, bad), toks
+            return (pages, meta, rng, bad, stats), toks
 
         bad0 = jnp.zeros(max_seqs, jnp.bool_)
-        (kv_pages, meta, _, bad), toks = jax.lax.scan(
-            body, (kv_pages, meta, rng, bad0), None, length=steps)
-        return toks, kv_pages, meta, bad
+        (kv_pages, meta, _, bad, stats), toks = jax.lax.scan(
+            body, (kv_pages, meta, rng, bad0, stats0), None, length=steps)
+        if stats0 is None:
+            return toks, kv_pages, meta, bad
+        return toks, kv_pages, meta, bad, stats
 
     return jax.jit(loop, donate_argnums=(1,)) if jit else loop

@@ -7,7 +7,9 @@ view of logical page ``p`` is physical page ``l * num_blocks + p``, so a
 per-layer page table is plain metadata arithmetic (``table + l * num_blocks``)
 and the paged-attention kernel needs no in-kernel layer index.  One page
 fetch carries K AND V for every kv head — a single contiguous DMA feeds all
-heads' compute (see kernels/ragged_ops.py).
+heads' compute (see kernels/ragged_ops.py).  A latent pool
+(``KVCacheConfig.latent_row``) keeps the same page arithmetic with pages of
+``[block_size, latent_row]``.
 
 The FINAL page (index ``num_layers * num_blocks``) is a shared trash page
 that padded tokens write into, keeping the append a single dense scatter
@@ -28,6 +30,18 @@ class KVCacheConfig:
     num_kv_heads: int
     head_dim: int
     dtype: object = jnp.bfloat16
+    #: > 0: a LATENT pool (multi-head latent attention).  A token's cache is
+    #: one row of ``latent_row`` values a layer, with no K/V pair and no
+    #: heads (``num_kv_heads`` / ``head_dim`` are then unused); pages are
+    #: ``[block_size, latent_row]`` (kernels/mla_ops.py).
+    latent_row: int = 0
+
+    @property
+    def token_shape(self) -> tuple:
+        """What one token holds in one layer's page."""
+        if self.latent_row:
+            return (self.latent_row,)
+        return (2 * self.num_kv_heads, self.head_dim)
 
     @property
     def total_pages(self) -> int:
@@ -51,8 +65,7 @@ class BlockedKVCache:
         self.config = config
         c = config
         self.pages = jnp.zeros(
-            (c.total_pages, c.block_size, 2 * c.num_kv_heads, c.head_dim),
-            c.dtype)
+            (c.total_pages, c.block_size) + c.token_shape, c.dtype)
 
     def update(self, pages) -> None:
         self.pages = pages

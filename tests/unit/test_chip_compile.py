@@ -57,13 +57,15 @@ def for_the_chip(monkeypatch):
 
     from deepspeed_tpu.accelerator import real_accelerator
     from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
-    from deepspeed_tpu.inference.v2.kernels import ragged_ops
+    from deepspeed_tpu.inference.v2.kernels import mla_ops, ragged_ops
     from deepspeed_tpu.kernels import fused_collective_matmul as fcm
+    from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.adam import fused_adam
     from deepspeed_tpu.ops.transformer import flash_attention as fa
 
-    for mod in (fa, fcm, ragged_ops, fused_adam):
+    for mod in (fa, fcm, ragged_ops, mla_ops, fused_adam):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     monkeypatch.setattr(fcm, "resolve_impl",
                         lambda impl="auto": "pallas" if impl == "auto"
                         else impl)
@@ -188,7 +190,70 @@ def _train_step(zero_stage):
     return build
 
 
+def _mla(decode):
+    """Xing4.0-29B-A4B's latent attention at its published widths: 32
+    heads on one 640-lane latent row a token, contexts to 25,088."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels import mla_ops
+
+        seqs, blocks, row = 64, 25088 // PAGE, 640
+        pool = _on(dev, (5 * 3000 + 1, PAGE, row))
+        lens = _on(dev, (seqs,), jnp.int32)
+        table = _on(dev, (seqs, blocks), jnp.int32)
+        kw = dict(rank=512, scale=0.1447)
+        if decode:
+            return (lambda q, p, n, t: mla_ops.mla_paged_decode(
+                q, p, n, t, **kw)), (_on(dev, (seqs, H, row)), pool, lens,
+                                     table)
+        return (lambda q, p, n, t, cu: mla_ops.mla_ragged_prefill(
+            q, p, n, t, cu, **kw)), \
+            (_on(dev, (512, H, row)), pool, lens, table,
+             _on(dev, (seqs + 1,), jnp.int32))
+    return build
+
+
+def _grouped_matmul(rows):
+    """64 experts of width 1024 on hidden 3584: ``rows`` (token, choice)
+    pairs sorted by expert (256 = a 64-wide decode step, 2048 = a 512-token
+    prefill chunk), up and down."""
+    def build(dev):
+        from deepspeed_tpu.moe.dropless import grouped_matmul
+
+        def both(x, up, down, sizes):
+            return grouped_matmul(grouped_matmul(x, up, sizes), down, sizes)
+
+        return both, (_on(dev, (rows, 3584)), _on(dev, (64, 3584, 1024)),
+                      _on(dev, (64, 1024, 3584)), _on(dev, (64,), jnp.int32))
+    return build
+
+
+def _xing4_decode_window(dev):
+    """A whole fused decode window of the benchmark's Xing4 configuration
+    (1 dense + 4 expert layers, published widths, 64 sequences x 2 steps):
+    five Mosaic calls in one program, two scans, the [T, 4, D] carry."""
+    from deepspeed_tpu.inference.v2.model_runner import build_decode_loop
+    from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import pack_layout
+    from deepspeed_tpu.models.xing4 import Xing4Config, Xing4LM
+
+    cfg = Xing4Config(num_layers=5, first_k_dense=1)
+    shapes = jax.eval_shape(lambda k: Xing4LM(cfg).init_params(k, BF16),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
+    seqs, blocks, nb = 64, 25088 // PAGE, 15000
+    loop = build_decode_loop(
+        cfg, max_q=seqs, max_seqs=seqs, max_blocks=blocks, block_size=PAGE,
+        num_blocks=nb, attn_impl="paged", steps=2, jit=False)
+    meta = pack_layout(seqs, seqs, blocks)["_total"][0]
+    return loop, (params, _on(dev, (5 * nb + 1, PAGE, cfg.latent_row)),
+                  _on(dev, (meta,), jnp.int32), _on(dev, (2,), jnp.uint32))
+
+
 CASES = {
+    "mla_paged_decode": _mla(decode=True),
+    "mla_ragged_prefill": _mla(decode=False),
+    "grouped_matmul[256 pairs]": _grouped_matmul(256),
+    "grouped_matmul[2048 pairs]": _grouped_matmul(2048),
+    "xing4_decode_window": _xing4_decode_window,
     "flash_fwd": _flash(grad=False),
     "flash_bwd": _flash(grad=True),
     "decode_paged_attention": _paged(decode=True),

@@ -270,6 +270,10 @@ def _http(method, url, body=None, timeout=30):
         return e.code, resp
 
 
+#: the drain scenario's in-flight answer (see its docstring)
+DRAIN_TOKENS = 900
+
+
 def scenario_drain(check):
     """SIGTERM the real dstpu-serve during an active decode.
 
@@ -281,12 +285,19 @@ def scenario_drain(check):
     the moment the request finishes; the budget is a ceiling, not a
     sleep), and every wait below synchronizes on an observable state
     transition (healthz pending / draining, process exit) rather than a
-    fixed wall-time margin."""
+    fixed wall-time margin.
+
+    Deflaked again (PR 28): with the compile cache warm (``.jax_cache`` in
+    the checkout, PR 21) the toy model's 64-token answer was over inside
+    one 0.1 s poll of ``/healthz``, so ``pending >= 1`` was never seen, the
+    SIGTERM met an idle server and nothing reported ``draining``.  The
+    in-flight answer is 900 tokens now (225 windows): long enough to be
+    observed and to be drained, whatever the cache holds."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO_ROOT, "bin", "dstpu-serve"),
          "--port", "0", "--bind", "127.0.0.1", "--max-tokens", "16",
-         "--max-seqs", "4", "--max-ctx", "96", "--block-size", "8",
+         "--max-seqs", "4", "--max-ctx", "1024", "--block-size", "8",
          "--window-steps", "4", "--drain-deadline", "300",
          "--telemetry-dir", "/tmp/dstpu_serve_smoke_tel"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
@@ -325,7 +336,8 @@ def scenario_drain(check):
         def long_request():
             result["resp"] = _http(
                 "POST", f"{base}/v1/generate",
-                {"prompt": [5, 6, 7], "max_new_tokens": 64}, timeout=400)
+                {"prompt": [5, 6, 7], "max_new_tokens": DRAIN_TOKENS},
+                timeout=400)
 
         t = threading.Thread(target=long_request, daemon=True)
         t.start()
@@ -390,7 +402,7 @@ def scenario_drain(check):
         code, resp = result.get("resp", (None, None))
         check("drain: in-flight request completed",
               code == 200 and resp and resp.get("state") == "finished"
-              and len(resp.get("tokens") or []) == 64,
+              and len(resp.get("tokens") or []) == DRAIN_TOKENS,
               f"code={code} resp={str(resp)[:200]}")
     except Exception as exc:  # noqa: BLE001
         check("drain scenario", False, repr(exc)[-300:])
