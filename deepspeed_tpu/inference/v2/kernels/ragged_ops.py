@@ -1,11 +1,9 @@
 """Pallas ragged/paged serving attention — the FastGen ``blocked_flash``
 equivalent on TPU.
 
-Round-4 redesign: the round-3 kernel walked ``max_blocks``
-grid steps per (atom, kv-head) with one tiny ``[rows, block_size]`` tile
-each — grid-step overhead swamped decode (measured: paged 11.8 tok/s vs its
-own dense-gather oracle at 16.9, 8k ctx on v5e).  This kernel moves the
-context walk INSIDE the kernel:
+The context walk runs INSIDE the kernel (an earlier design walked
+``max_blocks`` grid steps per (atom, kv-head) with one ``[rows, block_size]``
+tile each, and grid-step overhead swamped decode):
 
   * the grid is ``(num_q_blocks,)`` over the FLAT token axis — no atom
     packing, no per-sequence padding; a 64-seq decode batch is ONE grid step.
@@ -39,17 +37,36 @@ kernel (the final page is the shared trash page padded tokens write into).
 
 HBM traffic is O(tokens actually cached) and walk length O(real context),
 making 32k+ contexts servable at decode cost, not prefill cost.
+
+What the chip read (TPU v5e, 819 GB/s; PERF.md section 6, PR 29: the decode
+kernel alone at the Mistral-7B serving shapes, 32/8 heads of 128, bf16 pool,
+64-token pages, a layer's call).  With a head taken by a value slice of the
+chunk (``kv[:, :, h, :]`` on ``[P, ps, 2KV, hd]``, the 16 combined heads
+being the packed sublane axis) and float32 operands: 1,517 us for 64
+sequences of 128-1,280 tokens (15% of the HBM roof), 1,786 us for 28 of
+2-3k (21%).  The slice alone was two thirds of that (503 / 523 us with a
+same-sized contiguous read in its place); the float32 casts and dots cost
+nothing measurable, and bf16 operands WITH the slice were 1.6x slower
+still.  So ``decode_paged_attention`` reads a chunk's heads in pairs with a
+sublane-strided ref load of 32-bit words (``_decode_paged_kernel``), in the
+pool's dtype, and hands each sequence's first chunk to the DMA engine
+behind the previous sequence's last compute: 308 us (74%) and 428 us (86%),
+which is what the same walk takes with its compute removed.  The ragged
+(prefill / verify) kernel below still slices and casts per head.
 """
 from __future__ import annotations
 
 import functools
 import math
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ....telemetry import get_tracer
 
 _NEG_INF = -1e30
 
@@ -379,78 +396,149 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
 # ===================================================================== #
 # Decode-specialized paged attention (the serving fast path)
 # ===================================================================== #
+def _decode_head_load(dtype, KV: int, hd: int, ps: int) -> str:
+    """Which load a chunk's heads get — decided from what the pool shows.
+
+    ``"strided"``: the pool's dtype packs two rows into a 32-bit word
+    (bf16), the ``2·KV`` combined heads of a token fill whole sublane
+    tiles of that dtype (so the VMEM pages flatten to ``[tokens·2KV, hd]``
+    without padding) and ``hd`` fills whole lanes.  Anything else —
+    float32 pools, ``2·KV`` below a tile, narrow heads — is ``"general"``.
+    """
+    packing = 4 // jnp.dtype(dtype).itemsize
+    if packing == 2 and (2 * KV) % (8 * packing) == 0 and hd % 128 == 0 \
+            and ps % 8 == 0:
+        return "strided"
+    return "general"
+
+
 def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                          q_ref, pages_ref, o_ref,        # VMEM block / HBM
-                         kv_bufs, sems, acc, m_scr, l_scr,
-                         *, scale, ps, P, KV, G, NB, alibi, alibi_scaled):
+                         kv_bufs, sems, acc, m_scr, l_scr, carry,
+                         *, scale, ps, P, KV, G, NB, alibi, alibi_scaled, hpg):
     """One grid step = ONE decoding sequence's single query token.
 
     The ragged kernel spends a ``[block_q·G, chunk]`` MXU tile per chunk even
     when only one row is a real decode query — ~``block_q``× wasted compute
-    per sequence.  Here the tile is ``[G, chunk]`` (just the query heads that
-    share a KV head), the context walk covers ONLY this sequence's pages, and
-    there is no in-kernel sequence scan at all.  GQA head packing is free:
-    a page holds K and V for every kv head (``[ps, 2KV, hd]``), so the G
-    query heads of each KV group ride the same double-buffered page fetch.
+    per sequence.  Here the walk covers ONLY this sequence's pages, there is
+    no in-kernel sequence scan, and a page's K and V for every kv head ride
+    one double-buffered fetch (``[ps, 2KV, hd]``).
+
+    How a chunk's heads reach the MXU (PR 29; the figures are in the module
+    docstring).  Operands go in the POOL's dtype, accumulation and the
+    softmax state are float32.  ``hpg`` is the number of kv heads scored a
+    pass.  With ``hpg == 2`` (the strided load) the chunk buffer is read as
+    32-bit words: word row ``t·KV + j`` holds combined heads
+    ``2j`` (low half) and ``2j+1`` (high half) of token ``t``, so ONE
+    sublane-strided ref load (start ``j``, stride ``KV``) yields heads
+    ``2j, 2j+1`` of all ``CH`` tokens, each vreg of the buffer read once,
+    and its bitcast back to the pool's dtype is the ``[2·CH, hd]`` matrix
+    whose even rows are head ``2j`` and odd rows head ``2j+1``.  That pair
+    is scored in one pass against the ``2·G`` query rows of both heads;
+    the columns of the other head's parity are masked like columns past
+    the context, so their probabilities are exactly 0 and the same
+    interleaved V pair sums each head's own rows.  No per-head sublane
+    gather, no unpacking, no cast.  ``hpg == 1`` (the general load) scores
+    one head a pass from a value slice of the chunk: the same body with no
+    parity mask.
     """
-    s = pl.program_id(0)
+    s, S = pl.program_id(0), pl.num_programs(0)
     kvl = kvl_ref[s]
     CH = P * ps                               # context tokens per chunk
     nch = _cdiv(kvl, CH)
+    NG, R, W = KV // hpg, hpg * G, hpg * CH   # passes, query rows, columns
+    dtype, hd = kv_bufs.dtype, kv_bufs.shape[-1]
 
-    def page_needed(page_idx):
-        return page_idx * ps < kvl
+    def page_needed(seq, page_idx):
+        return page_idx * ps < kvl_ref[seq]
 
-    def chunk_dma(c, slot, p):
+    def chunk_dma(seq, c, slot, p):
         page_idx = c * P + p
-        pid = pt_ref[s, jnp.minimum(page_idx, NB - 1)]
+        pid = pt_ref[seq, jnp.minimum(page_idx, NB - 1)]
         return pltpu.make_async_copy(
             pages_ref.at[pid], kv_bufs.at[slot, p], sems.at[slot, p])
 
-    def start_chunk(c, slot):
+    def start_chunk(seq, c, slot):
         for p in range(P):
-            @pl.when(page_needed(c * P + p))
+            @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(c, slot, p).start()
+                chunk_dma(seq, c, slot, p).start()
 
-    def wait_chunk(c, slot):
+    def wait_chunk(seq, c, slot):
         for p in range(P):
-            @pl.when(page_needed(c * P + p))
+            @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(c, slot, p).wait()
+                chunk_dma(seq, c, slot, p).wait()
 
     acc[:] = jnp.zeros_like(acc)
     m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
 
+    # carry[0] = 1: the previous grid step already started THIS sequence's
+    # first chunk, into slot carry[1].  Scratch and semaphores persist over
+    # grid steps, and the grid runs in order on one core.
+    @pl.when(s == 0)
+    def _():
+        carry[0] = 0
+    fetched = carry[0] == 1
+    slot0 = jnp.where(fetched, carry[1], 0)
+    carry[0] = 0
+
     @pl.when(kvl > 0)
     def _walk():
-        start_chunk(0, 0)
+        @pl.when(jnp.logical_not(fetched))
+        def _():
+            start_chunk(s, 0, slot0)
 
         def compute(c, slot):
-            k_pos = c * CH + \
-                jax.lax.broadcasted_iota(jnp.int32, (G, CH), 1)
+            col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+            k_pos = c * CH + col // hpg        # column -> context position
             mask = k_pos < kvl                 # decode: attend all cached ctx
-            col_ok = jax.lax.broadcasted_iota(
+            if hpg > 1:                        # ... of the row's own head
+                row = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
+                mask = mask & (row // G == col % hpg)
+            # never-DMA'd tokens hold stale data: scores there are masked,
+            # but V rows must be zeroed so 0·garbage(NaN) cannot poison the
+            # accumulate (select-before-multiply — the
+            # masked-nan-propagation pass contract)
+            tok_ok = jax.lax.broadcasted_iota(
                 jnp.int32, (CH, 1), 0) + c * CH < kvl
-            kv = kv_bufs[slot]                 # [P, ps, 2KV, hd]
-            for h in range(KV):
-                qh = q_ref[0, h * G:(h + 1) * G, :].astype(jnp.float32)
-                kh = kv[:, :, h, :].reshape(CH, -1).astype(jnp.float32)
-                # never-DMA'd columns hold stale data: scores there are
-                # masked, but V rows must be zeroed so 0·garbage(NaN)
-                # cannot poison the accumulate (select-before-multiply —
-                # the masked-nan-propagation pass contract)
-                vh = jnp.where(col_ok, kv[:, :, KV + h, :].reshape(CH, -1),
-                               0.0).astype(jnp.float32)
-                s_mat = jnp.dot(qh, kh.T,
-                                preferred_element_type=jnp.float32) * scale
+            if hpg == 2:
+                words = kv_bufs.at[slot].reshape(CH * 2 * KV, hd) \
+                    .bitcast(jnp.uint32)       # [CH·KV, hd]
+
+                def pair(j, keep=None):        # combined heads 2j, 2j+1
+                    w = words[pl.ds(j, CH, stride=KV), :]
+                    if keep is not None:       # a word row is one token
+                        w = jnp.where(keep, w, jnp.uint32(0))
+                    return pltpu.bitcast(w, dtype)        # [2·CH, hd]
+
+                def load_k(g):
+                    return pair(g)
+
+                def load_v(g):
+                    return pair(KV // 2 + g, tok_ok)
+            else:
+                kv = kv_bufs[slot]             # [P, ps, 2KV, hd]
+
+                def load_k(g):
+                    return kv[:, :, g, :].reshape(CH, hd)
+
+                def load_v(g):
+                    return jnp.where(
+                        tok_ok, kv[:, :, KV + g, :].reshape(CH, hd), 0.0)
+
+            for g in range(NG):
+                qg = q_ref[0, g * R:(g + 1) * R, :].astype(dtype)
+                s_mat = jax.lax.dot_general(
+                    qg, load_k(g), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
                 if alibi is not None:
-                    r = jax.lax.broadcasted_iota(jnp.int32, (G, CH), 0)
-                    slope = jnp.zeros((G, CH), jnp.float32)
-                    for g in range(G):         # static per-head slope
-                        slope = jnp.where(r == g,
-                                          jnp.float32(alibi[h * G + g]),
+                    r = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
+                    slope = jnp.zeros((R, W), jnp.float32)
+                    for i in range(R):         # static per-head slope
+                        slope = jnp.where(r == i,
+                                          jnp.float32(alibi[g * R + i]),
                                           slope)
                     if alibi_scaled:           # falcon: bf16 pre-scale bias
                         bias = (slope.astype(jnp.bfloat16) *
@@ -461,36 +549,48 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                     s_mat = s_mat + bias
                 s_mat = jnp.where(mask, s_mat, _NEG_INF)
 
-                m_prev = m_scr[h][:, :1]
+                m_prev = m_scr[g][:, :1]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s_mat, axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
                 p_mat = jnp.exp(s_mat - m_new)
-                l_scr[h] = jnp.broadcast_to(
-                    alpha * l_scr[h][:, :1] +
-                    jnp.sum(p_mat, axis=1, keepdims=True), l_scr[h].shape)
-                acc[h] = acc[h] * alpha + \
-                    jnp.dot(p_mat, vh, preferred_element_type=jnp.float32)
-                m_scr[h] = jnp.broadcast_to(m_new, m_scr[h].shape)
+                l_scr[g] = jnp.broadcast_to(
+                    alpha * l_scr[g][:, :1] +
+                    jnp.sum(p_mat, axis=1, keepdims=True), l_scr[g].shape)
+                acc[g] = acc[g] * alpha + \
+                    jnp.dot(p_mat.astype(dtype), load_v(g),
+                            preferred_element_type=jnp.float32)
+                m_scr[g] = jnp.broadcast_to(m_new, m_scr[g].shape)
 
         def body(state):
             c, slot = state
 
             @pl.when(c + 1 < nch)
             def _prefetch():
-                start_chunk(c + 1, 1 - slot)
+                start_chunk(s, c + 1, 1 - slot)
 
-            wait_chunk(c, slot)
+            # behind this sequence's LAST compute: the next grid step's
+            # first chunk, into the buffer the walk has just left (a
+            # kv_lens == 0 row starts nothing and is handed nothing)
+            nxt = jnp.minimum(s + 1, S - 1)
+
+            @pl.when((c + 1 == nch) & (s + 1 < S) & (kvl_ref[nxt] > 0))
+            def _next_seq():
+                start_chunk(nxt, 0, 1 - slot)
+                carry[0] = 1
+                carry[1] = 1 - slot
+
+            wait_chunk(s, c, slot)
             compute(c, slot)
             return c + 1, 1 - slot
 
         jax.lax.while_loop(lambda st: st[0] < nch, body,
-                           (jnp.int32(0), jnp.int32(0)))
+                           (jnp.int32(0), slot0))
 
-    for h in range(KV):
-        l = l_scr[h][:, :1]
-        o = acc[h] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, h * G:(h + 1) * G, :] = o.astype(o_ref.dtype)
+    for g in range(NG):
+        l = l_scr[g][:, :1]
+        o = acc[g] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, g * R:(g + 1) * R, :] = o.astype(o_ref.dtype)
 
 
 def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
@@ -510,6 +610,11 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
                   Rows with kv_lens == 0 are padding and yield zeros.
       page_table: [S, NB] int32 physical page ids.
     Returns [S, H, hd].
+
+    The MXU sees ``q``, K, V and the probabilities in the POOL's dtype and
+    accumulates in float32 (a float32 pool rounds nothing).  Every traced
+    call leaves one ring-only ``attn/decode_layout`` record saying which
+    head load the compiled kernel got (:func:`_decode_head_load`).
     """
     S, H, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
@@ -523,8 +628,11 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     P = min(pages_per_chunk, NB)
+    load = _decode_head_load(kv_pages.dtype, KV, hd, ps)
+    hpg = 2 if load == "strided" else 1
 
-    # same VMEM accounting as the ragged kernel, with the [G, chunk] tile
+    # same VMEM accounting as the ragged kernel, with the [hpg·G, hpg·chunk]
+    # score tile
     VMEM_BUDGET = 12 * 1024 * 1024
     kv_itemsize = jnp.dtype(kv_pages.dtype).itemsize
 
@@ -532,7 +640,7 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         kv_bufs = 2 * p * ps * ckv * hd * kv_itemsize
         softmax = KV * G * (hd + 2 * 128) * 4
         qo = 2 * 2 * H * hd * jnp.dtype(q.dtype).itemsize
-        temps = 3 * G * (p * ps) * 4
+        temps = 3 * (hpg * G) * (hpg * p * ps) * 4
         return kv_bufs + softmax + qo + temps
 
     while P > 1 and _vmem_bytes(P) > VMEM_BUDGET:
@@ -550,9 +658,15 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         alibi = tuple(np.asarray(alibi, np.float32).tolist())
         assert len(alibi) == H, "alibi slopes must be per query head"
 
+    # trace time only: what a run says about the kernel it compiled
+    get_tracer().record(
+        "attn/decode_layout", time.perf_counter(), 0.0, load=load, P=P,
+        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=KV, group=G)
+
     kernel = functools.partial(
         _decode_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, NB=NB,
-        alibi=alibi, alibi_scaled=alibi_scaled)
+        alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg)
+    NG, R = KV // hpg, hpg * G
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -566,9 +680,10 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
             scratch_shapes=[
                 pltpu.VMEM((2, P, ps, ckv, hd), kv_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, P)),
-                pltpu.VMEM((KV, G, hd), jnp.float32),
-                pltpu.VMEM((KV, G, 128), jnp.float32),
-                pltpu.VMEM((KV, G, 128), jnp.float32),
+                pltpu.VMEM((NG, R, hd), jnp.float32),
+                pltpu.VMEM((NG, R, 128), jnp.float32),
+                pltpu.VMEM((NG, R, 128), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),

@@ -118,6 +118,24 @@ def _paged(decode):
     return build
 
 
+def _paged_decode_cell(rows):
+    """The K/V decode kernel as the Mistral serving cells run it: a
+    16-layer pool of 1,730 pages a layer, 64-page tables, bf16, 4 / 32 / 64
+    rows (prefill cell's side windows, long-context, closed-loop decode).
+    The strided pair load must lower for the chip, not only interpret."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
+            _decode_head_load, decode_paged_attention)
+
+        assert _decode_head_load(BF16, KV, HD, PAGE) == "strided"
+        return (lambda q, p, n, t: decode_paged_attention(
+            q, p, n, t, num_kv_heads=KV)), \
+            (_on(dev, (rows, H, HD)),
+             _on(dev, (16 * 1730 + 1, PAGE, 2 * KV, HD)),
+             _on(dev, (rows,), jnp.int32), _on(dev, (rows, 64), jnp.int32))
+    return build
+
+
 def _rmsnorm(d, f):
     def build(dev):
         from deepspeed_tpu.kernels.fused_collective_matmul import \
@@ -258,6 +276,9 @@ CASES = {
     "flash_bwd": _flash(grad=True),
     "decode_paged_attention": _paged(decode=True),
     "ragged_paged_attention": _paged(decode=False),
+    "decode_paged_attention[4 rows]": _paged_decode_cell(4),
+    "decode_paged_attention[32 rows]": _paged_decode_cell(32),
+    "decode_paged_attention[64 rows]": _paged_decode_cell(64),
     "rmsnorm_matmul[4096x14336]": _rmsnorm(D, F),      # gate / up
     "rmsnorm_matmul[4096x6144]": _rmsnorm(D, 6144),    # fused qkv width
     "rmsnorm_matmul[4096x1024]": _rmsnorm(D, 1024),    # k / v
@@ -336,21 +357,32 @@ def test_chip_smoke_needs_a_chip_or_its_rehearsal_option(capsys):
     assert "32->2" in phases["config"]["reduced"]
 
 
-@pytest.mark.parametrize("case", ["from_env", "checkout", "held_to_cpu"])
+@pytest.mark.parametrize("case", ["from_env", "checkout", "held_to_cpu",
+                                  "threshold_from_env"])
 def test_compile_cache_is_placed_from_outside(monkeypatch, case):
-    """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code.  Unset:
-    the one fixed path inside the checkout — except in a process held to
-    the CPU (this one), which gets no cache."""
+    """``JAX_COMPILATION_CACHE_DIR`` set: no directory is set in code.
+    Unset: the one fixed path inside the checkout — except in a process
+    held to the CPU (this one), which gets no cache.  Wherever the cache
+    lives, programs from a tenth of a second of compile time up are kept
+    (the narrow decode programs compile in under JAX's own second), unless
+    the threshold too is given from outside."""
     from deepspeed_tpu.utils import compile_cache
 
     calls = []
     monkeypatch.setattr(jax.config, "update",
                         lambda name, value: calls.append((name, value)))
     assert os.environ["JAX_PLATFORMS"] == "cpu"        # tests/conftest.py
-    if case == "from_env":
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    keep = ("jax_persistent_cache_min_compile_time_secs",
+            compile_cache.MIN_COMPILE_SECS)
+    if case in ("from_env", "threshold_from_env"):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        if case == "threshold_from_env":
+            monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                               "2")
         assert compile_cache.configure_compile_cache() == "/some/dir"
-        assert calls == []
+        assert calls == ([keep] if case == "from_env" else [])
         return
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     if case == "held_to_cpu":
@@ -360,4 +392,4 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, case):
     monkeypatch.delenv("JAX_PLATFORMS")                # as on the chip
     fixed = os.path.join(REPO_ROOT, ".jax_cache")
     assert compile_cache.configure_compile_cache() == fixed
-    assert calls == [("jax_compilation_cache_dir", fixed)]
+    assert calls == [keep, ("jax_compilation_cache_dir", fixed)]

@@ -131,6 +131,135 @@ class TestDecodeKernelParity:
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_d),
                                    atol=3e-5, rtol=3e-5)
 
+    # ---- the cells' geometry: a bf16 pool, KV 8 x G 4 x hd 128, page 64 -- #
+    # Tolerance.  The kernel hands q, K, V and the probabilities to the MXU
+    # in the pool's dtype and accumulates in float32; the dense lowering
+    # computes everything in float32 from the same bf16 inputs.  q.K
+    # products of bf16 values are exact in float32, so the scores differ
+    # by summation order only; the probabilities are rounded to bf16
+    # (relative 2^-9 each, on a weighted mean of |v| ~ 1 values: a few
+    # 1e-3 absolute at most) and BOTH outputs are rounded to bf16 at the
+    # end (2^-9 relative each: one bf16 ulp, 2^-8, between them).
+    BF16_TOL = dict(rtol=2.0 ** -7, atol=6e-3)
+    CELL = dict(KV=8, G=4, hd=128, ps=64, NB=10)
+
+    def _cell_case(self, seed, ctx, poison=True):
+        rng = np.random.default_rng(seed)
+        g = self.CELL
+        q, pages, kvl, pt = _decode_case(rng, ctx, g["KV"], g["G"], g["hd"],
+                                         g["ps"], g["NB"])
+        q, pages = q.astype(jnp.bfloat16), pages.astype(jnp.bfloat16)
+        if poison:        # every page the walk must not read: NaN
+            for s, c in enumerate(ctx):
+                for b in range(-(-c // g["ps"]), g["NB"]):
+                    pages = pages.at[int(pt[s, b])].set(jnp.nan)
+        return q, pages, kvl, pt
+
+    def _assert_matches_dense(self, q, pages, kvl, pt, KV, **kernel_kw):
+        """Kernel (interpret mode) against the dense float32 lowering;
+        returns the kernel's output as float32."""
+        dense_kw = {k: v for k, v in kernel_kw.items() if k == "alibi"}
+        out, ref = (np.asarray(o.astype(jnp.float32)) for o in (
+            decode_paged_attention(q, pages, kvl, pt, num_kv_heads=KV,
+                                   interpret=True, **kernel_kw),
+            decode_attend_dense(q, pages, kvl, pt, num_kv_heads=KV,
+                                **dense_kw)))
+        assert np.all(np.isfinite(out))
+        np.testing.assert_allclose(out, ref, **self.BF16_TOL)
+        return out
+
+    @pytest.mark.parametrize("ctx", [
+        pytest.param([512, 511, 513], id="chunk-boundary"),
+        pytest.param([1, 130, 1], id="one-token"),
+        pytest.param([513, 0, 65, 0, 512], id="padding-rows-between"),
+        pytest.param([640, 64, 600], id="nan-never-fetched"),
+    ])
+    def test_bf16_pool_at_cell_geometry(self, ctx):
+        """bf16 pool, the strided pair load (interpret mode) against the
+        dense float32 lowering: contexts on and either side of a 512-token
+        chunk boundary, one-token contexts, ``kv_lens == 0`` padding rows
+        between live rows (the first-chunk hand-over must skip them), and
+        NaN in every page past each context (never fetched, or fetched
+        behind the context's end: masked AND zeroed)."""
+        out = self._assert_matches_dense(*self._cell_case(30, ctx),
+                                         self.CELL["KV"])
+        for s, c in enumerate(ctx):
+            if c == 0:
+                np.testing.assert_array_equal(out[s], 0.0)
+
+    def test_bf16_pool_partial_last_page_holds_nan(self):
+        """A context ending INSIDE a page: the page is fetched, the rows
+        behind the context's end hold NaN (bf16) and must not reach the
+        output through a 0-probability product."""
+        ctx = [100, 577]
+        q, pages, kvl, pt = self._cell_case(31, ctx, poison=False)
+        ps = self.CELL["ps"]
+        for s, c in enumerate(ctx):
+            pid = int(pt[s, c // ps])
+            pages = pages.at[pid, c % ps:].set(jnp.nan)
+        self._assert_matches_dense(q, pages, kvl, pt, self.CELL["KV"])
+
+    @pytest.mark.parametrize("ppc", [1, 4, 8])
+    def test_bf16_pool_pages_per_chunk_invariance(self, ppc):
+        """1, 4 and 8 pages a chunk walk the same context to the same
+        answer (against the dense lowering, so each case stands alone)."""
+        self._assert_matches_dense(
+            *self._cell_case(32, [577, 0, 256, 129]), self.CELL["KV"],
+            pages_per_chunk=ppc)
+
+    def test_bf16_pool_alibi_rides_the_pair_tile(self):
+        """Per-head slopes in the two-heads-a-pass layout (MHA, so a pass
+        holds two query rows of different heads)."""
+        rng = np.random.default_rng(33)
+        KV, hd, ps, NB = 8, 128, 64, 4
+        slopes = [2.0 ** (-(i + 1) / 2) for i in range(KV)]
+        q, pages, kvl, pt = _decode_case(rng, [130, 64], KV, 1, hd, ps, NB)
+        self._assert_matches_dense(
+            q.astype(jnp.bfloat16), pages.astype(jnp.bfloat16), kvl, pt, KV,
+            alibi=slopes, pages_per_chunk=2)
+
+    def test_decode_layout_record_names_the_load(self):
+        """``attn/decode_layout`` (ring only, one per traced call): strided
+        at the cells' geometry, general at the float32 toy shapes, at a
+        bf16 pool whose 2.KV does not fill a sublane tile, at narrow
+        heads."""
+        from deepspeed_tpu.telemetry import get_tracer
+
+        def layout(q, pages, kvl, pt, KV):
+            n = len(get_tracer().records())
+            decode_paged_attention(q, pages, kvl, pt, num_kv_heads=KV,
+                                   interpret=True)
+            recs = [r.attrs for r in get_tracer().records()[n:]
+                    if r.name == "attn/decode_layout"]
+            assert len(recs) == 1
+            return recs[0]
+
+        q, pages, kvl, pt = self._cell_case(34, [70], poison=False)
+        assert layout(q, pages, kvl, pt, 8) == dict(
+            load="strided", P=8, dtype="bfloat16", kv_heads=8, group=4)
+        rng = np.random.default_rng(35)
+        toy = _decode_case(rng, [9, 5], 1, 2, 16, 4, 3)
+        assert layout(*toy, 1) == dict(
+            load="general", P=3, dtype="float32", kv_heads=1, group=2)
+        q4, p4, kvl4, pt4 = _decode_case(rng, [40], 4, 2, 128, 16, 4)
+        rec = layout(q4.astype(jnp.bfloat16), p4.astype(jnp.bfloat16),
+                     kvl4, pt4, 4)
+        assert (rec["load"], rec["dtype"]) == ("general", "bfloat16")
+        q5, p5, kvl5, pt5 = _decode_case(rng, [40], 8, 2, 64, 16, 4)
+        assert layout(q5.astype(jnp.bfloat16), p5.astype(jnp.bfloat16),
+                      kvl5, pt5, 8)["load"] == "general"
+
+    def test_bf16_pool_general_load_parity(self):
+        """A bf16 pool the strided load cannot take (2.KV = 8 rows: half
+        a bf16 sublane tile) runs the general load with bf16 operands."""
+        rng = np.random.default_rng(36)
+        KV, G, hd, ps, NB = 4, 2, 128, 16, 6
+        ctx = [33, 0, 80]
+        q, pages, kvl, pt = _decode_case(rng, ctx, KV, G, hd, ps, NB)
+        self._assert_matches_dense(
+            q.astype(jnp.bfloat16), pages.astype(jnp.bfloat16), kvl, pt, KV,
+            pages_per_chunk=2)
+
     def test_dispatch_seam(self):
         """decode_attention(impl=...) forces either lowering explicitly."""
         rng = np.random.default_rng(25)
