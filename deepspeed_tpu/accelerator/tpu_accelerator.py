@@ -28,7 +28,8 @@ class TPUAccelerator(Accelerator):
         """True when JAX reports a TPU device.  A backend that fails to
         start raises: the caller decides whether the CPU is acceptable
         (``real_accelerator._probe`` says so once; ``chip_smoke.py`` and
-        ``bench.py`` ask JAX directly and never come through here)."""
+        ``benchmark/run.py`` ask JAX directly and never come through
+        here)."""
         import jax
 
         return any(d.platform == "tpu" for d in jax.devices())

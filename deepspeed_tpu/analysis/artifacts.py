@@ -53,7 +53,6 @@ def build_inference_artifacts(attn_impl: str = "gather",
     from ..inference.v2.model_runner import (build_decode_loop,
                                              build_ragged_step,
                                              build_verify_step)
-    from ..inference.v2.ragged.ragged_wrapper import pack_layout
     from ..models.transformer import CausalLM, TransformerConfig
 
     cfg = TransformerConfig.tiny(use_flash=False)
@@ -63,7 +62,6 @@ def build_inference_artifacts(attn_impl: str = "gather",
         max_tokens=16, max_seqs=4, max_ctx=64, block_size=8,
         dtype=jnp.float32, attn_impl=attn_impl, block_q=16,
         pages_per_chunk=2))
-    c = eng.config
     params_struct = _struct_of(eng.params)
     pages = eng.kv.pages
     pages_struct = jax.ShapeDtypeStruct(pages.shape, pages.dtype)
@@ -77,21 +75,12 @@ def build_inference_artifacts(attn_impl: str = "gather",
             + ([None] if with_rng else [])
 
     def meta_struct(key):
-        n = pack_layout(key[0], key[1],
-                        eng._wrapper_for(key).max_blocks)["_total"][0]
-        return jax.ShapeDtypeStruct((n,), jnp.int32)
-
-    def common(key):
-        return dict(num_blocks=eng._num_blocks, attn_impl=c.attn_impl,
-                    max_seqs=key[1],
-                    max_blocks=eng._wrapper_for(key).max_blocks,
-                    block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
-                    jit=False, kv_replicate=eng._kv_replicate)
+        return jax.ShapeDtypeStruct((eng._meta_len(key),), jnp.int32)
 
     out: List[Artifact] = []
     # prefill bucket for an 8-token single-sequence put()
     pkey = eng.bucket_for(8, 1)
-    step = build_ragged_step(eng.cfg, max_q=pkey[0], **common(pkey))
+    step = build_ragged_step(eng.family, **eng._program_kw(pkey))
     out.append(Artifact(
         f"prefill[{attn_impl},bucket={pkey}]",
         jax.make_jaxpr(step)(params_struct, pages_struct,
@@ -103,12 +92,8 @@ def build_inference_artifacts(attn_impl: str = "gather",
     s_b = eng._seq_bucket(2)
     dkey = (s_b, s_b)
     loop = build_decode_loop(
-        eng.cfg, max_q=dkey[0], max_seqs=dkey[1],
-        max_blocks=eng._wrapper_for(dkey).max_blocks,
-        block_size=c.block_size, num_blocks=eng._num_blocks,
-        attn_impl=c.attn_impl, steps=4, temperature=0.0,
-        block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
-        top_k=0, jit=False, kv_replicate=eng._kv_replicate)
+        eng.family, block_size=eng.config.block_size, steps=4,
+        temperature=0.0, top_k=0, **eng._program_kw(dkey))
     rng_struct = _struct_of(jax.random.PRNGKey(0))
     out.append(Artifact(
         f"decode_loop[{attn_impl},bucket={dkey},steps=4]",
@@ -118,7 +103,7 @@ def build_inference_artifacts(attn_impl: str = "gather",
                     arg_shardings=arg_shardings(with_rng=True))))
 
     # spec-dec verify window at the same bucket
-    vstep = build_verify_step(eng.cfg, max_q=dkey[0], **common(dkey))
+    vstep = build_verify_step(eng.family, **eng._program_kw(dkey))
     out.append(Artifact(
         f"verify[{attn_impl},bucket={dkey}]",
         jax.make_jaxpr(vstep)(params_struct, pages_struct,

@@ -24,13 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.transformer import CausalLM, TransformerConfig
+from ...models.transformer import CausalLM
 from ...runtime.fault.injection import InjectedNaN, inject
 from ...telemetry.trace import get_tracer
 from ...utils.logging import log_dist, logger
 from .model_runner import build_ragged_step
 from .ragged.kv_cache import BlockedKVCache, KVCacheConfig
-from .ragged.ragged_wrapper import RaggedBatchWrapper
+from .ragged.ragged_wrapper import RaggedBatchWrapper, pack_layout
 from .ragged.sequence_descriptor import DSStateManager
 
 #: every call into the engine is a span on the process-global tracer (and a
@@ -109,24 +109,23 @@ class RaggedInferenceEngineConfig:
 class InferenceEngineV2:
     def __init__(self, model: CausalLM, params,
                  config: Optional[RaggedInferenceEngineConfig] = None):
-        from ...models.families import ArchConfig
-        from ...models.xing4 import Xing4Config
         from ...utils.compile_cache import configure_compile_cache
 
         configure_compile_cache()
         self.model = model
         self.cfg = model.config
-        if not isinstance(self.cfg, (TransformerConfig, ArchConfig,
-                                     Xing4Config)):
+        if not callable(getattr(model, "serving_family", None)):
             raise NotImplementedError(
-                f"ragged serving needs a TransformerConfig (native llama "
-                f"families), ArchConfig (universal gpt2/gptj/opt/bloom/"
-                f"falcon/phi families) or Xing4Config model; got "
-                f"{type(self.cfg).__name__}")
+                f"ragged serving needs a model that says what family it is "
+                f"(serving_family(), models/serving.py: CausalLM, "
+                f"UniversalCausalLM, Xing4LM); got {type(model).__name__}")
+        #: what the model is to the serving path: the cached row, the layer
+        #: stacks and their bodies (models/serving.ServingFamily)
+        self.family = model.serving_family()
         self.config = config or RaggedInferenceEngineConfig()
         c = self.config
         #: a latent (MLA) page pool: one row a token, no K/V pair, no heads
-        self.latent_kv = isinstance(self.cfg, Xing4Config)
+        self.latent_kv = self.family.row.latent
         if self.latent_kv and c.host_tier_mb > 0:
             # kv_swap parks pages as kv_ship rows [L, n, 2*KV, hd]: a
             # latent page has no such shape, and a reshaped copy would be
@@ -142,20 +141,10 @@ class InferenceEngineV2:
 
             self.state_manager.prefix_cache = RadixPrefixCache(
                 self.state_manager.allocator, c.block_size)
-        if self.latent_kv:
-            # decode_window_bytes counts 2*KV*hd values a token a layer:
-            # 2 * 1 * latent_dim/2 is the latent row
-            self._kv_row_heads = (1, self.cfg.latent_dim // 2)
-            self.kv = BlockedKVCache(KVCacheConfig(
-                num_layers=self.cfg.num_layers, num_blocks=num_blocks,
-                block_size=c.block_size, num_kv_heads=0, head_dim=0,
-                dtype=c.dtype, latent_row=self.cfg.latent_row))
-        else:
-            self._kv_row_heads = (self.cfg.num_kv_heads, self.cfg.head_dim)
-            self.kv = BlockedKVCache(KVCacheConfig(
-                num_layers=self.cfg.num_layers, num_blocks=num_blocks,
-                block_size=c.block_size, num_kv_heads=self.cfg.num_kv_heads,
-                head_dim=self.cfg.head_dim, dtype=c.dtype))
+        self.kv = BlockedKVCache(KVCacheConfig(
+            num_layers=self.family.num_layers, num_blocks=num_blocks,
+            block_size=c.block_size, token_shape=self.family.row.token_shape,
+            dtype=c.dtype))
         #: page-heat tracker (None = tracking off): observes the allocator
         #: so its live set mirrors the free list, ticked per forward below
         self.heat = None
@@ -315,11 +304,9 @@ class InferenceEngineV2:
         import weakref
 
         from ...profiling.xprof_parse import register_step_text
-        from .ragged.ragged_wrapper import pack_layout
 
         ref = weakref.ref(self)
-        n_meta = pack_layout(bucket[0], bucket[1], self._wrapper_for(
-            bucket).max_blocks)["_total"][0]
+        n_meta = self._meta_len(bucket)
 
         def text():
             eng = ref()
@@ -349,7 +336,6 @@ class InferenceEngineV2:
         try:
             from ...analysis import PassContext, run_graph_passes
             from ...telemetry.hub import emit_event
-            from .ragged.ragged_wrapper import pack_layout
 
             structs = [
                 jax.tree.map(
@@ -357,10 +343,7 @@ class InferenceEngineV2:
                     self.params),
                 jax.ShapeDtypeStruct(self.kv.pages.shape,
                                      self.kv.pages.dtype),
-                jax.ShapeDtypeStruct((pack_layout(
-                    key[0], key[1],
-                    self._wrapper_for(key).max_blocks)["_total"][0],),
-                    jnp.int32),
+                jax.ShapeDtypeStruct((self._meta_len(key),), jnp.int32),
             ]
             if with_rng:
                 structs.append(jax.ShapeDtypeStruct(self._rng.shape,
@@ -389,15 +372,23 @@ class InferenceEngineV2:
             log_dist(f"graph_lint: lint of {kind}{key} failed ({e}); "
                      f"serving continues", ranks=[0])
 
+    def _meta_len(self, key: Tuple[int, int]) -> int:
+        """Length of the packed metadata vector of the bucket ``key``."""
+        return pack_layout(key[0], key[1],
+                           self._wrapper_for(key).max_blocks)["_total"][0]
+
+    def _program_kw(self, key: Tuple[int, int]) -> Dict:
+        """What model_runner's builders take for the bucket ``key``."""
+        c = self.config
+        return dict(max_q=key[0], max_seqs=key[1],
+                    max_blocks=self._wrapper_for(key).max_blocks,
+                    num_blocks=self._num_blocks, attn_impl=c.attn_impl,
+                    block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
+                    jit=False, kv_replicate=self._kv_replicate)
+
     def _step_for(self, key: Tuple[int, int]):
         if key not in self._steps:
-            c = self.config
-            fn = build_ragged_step(
-                self.cfg, max_q=key[0], num_blocks=self._num_blocks,
-                attn_impl=c.attn_impl, max_seqs=key[1],
-                max_blocks=self._wrapper_for(key).max_blocks,
-                block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
-                jit=False, kv_replicate=self._kv_replicate)
+            fn = build_ragged_step(self.family, **self._program_kw(key))
             self._graph_lint_bucket("prefill", key, fn)
             name = f"serve_prefill_t{key[0]}"
             self._steps[key] = jax.jit(self._counted(key, fn, name),
@@ -414,13 +405,7 @@ class InferenceEngineV2:
         if first:
             from .model_runner import build_verify_step
 
-            c = self.config
-            fn = build_verify_step(
-                self.cfg, max_q=key[0], num_blocks=self._num_blocks,
-                attn_impl=c.attn_impl, max_seqs=key[1],
-                max_blocks=self._wrapper_for(key).max_blocks,
-                block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
-                jit=False, kv_replicate=self._kv_replicate)
+            fn = build_verify_step(self.family, **self._program_kw(key))
             self._graph_lint_bucket("verify", key, fn)
             self._verify_steps[key] = jax.jit(
                 self._counted(("verify",) + key, fn,
@@ -587,7 +572,7 @@ class InferenceEngineV2:
         """Copy one logical page across every layer's physical slot — the
         copy-on-write materialization for a shared partial page."""
         src = jnp.asarray([src_block + layer * self._num_blocks
-                           for layer in range(self.cfg.num_layers)])
+                           for layer in range(self.family.num_layers)])
         dst = src + (dst_block - src_block)
         self.kv.update(self.kv.pages.at[dst].set(self.kv.pages[src]))
         if self.heat is not None:
@@ -600,7 +585,7 @@ class InferenceEngineV2:
         2*KV, HD]`` into every layer's physical slot — the restore leg of
         a host-tier prefix spill."""
         phys = jnp.asarray([block + layer * self._num_blocks
-                            for layer in range(self.cfg.num_layers)])
+                            for layer in range(self.family.num_layers)])
         self.kv.update(self.kv.pages.at[phys].set(
             jnp.asarray(rows, self.kv.pages.dtype)))
 
@@ -1011,12 +996,9 @@ class InferenceEngineV2:
             from .model_runner import build_decode_loop
 
             loop = build_decode_loop(
-                self.cfg, max_q=bucket[0], max_seqs=bucket[1],
-                max_blocks=self._wrapper_for(bucket).max_blocks,
-                block_size=c.block_size, num_blocks=self._num_blocks,
-                attn_impl=c.attn_impl, steps=steps, temperature=temperature,
-                block_q=c.block_q, pages_per_chunk=c.pages_per_chunk,
-                top_k=top_k, jit=False, kv_replicate=self._kv_replicate)
+                self.family, block_size=c.block_size, steps=steps,
+                temperature=temperature, top_k=top_k,
+                **self._program_kw(bucket))
             self._graph_lint_bucket("decode_loop", bucket, loop,
                                     with_rng=True)
             name = f"serve_decode_s{bucket[0]}x{steps}"
@@ -1105,7 +1087,7 @@ class InferenceEngineV2:
                            f"private page")
             return
         phys = [b + layer * self._num_blocks
-                for layer in range(self.cfg.num_layers) for b in own]
+                for layer in range(self.family.num_layers) for b in own]
         self.kv.update(self.kv.pages.at[jnp.asarray(phys)].set(jnp.nan))
 
     @property
@@ -1123,11 +1105,11 @@ class InferenceEngineV2:
                 decode_roofline_report, decode_window_bytes)
 
             n_seqs, steps, mean_ctx, duration_s, resumed, compiled = facts
-            cfg = self.cfg
+            # decode_window_bytes counts 2*KV*hd values a token a layer:
+            # the family's row says how many that is
             report = decode_roofline_report(decode_window_bytes(
-                num_layers=cfg.num_layers,
-                num_kv_heads=self._kv_row_heads[0],
-                head_dim=self._kv_row_heads[1],
+                num_layers=self.family.num_layers, num_kv_heads=1,
+                head_dim=self.family.row.read_values // 2,
                 kv_dtype_bytes=jnp.dtype(self.kv.config.dtype).itemsize,
                 param_bytes=self._param_bytes, n_seqs=n_seqs, steps=steps,
                 mean_ctx=mean_ctx), duration_s, n_seqs, steps)
@@ -1168,15 +1150,11 @@ class InferenceEngineV2:
         # kernel: its analytic page-walk bytes over the window wall, plus
         # the QK+PV flops (decode is memory-bound — pct_peak_hbm is the
         # number that matters; flops ride along for the AI)
-        cfg = self.cfg
+        fam = self.family
         attn_bytes = report["kernels"]["decode_attention"]["bytes"]
-        # QK + PV per cached token per head: 2*hd each, or in the absorbed
-        # latent form 2*(latent_dim + kv_lora_rank)
-        per_ctx = 2.0 * (cfg.latent_dim + cfg.kv_lora_rank) \
-            if self.latent_kv else 4.0 * cfg.head_dim
-        attn_flops = (per_ctx * cfg.num_heads
+        attn_flops = (fam.row.attn_flops * fam.num_heads
                       * window.mean_ctx * window.n_seqs * window.steps
-                      * cfg.num_layers)
+                      * fam.num_layers)
         kname = "decode_paged" if self.config.attn_impl == "paged" \
             else "decode_dense"
         publish_kernel_gauges(tel.metrics, kernel_roofline_report(
@@ -1370,10 +1348,9 @@ class DecodeWindow:
         window's live rows reached an expert.  ``moe_pairs_dropped`` is
         what the routing says it should have computed less what the
         experts' groups held: 0, asserted."""
-        cfg = self.engine.cfg
         pairs = int(self.moe_pairs.sum())
-        expected = (self.n_seqs * self.steps * cfg.num_moe_layers
-                    * cfg.num_experts_per_tok)
+        expected = (self.n_seqs * self.steps
+                    * self.engine.family.counts.per_token)
         dropped = expected - pairs
         assert dropped == 0, \
             f"dropless expert layer lost pairs: {expected} routed, " \

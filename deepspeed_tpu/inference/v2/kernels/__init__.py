@@ -1,15 +1,7 @@
 """Ragged serving kernels (reference: deepspeed/inference/v2/kernels/ —
 blocked_flash, linear_blocked_kv_rotary, moe_gather/moe_scatter, logits_gather).
 
-TPU equivalents live here as Pallas kernels + XLA-native ops; see
-``ragged_ops.py``.
+TPU equivalents live here as Pallas kernels + XLA-native ops: ``ragged_ops.py``
+(K/V pages), ``mla_ops.py`` (latent pages); ``page_ops.py`` lists each cache
+kind's operations for the paged forward.
 """
-from .ragged_ops import (
-    decode_attention,
-    decode_paged_attention,
-    paged_kv_append,
-    ragged_paged_attention,
-)
-
-__all__ = ["ragged_paged_attention", "paged_kv_append",
-           "decode_paged_attention", "decode_attention"]

@@ -1,0 +1,119 @@
+"""What the paged forward asks of a cache kind, for each kind of cached row
+(``models/serving.py``): append the new tokens' rows, and attend through the
+decode kernel, the ragged prefill kernel, the verify-window kernel, or the
+dense page-gather oracle.  ``model_runner._LayerCache`` dispatches among the
+four and owns the page arithmetic: ``pages`` is always the FULL multi-layer
+pool and ``page_table`` rows are ABSOLUTE physical page ids.
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import Callable, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from . import mla_ops
+from .ragged_ops import (decode_attention, paged_kv_append,
+                         ragged_paged_attention, verify_window_attention)
+
+
+def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
+                   scale, alibi=None, alibi_scaled=False):
+    """Dense page-gather reference attention (the numerics oracle).
+
+    Gathers the full padded context per sequence straight from the page pool
+    and runs masked softmax attention.  ``alibi`` ([H] slopes) adds the
+    position bias (bloom semantics; the falcon ``alibi_scaled`` variant
+    computes bf16(slope·pos) pre-scaling).
+
+    q_seq: [S, mq, H, hd]; kv_pages: [NP_total, ps, 2KV, hd];
+    page_table: [S, NB] → output [S, mq, H, hd] (f32).
+    """
+    S, mq, H, hd = q_seq.shape
+    _, ps, ckv, _ = kv_pages.shape
+    KV = ckv // 2
+    NB = page_table.shape[1]
+    C = NB * ps
+    ctx_pos = jnp.arange(C, dtype=jnp.int32)
+    pg = jnp.take_along_axis(
+        page_table, (ctx_pos // ps)[None, :].repeat(S, 0), axis=1)   # [S, C]
+    off = jnp.broadcast_to((ctx_pos % ps)[None, :], (S, C))
+    ctx = kv_pages[pg, off]                           # [S, C, 2KV, hd]
+    k_ctx, v_ctx = ctx[..., :KV, :], ctx[..., KV:, :]
+    # zero V at out-of-context columns: masked scores become -1e30 (so K
+    # garbage can't leak) but probs*V still multiplies 0-weight columns —
+    # and 0*NaN = NaN.  A sequence's UNUSED block-table slots are 0 and
+    # alias page 0, so a NaN-poisoned page 0 would contaminate every
+    # sequence through its padding columns without this (same hardening
+    # the dense decode lowering already has).  Select-BEFORE-multiply is
+    # the contract dstpu-check's masked-nan-propagation pass enforces.
+    valid_col = ctx_pos[None, :] < ctx_len[:, None]   # [S, C]
+    v_ctx = jnp.where(valid_col[:, :, None, None], v_ctx, 0)
+    if KV != H:
+        k_ctx = jnp.repeat(k_ctx, H // KV, axis=2)
+        v_ctx = jnp.repeat(v_ctx, H // KV, axis=2)
+
+    q_pos = ctx_len[:, None] - q_len[:, None] + jnp.arange(mq)[None, :]
+    q_mask = jnp.arange(mq)[None, :] < q_len[:, None]
+    attn_mask = (ctx_pos[None, None, :] <= q_pos[:, :, None]) & \
+        (ctx_pos[None, None, :] < ctx_len[:, None, None]) & q_mask[:, :, None]
+
+    scores = jnp.einsum("sqhd,schd->shqc", q_seq.astype(jnp.float32),
+                        k_ctx.astype(jnp.float32)) * scale
+    if alibi is not None:
+        slopes = jnp.asarray(alibi, jnp.float32)              # [H]
+        if alibi_scaled:
+            bias = (slopes[:, None].astype(jnp.bfloat16) *
+                    ctx_pos[None, :].astype(jnp.bfloat16)
+                    ).astype(jnp.float32) * scale             # [H, C]
+        else:
+            bias = slopes[:, None] * ctx_pos[None, :].astype(jnp.float32)
+        scores = scores + bias[None, :, None, :]
+    scores = jnp.where(attn_mask[:, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("shqc,schd->sqhd", probs, v_ctx.astype(jnp.float32))
+
+
+class PageOps(NamedTuple):
+    """One cache kind.  ``attn`` is the attention's own arithmetic as the
+    layer body gave it (``scale``; K/V also ``alibi``, ``alibi_scaled``)."""
+
+    append: Callable    # (pages, *rows, page_of_token, off_of_token) → pages
+    decode: Callable    # (q [S,H,d], pages, ctx_len, page_table, *,
+    #                      pages_per_chunk, **attn)
+    ragged: Callable    # (q [T,H,d], pages, ctx_len, page_table, cu_q_lens,
+    #                      *, block_q, pages_per_chunk, **attn)
+    verify: Optional[Callable]   # as ragged, for verify windows; None: none
+    dense: Callable     # (q_seq [S,mq,H,d], pages, page_table, q_len,
+    #                      ctx_len, **attn) → f32
+
+
+def page_ops(row, replicate=None) -> PageOps:
+    """The operations of the cache kind that holds ``row``.  ``replicate`` (a
+    replicated NamedSharding) must be given when the parameters are
+    tensor-parallel: see :func:`paged_kv_append`."""
+    if row.latent:
+        # pages [ps, width]: a body appends rows [T, width] and attends
+        # absorbed queries [T, H, width] → [T, H, rank]
+        if replicate is not None:
+            raise NotImplementedError(
+                "latent (MLA) pages: tensor-parallel params are not "
+                "supported")
+        kw = dict(rank=row.rank)
+        return PageOps(
+            append=mla_ops.latent_append,
+            decode=partial(mla_ops.mla_decode_attention, **kw),
+            ragged=lambda *a, block_q, **k: mla_ops.mla_ragged_prefill(
+                *a, block_q=min(block_q, 16), **kw, **k),
+            verify=None,
+            dense=partial(mla_ops.mla_attend_dense, **kw))
+    # pages [ps, 2*KV, hd]: a body appends (k, v) [T, KV, hd] and attends
+    # q [T, H, hd] → [T, H, hd]
+    kw = dict(num_kv_heads=row.num_kv_heads)
+    return PageOps(
+        append=partial(paged_kv_append, replicate=replicate),
+        decode=partial(decode_attention, **kw),
+        ragged=partial(ragged_paged_attention, **kw),
+        verify=partial(verify_window_attention, **kw),
+        dense=_attend_gather)

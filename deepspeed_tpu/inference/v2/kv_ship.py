@@ -90,15 +90,15 @@ def export_kv(engine, uid: int, tokens: List[int],
     nb = engine.kv.config.num_blocks
     # one gather for all layers: [L * n_pages] physical page ids
     phys = np.asarray([b + layer * nb
-                       for layer in range(engine.cfg.num_layers)
+                       for layer in range(engine.family.num_layers)
                        for b in seq.blocks[:n_pages]], np.int64)
     pages = np.asarray(engine.kv.pages[jnp.asarray(phys)], np.float32)
-    c = engine.kv.config
-    rows = pages.reshape(engine.cfg.num_layers, n_pages * bs,
-                         2 * c.num_kv_heads, c.head_dim)[:, :n]
+    row = engine.family.row
+    rows = pages.reshape(engine.family.num_layers, n_pages * bs,
+                         *row.token_shape)[:, :n]
     return KVShipment(tokens=[int(t) for t in tokens[:n]],
-                      num_layers=engine.cfg.num_layers,
-                      num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+                      num_layers=engine.family.num_layers,
+                      num_kv_heads=row.num_kv_heads, head_dim=row.head_dim,
                       src_block_size=bs, wire="fp32", rows=rows)
 
 
@@ -111,15 +111,15 @@ def import_kv(engine, shipment: KVShipment, uid: int) -> bool:
     import jax.numpy as jnp
 
     _refuse_latent(engine, "import_kv (kv_import requests)")
-    c = engine.kv.config
-    if (shipment.num_layers != engine.cfg.num_layers
-            or shipment.num_kv_heads != c.num_kv_heads
-            or shipment.head_dim != c.head_dim):
+    row = engine.family.row
+    if (shipment.num_layers != engine.family.num_layers
+            or shipment.num_kv_heads != row.num_kv_heads
+            or shipment.head_dim != row.head_dim):
         raise ValueError(
             f"KV shipment geometry mismatch: shipment "
             f"L{shipment.num_layers}/kv{shipment.num_kv_heads}"
-            f"/hd{shipment.head_dim} vs engine L{engine.cfg.num_layers}"
-            f"/kv{c.num_kv_heads}/hd{c.head_dim}")
+            f"/hd{shipment.head_dim} vs engine L{engine.family.num_layers}"
+            f"/kv{row.num_kv_heads}/hd{row.head_dim}")
     n = shipment.n_tokens
     sm = engine.state_manager
     seq = sm.get_or_create_sequence(uid)
@@ -135,13 +135,13 @@ def import_kv(engine, shipment: KVShipment, uid: int) -> bool:
     if pad:
         rows = np.pad(rows, ((0, 0), (0, pad), (0, 0), (0, 0)))
     pages = rows.reshape(shipment.num_layers, n_pages, bs,
-                         2 * c.num_kv_heads, c.head_dim)
-    nb = c.num_blocks
+                         *row.token_shape)
+    nb = engine.kv.config.num_blocks
     phys = np.asarray([b + layer * nb
                        for layer in range(shipment.num_layers)
                        for b in seq.blocks[:n_pages]], np.int64)
     flat = pages.reshape(shipment.num_layers * n_pages, bs,
-                         2 * c.num_kv_heads, c.head_dim)
+                         *row.token_shape)
     engine.kv.update(engine.kv.pages.at[jnp.asarray(phys)].set(
         jnp.asarray(flat, engine.kv.pages.dtype)))
     seq.seen_tokens = n

@@ -13,10 +13,11 @@ class ModelImplementation:
     """Policy for serving one HF architecture.
 
     ``family``: models/hf.py policy name; ``ragged_native``: True when the
-    paged-KV ragged engine serves it — since the universal ragged runner
-    (model_runner.ragged_forward_universal) landed, that is EVERY buildable
-    family (native CausalLM recipes ride ragged_forward, ArchConfig
-    recipes ride the universal runner; both share the flat-token paged kernel).
+    paged-KV ragged engine serves it — that is EVERY buildable family: the
+    model object each recipe builds says what it is to the serving path
+    (``CausalLM.serving_family`` / ``UniversalCausalLM.serving_family``,
+    models/serving.py) and the one paged forward
+    (``model_runner.ragged_forward``) serves it.
     """
     arch: str
     family: str

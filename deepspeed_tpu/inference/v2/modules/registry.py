@@ -86,7 +86,7 @@ def _register_builtins():
 
     from ....models.transformer import rms_norm
     from ..kernels.ragged_ops import ragged_paged_attention
-    from ..model_runner import _attend_gather
+    from ..kernels.page_ops import _attend_gather
 
     DSModuleRegistry.register("attention", "paged", ragged_paged_attention,
                               _builtin=True)

@@ -8,8 +8,8 @@ per-layer page table is plain metadata arithmetic (``table + l * num_blocks``)
 and the paged-attention kernel needs no in-kernel layer index.  One page
 fetch carries K AND V for every kv head — a single contiguous DMA feeds all
 heads' compute (see kernels/ragged_ops.py).  A latent pool
-(``KVCacheConfig.latent_row``) keeps the same page arithmetic with pages of
-``[block_size, latent_row]``.
+(``KVCacheConfig.token_shape`` of one axis) keeps the same page arithmetic
+with pages of ``[block_size, width]``.
 
 The FINAL page (index ``num_layers * num_blocks``) is a shared trash page
 that padded tokens write into, keeping the append a single dense scatter
@@ -27,31 +27,17 @@ class KVCacheConfig:
     num_layers: int
     num_blocks: int              # logical pages per layer
     block_size: int              # tokens per page
-    num_kv_heads: int
-    head_dim: int
+    #: what one token holds in one layer's page, as the model's family says
+    #: (models/serving.py): ``(2 * kv_heads, head_dim)`` for K/V rows, or
+    #: ``(width,)`` for a LATENT pool (multi-head latent attention: one row
+    #: a token, no K/V pair and no heads; kernels/mla_ops.py)
+    token_shape: tuple
     dtype: object = jnp.bfloat16
-    #: > 0: a LATENT pool (multi-head latent attention).  A token's cache is
-    #: one row of ``latent_row`` values a layer, with no K/V pair and no
-    #: heads (``num_kv_heads`` / ``head_dim`` are then unused); pages are
-    #: ``[block_size, latent_row]`` (kernels/mla_ops.py).
-    latent_row: int = 0
-
-    @property
-    def token_shape(self) -> tuple:
-        """What one token holds in one layer's page."""
-        if self.latent_row:
-            return (self.latent_row,)
-        return (2 * self.num_kv_heads, self.head_dim)
 
     @property
     def total_pages(self) -> int:
         """Physical pages including the trailing shared trash page."""
         return self.num_layers * self.num_blocks + 1
-
-    @property
-    def trash_page(self) -> int:
-        """Physical index of the shared trash page."""
-        return self.num_layers * self.num_blocks
 
     @property
     def pad_page_flag(self) -> int:

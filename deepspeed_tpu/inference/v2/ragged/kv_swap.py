@@ -76,9 +76,8 @@ class KVSwapManager:
     def _page_row_bytes(self) -> int:
         """Bytes one logical page occupies in canonical row space (all
         layers, K+V, float32)."""
-        c = self.eng.kv.config
-        return (self.eng.cfg.num_layers * self.eng.config.block_size
-                * 2 * c.num_kv_heads * c.head_dim * 4)
+        return (self.eng.family.num_layers * self.eng.config.block_size
+                * self.eng.family.row.read_values * 4)
 
     # ------------------------------------------------------------------ #
     # Sequence spill / restore
@@ -174,11 +173,11 @@ class KVSwapManager:
             logger.warning(f"kv swap: uid={uid} parked rows fail "
                            f"re-attestation; recomputing prefill")
             return 0
-        c = self.eng.kv.config
+        row = self.eng.family.row
         ship = KVShipment(tokens=list(entry.tokens[:n]),
-                          num_layers=self.eng.cfg.num_layers,
-                          num_kv_heads=c.num_kv_heads,
-                          head_dim=c.head_dim,
+                          num_layers=self.eng.family.num_layers,
+                          num_kv_heads=row.num_kv_heads,
+                          head_dim=row.head_dim,
                           src_block_size=self.eng.config.block_size,
                           wire="fp32", rows=rows[:, :n])
         if not import_kv(self.eng, ship, uid):
@@ -221,7 +220,7 @@ class KVSwapManager:
         c = self.eng.kv.config
         nb = c.num_blocks
         phys = np.asarray([node.block + layer * nb
-                           for layer in range(self.eng.cfg.num_layers)],
+                           for layer in range(self.eng.family.num_layers)],
                           np.int64)
         rows = np.asarray(self.eng.kv.pages[jnp.asarray(phys)], np.float32)
         try:

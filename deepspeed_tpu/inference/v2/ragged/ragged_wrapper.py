@@ -118,14 +118,6 @@ class RaggedBatchWrapper:
         self._entries: List[Tuple[DSSequenceDescriptor, List[int]]] = []
         self._n_tokens = 0
 
-    @property
-    def current_tokens(self) -> int:
-        return self._n_tokens
-
-    @property
-    def current_sequences(self) -> int:
-        return len(self._entries)
-
     def can_fit(self, n_new_tokens: int) -> bool:
         return (self._n_tokens + n_new_tokens <= self.max_tokens and
                 len(self._entries) < self.max_seqs)

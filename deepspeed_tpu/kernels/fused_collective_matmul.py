@@ -436,15 +436,3 @@ def supports_fused_rmsnorm() -> bool:
     from ..accelerator import get_accelerator
 
     return bool(get_accelerator().supports_pallas())
-
-
-# --------------------------------------------------------------------- #
-# Analytic cost (the kernel_sweep roofline + selector inputs)
-# --------------------------------------------------------------------- #
-def matmul_costs(M: int, K: int, N: int,
-                 dtype_bytes: int = 4) -> Tuple[float, float]:
-    """(flops, hbm bytes) of one ``[M,K]@[K,N]`` — the kernel_sweep's
-    %-of-peak numerator for the fused-gemm family."""
-    flops = 2.0 * M * K * N
-    bytes_ = float(dtype_bytes) * (M * K + K * N + M * N)
-    return flops, bytes_

@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import rms_norm
+from .serving import KVRow, LayerStack, ServingFamily
+from .transformer import _proj, apply_rope_flat, rms_norm, rope_at
 
 
 @dataclasses.dataclass
@@ -153,13 +154,6 @@ def _attention(q, k, v, cfg: ArchConfig, alibi: Optional[jnp.ndarray]):
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs,
                       v.astype(jnp.float32)).astype(q.dtype)
-
-
-def _proj(x, p):
-    y = x @ p["kernel"]
-    if "bias" in p:
-        y = y + p["bias"]
-    return y
 
 
 # --------------------------------------------------------------------- #
@@ -293,6 +287,77 @@ def init_universal_params(cfg: ArchConfig, key: jax.Array,
     return params
 
 
+# --------------------------------------------------------------------- #
+# Paged serving (models/serving.py says what each piece is handed)
+# --------------------------------------------------------------------- #
+def serving_family(cfg: ArchConfig) -> ServingFamily:
+    """gpt2/gptj/opt/bloom/falcon/phi on the flat token axis (reference:
+    inference/v2/model_implementations/{falcon,phi,opt}/), with the knobs of
+    :func:`universal_forward`: learned positions (+opt's offset), ALiBi
+    inside the attention (bloom + falcon-scaled), partial/interleaved rotary,
+    parallel-attn, dual-LN, LayerNorm-with-bias, gelu/relu/glu MLPs."""
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    attn = dict(scale=1.0 / math.sqrt(hd),
+                alibi=alibi_slopes(H) if cfg.pos == "alibi" else None,
+                alibi_scaled=cfg.alibi_scaled)
+
+    def embed(params, ids, pos, valid):
+        dtype = params["layers"]["q_proj"]["kernel"].dtype
+        x = jnp.take(params["embed"]["embedding"], ids, axis=0).astype(dtype)
+        if cfg.pos == "learned":
+            x = x + jnp.take(params["pos_embed"]["embedding"],
+                             pos + cfg.pos_offset, axis=0).astype(dtype)
+        if cfg.embed_layernorm:
+            x = _norm(x, params["embed_ln"], cfg)
+        if cfg.pos == "rope":
+            return x, rope_at(pos, cfg.rotary_dim, cfg.rope_theta)
+        return x, None
+
+    def layer(x, lp, l_idx, cache, ctx):
+        T, dtype = x.shape[0], x.dtype
+        h_attn_in = _norm(x, lp["ln1"], cfg)
+        q = _proj(h_attn_in, lp["q_proj"]).reshape(T, H, hd)
+        k = _proj(h_attn_in, lp["k_proj"]).reshape(T, KV, hd)
+        v = _proj(h_attn_in, lp["v_proj"]).reshape(T, KV, hd)
+        if cfg.pos == "rope":
+            cos, sin = ctx
+            q = apply_rope_flat(q, cos, sin, cfg.rotary_dim, cfg.rope_style)
+            k = apply_rope_flat(k, cos, sin, cfg.rotary_dim, cfg.rope_style)
+        o_flat = cache(q, k, v, **attn).reshape(T, H * hd).astype(dtype)
+        attn_out = _proj(o_flat, lp["o_proj"])
+
+        if cfg.parallel_attn:
+            h_mlp_in = _norm(x, lp["ln2"], cfg) if cfg.dual_ln else h_attn_in
+        else:
+            x = x + attn_out
+            h_mlp_in = _norm(x, lp["ln2"], cfg)
+
+        if cfg.mlp == "silu_glu":
+            gate = jax.nn.silu(_proj(h_mlp_in, lp["gate_proj"]))
+            up = _proj(h_mlp_in, lp["up_proj"])
+            mlp_out = _proj(gate * up, lp["down_proj"])
+        else:
+            act = (lambda y: jax.nn.gelu(y, approximate=not cfg.gelu_exact)) \
+                if cfg.mlp == "gelu" else jax.nn.relu
+            mlp_out = _proj(act(_proj(h_mlp_in, lp["fc1"])), lp["fc2"])
+
+        return x + attn_out + mlp_out if cfg.parallel_attn else x + mlp_out
+
+    def stacks(params):
+        yield LayerStack(params["layers"], range(cfg.num_layers), layer)
+
+    def head(params, x, pick):
+        last = pick(_norm(x, params["norm_f"], cfg))
+        if cfg.tie_embeddings:
+            return last @ params["embed"]["embedding"].T
+        return _proj(last, params["lm_head"])       # phi: an lm-head bias
+
+    return ServingFamily(num_layers=cfg.num_layers, num_heads=H,
+                         row=KVRow(KV, hd), embed=embed, stacks=stacks,
+                         head=head)
+
+
+
 class UniversalCausalLM:
     """Per-arch compat model with the same engine interface as CausalLM."""
 
@@ -305,6 +370,9 @@ class UniversalCausalLM:
 
     def __call__(self, params, tokens):
         return universal_forward(params, tokens, self.config)
+
+    def serving_family(self) -> ServingFamily:
+        return serving_family(self.config)
 
     def loss_fn(self, params, batch, rng=None):
         tokens = batch["input_ids"] if isinstance(batch, dict) else batch

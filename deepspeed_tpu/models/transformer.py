@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..runtime.topology import (DATA, DATA_OUTER, EXPERT, SEQ, TENSOR,
                                 shard_kernel)
+from .serving import KVRow, LayerStack, ServingFamily
 
 #: mesh axes the batch dimension of activations is laid out over
 _BATCH_AXES = (DATA_OUTER, DATA, EXPERT)
@@ -92,29 +93,6 @@ class TransformerConfig:
                                  intermediate_size=128, num_layers=2,
                                  num_heads=4, num_kv_heads=2, max_seq_len=128,
                                  num_experts=4, moe_top_k=2, **kw)
-
-    @staticmethod
-    def mixtral_8x7b(**kw):
-        # 32k context (Mixtral's published window): the default sparse-slot
-        # dispatch is linear in routing-chunk tokens, so long chunks no
-        # longer materialize an O(S²·E/cf) dispatch tensor.
-        return TransformerConfig(vocab_size=32000, hidden_size=4096,
-                                 intermediate_size=14336, num_layers=32,
-                                 num_heads=32, num_kv_heads=8, max_seq_len=32768,
-                                 rope_theta=1e6, num_experts=8, moe_top_k=2, **kw)
-
-    @staticmethod
-    def llama3_8b(**kw):
-        return TransformerConfig(vocab_size=128256, hidden_size=4096,
-                                 intermediate_size=14336, num_layers=32,
-                                 num_heads=32, num_kv_heads=8, max_seq_len=8192,
-                                 rope_theta=500000.0, **kw)
-
-    @staticmethod
-    def gpt2_small(**kw):
-        return TransformerConfig(vocab_size=50257, hidden_size=768,
-                                 intermediate_size=3072, num_layers=12,
-                                 num_heads=12, num_kv_heads=12, max_seq_len=1024, **kw)
 
 
 # --------------------------------------------------------------------- #
@@ -216,6 +194,11 @@ def rms_norm(x, scale, eps):
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale
 
 
+def _proj(x, p):
+    y = x @ p["kernel"]
+    return y + p["bias"] if "bias" in p else y
+
+
 def _fused_rmsnorm_active(cfg: "TransformerConfig") -> bool:
     """"on"/"off" force; "auto" enables on TPU Pallas only — the CPU sim's
     jaxpr (and therefore every tier-1 numeric) is unchanged by default."""
@@ -242,6 +225,34 @@ def apply_rope(x, cos, sin):
     cos = cos[None, :, None, :].astype(x.dtype)
     sin = sin[None, :, None, :].astype(x.dtype)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def rope_at(pos, rotary_dim, theta):
+    """cos/sin tables gathered at arbitrary positions [T] → [T, rd/2] (the
+    flat serving token axis)."""
+    inv = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                           / rotary_dim))
+    freqs = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def apply_rope_flat(x, cos, sin, rotary_dim=None, style="neox"):
+    """x [T, H, hd] with per-token tables [T, rd/2]; partial rotary (phi)
+    and interleaved-pair style (gptj) supported, mirroring
+    families._rope_partial for the flat serving token axis."""
+    hd = x.shape[-1]
+    rd = hd if rotary_dim is None else rotary_dim
+    rot, passthrough = x[..., :rd], x[..., rd:]
+    c = cos[:, None, :].astype(x.dtype)
+    s = sin[:, None, :].astype(x.dtype)
+    if style == "gptj":
+        x1, x2 = rot[..., 0::2], rot[..., 1::2]
+        rot = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c],
+                        axis=-1).reshape(rot.shape)
+    else:
+        x1, x2 = jnp.split(rot, 2, axis=-1)
+        rot = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return jnp.concatenate([rot, passthrough], axis=-1) if rd < hd else rot
 
 
 def _xla_attention(q, k, v, causal=True, seq_offset=0):
@@ -464,6 +475,64 @@ def lm_loss(params: Dict, batch: Any, cfg: TransformerConfig,
     return loss
 
 
+# --------------------------------------------------------------------- #
+# Paged serving (models/serving.py says what each piece is handed)
+# --------------------------------------------------------------------- #
+def serving_family(cfg: TransformerConfig) -> ServingFamily:
+    """The llama recipe (Mistral, Mixtral) on the flat token axis:
+    rmsnorm → qkv → RoPE → cache → o proj → SwiGLU or the routed experts."""
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    scale = 1.0 / math.sqrt(hd)
+
+    def embed(params, ids, pos, valid):
+        dtype = params["layers"]["q_proj"]["kernel"].dtype
+        x = jnp.take(params["embed"]["embedding"], ids,
+                     axis=0).astype(dtype)                          # [T, D]
+        cos, sin = rope_at(pos, hd, cfg.rope_theta)
+        return x, (cos, sin, valid())
+
+    def layer(x, lp, l_idx, cache, ctx):
+        cos, sin, valid = ctx
+        T, dtype = x.shape[0], x.dtype
+        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
+        q = _proj(h, lp["q_proj"]).reshape(T, H, hd)
+        k = _proj(h, lp["k_proj"]).reshape(T, KV, hd)
+        v = _proj(h, lp["v_proj"]).reshape(T, KV, hd)
+        q = apply_rope_flat(q, cos, sin)
+        k = apply_rope_flat(k, cos, sin)
+        o_flat = cache(q, k, v, scale=scale).reshape(T, H * hd).astype(dtype)
+        x = x + o_flat @ lp["o_proj"]["kernel"]
+        h = rms_norm(x, lp["mlp_norm"]["scale"], cfg.norm_eps)
+        if cfg.num_experts > 1:
+            # MoE serving (moe_gather/moe_scatter analogue): sparse-slot
+            # dispatch over flat ragged tokens; the batch's padding is
+            # excluded from expert capacity.
+            from ..moe.sharded_moe import moe_mlp_block
+
+            mlp_out, _ = moe_mlp_block(
+                lp, h, k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor,
+                dispatch_impl="sparse", valid=valid)
+            return x + mlp_out
+        gate = jax.nn.silu(h @ lp["gate_proj"]["kernel"])
+        up = h @ lp["up_proj"]["kernel"]
+        return x + (gate * up) @ lp["down_proj"]["kernel"]
+
+    def stacks(params):
+        yield LayerStack(params["layers"], range(cfg.num_layers), layer)
+
+    def head(params, x, pick):
+        last = pick(rms_norm(x, params["norm_f"]["scale"], cfg.norm_eps))
+        if cfg.tie_embeddings:
+            return last @ params["embed"]["embedding"].T
+        return last @ params["lm_head"]["kernel"]
+
+    return ServingFamily(num_layers=cfg.num_layers, num_heads=H,
+                         row=KVRow(KV, hd), embed=embed, stacks=stacks,
+                         head=head)
+
+
+
 class CausalLM:
     """Model object consumable by ``deepspeed_tpu.initialize``.
 
@@ -483,6 +552,9 @@ class CausalLM:
 
     def __call__(self, params, tokens):
         return forward(params, tokens, self.config)
+
+    def serving_family(self) -> ServingFamily:
+        return serving_family(self.config)
 
     def num_params(self, params=None) -> int:
         if params is None:

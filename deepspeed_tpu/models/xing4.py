@@ -6,9 +6,10 @@ of the plain residual add.
 
 This module holds the configuration (built from the published
 ``config.json`` keys), the seeded parameter tree, and the per-token layer
-mathematics on the flat token axis ``[T, ...]``.  The serving path
-(``inference/v2/model_runner.ragged_forward_xing``) composes them around
-the latent page pool; the training path is open (``loss_fn`` raises).
+mathematics on the flat token axis ``[T, ...]``.  :func:`serving_family`
+composes them into what the paged serving path asks of a model
+(``models/serving.py``): a latent row, two layer stacks, the pair counts;
+the training path is open (``loss_fn`` raises).
 
 Layers are NOT all alike, so the parameters are two stacks, each scanned on
 its own: ``dense_layers`` (the ``first_k_dense_replace`` leading ones) and
@@ -23,6 +24,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from .serving import ExpertPairs, LatentRow, LayerStack, ServingFamily
 from .transformer import rms_norm
 
 _HI = jax.lax.Precision.HIGHEST
@@ -276,6 +278,9 @@ class Xing4LM:
             "xing4: the training path is open (ROADMAP R3); this family is "
             "served through inference/v2 only")
 
+    def serving_family(self) -> ServingFamily:
+        return serving_family(self.config)
+
     def num_params(self, params=None) -> int:
         if params is None:
             params = jax.eval_shape(lambda k: self.init_params(k),
@@ -454,3 +459,97 @@ def mla_output(o_lat, lp: Dict, cfg: Xing4Config):
 # --------------------------------------------------------------------- #
 def dense_mlp(h, gate, up, down):
     return (jax.nn.silu(h @ gate) * (h @ up)) @ down
+
+
+# --------------------------------------------------------------------- #
+# Paged serving (models/serving.py says what each piece is handed)
+# --------------------------------------------------------------------- #
+def serving_family(cfg: Xing4Config) -> ServingFamily:
+    """Latent (MLA) rows, attended in the absorbed form for prefill chunks
+    and decode alike.  Two stacks, because the layers are not all alike: the
+    leading dense ones, then the expert ones.  The carry is ``[T, hc_mult,
+    D]`` float32; a step also returns the pairs per expert ``[E]``."""
+    from ..moe.dropless import sigmoid_moe_block
+
+    def embed(params, ids, pos, valid):
+        valid = valid()
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"]["embedding"], ids, axis=0
+                         ).astype(jnp.float32)
+            # the embedding is copied into every residual stream; the carry
+            # is float32 (hc_sublayer)
+            x = jnp.broadcast_to(x[:, None, :],
+                                 (x.shape[0], cfg.hc_mult, x.shape[-1]))
+        cos, sin = rope_at(pos, cfg)
+        return x, (cos, sin, valid, params["embed"]["embedding"].dtype)
+
+    def body(moe: bool, experts=None):
+        def layer(xs, lp, l_idx, cache, ctx):
+            cos, sin, valid, dtype = ctx
+
+            def attention(h):
+                with jax.named_scope("attention/mla_q"):
+                    q_nope, q_rope = mla_query(h, lp, cos, sin, cfg)
+                    q_abs = mla_absorb_query(q_nope, q_rope, lp, cfg)
+                with jax.named_scope("attention/mla_kv"):
+                    cache.append(mla_latent(h, lp, cos, sin, cfg))
+                with jax.named_scope("attention/mla_core"):
+                    o_lat = cache.attend(
+                        q_abs, scale=cfg.softmax_scale).astype(dtype)
+                    return mla_output(o_lat, lp, cfg), None
+
+            xs, _ = hc_sublayer(xs, lp["hc_attn"], lp["attn_norm"]["scale"],
+                                attention, cfg, dtype)
+
+            def mlp(h):
+                if not moe:
+                    with jax.named_scope("mlp"):
+                        return dense_mlp(
+                            h, lp["gate_proj"]["kernel"],
+                            lp["up_proj"]["kernel"],
+                            lp["down_proj"]["kernel"]), None
+                # the experts' stack rides the closure, not the scan: see
+                # moe/dropless.dropless_experts
+                return sigmoid_moe_block(
+                    h, lp, k=cfg.num_experts_per_tok,
+                    scaling=cfg.routed_scaling_factor,
+                    renormalise=cfg.norm_topk_prob, valid=valid,
+                    experts=experts, layer=l_idx - cfg.num_dense_layers)
+
+            return hc_sublayer(xs, lp["hc_mlp"], lp["mlp_norm"]["scale"],
+                               mlp, cfg, dtype)
+
+        return layer
+
+    def stacks(params):
+        Ld = cfg.num_dense_layers
+        if Ld:
+            yield LayerStack(params["dense_layers"], range(Ld), body(False),
+                             scope="layers")
+        if cfg.num_moe_layers:
+            moe = params["moe_layers"]
+            yield LayerStack(
+                {k: v for k, v in moe.items() if k != "experts"},
+                range(Ld, cfg.num_layers), body(True, moe["experts"]),
+                scope="layers")
+
+    def head(params, x, pick):
+        dtype = params["embed"]["embedding"].dtype
+        with jax.named_scope("final_norm"):
+            # the streams are summed before the final norm
+            x = rms_norm(jnp.sum(x, axis=1),
+                         params["norm_f"]["scale"].astype(jnp.float32),
+                         cfg.norm_eps).astype(dtype)
+        with jax.named_scope("lm_head"):
+            last = pick(x)
+            if cfg.tie_embeddings:
+                return last @ params["embed"]["embedding"].T
+            return last @ params["lm_head"]["kernel"]
+
+    return ServingFamily(
+        num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+        row=LatentRow(width=cfg.latent_row, dim=cfg.latent_dim,
+                      rank=cfg.kv_lora_rank),
+        embed=embed, stacks=stacks, head=head,
+        counts=ExpertPairs(cfg.n_routed_experts,
+                           cfg.num_moe_layers * cfg.num_experts_per_tok))

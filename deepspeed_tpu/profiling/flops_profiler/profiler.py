@@ -87,8 +87,8 @@ def emit_report(text: str, output_file: Optional[str] = None) -> None:
 
     Rank 0 only (every output — a shared output_file must not collect one
     interleaved copy per host): prints to STDERR (the profiler runs inside
-    training processes whose stdout may be a protocol, e.g. bench.py's
-    one-JSON-line contract; the lint exempts ``emit_report`` by name — keep
+    training processes whose stdout may be a protocol, e.g. the one JSON
+    line of ``benchmark/run.py``; the lint exempts ``emit_report`` by name — keep
     all profiler printing here), appends to ``output_file`` when given, and
     mirrors the report into the telemetry event log when one is active.
     """

@@ -45,8 +45,8 @@ class DeviceSpec:
         return self.peak_flops / max(self.hbm_bandwidth, 1.0)
 
 
-#: THE peaks table — bench.py, the engine's roofline gauges and the
-#: offline summaries all read it; there is no second copy.  Ordered: first
+#: THE peaks table — the engine's roofline gauges and the offline
+#: summaries read it; there is no second copy.  Ordered: first
 #: substring match against ``device_kind`` wins.  Sources: bf16 peak, HBM
 #: bandwidth and ICI are the per-chip figures of the Google Cloud TPU
 #: documentation's system-architecture pages ("TPU v6e", "TPU v5p",

@@ -107,12 +107,3 @@ def publish_decode_gauges(metrics, report: Dict[str, Any]) -> None:
             float(row["hbm_gbps"]), kernel=name, device=kind)
         metrics.gauge("serving/kernel_hbm_pct_peak").set(
             float(row["hbm_pct_peak"]), kernel=name, device=kind)
-
-
-def format_decode_roofline(report: Dict[str, Any]) -> str:
-    """One human line for logs and the bench's stderr trace."""
-    return (f"decode roofline [{report['device_kind']}]: "
-            f"{report['decode_tok_per_s']:.1f} tok/s, "
-            f"HBM {report['hbm_gbps']:.1f}/{report['peak_hbm_gbps']:.0f} "
-            f"GB/s ({report['hbm_pct_peak']:.1f}% of peak) over "
-            f"{report['n_seqs']} seqs × {report['steps']} steps")

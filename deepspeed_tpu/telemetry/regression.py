@@ -1,8 +1,10 @@
 """Cross-run performance regression tracking against BENCH history.
 
-``bench.py`` leaves one ``BENCH_r*.json`` per run at the repo root — a
-step-time / MFU / tokens-per-chip record of every prior session.  This
-module turns that archive into a regression gate: extract the comparable
+Nothing in the repo writes a ``BENCH_r*.json`` (a step-time / MFU /
+tokens-per-chip record of one run) any more: the benchmark is
+``benchmark/run.py`` and its history ``PERF_LEDGER.jsonl`` (ROADMAP D16).
+This module turns an archive of such files into a regression gate: extract
+the comparable
 metrics from the current run (a bench JSON *or* a telemetry output dir),
 take the median of the history as the baseline (median, not mean — one
 broken historical run must not move the bar), and flag any metric that
@@ -88,7 +90,7 @@ def load_history(history_dir: str, pattern: str = DEFAULT_PATTERN,
     ``[{"file", "metrics"}, ...]``; entries with no numbers keep ``metrics:
     {}`` so callers can report how much history was unusable.  ``exclude``
     drops one path — the run UNDER comparison often sits in the same dir
-    (bench.py writes to the repo root), and letting it join its own
+    (the history is one flat directory), and letting it join its own
     baseline dilutes the median toward itself, masking the regression."""
     entries: List[Dict[str, Any]] = []
     skip = os.path.abspath(exclude) if exclude else None

@@ -42,8 +42,9 @@ MIN_COMPILE_SECS = 0.1
 def configure_compile_cache() -> Optional[str]:
     """Place the persistent compilation cache; returns the directory in
     effect (``None``: held to the CPU, no cache).  Cheap and idempotent —
-    ``deepspeed_tpu.initialize()``, ``InferenceEngineV2``, ``bench.py`` and
-    ``chip_smoke.py`` all call it before they compile anything."""
+    ``deepspeed_tpu.initialize()``, ``InferenceEngineV2``,
+    ``benchmark/run.py`` and ``chip_smoke.py`` all call it before they
+    compile anything."""
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not from_env and \
             os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":

@@ -259,7 +259,7 @@ def _xing4_decode_window(dev):
     params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
     seqs, blocks, nb = 64, 25088 // PAGE, 15000
     loop = build_decode_loop(
-        cfg, max_q=seqs, max_seqs=seqs, max_blocks=blocks, block_size=PAGE,
+        Xing4LM(cfg).serving_family(), max_q=seqs, max_seqs=seqs, max_blocks=blocks, block_size=PAGE,
         num_blocks=nb, attn_impl="paged", steps=2, jit=False)
     meta = pack_layout(seqs, seqs, blocks)["_total"][0]
     return loop, (params, _on(dev, (5 * nb + 1, PAGE, cfg.latent_row)),

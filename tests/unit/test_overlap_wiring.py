@@ -238,9 +238,3 @@ class TestTooling:
         with open(ini) as f:
             content = f.read()
         assert "overlap:" in content
-
-    def test_bench_has_overlap_sweep_mode(self):
-        """bench.py must dispatch DSTPU_BENCH_MODE=overlap_sweep."""
-        src = open(os.path.join(REPO_ROOT, "bench.py")).read()
-        assert "def run_overlap_sweep" in src
-        assert '"overlap_sweep": run_overlap_sweep' in src

@@ -166,26 +166,3 @@ class TestFlopsProfilerStartProfile:
         prof = FlopsProfiler()
         msg = prof.print_model_profile(detailed=False)
         assert "flops profiler" in msg
-
-
-class TestBenchConsumesProfiler:
-    def test_bench_mfu_uses_train_step_cost(self):
-        """bench.py's MFU line must be sourced from the profiler's step cost
-        (satellite: no more hand-rolled formula on the primary path)."""
-        import ast
-        import os
-
-        bench = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "bench.py")
-        with open(bench) as f:
-            src = f.read()
-        assert "train_step_cost" in src
-        assert "mfu_flops_source" in src
-        tree = ast.parse(src)
-        fn = next(n for n in ast.walk(tree)
-                  if isinstance(n, ast.FunctionDef)
-                  and n.name == "run_train_bench")
-        calls = [n.func.attr for n in ast.walk(fn)
-                 if isinstance(n, ast.Call)
-                 and isinstance(n.func, ast.Attribute)]
-        assert "train_step_cost" in calls

@@ -16,7 +16,7 @@ from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
     paged_kv_append,
     ragged_paged_attention,
 )
-from deepspeed_tpu.inference.v2.model_runner import _attend_gather
+from deepspeed_tpu.inference.v2.kernels.page_ops import _attend_gather
 
 pytestmark = pytest.mark.kernels
 

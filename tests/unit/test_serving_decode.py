@@ -14,15 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference.v2.kernels.page_ops import _attend_gather
 from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
     decode_attend_dense,
     decode_attention,
     decode_paged_attention,
 )
-from deepspeed_tpu.inference.v2.model_runner import (
-    _attend_gather,
-    sample_tokens,
-)
+from deepspeed_tpu.inference.v2.model_runner import sample_tokens
 
 pytestmark = pytest.mark.serving
 

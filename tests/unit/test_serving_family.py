@@ -1,0 +1,215 @@
+"""The seam between a model and the paged serving path (``models/serving.py``):
+the engine serves whatever model object says what family it is, and asks the
+family — not the config's class — for the row a cached token holds.
+
+``RenamedLM`` is a fourth family written HERE: the llama layer under other
+parameter names, with its own dense forward.  It imports nothing of
+``inference/v2`` but the engine and its config; serving it edits nothing there.
+"""
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2.engine_v2 import (
+    InferenceEngineV2,
+    RaggedInferenceEngineConfig,
+)
+from deepspeed_tpu.models.families import ArchConfig, UniversalCausalLM
+from deepspeed_tpu.models.serving import KVRow, LayerStack, ServingFamily
+from deepspeed_tpu.models.transformer import (
+    CausalLM,
+    TransformerConfig,
+    apply_rope_flat,
+    rms_norm,
+    rope_at,
+)
+from deepspeed_tpu.models.xing4 import Xing4Config, Xing4LM
+
+pytestmark = pytest.mark.inference
+
+
+# --------------------------------------------------------------------- #
+# A family the serving path has never heard of
+# --------------------------------------------------------------------- #
+@dataclasses.dataclass(frozen=True)
+class RenamedConfig:
+    vocab: int = 89
+    width: int = 32
+    ffn: int = 48
+    depth: int = 3
+    heads: int = 4
+    kv_heads: int = 2
+    theta: float = 10000.0
+    eps: float = 1e-5
+
+
+class RenamedLM:
+    def __init__(self, cfg: RenamedConfig):
+        self.config = cfg
+
+    def init_params(self, key, dtype=jnp.float32):
+        c = self.config
+        D, kv = c.width, c.kv_heads * (c.width // c.heads)
+        keys = iter(jax.random.split(key, 9))
+
+        def w(*shape):
+            return (jax.random.normal(next(keys), shape)
+                    / math.sqrt(shape[-2])).astype(dtype)
+
+        blocks = {n: w(c.depth, *s) for n, s in dict(
+            wq=(D, D), wk=(D, kv), wv=(D, kv), wo=(D, D), wg=(D, c.ffn),
+            wu=(D, c.ffn), wd=(c.ffn, D)).items()}
+        blocks["n1"] = blocks["n2"] = jnp.ones((c.depth, D), dtype)
+        return {"wte": w(c.vocab, D), "blocks": blocks,
+                "nf": jnp.ones((D,), dtype), "out": w(D, c.vocab)}
+
+    def _qkv(self, x, bp, cos, sin):
+        c, T = self.config, x.shape[0]
+        h = rms_norm(x, bp["n1"], c.eps)
+        q, k, v = ((h @ bp[w]).reshape(T, n, -1) for w, n in
+                   (("wq", c.heads), ("wk", c.kv_heads), ("wv", c.kv_heads)))
+        return apply_rope_flat(q, cos, sin), apply_rope_flat(k, cos, sin), v
+
+    def _rest(self, x, o, bp):
+        x = x + o.reshape(x.shape[0], -1) @ bp["wo"]
+        h = rms_norm(x, bp["n2"], self.config.eps)
+        return x + (jax.nn.silu(h @ bp["wg"]) * (h @ bp["wu"])) @ bp["wd"]
+
+    def __call__(self, params, tokens):
+        """The dense forward of ONE sequence [S] → logits [S, V]: plain
+        causal attention, no cache."""
+        c = self.config
+        S, hd = tokens.shape[0], c.width // c.heads
+        cos, sin = rope_at(jnp.arange(S), hd, c.theta)
+        x = params["wte"][tokens]
+        for i in range(c.depth):
+            bp = jax.tree.map(lambda a: a[i], params["blocks"])
+            q, k, v = self._qkv(x, bp, cos, sin)
+            k = jnp.repeat(k, c.heads // c.kv_heads, axis=1)
+            v = jnp.repeat(v, c.heads // c.kv_heads, axis=1)
+            s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(hd)
+            s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -1e30)
+            o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+            x = self._rest(x, o, bp)
+        return rms_norm(x, params["nf"], c.eps) @ params["out"]
+
+    def serving_family(self) -> ServingFamily:
+        c = self.config
+        hd = c.width // c.heads
+
+        def embed(params, ids, pos, valid):
+            return params["wte"][ids], rope_at(pos, hd, c.theta)
+
+        def layer(x, bp, l_idx, cache, ctx):
+            q, k, v = self._qkv(x, bp, *ctx)
+            o = cache(q, k, v, scale=1.0 / math.sqrt(hd)).astype(x.dtype)
+            return self._rest(x, o, bp)
+
+        def stacks(params):
+            yield LayerStack(params["blocks"], range(c.depth), layer)
+
+        def head(params, x, pick):
+            return pick(rms_norm(x, params["nf"], c.eps)) @ params["out"]
+
+        return ServingFamily(num_layers=c.depth, num_heads=c.heads,
+                             row=KVRow(c.kv_heads, hd), embed=embed,
+                             stacks=stacks, head=head)
+
+
+def _engine(model, params, impl="paged", **kw):
+    kw = {**dict(max_tokens=8, max_seqs=2, max_ctx=64, block_size=8,
+                 dtype=jnp.float32, attn_impl=impl), **kw}
+    return InferenceEngineV2(model, params, RaggedInferenceEngineConfig(**kw))
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_a_family_defined_in_the_test_is_served(impl):
+    """Split prefill, a put() decode step and a fused decode window of a
+    family ``inference/v2`` has no line about, against its dense forward."""
+    model = RenamedLM(RenamedConfig())
+    params = model.init_params(jax.random.PRNGKey(0))
+    eng = _engine(model, params, impl)
+    prompt = np.random.default_rng(0).integers(1, 88, size=13).tolist()
+    for i in range(0, len(prompt), 8):
+        logits = eng.put([0], [prompt[i:i + 8]])
+    np.testing.assert_allclose(
+        np.asarray(logits[0]),
+        np.asarray(model(params, jnp.asarray(prompt))[-1]),
+        atol=2e-4, rtol=2e-4)
+
+    toks = prompt + [int(jnp.argmax(logits[0]))]
+    logits = eng.put([0], [toks[-1:]])
+    np.testing.assert_allclose(
+        np.asarray(logits[0]),
+        np.asarray(model(params, jnp.asarray(toks))[-1]),
+        atol=2e-4, rtol=2e-4)
+
+    # the fused window's greedy tokens are the dense forward's greedy chain
+    seed = int(jnp.argmax(logits[0]))
+    window = eng.decode_batch([0], [seed], 3)[:, 0].tolist()
+    chain = toks + [seed]
+    for tok in window:
+        want = int(jnp.argmax(model(params, jnp.asarray(chain))[-1]))
+        assert tok == want
+        chain.append(tok)
+    assert all(n == 1 for n in eng.trace_counts.values())
+
+
+def test_a_model_without_a_family_is_refused_by_name():
+    class NoFamilyLM:
+        config = TransformerConfig.tiny()
+
+    with pytest.raises(NotImplementedError,
+                       match=r"ragged serving needs .*NoFamilyLM"):
+        InferenceEngineV2(NoFamilyLM(), {})
+
+
+# --------------------------------------------------------------------- #
+# The pool is what the family's row says
+# --------------------------------------------------------------------- #
+_UNIVERSAL = dict(vocab_size=97, hidden_size=32, intermediate_size=64,
+                  num_layers=2, num_heads=4, max_seq_len=128,
+                  norm="layernorm", mlp="gelu")
+#: name → (model, one token's shape in a page, the bf16 bytes attention reads
+#: of it: K and V of every kv head, or the 40 real values of a 128-wide row)
+CASES = {
+    "llama": (lambda: CausalLM(TransformerConfig.tiny(use_flash=False)),
+              (4, 16), 128),
+    "mixtral": (lambda: CausalLM(TransformerConfig.tiny_moe(use_flash=False)),
+                (4, 16), 128),
+    "gpt2": (lambda: UniversalCausalLM(ArchConfig(
+        **_UNIVERSAL, num_kv_heads=4, pos="learned")), (8, 8), 128),
+    "falcon": (lambda: UniversalCausalLM(ArchConfig(
+        **_UNIVERSAL, num_kv_heads=1, pos="rope", parallel_attn=True,
+        qkv_bias=False, out_bias=False)), (2, 8), 32),
+    "xing4": (lambda: Xing4LM(Xing4Config.tiny()), (128,), 80),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_the_pool_has_the_row_the_family_says(name):
+    """The pool's shape and dtype, and the bytes per cached token that
+    ``last_decode_roofline`` reports after one fused window, from the
+    family's row alone."""
+    make, token_shape, token_bytes = CASES[name]
+    model = make()
+    fam = model.serving_family()
+    assert fam.row.token_shape == token_shape
+    eng = _engine(model, model.init_params(jax.random.PRNGKey(0)),
+                  dtype=jnp.bfloat16, num_blocks=6)
+    assert eng.kv.pages.shape == (fam.num_layers * 6 + 1, 8) + token_shape
+    assert eng.kv.pages.dtype == jnp.bfloat16
+    assert eng.latent_kv == fam.row.latent == (name == "xing4")
+
+    logits = eng.put([0, 1], [[3, 5, 7], [11, 13, 17, 19, 23]])
+    window = eng.decode_batch_async(
+        [0, 1], [int(t) for t in jnp.argmax(logits, axis=-1)], 2)
+    window.tokens()
+    assert (window.moe_pairs is not None) == (fam.counts is not None)
+    # 2 sequences x 2 steps append one row a layer each
+    appended = eng.last_decode_roofline["kernels"]["kv_append"]["bytes"]
+    assert appended / (2 * 2 * fam.num_layers) == token_bytes

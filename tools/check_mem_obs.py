@@ -239,9 +239,10 @@ def main(argv=None) -> int:
                   buckets.get("params", 0) > 0, str(buckets)[:200])
             check("serve: kv_pages bucket attributed",
                   buckets.get("kv_pages", 0) > 0, str(buckets)[:200])
+            frac = mid.get("unattributed_frac")     # 0.0 is a reading
             check("serve: unattributed within bound",
-                  abs(mid.get("unattributed_frac") or 1.0) <= 0.02,
-                  f"unattributed_frac={mid.get('unattributed_frac')}")
+                  frac is not None and abs(frac) <= 0.02,
+                  f"unattributed_frac={frac}")
 
         # ---- cli phase: live ledger render --------------------------- #
         cli = subprocess.run(
