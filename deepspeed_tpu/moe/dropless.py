@@ -9,11 +9,17 @@ straddles two groups is visited once per group, masked), so the padding is
 to a tile and never to a capacity.  At decode widths (a handful of tokens an
 expert) the layer is a stream of every touched expert's weights.
 
-On the TPU the grouped matmul is ``jax.experimental.pallas.ops.tpu.megablox``;
-elsewhere ``jax.lax.ragged_dot`` (same semantics, XLA).
+:func:`grouped_matmul` is the repo's one grouped matmul and also trains: the
+Mixtral block's data-sharded region (``moe/sharded_moe.py``) runs each
+shard's kept pairs through it, forward and backward, with tiles chosen from
+the shapes per program (:func:`_tilings`).  On the TPU it is
+``jax.experimental.pallas.ops.tpu.megablox`` (``gmm``, and for the gradients
+``gmm`` on the transposed weights and ``tgmm``); elsewhere
+``jax.lax.ragged_dot`` (same semantics, XLA).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -53,23 +59,121 @@ def _tile(dim: int, want: int) -> int:
     return best or dim
 
 
+#: the row tile stops growing here: a tile that straddles two groups is
+#: visited once per group, and a taller tile leaves no room in VMEM for a
+#: whole contraction (:func:`_tilings`); swept on the chip, PERF.md PR 31
+MAX_ROW_TILE = 256
+
+
+def row_tile(M: int, groups: int) -> int:
+    """Row tile of a grouped matmul over ``M`` rows in ``groups`` groups; the
+    caller pads its rows to a multiple of it.  128 rows (all of them, to a
+    multiple of 16, where there are fewer) as long as a mean group is
+    smaller than two tiles — decode and prefill widths, where a tile is
+    mostly one expert's padding anyway; :data:`MAX_ROW_TILE` where it is
+    larger — training widths, where a 128-row tile re-reads a group's
+    weights once per 128 rows."""
+    if M < 128:
+        return -(-M // 16) * 16
+    tile = 128
+    while tile * 2 <= min(M // groups, MAX_ROW_TILE):
+        tile *= 2
+    return tile
+
+
+def whole_tiles(M: int, groups: int) -> int:
+    """``M`` rows up to a whole number of row tiles."""
+    tile = row_tile(M, groups)
+    return -(-M // tile) * tile
+
+
+def _tilings(M: int, K: int, N: int, groups: int):
+    """Tiles of the three programs of ``[M, K] x [groups, K, N]``: forward
+    ``(rows, K, N)``, the rows' gradient ``(rows, N, K)`` (it contracts N),
+    the weights' gradient ``(rows, K, N)``.  At a 128-row tile — every
+    shape the serving path compiles — the forward's are PR 28's."""
+    tm = row_tile(M, groups)
+
+    def rows_by(contracted: int, out: int):
+        if tm <= 128:
+            return tm, _tile(contracted, 1792), _tile(out, 512)
+        if contracted <= 4096:
+            # the whole contraction in one block: the next row tile of the
+            # same group finds its [K, 512] of weights still in VMEM (a
+            # block whose index did not change is not copied again), so the
+            # weights are read once and not once per row tile
+            return tm, contracted, _tile(out, 512)
+        return tm, _tile(contracted, 1024), _tile(out, 2048)
+
+    return rows_by(K, N), rows_by(N, K), (tm, _tile(K, 1024), _tile(N, 1024))
+
+
+@functools.cache
+def _megablox_kernels():
+    """megablox's ``gmm`` and ``tgmm``, with the group metadata they derive
+    from the sizes under a ``jit`` of its own.  Each kernel builds that
+    metadata from some sixty ``jax.numpy`` calls, traced from Python anew
+    in every kernel of every program: 2.9 s for the twelve calls of the
+    Mixtral step on the chip's host, at every start, compile cache or not
+    (PERF.md PR 31).  Under its own ``jit`` it is traced once per (rows,
+    tile) and the other kernels find that trace."""
+    import importlib
+
+    backend = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    backend.make_group_metadata = jax.jit(
+        backend.make_group_metadata,
+        static_argnames=("m", "tm", "num_nonzero_groups",
+                         "visit_empty_groups"))
+    return backend.gmm, backend.tgmm
+
+
+def _megablox_forward(x, w, group_sizes):
+    gmm, _ = _megablox_kernels()
+    forward, _, _ = _tilings(*x.shape, w.shape[-1], w.shape[0])
+    return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+               tiling=forward, interpret=not _on_tpu())
+
+
+_megablox = jax.custom_vjp(_megablox_forward)
+
+
+def _megablox_fwd(x, w, group_sizes):
+    return _megablox_forward(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _megablox_bwd(saved, dy):
+    """megablox's own VJP (``ops.gmm``) with a tiling per program."""
+    gmm, tgmm = _megablox_kernels()
+    x, w, group_sizes = saved
+    _, rows, weights = _tilings(*x.shape, w.shape[-1], w.shape[0])
+    dy = dy.astype(x.dtype)
+    dx = gmm(dy, w, group_sizes, preferred_element_type=x.dtype, tiling=rows,
+             transpose_rhs=True, interpret=not _on_tpu())
+    dw = tgmm(x.swapaxes(0, 1), dy, group_sizes,
+              preferred_element_type=w.dtype, tiling=weights,
+              interpret=not _on_tpu())
+    return dx, dw, None
+
+
+_megablox.defvjp(_megablox_fwd, _megablox_bwd)
+
+
 def grouped_matmul(x, w, group_sizes, impl: Optional[str] = None):
     """``x`` [M, K] rows sorted by group, ``w`` [G, K, N], ``group_sizes``
-    [G] int32 summing to M → [M, N] in ``x``'s dtype, float32 accumulate.
-    M is a multiple of the row tile (see :func:`dropless_experts`)."""
+    [G] int32 → [M, N] in ``x``'s dtype, float32 accumulate.  M is a whole
+    number of row tiles (:func:`whole_tiles`).  The sizes may sum to less than M: the rows
+    behind the last group are in no group, a tile of them alone is skipped,
+    and what the result — and, differentiated, ``x``'s gradient — holds
+    there is whatever memory held (megablox) or zeros (``ragged_dot``): the
+    caller selects them out.  Differentiable in ``x`` and ``w``."""
     if impl is None:
         impl = "megablox" if _on_tpu() else "ragged_dot"
     if impl == "ragged_dot":
         return jax.lax.ragged_dot(
             x, w, group_sizes, preferred_element_type=jnp.float32
         ).astype(x.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-
-    M, K = x.shape
-    N = w.shape[-1]
-    tiling = (min(M, 128), _tile(K, 1792), _tile(N, 512))
-    return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-               tiling=tiling, interpret=not _on_tpu())
+    return _megablox(x, w, group_sizes)
 
 
 def dropless_experts(h, idx, weights, experts: Dict,
@@ -97,8 +201,7 @@ def dropless_experts(h, idx, weights, experts: Dict,
     sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
     x = jnp.take(h, token_of, axis=0)                   # [M, D]
     # rows to a whole tile: the extra rows are zeros in the last group
-    tm = 128 if M >= 128 else -(-M // 16) * 16
-    M_pad = -(-M // tm) * tm
+    M_pad = whole_tiles(M, E)
     if M_pad != M:
         x = jnp.pad(x, ((0, M_pad - M), (0, 0)))
         sizes = sizes.at[E - 1].add(M_pad - M)

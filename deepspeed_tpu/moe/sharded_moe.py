@@ -16,25 +16,32 @@ observes at trace time:
     explicit-comm step, manual over the batch axes): plain local code on the
     tokens the call sees, no collective of its own;
   * a mesh whose devices all lie on the batch axes ``data_outer``/``data``
-    (G shards; the ZeRO cells): one ``shard_map`` over the whole mesh.  Each
-    shard routes its own tokens into their GLOBAL slots (an all-gather of
-    G×E counts gives the offsets), the shards' dispatch buffers are
-    reduce-scattered so that each keeps C/G slots of every expert, the
-    expert matmuls run on those, and the outputs are all-gathered for a
-    local combine: two collectives of the [E, C, D] buffer a pass.  The
-    expert weights enter replicated, so under ZeRO-3 GSPMD all-gathers each
-    bf16 weight at the region's boundary and reduce-scatters its gradient
-    on the way back, like every other parameter.  (Capacity per SHARD, the
-    reference's ``TopKGate`` semantics, would save the two buffer
-    collectives but drops pairs the global capacity keeps: a sequence's
-    tokens lean to the same few experts, so a shard's fullest expert runs
-    over 2 × its mean long before the batch's does.  The same layout asked
-    of GSPMD by sharding constraints alone costs an all-reduce of the whole
-    scatter buffer per top-k choice: PERF.md section 6, PR 26.)
+    (G shards; the ZeRO cells), sparse dispatch: one ``shard_map`` over the
+    whole mesh.  Each shard routes its own tokens into their GLOBAL slots
+    (an all-gather of G×E counts gives the offsets; capacity, drops and the
+    balance term are the whole batch's), then applies the experts to ITS
+    OWN kept pairs where they are: sorted by expert, through a grouped
+    matmul over the real rows (``dropless.grouped_matmul``), summed back per
+    token with the gate's weights.  Only the router's counts cross chips
+    under ``moe/*``.  The expert weights enter replicated, so under ZeRO-3
+    GSPMD all-gathers each bf16 weight at the region's boundary and
+    reduce-scatters its gradient on the way back, like every other
+    parameter — and since they are whole on every chip there, which chip
+    computes a kept pair is free to choose.  (Until PR 31 a pair was
+    computed in its global SLOT: the shards' [E, C, D] dispatch buffers
+    were reduce-scattered so that each kept C/G slots of every expert, and
+    the outputs all-gathered back — two collectives of the buffer a pass,
+    and matmuls over ``E·C/G`` capacity-padded rows a chip, twice its own
+    pairs at capacity factor 2.  Capacity per SHARD, the reference's
+    ``TopKGate`` semantics, drops pairs the global capacity keeps: a
+    sequence's tokens lean to the same few experts, so a shard's fullest
+    expert runs over 2 × its mean long before the batch's does.  PERF.md
+    section 6, PR 26 and PR 31.)
   * any other axis of more than one device (``expert``: expert-parallel
-    weights, :func:`moe_partition_specs`; ``tensor``, ``seq``, ``pipe``), or
-    a token count or capacity that G does not divide: the same function as
-    one program under GSPMD.  The partitioner then chooses the exchange
+    weights, :func:`moe_partition_specs`; ``tensor``, ``seq``, ``pipe``), a
+    token count or capacity that G does not divide, or the dense [S, E, C]
+    dispatch (the oracle): the same function as one program under GSPMD,
+    on the capacity-padded [E, C, D] buffer like the one-device program.  The partitioner then chooses the exchange
     itself — on a data-only mesh it was an all-reduce of the [E, C, F]
     expert activations, not the reference's all-to-all (:96 ``_AllToAll``);
     an all-to-all over ``expert`` is a different mechanism that no cell
@@ -54,6 +61,7 @@ from jax.sharding import PartitionSpec as P
 from ..runtime import topology as _topo
 from ..runtime.topology import DATA, DATA_OUTER, EXPERT, get_topology
 from ..telemetry import get_tracer
+from .dropless import grouped_matmul, whole_tiles
 
 
 class GateOutput(NamedTuple):
@@ -428,14 +436,15 @@ def combine_from_experts(combine: jnp.ndarray, expert_out: jnp.ndarray,
 
 
 def _routing_groups(num_tokens: int, capacity: int):
-    """``(mesh, batch axes)`` when the block spreads its expert slots over
-    the data shards: the mesh's batch axes (what ``models/transformer.py``
-    lays every activation's batch dimension over) hold all of its G > 1
+    """``(mesh, batch axes)`` when each data shard computes its own pairs
+    in a region of its own: the mesh's batch axes (what
+    ``models/transformer.py`` lays every activation's batch dimension over)
+    hold all of its G > 1
     devices — no expert, tensor, sequence or pipeline parallelism, whose
     exchanges are other mechanisms that no cell runs — no axis is manual
     already, and G divides the token count and the capacity.  The region is
-    then manual over the whole mesh (one manual over some axes only aborts
-    jax 0.9's CPU compiler on the bf16 ``psum_scatter``).  ``None`` = one
+    then manual over the whole mesh (one manual over some axes only aborted
+    jax 0.9's CPU compiler on a bf16 collective, PR 26).  ``None`` = one
     program under GSPMD."""
     topo = _topo._TOPOLOGY
     if topo is None or topo.mesh.size <= 1:
@@ -466,9 +475,12 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
     The routing is one function of the whole batch on every mesh: capacity
     ``ceil(T × k / E × capacity_factor)``, slots filled in token order.
     Where the tokens are sharded over the batch axes (module docstring,
-    :func:`_routing_groups`) each shard computes C/G slots of every expert.
-    Every traced call leaves one ``moe/layout`` record on the tracer saying
-    which program it became.
+    :func:`_routing_groups`) each shard computes its own kept pairs
+    (:func:`_experts_on_own_pairs`).  Every traced call leaves one
+    ``moe/layout`` record on the tracer saying which program it became:
+    ``compute`` ``grouped`` with the ``rows_per_group`` a shard's grouped
+    matmul is handed, or ``padded`` with the ``E·C`` rows of the slot
+    buffer; ``padded_rows_per_group`` is ``E·C/G`` either way.
     """
     assert dispatch_impl in ("sparse", "dense"), dispatch_impl
     sparse = dispatch_impl == "sparse"
@@ -478,15 +490,20 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
     T, E = tokens.shape[0], lp["router"]["kernel"].shape[1]
     capacity = _capacity(T * k, min(num_experts_logical or E, E),
                          capacity_factor, MIN_CAPACITY)
-    grouped = _routing_groups(T, capacity)
+    # the dense [S, E, C] one-hots (the oracle) cannot feed a grouped matmul
+    grouped = _routing_groups(T, capacity) if sparse else None
     mesh, group_axes = grouped or (None, ())
     groups = math.prod(mesh.shape[a] for a in group_axes)
+    # a shard's (token, choice) pairs, to a whole row tile
+    rows = whole_tiles(T // groups * k, E)
     # trace time only: what a run says about the program it compiled
     get_tracer().record(
         "moe/layout", time.perf_counter(), 0.0, groups=groups,
-        tokens_per_group=T // groups, capacity=capacity,
-        slots_per_group=capacity // groups, experts=E,
-        local=grouped is not None)
+        tokens_per_group=T // groups, capacity=capacity, experts=E,
+        local=grouped is not None,
+        compute="grouped" if grouped else "padded",
+        rows_per_group=rows if grouped else E * capacity,
+        padded_rows_per_group=E * capacity // groups)
 
     def routed(tokens, valid, rng, router, w_gate, w_up, w_down):
         # one name scope per phase: device time (and every collective) shows
@@ -502,6 +519,9 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
                 gate_out = topkgating(logits_r, **gating)
             assert capacity == (gate_out.capacity if sparse
                                 else gate_out.dispatch.shape[2])
+        if group_axes:
+            return _experts_on_own_pairs(
+                gate_out, tokens, rows, w_gate, w_up, w_down), gate_out.l_aux
         with jax.named_scope("moe/dispatch"):
             if sparse:
                 dispatched = dispatch_sparse(gate_out.slot, tokens, E,
@@ -509,19 +529,11 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
             else:
                 dispatched = dispatch_to_experts(gate_out.dispatch, tokens,
                                                  dtype)
-            if group_axes:
-                # a slot holds one token of one shard: the sum of the shards'
-                # buffers is the global one, of which each keeps C/G slots
-                # of every expert
-                dispatched = jax.lax.psum_scatter(
-                    dispatched, group_axes, scatter_dimension=1, tiled=True)
         with jax.named_scope("moe/experts"):
             act = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched, w_gate))
             up = jnp.einsum("ecd,edf->ecf", dispatched, w_up)
             eo = jnp.einsum("ecf,efd->ecd", act * up, w_down)
         with jax.named_scope("moe/combine"):
-            if group_axes:
-                eo = jax.lax.all_gather(eo, group_axes, axis=1, tiled=True)
             if sparse:
                 out = combine_sparse(gate_out.slot, gate_out.gate_val, eo,
                                      dtype)
@@ -532,16 +544,48 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
     if grouped is not None:
         # the weights come in whole: ZeRO-3's gather lands at this boundary,
         # outside moe/*
-        rows, whole = P(group_axes), P()
+        shard, whole = P(group_axes), P()
         routed = _topo.compat_shard_map(
             routed, mesh,
-            in_specs=(rows, None if valid is None else rows,
+            in_specs=(shard, None if valid is None else shard,
                       None if rng is None else whole,
                       whole, whole, whole, whole),
-            out_specs=(rows, whole))
+            out_specs=(shard, whole))
     return routed(tokens, valid, rng, lp["router"]["kernel"],
                   lp["gate_proj"]["kernel"], lp["up_proj"]["kernel"],
                   lp["down_proj"]["kernel"])
+
+
+def _experts_on_own_pairs(gate_out: SparseGateOutput, tokens, rows: int,
+                          w_gate, w_up, w_down):
+    """One shard's kept (token, choice) pairs through their experts, sorted
+    by expert, with a grouped matmul over ``rows`` rows (the pairs, to a
+    whole row tile); nothing leaves the shard.  A pair the gate dropped or
+    masked sorts behind the last expert and is in no group: its rows are
+    not computed, and hold whatever memory held."""
+    dtype = w_gate.dtype
+    (S, k), E, D = gate_out.slot.shape, w_gate.shape[0], tokens.shape[1]
+    with jax.named_scope("moe/dispatch"):
+        # a trash slot E*C gives expert E
+        expert = (gate_out.slot // gate_out.capacity).reshape(S * k)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1, mode="drop")
+        token_of = jnp.pad(order // k, (0, rows - S * k))
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        # select, on the way in as on the way out: transposed, this one
+        # keeps what the rows' gradient holds in no group's rows out of the
+        # tokens' gradient
+        x = jnp.where(live, jnp.take(tokens.astype(dtype), token_of, axis=0),
+                      0)
+    with jax.named_scope("moe/experts"):
+        act = jax.nn.silu(grouped_matmul(x, w_gate, sizes))
+        up = grouped_matmul(x, w_up, sizes)
+        y = grouped_matmul(act * up, w_down, sizes)
+    with jax.named_scope("moe/combine"):
+        y = jnp.where(live, y, 0)
+        y = jnp.take(y, jnp.argsort(order), axis=0).reshape(S, k, D)
+        return jnp.sum(gate_out.gate_val[:, :, None].astype(dtype) * y,
+                       axis=1)
 
 
 def moe_layer(params: Dict, x: jnp.ndarray, k: int = 1,

@@ -245,6 +245,27 @@ def _grouped_matmul(rows):
     return build
 
 
+def _grouped_matmul_train(dev):
+    """The Mixtral ZeRO-3 cell's expert block on one data shard: 8,192
+    (token, choice) pairs sorted by expert through 8 experts of 4096 ->
+    14336 -> 4096, forward and both gradients (megablox's ``gmm``, ``gmm``
+    on the transposed weights, ``tgmm``), each at its own tiling."""
+    from deepspeed_tpu.moe.dropless import grouped_matmul
+
+    def grads(x, gate, up, down, sizes):
+        def ffn(x, gate, up, down):
+            act = jax.nn.silu(grouped_matmul(x, gate, sizes))
+            y = grouped_matmul(act * grouped_matmul(x, up, sizes), down,
+                               sizes)
+            return y.astype(jnp.float32).sum()
+
+        return jax.grad(ffn, argnums=(0, 1, 2, 3))(x, gate, up, down)
+
+    return grads, (_on(dev, (8192, D)), _on(dev, (8, D, F)),
+                   _on(dev, (8, D, F)), _on(dev, (8, F, D)),
+                   _on(dev, (8,), jnp.int32))
+
+
 def _xing4_decode_window(dev):
     """A whole fused decode window of the benchmark's Xing4 configuration
     (1 dense + 4 expert layers, published widths, 64 sequences x 2 steps):
@@ -271,6 +292,7 @@ CASES = {
     "mla_ragged_prefill": _mla(decode=False),
     "grouped_matmul[256 pairs]": _grouped_matmul(256),
     "grouped_matmul[2048 pairs]": _grouped_matmul(2048),
+    "grouped_matmul[8192 pairs, trained]": _grouped_matmul_train,
     "xing4_decode_window": _xing4_decode_window,
     "flash_fwd": _flash(grad=False),
     "flash_bwd": _flash(grad=True),
@@ -302,6 +324,30 @@ def test_compiles_for_v5e(v5e, for_the_chip, case):
     assert "tpu_custom_call" in lowered.as_text(), \
         f"{case}: no Mosaic kernel in the lowered program"
     lowered.compile()                   # raises what the chip would raise
+
+
+@pytest.mark.parametrize("rows", [256, 512, 1024, 2048])
+def test_grouped_matmul_tiles_of_the_serving_shapes_are_pr28s(rows):
+    """Xing4's expert layer (64 experts, 3584 <-> 1024; a layer's, and the
+    whole stack of 4 x 64 groups) keeps the tiles it was measured with."""
+    from deepspeed_tpu.moe.dropless import _tilings
+
+    for groups in (64, 256):
+        assert _tilings(rows, 3584, 1024, groups)[0] == (128, 1792, 512)
+        assert _tilings(rows, 1024, 3584, groups)[0] == (128, 1024, 512)
+
+
+def test_grouped_matmul_tiles_of_the_training_shapes():
+    """Mixtral's 8 experts at 8,192 pairs a shard (4,096 in the benchmark's
+    forward check): 256-row tiles, a 4096 contraction whole (PERF.md PR 31);
+    forward, rows' gradient, weights' gradient."""
+    from deepspeed_tpu.moe.dropless import _tilings
+
+    for rows in (4096, 8192):
+        assert _tilings(rows, D, F, 8) == (
+            (256, 4096, 512), (256, 1024, 2048), (256, 1024, 1024))
+        assert _tilings(rows, F, D, 8) == (
+            (256, 1024, 2048), (256, 4096, 512), (256, 1024, 1024))
 
 
 def test_rmsnorm_blocks_follow_the_shapes():

@@ -244,23 +244,41 @@ class TestSparseDispatchCombine:
 
 
 # --------------------------------------------------------------------- #
-# The block on a data mesh: global routing, expert slots spread (ISSUE 26)
+# The block on a data mesh: global routing, each shard computes its own
+# kept pairs with a grouped matmul (ISSUE 26, ISSUE 31)
 # --------------------------------------------------------------------- #
 #: sizes chosen so that no two dimensions coincide: a shape in the compiled
-#: text names its tensor (at capacity factor 2.0, C = 128 and C/G = 32)
+#: text names its tensor
 N_EXP, D_MODEL, D_FFN, N_TOK = 8, 16, 48, 256
+SHARDS = 4
 QUANTITIES = ("out", "l_aux", "d_tokens", "d_gate_proj", "d_up_proj",
               "d_down_proj")
 COLLECTIVE = re.compile(
     r"= .*\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(-start)?\(")
-#: (dispatch, mesh, capacity factor): at 0.5 most pairs are over capacity,
-#: and which ones are dropped must not depend on the mesh either
-CASES = [("sparse", "data4", 2.0), ("dense", "data4", 2.0),
-         ("sparse", "data4", 0.5), ("sparse", "mics2x2", 2.0)]
 
 
-def _block_params():
+def _case(name, impl="sparse", mesh="data4", cf=2.0, k=2, tokens=N_TOK,
+          rows=128):
+    """``rows``: what a shard hands its grouped matmul, the shard's
+    ``tokens / SHARDS * k`` pairs to a whole row tile."""
+    return dict(name=name, impl=impl, mesh=mesh, cf=cf, k=k, tokens=tokens,
+                rows=rows)
+
+
+#: at capacity factor 0.5 most pairs are over capacity, and which ones are
+#: dropped must not depend on the mesh either; the named cases steer the
+#: router (:func:`_steer`)
+CASES = [_case("cf2"), _case("cf0.5-drops", cf=0.5),
+         _case("mics2x2", mesh="mics2x2"),
+         _case("expert_absent_on_a_shard"),
+         _case("one_expert_takes_a_shard", cf=4.0, k=1, rows=64),
+         _case("valid_cuts_a_shards_tail"),
+         _case("pairs_no_multiple_of_the_tile", tokens=264, rows=256)]
+DENSE = _case("dense", impl="dense")
+
+
+def _block_params(tokens=N_TOK):
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     lp = {"router": {"kernel": jax.random.normal(ks[0], (D_MODEL, N_EXP))},
           "gate_proj": {"kernel": 0.2 * jax.random.normal(
@@ -269,13 +287,41 @@ def _block_params():
               ks[2], (N_EXP, D_MODEL, D_FFN))},
           "down_proj": {"kernel": 0.2 * jax.random.normal(
               ks[3], (N_EXP, D_FFN, D_MODEL))}}
-    return lp, jax.random.normal(ks[4], (N_TOK, D_MODEL))
+    return lp, jax.random.normal(ks[4], (tokens, D_MODEL))
 
 
-def _value_and_grads(impl, capacity_factor):
-    def objective(lp, x):
-        out, l_aux = moe_mlp_block(lp, x, k=2, dispatch_impl=impl,
-                                   capacity_factor=capacity_factor)
+def _steer(case, lp, x):
+    """The named cases: tokens moved along a router column so that one
+    shard's routing is what the name says, or a validity mask; returns
+    ``(x, valid)``.  What the grouped matmul of that shard then sees is
+    asserted here, from the router's own logits."""
+    name, per = case["name"], case["tokens"] // SHARDS
+    router = np.asarray(lp["router"]["kernel"])
+    x, valid = np.array(x), None
+
+    def loads(shard):
+        logits = x[shard * per:(shard + 1) * per] @ router
+        top = np.argsort(-logits, axis=1)[:, :case["k"]]
+        return np.bincount(top.reshape(-1), minlength=N_EXP)
+
+    if name == "expert_absent_on_a_shard":
+        x[per:2 * per] -= 3.0 * router[:, 3]
+        assert loads(1)[3] == 0 and (loads(0) > 0).all()
+    elif name == "one_expert_takes_a_shard":
+        x[2 * per:3 * per] += 3.0 * router[:, 5]
+        assert loads(2)[5] == per * case["k"]
+    elif name == "valid_cuts_a_shards_tail":
+        valid = np.ones(case["tokens"], bool)
+        valid[per + 40:2 * per] = False
+        valid[-24:] = False
+    return jnp.asarray(x), None if valid is None else jnp.asarray(valid)
+
+
+def _value_and_grads(case):
+    def objective(lp, x, valid):
+        out, l_aux = moe_mlp_block(lp, x, k=case["k"], valid=valid,
+                                   dispatch_impl=case["impl"],
+                                   capacity_factor=case["cf"])
         return jnp.sum(out ** 2) + l_aux, (out, l_aux)
 
     return jax.jit(jax.value_and_grad(objective, argnums=(0, 1),
@@ -290,86 +336,111 @@ def _named(result):
     return {k: np.asarray(v) for k, v in named.items()}
 
 
-def _on_data_mesh(lp, x, mesh="data4"):
+def _on_data_mesh(lp, x, mesh="data4", valid=None):
     """Four data shards (``mics2x2``: as data_outer 2 × data 2), the tokens
     batch-sharded and the expert weights stored as ZeRO-3 stores them (their
     last dimension over ``data``)."""
     topo = initialize_mesh(
         TopologyConfig(data=4, zero_shard_size=2 if mesh == "mics2x2" else -1),
         devices=jax.devices()[:4], force=True)
-    x = jax.device_put(x, NamedSharding(topo.mesh, P((DATA_OUTER, DATA))))
+    rows = NamedSharding(topo.mesh, P((DATA_OUTER, DATA)))
+    x = jax.device_put(x, rows)
+    if valid is not None:
+        valid = jax.device_put(valid, rows)
     lp = jax.tree.map(
         lambda w: jax.device_put(w, NamedSharding(
             topo.mesh, P(None, None, DATA) if w.ndim == 3 else P())), lp)
-    return topo, lp, x
+    return topo, lp, x, valid
 
 
 def _layouts():
     return [r.attrs for r in get_tracer().records() if r.name == "moe/layout"]
 
 
-@pytest.fixture(scope="module", params=CASES,
-                ids=lambda case: "-".join(map(str, case)))
-def both_programs(request):
+def _both_programs(case):
     """One block on one device and on four data shards, with the compiled
     text of the second."""
-    impl, mesh, capacity_factor = request.param
-    lp, x = _block_params()
+    lp, x = _block_params(case["tokens"])
+    x, valid = _steer(case, lp, x)
     topo_mod.reset_topology()
     get_tracer().clear()
-    one = _named(_value_and_grads(impl, capacity_factor)(lp, x))
+    one = _named(_value_and_grads(case)(lp, x, valid))
     one_layout = _layouts()
-    _, lp4, x4 = _on_data_mesh(lp, x, mesh)
-    fn = _value_and_grads(impl, capacity_factor)
+    _, lp4, x4, valid4 = _on_data_mesh(lp, x, case["mesh"], valid)
+    fn = _value_and_grads(case)
     get_tracer().clear()
-    four = _named(fn(lp4, x4))
+    four = _named(fn(lp4, x4, valid4))
     four_layout = _layouts()
-    text = fn.lower(lp4, x4).compile().as_text()
+    text = fn.lower(lp4, x4, valid4).compile().as_text()
     topo_mod.reset_topology()
+    capacity = max(int(np.ceil(
+        case["tokens"] * case["k"] / N_EXP * case["cf"])), 4)
     return dict(one=one, four=four, text=text, one_layout=one_layout,
-                four_layout=four_layout,
-                capacity=int(np.ceil(N_TOK * 2 / N_EXP * capacity_factor)))
+                four_layout=four_layout, capacity=capacity, case=case,
+                valid=valid)
+
+
+@pytest.fixture(scope="module", params=CASES, ids=lambda case: case["name"])
+def both_programs(request):
+    return _both_programs(request.param)
+
+
+@pytest.fixture(scope="module")
+def dense_programs():
+    return _both_programs(DENSE)
+
+
+def _assert_equal(programs, quantity):
+    want = programs["one"][quantity]
+    got = programs["four"][quantity]
+    assert got.shape == want.shape
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
 
 
 class TestBlockOnDataShards:
     """Routing stays one function of the whole batch (capacity, slots in
-    token order, drops, balance term); only where the slots are computed
+    token order, drops, balance term); only where a kept pair is computed
     changes, so a data mesh gives the one-device result."""
 
     @pytest.mark.parametrize("quantity", QUANTITIES)
     def test_equals_one_device(self, both_programs, quantity):
-        want = both_programs["one"][quantity]
-        got = both_programs["four"][quantity]
-        assert got.shape == want.shape
-        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+        _assert_equal(both_programs, quantity)
+        if both_programs["valid"] is not None and quantity == "out":
+            masked = ~np.asarray(both_programs["valid"])
+            assert masked.any()
+            assert not both_programs["four"]["out"][masked].any()
 
-    def test_each_shard_computes_its_part_of_every_experts_slots(
-            self, both_programs):
+    def test_each_shard_computes_its_own_pairs(self, both_programs):
         (one,), (four,) = (both_programs["one_layout"],
                            both_programs["four_layout"])
-        cap = both_programs["capacity"]
-        assert one == dict(groups=1, tokens_per_group=N_TOK, capacity=cap,
-                           slots_per_group=cap, experts=N_EXP, local=False)
-        assert four == dict(groups=4, tokens_per_group=N_TOK // 4,
-                            capacity=cap, slots_per_group=cap // 4,
-                            experts=N_EXP, local=True)
+        cap, case = both_programs["capacity"], both_programs["case"]
+        tokens = case["tokens"]
+        assert one == dict(groups=1, tokens_per_group=tokens, capacity=cap,
+                           experts=N_EXP, local=False, compute="padded",
+                           rows_per_group=N_EXP * cap,
+                           padded_rows_per_group=N_EXP * cap)
+        assert four == dict(groups=SHARDS, tokens_per_group=tokens // SHARDS,
+                            capacity=cap, experts=N_EXP, local=True,
+                            compute="grouped", rows_per_group=case["rows"],
+                            padded_rows_per_group=N_EXP * cap // SHARDS)
 
     def test_collectives_move_no_expert_activation(self, both_programs):
-        """What has the F dimension and crosses chips is a WEIGHT (ZeRO-3's
-        gather, the gradient's reduction): no [E, C, F] product, and nothing
-        at all under ``moe/experts``; dispatch and combine exchange the
-        [E, C, D] buffer."""
-        cap = both_programs["capacity"]
+        """Under ``moe/*`` only the router's bookkeeping crosses chips: the
+        gather of the shards' G × E loads, the sum of the kept counts and
+        the balance term's mean (and its transpose).  Nothing there has a
+        model or an expert dimension: a kept pair is computed where its
+        token lives."""
         lines = [ln for ln in both_programs["text"].splitlines()
-                 if COLLECTIVE.search(ln)]
-        assert any("moe/dispatch" in ln for ln in lines)
-        assert any("moe/combine" in ln for ln in lines)
+                 if COLLECTIVE.search(ln) and "moe/" in ln]
+        assert any("moe/route" in ln for ln in lines)
         for line in lines:
-            assert "moe/experts" not in line, line
+            for scope in ("moe/dispatch", "moe/experts", "moe/combine"):
+                assert scope not in line, line
             for shape in re.findall(r"\[([0-9,]+)\]", line.split("(")[0]):
                 dims = [int(d) for d in shape.split(",")]
-                assert dims not in ([N_EXP, cap, D_FFN],
-                                    [N_EXP, cap // 4, D_FFN]), line
+                assert D_MODEL not in dims and D_FFN not in dims, line
+                assert np.prod(dims) <= SHARDS * N_EXP, line
 
 
 def _block_jaxpr(lp, x, manual_mesh=None):
@@ -460,6 +531,18 @@ class TestBlockFallBacks:
         finally:
             topo_mod.reset_topology()
 
+    @pytest.mark.parametrize("quantity", QUANTITIES)
+    def test_dense_oracle_equals_one_device(self, dense_programs, quantity):
+        """The [S, E, C] one-hots cannot feed a grouped matmul: on the data
+        mesh the dense dispatch is the one GSPMD program, same result."""
+        _assert_equal(dense_programs, quantity)
+
+    def test_dense_oracle_keeps_the_padded_program(self, dense_programs):
+        (one,), (four,) = (dense_programs["one_layout"],
+                           dense_programs["four_layout"])
+        assert four == one and one["compute"] == "padded"
+        assert one["local"] is False and one["groups"] == 1
+
     def test_engages_on_the_data_mesh(self, monkeypatch):
         """The control of the cases above: same comparison, other verdict."""
         lp, x = _block_params()
@@ -470,3 +553,70 @@ class TestBlockFallBacks:
             assert text != _global_jaxpr(monkeypatch, lp, x)
         finally:
             topo_mod.reset_topology()
+
+
+# --------------------------------------------------------------------- #
+# The grouped matmul both expert layers share (moe/dropless.py), trained
+# --------------------------------------------------------------------- #
+class TestGroupedMatmul:
+    """megablox with the repo's VJP (interpret mode here) against
+    ``jax.lax.ragged_dot``: the product and both gradients, in the rows
+    some group covers."""
+
+    #: rows in 2 groups of K 256 -> N 384: 512 rows make a 256-row tile
+    SIZES = {"even": [256, 256], "straddles_a_tile": [200, 312],
+             "an_empty_group": [0, 512], "a_tail_in_no_group": [130, 250],
+             "nothing_kept": [0, 0]}
+
+    @pytest.mark.parametrize("sizes", list(SIZES))
+    def test_megablox_vjp_equals_ragged_dot(self, sizes):
+        from deepspeed_tpu.moe.dropless import grouped_matmul, row_tile
+
+        group_sizes = jnp.asarray(self.SIZES[sizes], jnp.int32)
+        live = int(group_sizes.sum())
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        x = jax.random.normal(ks[0], (512, 256))
+        w = jax.random.normal(ks[1], (2, 256, 384))
+        dy = jax.random.normal(ks[2], (512, 384))
+        assert row_tile(512, 2) == 256
+
+        def run(impl):
+            out, vjp = jax.vjp(lambda x, w: grouped_matmul(
+                x, w, group_sizes, impl), x, w)
+            dx, dw = vjp(dy)
+            # what rows in no group hold is the caller's to select out
+            return [np.asarray(a) for a in (out[:live], dx[:live], dw)]
+
+        with jax.default_matmul_precision("highest"):
+            for got, want in zip(run("megablox"), run("ragged_dot")):
+                assert got.shape == want.shape
+                np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("rows,groups,tile", [
+        (4, 64, 16), (64, 64, 64), (256, 64, 128), (2048, 64, 128),
+        (2048, 256, 128), (512, 2, 256), (4096, 8, 256), (8192, 8, 256),
+        (128, 8, 128), (1 << 16, 8, 256)])
+    def test_row_tile(self, rows, groups, tile):
+        from deepspeed_tpu.moe.dropless import row_tile
+
+        assert row_tile(rows, groups) == tile
+
+
+@pytest.fixture(scope="module")
+def through_megablox():
+    """The drops case with the region's grouped matmul on megablox (in
+    interpret mode) and not on ``ragged_dot``, the CPU's choice."""
+    import functools
+
+    from deepspeed_tpu.moe import dropless
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sharded_moe, "grouped_matmul", functools.partial(
+            dropless.grouped_matmul, impl="megablox"))
+        return _both_programs(_case("cf0.5-drops-megablox", cf=0.5))
+
+
+@pytest.mark.parametrize("quantity", QUANTITIES)
+def test_block_on_megablox_equals_one_device(through_megablox, quantity):
+    assert through_megablox["four_layout"][0]["compute"] == "grouped"
+    _assert_equal(through_megablox, quantity)

@@ -509,10 +509,15 @@ class TestTrainSpans:
         (layout,) = [r.attrs for r in tracer.records()
                      if r.name == "moe/layout"]
         # 32 tokens, top-2 of 4 experts, capacity factor 2.0: 32 slots an
-        # expert, of which every data shard computes its part
-        assert layout == dict(groups=shards, tokens_per_group=32 // shards,
-                              capacity=32, slots_per_group=32 // shards,
-                              experts=4, local=shards > 1)
+        # expert = 128 padded rows on one device; a data shard hands the
+        # grouped matmul its own 8 x 2 pairs (one tile of 16 rows) where its
+        # part of the slots was 32 rows
+        assert layout == dict(
+            groups=shards, tokens_per_group=32 // shards, capacity=32,
+            experts=4, local=shards > 1,
+            compute="grouped" if shards > 1 else "padded",
+            rows_per_group=16 if shards > 1 else 128,
+            padded_rows_per_group=128 // shards)
 
     def test_compiled_text_gives_scopes(self, dense_step):
         _, _, text = dense_step
