@@ -133,18 +133,37 @@ class InferenceEngineV2:
             raise ValueError(
                 "host_tier_mb > 0 (the host KV tier, ragged/kv_swap.py) is "
                 "not supported with latent (MLA) pages: set host_tier_mb=0")
+        #: per-sequence recurrent state of some layers (a Gated DeltaNet
+        #: family): a state can be restored only at the token it was saved
+        #: at, so what re-reads, parks or ships cached tokens is refused
+        state = self.family.state
+        if state is not None and (c.prefix_cache or c.host_tier_mb > 0):
+            raise NotImplementedError(
+                f"{'prefix_cache' if c.prefix_cache else 'host_tier_mb > 0'} "
+                f"is not supported with recurrent state "
+                f"({type(state).__name__}): a grafted or swapped-in prefix "
+                f"has pages but no state to start from — state snapshots "
+                f"are ROADMAP R5")
         num_blocks = c.num_blocks or (c.max_seqs * -(-c.max_ctx // c.block_size))
-        self.state_manager = DSStateManager(num_blocks=num_blocks,
-                                            block_size=c.block_size)
+        self.state_manager = DSStateManager(
+            num_blocks=num_blocks, block_size=c.block_size,
+            state_slots=c.max_seqs if state is not None else 0)
         if c.prefix_cache:
             from .ragged.prefix_cache import RadixPrefixCache
 
             self.state_manager.prefix_cache = RadixPrefixCache(
                 self.state_manager.allocator, c.block_size)
         self.kv = BlockedKVCache(KVCacheConfig(
-            num_layers=self.family.num_layers, num_blocks=num_blocks,
+            num_layers=self.family.page_layers, num_blocks=num_blocks,
             block_size=c.block_size, token_shape=self.family.row.token_shape,
             dtype=c.dtype))
+        #: the state pool beside the page pool: a slot a live sequence
+        #: (``max_seqs`` of them), handed out by the state manager
+        self.state_pool = None
+        if state is not None:
+            from .ragged.state_pool import StatePool
+
+            self.state_pool = StatePool(state, c.max_seqs, c.dtype)
         #: page-heat tracker (None = tracking off): observes the allocator
         #: so its live set mirrors the free list, ticked per forward below
         self.heat = None
@@ -239,6 +258,8 @@ class InferenceEngineV2:
         log_dist(f"InferenceEngineV2: blocks={num_blocks}×{c.block_size} "
                  f"budget={c.max_tokens}tok/{c.max_seqs}seq "
                  f"kv={self.kv.mem_bytes()/1e6:.0f}MB "
+                 + (f"state={self.state_pool.mem_bytes()/1e6:.0f}MB "
+                    if self.state_pool is not None else "") +
                  f"bucketing={'on' if c.bucket_tokens else 'off'}", ranks=[0])
 
     # ------------------------------------------------------------------ #
@@ -275,8 +296,24 @@ class InferenceEngineV2:
         if key not in self._wrappers:
             self._wrappers[key] = RaggedBatchWrapper(
                 key[0], key[1], self.config.max_ctx, self.config.block_size,
-                pad_page=self.kv.config.pad_page_flag)
+                pad_page=self.kv.config.pad_page_flag,
+                pad_slot=self.state_pool and self.state_pool.pad_slot)
         return self._wrappers[key]
+
+    def _cache(self):
+        """What a compiled program takes, donated, as its second argument
+        and returns: the page pool, or with recurrent state the pair (page
+        pool, state pool)."""
+        if self.state_pool is None:
+            return self.kv.pages
+        return self.kv.pages, self.state_pool.arrays
+
+    def _cache_update(self, new) -> None:
+        if self.state_pool is None:
+            self.kv.update(new)
+        else:
+            self.kv.update(new[0])
+            self.state_pool.update(new[1])
 
     def _counted(self, key, fn, name: str):
         """Wrap a traceable fn so each XLA trace bumps ``trace_counts[key]``
@@ -315,7 +352,8 @@ class InferenceEngineV2:
             jitted = getattr(eng, store)[key]
             struct = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
                 x.shape, x.dtype, sharding=getattr(x, "sharding", None))
-            args = [jax.tree.map(struct, eng.params), struct(eng.kv.pages),
+            args = [jax.tree.map(struct, eng.params),
+                    jax.tree.map(struct, eng._cache()),
                     jax.ShapeDtypeStruct((n_meta,), jnp.int32)]
             if with_rng:
                 args.append(struct(eng._rng))
@@ -341,8 +379,9 @@ class InferenceEngineV2:
                 jax.tree.map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                     self.params),
-                jax.ShapeDtypeStruct(self.kv.pages.shape,
-                                     self.kv.pages.dtype),
+                jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    self._cache()),
                 jax.ShapeDtypeStruct((self._meta_len(key),), jnp.int32),
             ]
             if with_rng:
@@ -352,7 +391,8 @@ class InferenceEngineV2:
             # (TP-sharded params are exactly the paged_kv_append class)
             shardings = [getattr(leaf, "sharding", None)
                          for leaf in jax.tree_util.tree_leaves(self.params)]
-            shardings += [getattr(self.kv.pages, "sharding", None), None]
+            shardings += [getattr(x, "sharding", None)
+                          for x in jax.tree.leaves(self._cache())] + [None]
             if with_rng:
                 shardings.append(None)
             artifact = f"{kind}[{self.config.attn_impl},bucket={key}]"
@@ -375,7 +415,8 @@ class InferenceEngineV2:
     def _meta_len(self, key: Tuple[int, int]) -> int:
         """Length of the packed metadata vector of the bucket ``key``."""
         return pack_layout(key[0], key[1],
-                           self._wrapper_for(key).max_blocks)["_total"][0]
+                           self._wrapper_for(key).max_blocks,
+                           self.state_pool is not None)["_total"][0]
 
     def _program_kw(self, key: Tuple[int, int]) -> Dict:
         """What model_runner's builders take for the bucket ``key``."""
@@ -427,8 +468,10 @@ class InferenceEngineV2:
         if len(uids) > self.config.max_seqs:
             return SchedulingResult.BatchSequenceLimitExceeded
         blocks_needed = 0
+        slots_needed = 0
         for uid, n in zip(uids, lengths):
             seq = self.state_manager.get_sequence(uid)
+            slots_needed += seq is None or seq.slot is None
             seen = seq.seen_tokens if seq else 0
             if seen + n > self.config.max_ctx:
                 return SchedulingResult.SequenceTooLong
@@ -436,6 +479,10 @@ class InferenceEngineV2:
             blocks_needed += max(-(-(seen + n) // self.config.block_size) - cur, 0)
         if blocks_needed > self.state_manager.free_blocks:
             return SchedulingResult.KVCacheLimitExceeded
+        if self.state_pool is not None \
+                and slots_needed > self.state_manager.free_slots:
+            # every slot of the state pool is owned by a live sequence
+            return SchedulingResult.EngineSequenceLimitExceeded
         return SchedulingResult.Success
 
     # ------------------------------------------------------------------ #
@@ -471,9 +518,9 @@ class InferenceEngineV2:
                 dev = jnp.asarray(packed)
                 # (a routed family's step also returns its pairs per
                 # expert; a prefill leaves them on the device)
-                logits, new_pages, *_ = self._step_for(bucket)(
-                    self.params, self.kv.pages, dev)
-                self.kv.update(new_pages)
+                logits, new_cache, *_ = self._step_for(bucket)(
+                    self.params, self._cache(), dev)
+                self._cache_update(new_cache)
                 for uid in batch.uids:
                     self.state_manager.get_sequence(uid).post_forward()
                 self._touch_heat(batch.uids)
@@ -535,6 +582,8 @@ class InferenceEngineV2:
         section), decode workspace, and the heat snapshot."""
         ledger.register_source("params", lambda: self._param_bytes)
         ledger.register_source("kv_pages", lambda: self.kv.mem_bytes())
+        if self.state_pool is not None:
+            ledger.register_source("state_pool", self.state_pool.mem_bytes)
         ledger.register_source("decode_workspace", self._workspace_bytes)
         ledger.register_source(
             "host_kv",
@@ -572,7 +621,7 @@ class InferenceEngineV2:
         """Copy one logical page across every layer's physical slot — the
         copy-on-write materialization for a shared partial page."""
         src = jnp.asarray([src_block + layer * self._num_blocks
-                           for layer in range(self.family.num_layers)])
+                           for layer in range(self.family.page_layers)])
         dst = src + (dst_block - src_block)
         self.kv.update(self.kv.pages.at[dst].set(self.kv.pages[src]))
         if self.heat is not None:
@@ -585,7 +634,7 @@ class InferenceEngineV2:
         2*KV, HD]`` into every layer's physical slot — the restore leg of
         a host-tier prefix spill."""
         phys = jnp.asarray([block + layer * self._num_blocks
-                            for layer in range(self.family.num_layers)])
+                            for layer in range(self.family.page_layers)])
         self.kv.update(self.kv.pages.at[phys].set(
             jnp.asarray(rows, self.kv.pages.dtype)))
 
@@ -733,6 +782,11 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 "verify_decode (speculative decoding) is not supported with "
                 "latent (MLA) pages: serve this model without a drafter")
+        if self.state_pool is not None:
+            raise NotImplementedError(
+                "verify_decode (speculative decoding) is not supported with "
+                "recurrent state: a rejected candidate cannot be taken back "
+                "out of a state without a snapshot (ROADMAP R5)")
         lens = [1 + len(d) for d in drafts]
         if sum(lens) > self.config.max_tokens:
             # fail BEFORE touching allocator/descriptor state: the ragged
@@ -1038,9 +1092,9 @@ class InferenceEngineV2:
                 meta_dev = jnp.asarray(wrapper.finalize().pack())
                 resume = False
             self._poison_kv(uids[0])
-        toks, new_pages, meta_out, nonfinite, *extra = \
-            self._decode_loops[key](self.params, self.kv.pages, meta_dev, rng)
-        self.kv.update(new_pages)
+        toks, new_cache, meta_out, nonfinite, *extra = \
+            self._decode_loops[key](self.params, self._cache(), meta_dev, rng)
+        self._cache_update(new_cache)
         seen = {}
         for uid in uids:
             seq = self.state_manager.get_sequence(uid)
@@ -1087,7 +1141,7 @@ class InferenceEngineV2:
                            f"private page")
             return
         phys = [b + layer * self._num_blocks
-                for layer in range(self.family.num_layers) for b in own]
+                for layer in range(self.family.page_layers) for b in own]
         self.kv.update(self.kv.pages.at[jnp.asarray(phys)].set(jnp.nan))
 
     @property
@@ -1108,7 +1162,7 @@ class InferenceEngineV2:
             # decode_window_bytes counts 2*KV*hd values a token a layer:
             # the family's row says how many that is
             report = decode_roofline_report(decode_window_bytes(
-                num_layers=self.family.num_layers, num_kv_heads=1,
+                num_layers=self.family.page_layers, num_kv_heads=1,
                 head_dim=self.family.row.read_values // 2,
                 kv_dtype_bytes=jnp.dtype(self.kv.config.dtype).itemsize,
                 param_bytes=self._param_bytes, n_seqs=n_seqs, steps=steps,
@@ -1154,7 +1208,7 @@ class InferenceEngineV2:
         attn_bytes = report["kernels"]["decode_attention"]["bytes"]
         attn_flops = (fam.row.attn_flops * fam.num_heads
                       * window.mean_ctx * window.n_seqs * window.steps
-                      * fam.num_layers)
+                      * fam.page_layers)
         kname = "decode_paged" if self.config.attn_impl == "paged" \
             else "decode_dense"
         publish_kernel_gauges(tel.metrics, kernel_roofline_report(
@@ -1333,6 +1387,11 @@ class DecodeWindow:
             with _TRACER.span("engine/window_account") as asp:
                 if self.moe_pairs is not None:
                     self._account_moe(asp)
+                pool = self.engine.state_pool
+                if pool is not None:
+                    used = pool.slots - self.engine.state_manager.free_slots
+                    asp.set(state_slots=used, state_bytes=pool.mem_bytes(),
+                            state_fill=used / pool.slots)
                 if self._state is not None and \
                         self.engine._decode_state is self._state:
                     # the last sampled token is the next window's seed: once
@@ -1348,15 +1407,21 @@ class DecodeWindow:
         window's live rows reached an expert.  ``moe_pairs_dropped`` is
         what the routing says it should have computed less what the
         experts' groups held: 0, asserted."""
-        pairs = int(self.moe_pairs.sum())
-        expected = (self.n_seqs * self.steps
-                    * self.engine.family.counts.per_token)
-        dropped = expected - pairs
+        counts = self.engine.family.counts
+        held = self.moe_pairs[:counts.num_experts]
+        pairs = int(held.sum())
+        # a chip's share: the pairs of experts held elsewhere are counted
+        # apart, neither computed nor dropped here
+        elsewhere = int(self.moe_pairs[counts.num_experts:].sum())
+        expected = self.n_seqs * self.steps * counts.per_token
+        dropped = expected - pairs - elsewhere
         assert dropped == 0, \
             f"dropless expert layer lost pairs: {expected} routed, " \
-            f"{pairs} computed"
+            f"{pairs} computed, {elsewhere} held elsewhere"
         sp.set(moe_pairs=pairs, moe_pairs_dropped=dropped,
-               moe_load_max_share=float(self.moe_pairs.max()) / max(pairs, 1))
+               moe_load_max_share=float(held.max()) / max(pairs, 1))
+        if counts.elsewhere:
+            sp.set(moe_pairs_elsewhere=elsewhere)
 
     def nonfinite_uids(self) -> List[int]:
         """uids whose logits went non-finite during this window (drains

@@ -13,7 +13,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from . import mla_ops
+from . import gdn_ops, mla_ops
 from .ragged_ops import (decode_attention, paged_kv_append,
                          ragged_paged_attention, verify_window_attention)
 
@@ -117,3 +117,12 @@ def page_ops(row, replicate=None) -> PageOps:
         ragged=partial(ragged_paged_attention, **kw),
         verify=partial(verify_window_attention, **kw),
         dense=_attend_gather)
+
+
+def state_ops(state) -> Callable:
+    """The update of the recurrent-state kind ``state`` (``models/
+    serving.py``): ``(*inputs, pool, rows, mode=, batch=, valid=) → (out,
+    pool)``, ``mode`` one of ``"decode"``, ``"ragged"``, ``"oracle"``
+    (``model_runner._LayerState`` picks it as ``_LayerCache`` picks among a
+    cache kind's operations)."""
+    return partial(gdn_ops.gdn_mix, kind=state)

@@ -66,6 +66,11 @@ def _refuse_latent(engine, what: str) -> None:
         raise NotImplementedError(
             f"{what} is not supported with latent (MLA) pages: the shipment "
             f"format holds K/V rows per head")
+    if getattr(engine, "state_pool", None) is not None:
+        raise NotImplementedError(
+            f"{what} is not supported with recurrent state: a shipment "
+            f"holds cached rows and no state to continue from — state "
+            f"snapshots are ROADMAP R5")
 
 
 def export_kv(engine, uid: int, tokens: List[int],

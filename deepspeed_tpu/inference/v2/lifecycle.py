@@ -239,6 +239,12 @@ class LifecycleScheduler:
             raise ValueError(
                 "speculative= / drafter= (verify windows) are not supported "
                 "with latent (MLA) pages: build the scheduler without them")
+        if (speculative is not None or drafter is not None) \
+                and getattr(engine, "state_pool", None) is not None:
+            raise NotImplementedError(
+                "speculative= / drafter= (verify windows) are not supported "
+                "with recurrent state: a rejected candidate cannot be taken "
+                "back out of a state without a snapshot (ROADMAP R5)")
         if self.spec is not None and self.drafter is None:
             from .speculative import make_drafter
 
@@ -672,12 +678,18 @@ class LifecycleScheduler:
             picked.append((uid, chunk))
             budget -= len(chunk)
         preempted_this_pass = False
+        sm = self.eng.state_manager
+        stateful = sm.free_slots is not None     # recurrent state: slots
+        state_blocked = False
         while self._waiting and budget > 0 and len(picked) < c.max_seqs:
             head = self._reqs[self._waiting[0]]
             t_try = self.clock()
             head._import_s = 0.0
             with _TRACER.span("serve/reserve", uid=head.uid) as rsp:
                 verdict = self._reserve_for(head)
+                if stateful and verdict is True:
+                    # its slot of the state pool came with its pages
+                    rsp.set(slot=sm.get_sequence(head.uid).slot)
             if verdict is True:
                 # admitted: close the queue_wait segment (re-opened by a
                 # preemption); the reservation / graft work is the admission
@@ -705,6 +717,9 @@ class LifecycleScheduler:
                              "serving_rejected", "serving/rejected")
                 continue
             if verdict is False:
+                # (with recurrent state the head may wait for a SLOT while
+                # pages are free: every slot is owned by a live sequence)
+                state_blocked = stateful and sm.free_slots == 0
                 # backpressure: try one preemption, then re-check; a
                 # second failure this pass means the pool genuinely cannot
                 # host the head yet — it keeps its place in the queue
@@ -733,6 +748,8 @@ class LifecycleScheduler:
                            and len(picked) < c.max_seqs),
                tokens=c.max_tokens - budget, prompt_tokens=prompt_tokens,
                prefix_tokens=prefix_tokens)
+        if stateful:
+            sp.set(state_blocked=int(state_blocked))
         return picked
 
     def _run_prefill(self, batch: List[Tuple[int, List[int]]]) -> List[int]:
@@ -1083,9 +1100,14 @@ class LifecycleScheduler:
     # Drain (SIGTERM path)
     # ------------------------------------------------------------------ #
     def start_drain(self) -> None:
-        with self._lock:
-            if not self.draining:
-                self.draining = True
+        # the flag goes up BEFORE the lock is asked for: a driver thread
+        # that steps back to back re-takes the lock the moment it lets go
+        # of it (Python's locks are not fair), and a drain that waits for
+        # the lock is not seen by ``/healthz`` or by ``submit`` until the
+        # last request is through (tools/check_serving_smoke.py, drain)
+        first, self.draining = not self.draining, True
+        if first:
+            with self._lock:
                 self._event("serving_drain_start",
                             pending=self.pending,
                             predicted_s=self.predicted_drain_s())
@@ -1132,10 +1154,12 @@ class LifecycleScheduler:
         """Serving status for /healthz: ``draining`` > ``degraded``
         (recent NaN/hang incident) > ``saturated`` (queue full or recent
         shed) > ``healthy``."""
+        if self.draining:       # without the lock: see start_drain
+            live = (len(self._waiting) + len(self._prefilling)
+                    + len(self._decodes))
+            return "draining", [f"{live} request(s) in flight"]
         with self._lock:
             now = self.clock()
-            if self.draining:
-                return "draining", [f"{self.pending} request(s) in flight"]
             if self.last_incident_t is not None and \
                     now - self.last_incident_t <= self.degraded_window_s:
                 return "degraded", [

@@ -38,16 +38,16 @@ import jax
 import jax.numpy as jnp
 
 from ...models.serving import ServingFamily
-from .kernels.page_ops import PageOps, page_ops
+from .kernels.page_ops import PageOps, page_ops, state_ops
 from .ragged.ragged_wrapper import pack_layout
 
 
-def _unpack_batch(batch, max_q, max_seqs, max_blocks):
+def _unpack_batch(batch, max_q, max_seqs, max_blocks, state_slot=False):
     """Packed int32 metadata vector → field dict via static on-device
     slices (one H2D transfer per forward; see ragged_wrapper.pack_layout)."""
     return {name: batch[off:off + math.prod(shape)].reshape(shape)
             for name, (off, shape)
-            in pack_layout(max_q, max_seqs, max_blocks).items()
+            in pack_layout(max_q, max_seqs, max_blocks, state_slot).items()
             if name != "_total"}
 
 
@@ -127,6 +127,32 @@ class _LayerCache:
         return self.attend(q, **attn)
 
 
+class _LayerState:
+    """The state pool as a layer body gets it: ``state(state_layer,
+    *inputs)`` runs the state kind's update for the batch's new tokens
+    through every sequence's slot and returns its output.
+
+    ``pool`` is the FULL pool ``[state_layers * slots + 1, ...]`` (a tuple of
+    arrays); row ``state_layer * slots + slot`` is a sequence's, the last is
+    the trash row of padded sequence rows (the wrapper's sentinel ``>=
+    slots``).  The mode follows the attention dispatch: the one-token kernel
+    for ``decode_mode`` batches, the chunked form for ragged ones, the
+    token-by-token oracle with ``attn_impl="gather"``."""
+
+    def __init__(self, pool, *, update, batch, slots, mode, valid):
+        self.pool, self.update, self.batch = pool, update, batch
+        self.slots, self.mode, self.valid = slots, mode, valid
+
+    def __call__(self, state_layer, *inputs):
+        slot = self.batch["state_slot"]
+        rows = jnp.where(slot < self.slots, slot + state_layer * self.slots,
+                         self.pool[0].shape[0] - 1)
+        out, self.pool = self.update(*inputs, self.pool, rows,
+                                     mode=self.mode, batch=self.batch,
+                                     valid=self.valid)
+        return out
+
+
 def ragged_forward(params, kv_pages: jnp.ndarray, batch,
                    family: ServingFamily, max_q: int, num_blocks: int,
                    attn_impl: str = "paged", max_seqs: int = 0,
@@ -135,6 +161,10 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
                    verify_mode: bool = False, kv_replicate=None):
     """→ (last-token logits [max_seqs, V], new kv_pages), and where the
     family counts (``family.counts``) its counts as a third.
+
+    A family with recurrent state (``family.state``): ``kv_pages`` is the
+    pair ``(page pool, state pool)``, donated and returned as one, and the
+    batch carries each row's slot.
 
     ``decode_mode`` dispatches the one-token-per-sequence decode attention
     (requires row-major decode batches).  ``verify_mode`` dispatches the
@@ -152,7 +182,16 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
         raise NotImplementedError(
             f"speculative verify windows are not supported with "
             f"{type(family.row).__name__} pages")
-    batch = _unpack_batch(batch, max_q, max_seqs, max_blocks)
+    state_pool = None
+    if family.state is not None:
+        if verify_mode:
+            raise NotImplementedError(
+                "speculative verify windows are not supported with "
+                "recurrent state: a rejected candidate cannot be taken back "
+                "out of a state (ROADMAP R5)")
+        kv_pages, state_pool = kv_pages
+    batch = _unpack_batch(batch, max_q, max_seqs, max_blocks,
+                          family.state is not None)
     layer_cache = partial(
         _LayerCache, ops=ops, batch=batch, attn_impl=attn_impl,
         num_blocks=num_blocks, max_q=max_q, block_q=block_q,
@@ -162,8 +201,14 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
     x, ctx = family.embed(
         params, batch["tokens"], batch["pos_of_token"],
         lambda: batch["page_of_token"] < num_blocks)
-    counts = jnp.zeros((family.counts.num_experts,), jnp.int32) \
+    counts = jnp.zeros((family.counts.size,), jnp.int32) \
         if family.counts else None
+    layer_state = None if state_pool is None else partial(
+        _LayerState, update=state_ops(family.state), batch=batch,
+        slots=(state_pool[0].shape[0] - 1) // family.state.num_layers,
+        valid=batch["page_of_token"] < num_blocks,
+        mode="oracle" if attn_impl != "paged"
+        else "decode" if decode_mode else "ragged")
 
     for stack in family.stacks(params):
         def layer_step(carry, inputs, body=stack.body):
@@ -172,19 +217,25 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
             # from the pool.  Scanning the cache as xs/ys instead would
             # slice-copy one full layer per iteration AND restack the whole
             # cache per forward — O(cache) HBM per decode step.
-            x, pages, counts = carry
+            x, pages, counts, state_pool = carry
             lp, l_idx = inputs
             cache = layer_cache(pages, l_idx)
-            out = body(x, lp, l_idx, cache, ctx)
+            if layer_state is None:
+                out = body(x, lp, l_idx, cache, ctx)
+            else:
+                # the state pool rides the carry beside the page pool
+                state = layer_state(state_pool)
+                out = body(x, lp, l_idx, cache, ctx, state)
+                state_pool = state.pool
             x, c = out if family.counts else (out, None)
             if c is not None:
                 counts = counts + c
-            return (x, cache.pages, counts), None
+            return (x, cache.pages, counts, state_pool), None
 
         with jax.named_scope(stack.scope) if stack.scope \
                 else contextlib.nullcontext():
-            (x, kv_pages, counts), _ = jax.lax.scan(
-                layer_step, (x, kv_pages, counts),
+            (x, kv_pages, counts, state_pool), _ = jax.lax.scan(
+                layer_step, (x, kv_pages, counts, state_pool),
                 (stack.params, jnp.arange(
                     stack.layers.start, stack.layers.stop, dtype=jnp.int32)))
 
@@ -194,6 +245,8 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
         params, x, (lambda x: x) if verify_mode
         else (lambda x: jnp.take(x, batch["logit_idx"], axis=0)))    # [S, V]
     logits = logits.astype(jnp.float32)
+    if state_pool is not None:
+        kv_pages = (kv_pages, state_pool)
     return (logits, kv_pages, counts) if family.counts \
         else (logits, kv_pages)
 
@@ -305,7 +358,10 @@ def build_decode_loop(family: ServingFamily, *, max_q: int, max_seqs: int,
     step_fn = build_ragged_step(family, max_q=max_q, num_blocks=num_blocks,
                                 max_seqs=max_seqs, max_blocks=max_blocks,
                                 jit=False, decode_mode=True, **step)
-    layout = pack_layout(max_q, max_seqs, max_blocks)
+    # (a family with state: its rows' slots ride behind the block table and
+    # do not advance; ``kv_pages`` below is the pair of pools)
+    layout = pack_layout(max_q, max_seqs, max_blocks,
+                         family.state is not None)
     NB, bs = max_blocks, block_size
     S = max_seqs
     # A decode row costs one flat token, so at most min(max_seqs, max_q)
@@ -346,7 +402,7 @@ def build_decode_loop(family: ServingFamily, *, max_q: int, max_seqs: int,
     counts = family.counts
 
     def loop(params, kv_pages, meta, rng):
-        stats0 = jnp.zeros((counts.num_experts,), jnp.int32) \
+        stats0 = jnp.zeros((counts.size,), jnp.int32) \
             if counts else None
 
         def body(carry, _):

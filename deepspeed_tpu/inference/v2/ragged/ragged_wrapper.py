@@ -25,6 +25,10 @@ contiguously at flat indices [cu_q_lens[s], cu_q_lens[s+1])):
                                           kernel's sequence walk terminates
   block_table   [max_seqs, max_blocks]    layer-relative KV page ids per seq
   logit_idx     [max_seqs]                flat index of each seq's last token
+  state_slot    [max_seqs]                only for a family with recurrent
+                                          state: each seq's slot of the state
+                                          pool (pad -> slots sentinel; the
+                                          runner routes it to the trash slot)
 
 INVARIANT (consumed by kernels/ragged_ops.py): cu_q_lens has no interior
 zero-length entries — every scheduled sequence contributes >= 1 query token
@@ -37,15 +41,16 @@ attention kernel dereferences it on-chip (SMEM scalar prefetch).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from .sequence_descriptor import DSSequenceDescriptor
 
 
-def pack_layout(max_tokens: int, max_seqs: int,
-                max_blocks: int) -> Dict[str, Tuple[int, Tuple[int, ...]]]:
+def pack_layout(max_tokens: int, max_seqs: int, max_blocks: int,
+                state_slot: bool = False
+                ) -> Dict[str, Tuple[int, Tuple[int, ...]]]:
     """Static (offset, shape) layout of the single packed int32 metadata
     vector shipped host→device per forward.  One transfer instead of ~12:
     per-array H2D latency would dominate a decode step, so all batch
@@ -64,6 +69,8 @@ def pack_layout(max_tokens: int, max_seqs: int,
         ("cu_q_lens", (max_seqs + 1,)),
         ("block_table", (max_seqs, max_blocks)),
     ]
+    if state_slot:      # behind everything else: nothing before it moves
+        fields.append(("state_slot", (max_seqs,)))
     layout = {}
     off = 0
     for name, shape in fields:
@@ -90,6 +97,7 @@ class RaggedBatch:
     n_tokens: int
     n_seqs: int
     uids: List[int]
+    state_slot: Optional[np.ndarray] = None
 
     def pack(self) -> np.ndarray:
         """Flatten all metadata into ONE int32 vector (see pack_layout)."""
@@ -98,12 +106,14 @@ class RaggedBatch:
             self.seq_of_token, self.pos_of_token, self.q_offset, self.q_len,
             self.ctx_len, self.logit_idx, self.cu_q_lens,
             self.block_table.reshape(-1),
-        ]).astype(np.int32)
+        ] + ([] if self.state_slot is None else [self.state_slot])
+        ).astype(np.int32)
 
 
 class RaggedBatchWrapper:
     def __init__(self, max_tokens: int, max_seqs: int, max_ctx: int,
-                 block_size: int, pad_page: int = 1 << 30):
+                 block_size: int, pad_page: int = 1 << 30,
+                 pad_slot: Optional[int] = None):
         self.max_tokens = max_tokens
         self.max_seqs = max_seqs
         self.max_ctx = max_ctx
@@ -112,6 +122,9 @@ class RaggedBatchWrapper:
         #: layer-relative page sentinel padded tokens carry (= pool
         #: num_blocks; the runner maps it to the shared trash page)
         self.pad_page = pad_page
+        #: a family with recurrent state: the batch carries each row's slot
+        #: of the state pool, padded rows this sentinel (None: no slots)
+        self.pad_slot = pad_slot
         self.clear()
 
     def clear(self):
@@ -146,6 +159,8 @@ class RaggedBatchWrapper:
         block_table = np.zeros((ms, self.max_blocks), np.int32)
         logit_idx = np.zeros(ms, np.int32)
         cu = np.zeros(ms + 1, np.int32)
+        slots = None if self.pad_slot is None \
+            else np.full(ms, self.pad_slot, np.int32)
         uids = []
 
         cursor = 0
@@ -168,6 +183,9 @@ class RaggedBatchWrapper:
             ctx_len[row] = total
             block_table[row, :len(blocks)] = blocks.astype(np.int32)
             logit_idx[row] = cursor + n - 1
+            if slots is not None:
+                assert seq.slot is not None, "state slot not allocated"
+                slots[row] = seq.slot
             cursor += n
             cu[row + 1] = cursor
         cu[len(self._entries) + 1:] = cursor    # trailing rows repeat total
@@ -178,4 +196,4 @@ class RaggedBatchWrapper:
                            ctx_len=ctx_len, block_table=block_table,
                            logit_idx=logit_idx, cu_q_lens=cu,
                            n_tokens=cursor, n_seqs=len(self._entries),
-                           uids=uids)
+                           uids=uids, state_slot=slots)

@@ -19,6 +19,8 @@ class DSSequenceDescriptor:
     in_flight_tokens: int = 0            # tokens scheduled this forward
     blocks: List[int] = dataclasses.field(default_factory=list)
     input_ids: List[int] = dataclasses.field(default_factory=list)
+    #: its slot of the state pool (families with recurrent state; else None)
+    slot: Optional[int] = None
 
     @property
     def cur_allocated_blocks(self) -> int:
@@ -39,8 +41,13 @@ class DSStateManager:
     block without ever starving admission."""
 
     def __init__(self, num_blocks: int, block_size: int = 128,
-                 max_tracked_sequences: int = 2048):
+                 max_tracked_sequences: int = 2048, state_slots: int = 0):
         self.block_size = block_size
+        #: free slots of the state pool (ragged/state_pool.py), None for a
+        #: family without recurrent state.  A sequence gets its slot with
+        #: its first pages and gives it back at flush, like them.
+        self._free_slots: Optional[List[int]] = \
+            list(range(state_slots - 1, -1, -1)) if state_slots else None
         self.allocator = BlockedAllocator(num_blocks)
         self.max_tracked_sequences = max_tracked_sequences
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
@@ -49,6 +56,10 @@ class DSStateManager:
     @property
     def free_blocks(self) -> int:
         return self.allocator.free_blocks
+
+    @property
+    def free_slots(self) -> Optional[int]:
+        return None if self._free_slots is None else len(self._free_slots)
 
     @property
     def n_tracked_sequences(self) -> int:
@@ -73,7 +84,10 @@ class DSStateManager:
 
     def maybe_allocate_kv(self, seq: DSSequenceDescriptor, new_tokens: int) -> bool:
         need = self.blocks_needed(seq, new_tokens)
-        if need == 0:
+        wants_slot = self._free_slots is not None and seq.slot is None
+        if wants_slot and not self._free_slots:
+            return False        # every slot of the state pool is owned
+        if need == 0:       # (a sequence without a slot has no pages yet)
             return True
         # injection site: `exhausted` makes a GENUINE allocation (need > 0)
         # report failure, so whole-lifetime-reserving schedulers (which only
@@ -92,6 +106,8 @@ class DSStateManager:
         if need > self.allocator.free_blocks:
             return False
         seq.blocks.extend(int(b) for b in self.allocator.allocate(need))
+        if wants_slot:
+            seq.slot = self._free_slots.pop()
         return True
 
     def share_blocks(self, seq: DSSequenceDescriptor, blocks,
@@ -116,3 +132,5 @@ class DSStateManager:
             return
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if seq.slot is not None:
+            self._free_slots.append(seq.slot)

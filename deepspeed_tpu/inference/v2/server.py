@@ -568,6 +568,9 @@ class ServingServer:
             if self.scheduler.pending:
                 try:
                     self.scheduler.step()
+                    # let a thread that waits for the scheduler's lock
+                    # (a submit, /healthz, the drain) have it between steps
+                    time.sleep(0)
                 except Exception as e:  # noqa: BLE001 — driver must survive
                     logger.error(f"scheduler step failed: {e!r}")
                     time.sleep(self.driver_idle_s)

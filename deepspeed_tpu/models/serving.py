@@ -22,6 +22,16 @@ axis ``[T, ...]``:
     body never sees a page table.
 ``head(params, x, pick) -> logits``: the final norm, ``pick`` (the rows that
     need logits: each sequence's last, or all in a verify window), the head.
+
+A family whose layers (some of them) keep a per-sequence RECURRENT STATE
+instead of cached rows says so with ``state`` (:class:`GatedDeltaState`): the
+engine then holds a state pool beside the page pool, a sequence owns one slot
+of it, and a body is called ``body(x, lp, layer, cache, ctx, state)``.
+``state(state_layer, *inputs)`` runs the state kind's update for the new
+tokens through every sequence's slot (``kernels/gdn_ops.gdn_mix``) and
+returns its output; ``state_layer`` counts the state-holding layers only, as
+``layer`` given to ``cache`` counts the page-owning ones (``page_layers``).
+A body never sees a slot.
 """
 from __future__ import annotations
 
@@ -77,14 +87,62 @@ class LatentRow:
 
 
 @dataclasses.dataclass(frozen=True)
+class GatedDeltaState:
+    """What a sequence owns in each of ``num_layers`` Gated DeltaNet layers:
+    the delta-rule state ``[num_heads, key_dim, value_dim]`` (float32) and
+    the causal convolution's last ``conv_kernel - 1`` inputs over its
+    ``conv_channels`` (in the serving dtype).  A state can be restored only
+    at the token it was saved at, so what re-reads or ships cached tokens is
+    refused by name: the prefix cache, speculative verify windows, the host
+    tier, ``kv_ship`` (ROADMAP R5)."""
+
+    num_layers: int
+    num_heads: int          # value heads: one state a head
+    num_key_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int
+
+    @property
+    def conv_channels(self) -> int:
+        return 2 * self.num_key_heads * self.key_dim \
+            + self.num_heads * self.value_dim
+
+    def arrays(self, dtype) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
+        """(shape, dtype) of what one slot holds in one layer."""
+        import jax.numpy as jnp
+
+        return (((self.num_heads, self.key_dim, self.value_dim), jnp.float32),
+                ((self.conv_kernel - 1, self.conv_channels), dtype))
+
+    def slot_bytes(self, dtype) -> int:
+        """Bytes a sequence owns, all state layers."""
+        import math
+
+        import jax.numpy as jnp
+
+        return self.num_layers * sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for shape, dt in self.arrays(dtype))
+
+
+@dataclasses.dataclass(frozen=True)
 class ExpertPairs:
     """What a routed family's step returns beside logits and pages: int32
     ``[num_experts]``, the (token, choice) pairs each expert computed, summed
     over the expert layers; one live token makes ``per_token`` of them a
-    step (expert layers × choices)."""
+    step (expert layers × choices).  ``elsewhere``: the ``num_experts``
+    experts are a chip's share of an expert-parallel layer; the pairs routed
+    to the experts of other chips are neither computed nor dropped here, and
+    the vector has one more entry, at its end, that counts them."""
 
     num_experts: int
     per_token: int
+    elsewhere: bool = False
+
+    @property
+    def size(self) -> int:
+        return self.num_experts + int(self.elsewhere)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,3 +165,13 @@ class ServingFamily:
     stacks: Callable[[Any], Iterable[LayerStack]]    # may yield lazily
     head: Callable[[Any, Any, Callable], Any]
     counts: Optional[ExpertPairs] = None
+    #: per-sequence recurrent state of some layers (None: every layer caches
+    #: rows); bodies then take a ``state`` handle after ``ctx``
+    state: Optional[GatedDeltaState] = None
+    #: layers that own PAGES (None: all ``num_layers``)
+    page_layer_count: Optional[int] = None
+
+    @property
+    def page_layers(self) -> int:
+        return self.num_layers if self.page_layer_count is None \
+            else self.page_layer_count

@@ -1,7 +1,9 @@
 """Dropless expert layer for serving: sigmoid scores with a selection bias
-(``noaux_tc``), renormalised and scaled weights, a grouped matmul over the
-(token, choice) pairs sorted by expert, and a shared expert added to every
-token.
+(``noaux_tc``) or softmax scores, renormalised weights, a grouped matmul over
+the (token, choice) pairs sorted by expert, and a shared expert added to
+every token (behind a sigmoid gate where the model has one).  The layer can
+be told that it holds only a chip's share of the experts
+(:func:`dropless_experts`, ``offset``).
 
 No capacity and no dropped pair: the pairs of one expert are a contiguous
 group of rows, the grouped matmul walks each group in row tiles (a tile that
@@ -43,6 +45,18 @@ def sigmoid_topk_route(h, router: Dict, k: int, scaling: float,
     if renormalise:
         g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), g * scaling
+
+
+def softmax_topk_route(h, router: Dict, k: int, renormalise: bool = True):
+    """``h`` [T, D] → (expert ids [T, k] int32, weights [T, k] float32):
+    ``p = softmax(h·W_r)`` over ALL the router's experts in float32, the top
+    ``k``, and with ``renormalise`` ``p_top / sum(p_top)``."""
+    logits = jnp.dot(h.astype(jnp.float32),
+                     router["kernel"].astype(jnp.float32), precision=_HI)
+    g, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalise:
+        g = g / jnp.sum(g, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), g
 
 
 def _on_tpu() -> bool:
@@ -177,7 +191,8 @@ def grouped_matmul(x, w, group_sizes, impl: Optional[str] = None):
 
 
 def dropless_experts(h, idx, weights, experts: Dict,
-                     valid=None, impl: Optional[str] = None, layer=None
+                     valid=None, impl: Optional[str] = None, layer=None,
+                     offset: Optional[int] = None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed experts' part of the layer: ``Σ_k g_k · E_idx_k(h)``.
 
@@ -190,21 +205,35 @@ def dropless_experts(h, idx, weights, experts: Dict,
     MB a layer a step at Xing4.0-29B's widths, over half of a decode step
     (read on the chip, PR 28).  Returns ([T, D], pairs per expert [E] int32
     over the ``valid`` tokens).  Rows of invalid (padding) tokens are
-    computed like any other and never read."""
+    computed like any other and never read.
+
+    A CHIP'S SHARE (``offset`` given): ``experts`` are experts ``offset`` to
+    ``offset + E`` of a layer whose router scores more (``idx`` are ids of
+    the whole layer).  The pairs of experts held elsewhere are sorted behind
+    the last group, where the grouped matmul computes nothing, add nothing
+    to the result, and are counted in one more entry at the end of the
+    pairs: ``[E + 1]``.  They are not dropped: the chip that holds their
+    expert computes them (expert parallelism without its exchange)."""
     T, D = h.shape
     k = idx.shape[1]
     E = experts["gate"].shape[-3]
     M = T * k
     flat = idx.reshape(M)
+    if offset is not None:
+        flat = flat - offset
+        flat = jnp.where((flat >= 0) & (flat < E), flat, E)
     order = jnp.argsort(flat, stable=True)              # pairs by expert
     token_of = order // k
-    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1) if offset is None \
+        else jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
     x = jnp.take(h, token_of, axis=0)                   # [M, D]
-    # rows to a whole tile: the extra rows are zeros in the last group
+    # rows to a whole tile: the extra rows are zeros in the last group (of a
+    # share: in no group, as the rows of the pairs held elsewhere)
     M_pad = whole_tiles(M, E)
     if M_pad != M:
         x = jnp.pad(x, ((0, M_pad - M), (0, 0)))
-        sizes = sizes.at[E - 1].add(M_pad - M)
+        if offset is None:
+            sizes = sizes.at[E - 1].add(M_pad - M)
     if layer is not None:
         L = experts["gate"].shape[0]
         sizes = jax.lax.dynamic_update_slice(
@@ -219,11 +248,16 @@ def dropless_experts(h, idx, weights, experts: Dict,
     with jax.named_scope("moe/combine"):
         inv = jnp.argsort(order)                        # back to pair order
         y = jnp.take(y, inv, axis=0).reshape(T, k, D)
+        if offset is not None:
+            # a row in no group holds whatever memory held: select, then
+            # weigh
+            y = jnp.where((flat < E).reshape(T, k, 1), y, 0)
         out = jnp.einsum("tk,tkd->td", weights,
                          y.astype(jnp.float32)).astype(h.dtype)
+    n = E if offset is None else E + 1                  # + held elsewhere
     counted = flat if valid is None else jnp.where(
-        jnp.repeat(valid, k), flat, E)
-    pairs = jnp.zeros((E + 1,), jnp.int32).at[counted].add(1)[:E]
+        jnp.repeat(valid, k), flat, n)
+    pairs = jnp.zeros((n + 1,), jnp.int32).at[counted].add(1)[:n]
     return out, pairs
 
 
@@ -245,5 +279,30 @@ def sigmoid_moe_block(h, lp: Dict, *, k: int, scaling: float,
     with jax.named_scope("moe/shared"):
         sh = lp["shared"]
         shared = (jax.nn.silu(h @ sh["gate"]) * (h @ sh["up"])) @ sh["down"]
+    with jax.named_scope("moe/combine"):
+        return routed + shared, pairs
+
+
+def softmax_moe_block(h, lp: Dict, *, k: int, renormalise: bool = True,
+                      offset: Optional[int] = None, valid=None,
+                      impl: Optional[str] = None, experts=None, layer=None):
+    """The expert layer of a Qwen3-Next decoder layer: softmax-routed experts
+    (all of them, or with ``offset`` a chip's share: see
+    :func:`dropless_experts`) plus the shared expert behind its sigmoid
+    gate.  ``lp``: ``router`` (``kernel`` [D, E_all]), ``shared`` (``gate``/
+    ``up`` [D, Fs], ``down`` [Fs, D]), ``shared_gate`` (``kernel`` [D, 1]),
+    ``experts`` unless the stack and ``layer`` are given apart.  → ([T, D],
+    pairs per expert held [E] or, of a share, [E + 1])."""
+    with jax.named_scope("moe/route"):
+        idx, weights = softmax_topk_route(h, lp["router"], k, renormalise)
+    routed, pairs = dropless_experts(
+        h, idx, weights, lp["experts"] if experts is None else experts,
+        valid=valid, impl=impl, layer=layer, offset=offset)
+    with jax.named_scope("moe/shared"):
+        sh = lp["shared"]
+        shared = (jax.nn.silu(h @ sh["gate"]) * (h @ sh["up"])) @ sh["down"]
+        gate = jax.nn.sigmoid((h @ lp["shared_gate"]["kernel"]
+                               ).astype(jnp.float32))
+        shared = (gate * shared.astype(jnp.float32)).astype(h.dtype)
     with jax.named_scope("moe/combine"):
         return routed + shared, pairs

@@ -57,13 +57,14 @@ def for_the_chip(monkeypatch):
 
     from deepspeed_tpu.accelerator import real_accelerator
     from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
-    from deepspeed_tpu.inference.v2.kernels import mla_ops, ragged_ops
+    from deepspeed_tpu.inference.v2.kernels import (gdn_ops, mla_ops,
+                                                    ragged_ops)
     from deepspeed_tpu.kernels import fused_collective_matmul as fcm
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.adam import fused_adam
     from deepspeed_tpu.ops.transformer import flash_attention as fa
 
-    for mod in (fa, fcm, ragged_ops, mla_ops, fused_adam):
+    for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, fused_adam):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     monkeypatch.setattr(fcm, "resolve_impl",
@@ -287,7 +288,63 @@ def _xing4_decode_window(dev):
                   _on(dev, (meta,), jnp.int32), _on(dev, (2,), jnp.uint32))
 
 
+def _gdn_decode(dev):
+    """A layer's Gated DeltaNet decode call at the Qwen3-Next cell's shape:
+    64 rows x 32 heads x a [128, 128] float32 state, the pool of 6 x 64 + 1
+    slots aliased in place."""
+    from deepspeed_tpu.inference.v2.kernels.gdn_ops import gdn_decode
+
+    f32 = jnp.float32
+    rows, heads = 64, 32
+    vec = _on(dev, (rows, heads, 128), f32)
+    gate = _on(dev, (rows, heads), f32)
+    return gdn_decode, (vec, vec, vec, gate, gate,
+                        _on(dev, (6 * rows + 1, heads, 128, 128), f32),
+                        _on(dev, (rows,), jnp.int32))
+
+
+def _qwen3next(decode):
+    """The benchmark's Qwen3-Next configuration (two periods of 3 DeltaNet +
+    1 attention layer, published widths, 128 experts held of 512, a quarter
+    of the vocabulary): a fused decode window of 64 sequences x 2 steps
+    (gdn_decode, the K/V page kernel on 2 x 256 rows, megablox, one scan of
+    periods with both pools in the carry), or a 512-token SplitFuse step."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                     Qwen3NextLM)
+
+        cfg = Qwen3NextConfig(num_layers=8, vocab_size=37984,
+                              experts_held=128)
+        model = Qwen3NextLM(cfg)
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
+        seqs, blocks, nb = 64, 3200 // PAGE, 3200
+        cache = (_on(dev, (2 * nb + 1, PAGE, 4, 256)),
+                 tuple(_on(dev, (6 * seqs + 1,) + shape, dtype)
+                       for shape, dtype in cfg.state.arrays(BF16)))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            loop = build_decode_loop(model.serving_family(), max_q=seqs,
+                                     block_size=PAGE, steps=2, **kw)
+            meta = pack_layout(seqs, seqs, blocks, True)["_total"][0]
+            return loop, (params, cache, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(model.serving_family(), max_q=512, **kw)
+        meta = pack_layout(512, seqs, blocks, True)["_total"][0]
+        return step, (params, cache, _on(dev, (meta,), jnp.int32))
+    return build
+
+
 CASES = {
+    "gdn_decode": _gdn_decode,
+    "qwen3next_decode_window": _qwen3next(decode=True),
+    "qwen3next_prefill_step": _qwen3next(decode=False),
     "mla_paged_decode": _mla(decode=True),
     "mla_ragged_prefill": _mla(decode=False),
     "grouped_matmul[256 pairs]": _grouped_matmul(256),

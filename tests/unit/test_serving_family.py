@@ -213,3 +213,141 @@ def test_the_pool_has_the_row_the_family_says(name):
     # 2 sequences x 2 steps append one row a layer each
     appended = eng.last_decode_roofline["kernels"]["kv_append"]["bytes"]
     assert appended / (2 * 2 * fam.num_layers) == token_bytes
+
+
+# --------------------------------------------------------------------- #
+# A family WITH recurrent state, written here
+# --------------------------------------------------------------------- #
+class HybridLM(RenamedLM):
+    """``RenamedLM`` with a gated delta-rule layer in front of every
+    attention layer: a period of two, the first keeping a per-sequence
+    state (``GatedDeltaState``) instead of cached rows.  Its dense forward
+    runs the recurrence token by token, no slot, no pool."""
+
+    HK, HV, DK, DV, K = 1, 2, 8, 8, 3
+
+    def init_params(self, key, dtype=jnp.float32):
+        c = self.config
+        params = super().init_params(key, dtype)
+        C = 2 * self.HK * self.DK + self.HV * self.DV
+        keys = iter(jax.random.split(jax.random.fold_in(key, 1), 6))
+        n = lambda *s: jax.random.normal(next(keys), s).astype(dtype)  # noqa: E731
+        params["delta"] = {
+            "mix": n(c.depth, c.width, C) / math.sqrt(c.width),
+            "gates": n(c.depth, c.width, 2 * self.HV) / math.sqrt(c.width),
+            "conv": n(c.depth, self.K, C) / math.sqrt(self.K),
+            "out": n(c.depth, self.HV * self.DV, c.width) / 4.0}
+        return params
+
+    def _delta_inputs(self, x, dp):
+        h = rms_norm(x, jnp.ones((x.shape[-1],), x.dtype), self.config.eps)
+        gates = (h @ dp["gates"]).astype(jnp.float32)
+        return (h @ dp["mix"], -jax.nn.softplus(gates[:, :self.HV]),
+                jax.nn.sigmoid(gates[:, self.HV:]))
+
+    def __call__(self, params, tokens):
+        c = self.config
+        S, hd = tokens.shape[0], c.width // c.heads
+        cos, sin = rope_at(jnp.arange(S), hd, c.theta)
+        x = params["wte"][tokens]
+        Kd = self.HK * self.DK
+        for i in range(c.depth):
+            dp = jax.tree.map(lambda a: a[i], params["delta"])
+            mixed, g, beta = self._delta_inputs(x, dp)
+            padded = jnp.concatenate(
+                [jnp.zeros((self.K - 1, mixed.shape[1])), mixed])
+            u = jax.nn.silu(sum(dp["conv"][j][None] * padded[j:j + S]
+                                for j in range(self.K)))
+            unit = lambda a: a / jnp.sqrt(  # noqa: E731
+                jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+            q = unit(u[:, :Kd].reshape(S, self.HK, self.DK)) \
+                / math.sqrt(self.DK)
+            k = unit(u[:, Kd:2 * Kd].reshape(S, self.HK, self.DK))
+            q, k = (jnp.repeat(a, self.HV // self.HK, axis=1) for a in (q, k))
+            v = u[:, 2 * Kd:].reshape(S, self.HV, self.DV)
+            state, outs = jnp.zeros((self.HV, self.DK, self.DV)), []
+            for t in range(S):
+                state = state * jnp.exp(g[t])[:, None, None]
+                delta = (v[t] - jnp.einsum("hkv,hk->hv", state, k[t])) \
+                    * beta[t][:, None]
+                state = state + k[t][:, :, None] * delta[:, None, :]
+                outs.append(jnp.einsum("hkv,hk->hv", state, q[t]))
+            x = x + jnp.stack(outs).reshape(S, -1) @ dp["out"]
+            bp = jax.tree.map(lambda a: a[i], params["blocks"])
+            q, k, v = self._qkv(x, bp, cos, sin)
+            k = jnp.repeat(k, c.heads // c.kv_heads, axis=1)
+            v = jnp.repeat(v, c.heads // c.kv_heads, axis=1)
+            s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(hd)
+            s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -1e30)
+            o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+            x = self._rest(x, o, bp)
+        return rms_norm(x, params["nf"], c.eps) @ params["out"]
+
+    def serving_family(self) -> ServingFamily:
+        from deepspeed_tpu.models.serving import GatedDeltaState
+
+        c = self.config
+        hd = c.width // c.heads
+        base = super().serving_family()
+
+        def period(x, lp, p_idx, cache, ctx, state):
+            bp, dp = lp
+            mixed, g, beta = self._delta_inputs(x, dp)
+            o = state(p_idx, mixed, g, beta, dp["conv"])
+            x = x + o.reshape(x.shape[0], -1).astype(x.dtype) @ dp["out"]
+            q, k, v = self._qkv(x, bp, *ctx)
+            o = cache(q, k, v, scale=1.0 / math.sqrt(hd)).astype(x.dtype)
+            return self._rest(x, o, bp)
+
+        def stacks(params):
+            yield LayerStack((params["blocks"], params["delta"]),
+                             range(c.depth), period)
+
+        return dataclasses.replace(
+            base, num_layers=2 * c.depth, stacks=stacks,
+            page_layer_count=c.depth,
+            state=GatedDeltaState(num_layers=c.depth, num_heads=self.HV,
+                                  num_key_heads=self.HK, key_dim=self.DK,
+                                  value_dim=self.DV, conv_kernel=self.K))
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_a_family_with_recurrent_state_is_served(impl):
+    """Two sequences through split prefill, put() steps and a fused window:
+    each continues from its own slot of the state pool, which the engine
+    sized from the family's descriptor alone; a flushed sequence's slot goes
+    to the next one, which starts from zeros."""
+    model = HybridLM(RenamedConfig(depth=2))
+    params = model.init_params(jax.random.PRNGKey(0))
+    eng = _engine(model, params, impl, max_tokens=16)
+    assert eng.kv.pages.shape[0] == 2 * eng.kv.config.num_blocks + 1
+    state, carry = eng.state_pool.arrays
+    assert state.shape == (2 * 2 + 1, 2, 8, 8) and state.dtype == jnp.float32
+    assert carry.shape == (2 * 2 + 1, 2, 32)
+    rng = np.random.default_rng(0)
+    a, b = (rng.integers(1, 88, size=n).tolist() for n in (13, 9))
+
+    def same(got, seq):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(model(params, jnp.asarray(seq))[-1]),
+            atol=3e-4, rtol=3e-4)
+
+    eng.put([0], [a[:8]])
+    logits = eng.put([0, 1], [a[8:], b])        # chunks of two sequences
+    same(logits[0], a)
+    same(logits[1], b)
+    a, b = a + [int(jnp.argmax(logits[0]))], b + [int(jnp.argmax(logits[1]))]
+    logits = eng.put([1, 0], [b[-1:], a[-1:]])
+    same(logits[0], b)
+    same(logits[1], a)
+    seeds = [int(jnp.argmax(logits[1])), int(jnp.argmax(logits[0]))]
+    window = eng.decode_batch([0, 1], seeds, 3)
+    for col, chain in enumerate((a + seeds[:1], b + seeds[1:])):
+        for tok in window[:, col].tolist():
+            assert tok == int(jnp.argmax(model(params, jnp.asarray(chain))[-1]))
+            chain.append(tok)
+    slot = eng.state_manager.get_sequence(1).slot
+    eng.flush([1])
+    fresh = rng.integers(1, 88, size=11).tolist()
+    same(eng.put([2], [fresh])[0], fresh)
+    assert eng.state_manager.get_sequence(2).slot == slot
