@@ -53,6 +53,18 @@ pool's dtype, and hands each sequence's first chunk to the DMA engine
 behind the previous sequence's last compute: 308 us (74%) and 428 us (86%),
 which is what the same walk takes with its compute removed.  The ragged
 (prefill / verify) kernel below still slices and casts per head.
+
+PR 33 (PERF.md section 6; the kernel alone at Qwen3-Next's serving shape,
+16/2 heads of 256, 64 sequences of 0.3-3.1k tokens): with 4 combined rows
+a token the value slice was 86% of the kernel (2,425 us, 9% of the roof;
+335 us with a contiguous read in its place, 264 us with no compute).  Few
+kv heads never stood in the strided load's way: Mosaic lowers a sublane-
+strided load whenever the BASE memref is one lane tile (128 lanes) wide,
+whatever its sublane tiling, and refuses it on a 256-wide one.  So the
+shape of the chunk buffer follows the lane tile, not the head count: a
+page lands by ``hd // 128`` copies, one a 128-lane tile, and the pair
+load reads each tile's buffer (283 us, 77%; at 128-wide heads one copy a
+page as before).  ``_decode_head_load`` says which pools get it.
 """
 from __future__ import annotations
 
@@ -397,16 +409,25 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
 # Decode-specialized paged attention (the serving fast path)
 # ===================================================================== #
 def _decode_head_load(dtype, KV: int, hd: int, ps: int) -> str:
-    """Which load a chunk's heads get — decided from what the pool shows.
+    """Which load a chunk's heads get — decided from what the pool shows,
+    and it says what Mosaic lowers (``test_chip_compile.py`` compiles both
+    answers for a described v5e).
 
     ``"strided"``: the pool's dtype packs two rows into a 32-bit word
-    (bf16), the ``2·KV`` combined heads of a token fill whole sublane
-    tiles of that dtype (so the VMEM pages flatten to ``[tokens·2KV, hd]``
-    without padding) and ``hd`` fills whole lanes.  Anything else —
-    float32 pools, ``2·KV`` below a tile, narrow heads — is ``"general"``.
+    (bf16), so that a word row is a PAIR of combined heads; ``KV`` is even
+    (a pair is two K heads or two V heads, never one of each); the ``KV``
+    word rows of a token tile the sublanes without padding (1, 2, 4 or 8
+    of them, or a multiple of 8 — 6 are padded to 8 and the flattened view
+    is refused); ``hd`` is whole lane tiles and a page whole sublane tiles.
+    The number of kv heads is NOT the obstacle (PR 33: 4 and 8 combined
+    rows a token lower as they are), and neither is a 256-wide head: the
+    kernel lands a page one 128-lane tile at a time, because the base
+    memref of a sublane-strided load must be one lane tile wide.  Anything
+    else — float32 pools, ``KV`` 1, 3 or 6, narrow heads — is
+    ``"general"``.
     """
     packing = 4 // jnp.dtype(dtype).itemsize
-    if packing == 2 and (2 * KV) % (8 * packing) == 0 and hd % 128 == 0 \
+    if packing == 2 and (KV in (2, 4) or KV % 8 == 0) and hd % 128 == 0 \
             and ps % 8 == 0:
         return "strided"
     return "general"
@@ -432,7 +453,7 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
     ``2j`` (low half) and ``2j+1`` (high half) of token ``t``, so ONE
     sublane-strided ref load (start ``j``, stride ``KV``) yields heads
     ``2j, 2j+1`` of all ``CH`` tokens, each vreg of the buffer read once,
-    and its bitcast back to the pool's dtype is the ``[2·CH, hd]`` matrix
+    and its bitcast back to the pool's dtype is the ``[2·CH, lanes]`` matrix
     whose even rows are head ``2j`` and odd rows head ``2j+1``.  That pair
     is scored in one pass against the ``2·G`` query rows of both heads;
     the columns of the other head's parity are masked like columns past
@@ -441,34 +462,49 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
     gather, no unpacking, no cast.  ``hpg == 1`` (the general load) scores
     one head a pass from a value slice of the chunk: the same body with no
     parity mask.
+
+    How a chunk's pages land (PR 33).  Mosaic lowers a strided load only
+    from a base memref that is ONE lane tile (128 lanes) wide — then any
+    sublane tiling of it is plain rows of 128 words.  That, and not the
+    number of kv heads, decides the buffer's shape: ``kv_bufs`` is
+    ``[2, LT, P, ps, 2·KV, hd/LT]`` and a page is fetched by ``LT`` copies,
+    one a lane tile (``LT = hd // 128`` for the strided load: 1 at 128-wide
+    heads, which is PR 29's kernel; 2 at Qwen3-Next's 256; 1 copy of the
+    whole page for the general load).  A pass sums ``q_t · K_tᵀ`` over the
+    lane tiles in float32 and writes ``p · V_t`` into the accumulator's own
+    columns: the same products in the same precision.
     """
     s, S = pl.program_id(0), pl.num_programs(0)
     kvl = kvl_ref[s]
     CH = P * ps                               # context tokens per chunk
     nch = _cdiv(kvl, CH)
     NG, R, W = KV // hpg, hpg * G, hpg * CH   # passes, query rows, columns
-    dtype, hd = kv_bufs.dtype, kv_bufs.shape[-1]
+    dtype, LT, LW = kv_bufs.dtype, kv_bufs.shape[1], kv_bufs.shape[-1]
 
     def page_needed(seq, page_idx):
         return page_idx * ps < kvl_ref[seq]
 
-    def chunk_dma(seq, c, slot, p):
+    def page_dmas(seq, c, slot, p):           # one copy a lane tile
         page_idx = c * P + p
         pid = pt_ref[seq, jnp.minimum(page_idx, NB - 1)]
-        return pltpu.make_async_copy(
-            pages_ref.at[pid], kv_bufs.at[slot, p], sems.at[slot, p])
+        page = pages_ref.at[pid]               # one tile: the whole page
+        return [pltpu.make_async_copy(
+            page if LT == 1 else page.at[:, :, pl.ds(t * LW, LW)],
+            kv_bufs.at[slot, t, p], sems.at[slot, t, p]) for t in range(LT)]
 
     def start_chunk(seq, c, slot):
         for p in range(P):
             @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(seq, c, slot, p).start()
+                for dma in page_dmas(seq, c, slot, p):
+                    dma.start()
 
     def wait_chunk(seq, c, slot):
         for p in range(P):
             @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(seq, c, slot, p).wait()
+                for dma in page_dmas(seq, c, slot, p):
+                    dma.wait()
 
     acc[:] = jnp.zeros_like(acc)
     m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
@@ -504,35 +540,37 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
             tok_ok = jax.lax.broadcasted_iota(
                 jnp.int32, (CH, 1), 0) + c * CH < kvl
             if hpg == 2:
-                words = kv_bufs.at[slot].reshape(CH * 2 * KV, hd) \
-                    .bitcast(jnp.uint32)       # [CH·KV, hd]
+                words = [kv_bufs.at[slot, t].reshape(CH * 2 * KV, LW)
+                         .bitcast(jnp.uint32)  # [CH·KV, 128] a lane tile
+                         for t in range(LT)]
 
-                def pair(j, keep=None):        # combined heads 2j, 2j+1
-                    w = words[pl.ds(j, CH, stride=KV), :]
+                def pair(t, j, keep=None):     # combined heads 2j, 2j+1
+                    w = words[t][pl.ds(j, CH, stride=KV), :]
                     if keep is not None:       # a word row is one token
                         w = jnp.where(keep, w, jnp.uint32(0))
-                    return pltpu.bitcast(w, dtype)        # [2·CH, hd]
+                    return pltpu.bitcast(w, dtype)        # [2·CH, 128]
 
-                def load_k(g):
-                    return pair(g)
+                def load_k(g, t):
+                    return pair(t, g)
 
-                def load_v(g):
-                    return pair(KV // 2 + g, tok_ok)
+                def load_v(g, t):
+                    return pair(t, KV // 2 + g, tok_ok)
             else:
-                kv = kv_bufs[slot]             # [P, ps, 2KV, hd]
+                kv = kv_bufs[slot, 0]          # [P, ps, 2KV, hd]
 
-                def load_k(g):
-                    return kv[:, :, g, :].reshape(CH, hd)
+                def load_k(g, t):
+                    return kv[:, :, g, :].reshape(CH, LW)
 
-                def load_v(g):
+                def load_v(g, t):
                     return jnp.where(
-                        tok_ok, kv[:, :, KV + g, :].reshape(CH, hd), 0.0)
+                        tok_ok, kv[:, :, KV + g, :].reshape(CH, LW), 0.0)
 
             for g in range(NG):
-                qg = q_ref[0, g * R:(g + 1) * R, :].astype(dtype)
-                s_mat = jax.lax.dot_general(
-                    qg, load_k(g), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                s_mat = functools.reduce(jnp.add, [jax.lax.dot_general(
+                    q_ref[0, g * R:(g + 1) * R, t * LW:(t + 1) * LW]
+                    .astype(dtype), load_k(g, t), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for t in range(LT)]) * scale
                 if alibi is not None:
                     r = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
                     slope = jnp.zeros((R, W), jnp.float32)
@@ -557,9 +595,11 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                 l_scr[g] = jnp.broadcast_to(
                     alpha * l_scr[g][:, :1] +
                     jnp.sum(p_mat, axis=1, keepdims=True), l_scr[g].shape)
-                acc[g] = acc[g] * alpha + \
-                    jnp.dot(p_mat.astype(dtype), load_v(g),
-                            preferred_element_type=jnp.float32)
+                for t in range(LT):            # the tile's own columns
+                    cols = slice(t * LW, (t + 1) * LW)
+                    acc[g, :, cols] = acc[g, :, cols] * alpha + \
+                        jnp.dot(p_mat.astype(dtype), load_v(g, t),
+                                preferred_element_type=jnp.float32)
                 m_scr[g] = jnp.broadcast_to(m_new, m_scr[g].shape)
 
         def body(state):
@@ -614,7 +654,9 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     The MXU sees ``q``, K, V and the probabilities in the POOL's dtype and
     accumulates in float32 (a float32 pool rounds nothing).  Every traced
     call leaves one ring-only ``attn/decode_layout`` record saying which
-    head load the compiled kernel got (:func:`_decode_head_load`).
+    head load the compiled kernel got (:func:`_decode_head_load`) and in
+    how many ``lane_tiles`` a page lands (``hd // 128`` for the strided
+    load, 1 otherwise).
     """
     S, H, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
@@ -629,7 +671,7 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         scale = 1.0 / math.sqrt(hd)
     P = min(pages_per_chunk, NB)
     load = _decode_head_load(kv_pages.dtype, KV, hd, ps)
-    hpg = 2 if load == "strided" else 1
+    hpg, LT = (2, hd // 128) if load == "strided" else (1, 1)
 
     # same VMEM accounting as the ragged kernel, with the [hpg·G, hpg·chunk]
     # score tile
@@ -661,7 +703,8 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     # trace time only: what a run says about the kernel it compiled
     get_tracer().record(
         "attn/decode_layout", time.perf_counter(), 0.0, load=load, P=P,
-        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=KV, group=G)
+        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=KV, group=G,
+        lane_tiles=LT)
 
     kernel = functools.partial(
         _decode_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, NB=NB,
@@ -678,8 +721,8 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
             ],
             out_specs=pl.BlockSpec((1, H, hd), lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, P, ps, ckv, hd), kv_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, P)),
+                pltpu.VMEM((2, LT, P, ps, ckv, hd // LT), kv_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, LT, P)),
                 pltpu.VMEM((NG, R, hd), jnp.float32),
                 pltpu.VMEM((NG, R, 128), jnp.float32),
                 pltpu.VMEM((NG, R, 128), jnp.float32),

@@ -119,22 +119,34 @@ def _paged(decode):
     return build
 
 
-def _paged_decode_cell(rows):
-    """The K/V decode kernel as the Mistral serving cells run it: a
-    16-layer pool of 1,730 pages a layer, 64-page tables, bf16, 4 / 32 / 64
-    rows (prefill cell's side windows, long-context, closed-loop decode).
-    The strided pair load must lower for the chip, not only interpret."""
+def _paged_decode_pool(kv, hd, load, pages, heads=16, rows=64, blocks=64,
+                       dtype=BF16):
+    """The K/V decode kernel on a pool ``_decode_head_load`` answers
+    ``load`` for: the rule must promise only what Mosaic lowers (the
+    strided pair load must lower for the chip, not only interpret), so the
+    answer is asserted and then BOTH kinds are compiled.  (A bf16 pool of
+    6 or 12 combined rows, or of 64-wide heads, is refused at the page DMA
+    whatever the load, at PR 32 too: PERF.md section 7.)"""
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
             _decode_head_load, decode_paged_attention)
 
-        assert _decode_head_load(BF16, KV, HD, PAGE) == "strided"
+        assert _decode_head_load(dtype, kv, hd, PAGE) == load
         return (lambda q, p, n, t: decode_paged_attention(
-            q, p, n, t, num_kv_heads=KV)), \
-            (_on(dev, (rows, H, HD)),
-             _on(dev, (16 * 1730 + 1, PAGE, 2 * KV, HD)),
-             _on(dev, (rows,), jnp.int32), _on(dev, (rows, 64), jnp.int32))
+            q, p, n, t, num_kv_heads=kv)), \
+            (_on(dev, (rows, heads, hd), dtype),
+             _on(dev, (pages, PAGE, 2 * kv, hd), dtype),
+             _on(dev, (rows,), jnp.int32),
+             _on(dev, (rows, blocks), jnp.int32))
     return build
+
+
+def _paged_decode_cell(rows):
+    """The K/V decode kernel as the Mistral serving cells run it: a
+    16-layer pool of 1,730 pages a layer, 64-page tables, bf16, 4 / 32 / 64
+    rows (prefill cell's side windows, long-context, closed-loop decode)."""
+    return _paged_decode_pool(KV, HD, "strided", 16 * 1730 + 1, heads=H,
+                              rows=rows)
 
 
 def _rmsnorm(d, f):
@@ -358,6 +370,18 @@ CASES = {
     "decode_paged_attention[4 rows]": _paged_decode_cell(4),
     "decode_paged_attention[32 rows]": _paged_decode_cell(32),
     "decode_paged_attention[64 rows]": _paged_decode_cell(64),
+    # Qwen3-Next's cell: 16 / 2 heads of 256, its two page-owning layers'
+    # 3,200 pages each, 50-page tables — two lane tiles a page
+    "decode_paged_attention[qwen3next, 64 rows]": _paged_decode_pool(
+        2, 256, "strided", 2 * 3200 + 1, blocks=50),
+    "decode_paged_attention[KV 8, hd 256]": _paged_decode_pool(
+        8, 256, "strided", 16 * 400 + 1),              # Gemma-2-9B's heads
+    "decode_paged_attention[KV 4, hd 128]": _paged_decode_pool(
+        4, 128, "strided", 16 * 1730 + 1),
+    "decode_paged_attention[KV 1, general]": _paged_decode_pool(
+        1, 128, "general", 16 * 400 + 1),              # a K/V word row
+    "decode_paged_attention[float32 KV 6, general]": _paged_decode_pool(
+        6, 128, "general", 16 * 400 + 1, heads=12, dtype=jnp.float32),
     "rmsnorm_matmul[4096x14336]": _rmsnorm(D, F),      # gate / up
     "rmsnorm_matmul[4096x6144]": _rmsnorm(D, 6144),    # fused qkv width
     "rmsnorm_matmul[4096x1024]": _rmsnorm(D, 1024),    # k / v

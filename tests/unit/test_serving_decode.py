@@ -16,6 +16,7 @@ import pytest
 
 from deepspeed_tpu.inference.v2.kernels.page_ops import _attend_gather
 from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
+    _decode_head_load,
     decode_attend_dense,
     decode_attention,
     decode_paged_attention,
@@ -129,7 +130,7 @@ class TestDecodeKernelParity:
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_d),
                                    atol=3e-5, rtol=3e-5)
 
-    # ---- the cells' geometry: a bf16 pool, KV 8 x G 4 x hd 128, page 64 -- #
+    # ---- the cells' geometries: bf16 pools of 64-token pages --------------- #
     # Tolerance.  The kernel hands q, K, V and the probabilities to the MXU
     # in the pool's dtype and accumulates in float32; the dense lowering
     # computes everything in float32 from the same bf16 inputs.  q.K
@@ -139,11 +140,16 @@ class TestDecodeKernelParity:
     # 1e-3 absolute at most) and BOTH outputs are rounded to bf16 at the
     # end (2^-9 relative each: one bf16 ulp, 2^-8, between them).
     BF16_TOL = dict(rtol=2.0 ** -7, atol=6e-3)
+    # Mistral's 32 / 8 heads of 128 (one lane tile a page) and Qwen3-Next's
+    # 16 / 2 heads of 256 (PR 33: 4 combined rows a token, two lane tiles)
     CELL = dict(KV=8, G=4, hd=128, ps=64, NB=10)
+    QWEN = dict(KV=2, G=8, hd=256, ps=64, NB=10)
+    GEOMETRIES = [pytest.param(CELL, id="mistral"),
+                  pytest.param(QWEN, id="qwen3next")]
 
-    def _cell_case(self, seed, ctx, poison=True):
+    def _cell_case(self, seed, ctx, poison=True, geom=None):
         rng = np.random.default_rng(seed)
-        g = self.CELL
+        g = geom or self.CELL
         q, pages, kvl, pt = _decode_case(rng, ctx, g["KV"], g["G"], g["hd"],
                                          g["ps"], g["NB"])
         q, pages = q.astype(jnp.bfloat16), pages.astype(jnp.bfloat16)
@@ -166,44 +172,67 @@ class TestDecodeKernelParity:
         np.testing.assert_allclose(out, ref, **self.BF16_TOL)
         return out
 
+    @pytest.mark.parametrize("geom", GEOMETRIES)
     @pytest.mark.parametrize("ctx", [
         pytest.param([512, 511, 513], id="chunk-boundary"),
         pytest.param([1, 130, 1], id="one-token"),
         pytest.param([513, 0, 65, 0, 512], id="padding-rows-between"),
         pytest.param([640, 64, 600], id="nan-never-fetched"),
     ])
-    def test_bf16_pool_at_cell_geometry(self, ctx):
+    def test_bf16_pool_at_cell_geometry(self, ctx, geom):
         """bf16 pool, the strided pair load (interpret mode) against the
         dense float32 lowering: contexts on and either side of a 512-token
         chunk boundary, one-token contexts, ``kv_lens == 0`` padding rows
         between live rows (the first-chunk hand-over must skip them), and
         NaN in every page past each context (never fetched, or fetched
         behind the context's end: masked AND zeroed)."""
-        out = self._assert_matches_dense(*self._cell_case(30, ctx),
-                                         self.CELL["KV"])
+        out = self._assert_matches_dense(
+            *self._cell_case(30, ctx, geom=geom), geom["KV"])
         for s, c in enumerate(ctx):
             if c == 0:
                 np.testing.assert_array_equal(out[s], 0.0)
 
-    def test_bf16_pool_partial_last_page_holds_nan(self):
+    @pytest.mark.parametrize("geom", GEOMETRIES)
+    def test_bf16_pool_partial_last_page_holds_nan(self, geom):
         """A context ending INSIDE a page: the page is fetched, the rows
         behind the context's end hold NaN (bf16) and must not reach the
         output through a 0-probability product."""
         ctx = [100, 577]
-        q, pages, kvl, pt = self._cell_case(31, ctx, poison=False)
-        ps = self.CELL["ps"]
+        q, pages, kvl, pt = self._cell_case(31, ctx, poison=False, geom=geom)
+        ps = geom["ps"]
         for s, c in enumerate(ctx):
             pid = int(pt[s, c // ps])
             pages = pages.at[pid, c % ps:].set(jnp.nan)
-        self._assert_matches_dense(q, pages, kvl, pt, self.CELL["KV"])
+        self._assert_matches_dense(q, pages, kvl, pt, geom["KV"])
 
-    @pytest.mark.parametrize("ppc", [1, 4, 8])
-    def test_bf16_pool_pages_per_chunk_invariance(self, ppc):
-        """1, 4 and 8 pages a chunk walk the same context to the same
+    @pytest.mark.parametrize("geom,ppc", [
+        pytest.param(CELL, 1, id="mistral-1"),
+        pytest.param(CELL, 4, id="mistral-4"),
+        pytest.param(CELL, 8, id="mistral-8"),
+        pytest.param(QWEN, 1, id="qwen3next-1"),
+        pytest.param(QWEN, 4, id="qwen3next-4"),
+        pytest.param(QWEN, 8, id="qwen3next-8"),
+        pytest.param(dict(QWEN, NB=17), 16, id="qwen3next-16"),
+    ])
+    def test_bf16_pool_pages_per_chunk_invariance(self, geom, ppc):
+        """1, 4, 8 and 16 pages a chunk walk the same context to the same
         answer (against the dense lowering, so each case stands alone)."""
         self._assert_matches_dense(
-            *self._cell_case(32, [577, 0, 256, 129]), self.CELL["KV"],
+            *self._cell_case(32, [577, 0, 256, 129], geom=geom), geom["KV"],
             pages_per_chunk=ppc)
+
+    @pytest.mark.parametrize("geom", [
+        pytest.param(dict(KV=8, G=2, hd=256, ps=64, NB=10), id="KV8-hd256"),
+        pytest.param(dict(KV=4, G=2, hd=128, ps=64, NB=10), id="KV4-hd128"),
+    ])
+    def test_bf16_pool_strided_load_at_other_pools(self, geom):
+        """Pools the strided load takes since PR 33 and no cell runs: GQA-8
+        with 256-wide heads (the parent promised ``strided`` there and the
+        chip refused the kernel) and 8 combined rows a token."""
+        assert _decode_head_load(jnp.bfloat16, geom["KV"], geom["hd"],
+                                 geom["ps"]) == "strided"
+        self._assert_matches_dense(
+            *self._cell_case(37, [513, 0, 70, 600], geom=geom), geom["KV"])
 
     def test_bf16_pool_alibi_rides_the_pair_tile(self):
         """Per-head slopes in the two-heads-a-pass layout (MHA, so a pass
@@ -218,9 +247,10 @@ class TestDecodeKernelParity:
 
     def test_decode_layout_record_names_the_load(self):
         """``attn/decode_layout`` (ring only, one per traced call): strided
-        at the cells' geometry, general at the float32 toy shapes, at a
-        bf16 pool whose 2.KV does not fill a sublane tile, at narrow
-        heads."""
+        at the cells' geometries (one lane tile a page at 128-wide heads,
+        two at Qwen3-Next's 256) and at a bf16 pool of 8 combined rows a
+        token; general at the float32 toy shapes, at an odd number of kv
+        heads, at narrow heads."""
         from deepspeed_tpu.telemetry import get_tracer
 
         def layout(q, pages, kvl, pt, KV):
@@ -234,24 +264,39 @@ class TestDecodeKernelParity:
 
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False)
         assert layout(q, pages, kvl, pt, 8) == dict(
-            load="strided", P=8, dtype="bfloat16", kv_heads=8, group=4)
+            load="strided", P=8, dtype="bfloat16", kv_heads=8, group=4,
+            lane_tiles=1)
+        q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
+                                            geom=self.QWEN)
+        assert layout(q, pages, kvl, pt, 2) == dict(
+            load="strided", P=8, dtype="bfloat16", kv_heads=2, group=8,
+            lane_tiles=2)
         rng = np.random.default_rng(35)
         toy = _decode_case(rng, [9, 5], 1, 2, 16, 4, 3)
         assert layout(*toy, 1) == dict(
-            load="general", P=3, dtype="float32", kv_heads=1, group=2)
+            load="general", P=3, dtype="float32", kv_heads=1, group=2,
+            lane_tiles=1)
         q4, p4, kvl4, pt4 = _decode_case(rng, [40], 4, 2, 128, 16, 4)
         rec = layout(q4.astype(jnp.bfloat16), p4.astype(jnp.bfloat16),
                      kvl4, pt4, 4)
-        assert (rec["load"], rec["dtype"]) == ("general", "bfloat16")
+        assert (rec["load"], rec["dtype"], rec["lane_tiles"]) == (
+            "strided", "bfloat16", 1)
+        q3, p3, kvl3, pt3 = _decode_case(rng, [40], 3, 2, 256, 16, 4)
+        rec = layout(q3.astype(jnp.bfloat16), p3.astype(jnp.bfloat16),
+                     kvl3, pt3, 3)
+        assert (rec["load"], rec["lane_tiles"]) == ("general", 1)
         q5, p5, kvl5, pt5 = _decode_case(rng, [40], 8, 2, 64, 16, 4)
         assert layout(q5.astype(jnp.bfloat16), p5.astype(jnp.bfloat16),
                       kvl5, pt5, 8)["load"] == "general"
 
-    def test_bf16_pool_general_load_parity(self):
-        """A bf16 pool the strided load cannot take (2.KV = 8 rows: half
-        a bf16 sublane tile) runs the general load with bf16 operands."""
+    @pytest.mark.parametrize("KV,G", [(6, 2), (1, 4)])
+    def test_bf16_pool_general_load_parity(self, KV, G):
+        """A bf16 pool the strided load cannot take (12 combined rows a
+        token are padded to 16 in VMEM; with one kv head a word row is K
+        beside V, not a pair of heads) runs the general load with bf16
+        operands."""
         rng = np.random.default_rng(36)
-        KV, G, hd, ps, NB = 4, 2, 128, 16, 6
+        hd, ps, NB = 128, 16, 6
         ctx = [33, 0, 80]
         q, pages, kvl, pt = _decode_case(rng, ctx, KV, G, hd, ps, NB)
         self._assert_matches_dense(
