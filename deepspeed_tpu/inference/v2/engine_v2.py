@@ -1391,7 +1391,8 @@ class DecodeWindow:
                 if pool is not None:
                     used = pool.slots - self.engine.state_manager.free_slots
                     asp.set(state_slots=used, state_bytes=pool.mem_bytes(),
-                            state_fill=used / pool.slots)
+                            state_fill=used / pool.slots,
+                            state_pad_share=pool.pad_share)
                 if self._state is not None and \
                         self.engine._decode_state is self._state:
                     # the last sampled token is the next window's seed: once

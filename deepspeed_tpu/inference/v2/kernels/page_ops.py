@@ -14,12 +14,12 @@ import jax
 import jax.numpy as jnp
 
 from . import gdn_ops, mla_ops
-from .ragged_ops import (decode_attention, paged_kv_append,
+from .ragged_ops import (_stored_heads, decode_attention, paged_kv_append,
                          ragged_paged_attention, verify_window_attention)
 
 
 def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
-                   scale, alibi=None, alibi_scaled=False):
+                   scale, alibi=None, alibi_scaled=False, num_kv_heads=None):
     """Dense page-gather reference attention (the numerics oracle).
 
     Gathers the full padded context per sequence straight from the page pool
@@ -28,11 +28,14 @@ def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
     computes bf16(slope·pos) pre-scaling).
 
     q_seq: [S, mq, H, hd]; kv_pages: [NP_total, ps, 2KV, hd];
-    page_table: [S, NB] → output [S, mq, H, hd] (f32).
+    page_table: [S, NB] → output [S, mq, H, hd] (f32).  ``num_kv_heads``:
+    the model's, where the pool stores a token in more (None: the pool's).
     """
-    S, mq, H, hd = q_seq.shape
+    H_model = q_seq.shape[2]
     _, ps, ckv, _ = kv_pages.shape
-    KV = ckv // 2
+    q_seq, KV, alibi = _stored_heads(q_seq, kv_pages, num_kv_heads or ckv // 2,
+                                     alibi)
+    S, mq, H, hd = q_seq.shape
     NB = page_table.shape[1]
     C = NB * ps
     ctx_pos = jnp.arange(C, dtype=jnp.int32)
@@ -72,7 +75,8 @@ def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
         scores = scores + bias[None, :, None, :]
     scores = jnp.where(attn_mask[:, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("shqc,schd->sqhd", probs, v_ctx.astype(jnp.float32))
+    out = jnp.einsum("shqc,schd->sqhd", probs, v_ctx.astype(jnp.float32))
+    return out if H == H_model else out[:, :, :H_model]
 
 
 class PageOps(NamedTuple):
@@ -108,15 +112,16 @@ def page_ops(row, replicate=None) -> PageOps:
                 *a, block_q=min(block_q, 16), **kw, **k),
             verify=None,
             dense=partial(mla_ops.mla_attend_dense, **kw))
-    # pages [ps, 2*KV, hd]: a body appends (k, v) [T, KV, hd] and attends
-    # q [T, H, hd] → [T, H, hd]
+    # pages [ps, 2*stored, hd] (``row.stored`` heads, the model's unless the
+    # row kind says otherwise: the operations read it off the pool): a body
+    # appends (k, v) [T, KV, hd] and attends q [T, H, hd] → [T, H, hd]
     kw = dict(num_kv_heads=row.num_kv_heads)
     return PageOps(
         append=partial(paged_kv_append, replicate=replicate),
         decode=partial(decode_attention, **kw),
         ragged=partial(ragged_paged_attention, **kw),
         verify=partial(verify_window_attention, **kw),
-        dense=_attend_gather)
+        dense=partial(_attend_gather, **kw))
 
 
 def state_ops(state) -> Callable:

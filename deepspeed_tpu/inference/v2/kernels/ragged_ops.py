@@ -91,6 +91,28 @@ def _cdiv(a, b):
     return (a + b - 1) // b
 
 
+def _stored_heads(q, kv_pages, num_kv_heads: int, alibi=None):
+    """A pool may STORE a token in more heads than the model has
+    (``models/serving.KVRow.stored_kv_heads``), and its shape says how many:
+    ``[pages, page_size, 2 * stored, hd]``, the K heads first.  The queries
+    get zero heads behind their own (one group a padded kv head) and the
+    operation runs on the stored count; its caller cuts the output back to
+    the model's heads, so no padded head's output reaches the model, and a
+    padded head's rows are zeros (:func:`paged_kv_append`).  → (q, stored,
+    alibi); a pool of the model's own count comes back as it came."""
+    stored = kv_pages.shape[2] // 2
+    assert kv_pages.shape[2] == 2 * stored and stored >= num_kv_heads, \
+        f"kv_pages combined-head dim {kv_pages.shape[2]} is not 2 x " \
+        f"(>= {num_kv_heads}) heads"
+    if stored == num_kv_heads:
+        return q, stored, alibi
+    extra = (stored - num_kv_heads) * (q.shape[-2] // num_kv_heads)
+    q = jnp.pad(q, [(0, 0)] * (q.ndim - 2) + [(0, extra), (0, 0)])
+    if alibi is not None:
+        alibi = tuple(alibi) + (0.0,) * extra
+    return q, stored, alibi
+
+
 def _ragged_paged_kernel(kvl_ref, pt_ref, cu_ref,        # scalar prefetch
                          q_ref, pages_ref, o_ref,        # VMEM block / HBM
                          kv_bufs, sems, acc, m_scr, l_scr,
@@ -322,57 +344,74 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
       cu_q_lens:  [S+1] exclusive prefix sum of per-sequence query counts.
     Returns [T, H, hd].
     """
-    T, H, hd = q.shape
+    T, H_model, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
     assert hd == hd_k, f"head_dim mismatch {hd} vs {hd_k}"
-    KV = num_kv_heads
-    assert ckv == 2 * KV, f"kv_pages combined-head dim {ckv} != 2*{KV}"
-    assert H % KV == 0, "query heads must be a multiple of kv heads"
+    assert H_model % num_kv_heads == 0, \
+        "query heads must be a multiple of kv heads"
+    if alibi is not None:
+        import numpy as np
+
+        alibi = tuple(np.asarray(alibi, np.float32).tolist())   # static const
+        assert len(alibi) == H_model, "alibi slopes must be per query head"
+    q, KV, alibi = _stored_heads(q, kv_pages, num_kv_heads, alibi)
+    H = q.shape[1]
     G = H // KV
     S, NB = page_table.shape
     assert cu_q_lens.shape == (S + 1,)
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    BQ = max(8, min(block_q, T))
-    T_pad = _cdiv(T, BQ) * BQ
-    if T_pad != T:
-        q = jnp.pad(q, ((0, T_pad - T), (0, 0), (0, 0)))
     # never walk chunks past the page-table budget
-    P = min(pages_per_chunk, NB)
+    BQ, P0 = max(8, min(block_q, T)), min(pages_per_chunk, NB)
 
     # ---- VMEM budget: scratch must fit alongside the q/o blocks --------- #
     # kv_bufs double-buffer 2*P pages of [ps, 2KV, hd]; softmax state is
     # f32 [KV, BQ*G, hd|128] x3; q/o blocks are [BQ, H, hd].  Mosaic fails
     # with an opaque error past ~16MB, so shrink P first (fewer pages per
-    # chunk costs DMA overlap, not correctness), then fail loudly.
+    # chunk costs DMA overlap, not correctness); a pool of so many heads that
+    # one page a chunk is still too much (32 stored heads of 128: the
+    # softmax state and the q/o blocks are 10 MB at 128 rows) halves the
+    # query block and tries again; then fail loudly.
     VMEM_BUDGET = 12 * 1024 * 1024
     kv_itemsize = jnp.dtype(kv_pages.dtype).itemsize
 
-    def _vmem_bytes(p):
+    def _vmem_bytes(p, bq):
         kv_bufs = 2 * p * ps * ckv * hd * kv_itemsize
-        softmax = KV * (BQ * G) * (hd + 2 * 128) * 4
+        softmax = KV * (bq * G) * (hd + 2 * 128) * 4
         # Pallas double-buffers the streamed q/o blocks across grid steps
-        qo = 2 * 2 * BQ * H * hd * jnp.dtype(q.dtype).itemsize
+        qo = 2 * 2 * bq * H * hd * jnp.dtype(q.dtype).itemsize
         # live f32 temporaries per compute step scale with the chunk width:
         # s_mat/p_mat [rows, P*ps] plus mask/iota registers of the same shape
-        temps = 3 * (BQ * G) * (p * ps) * 4
+        temps = 3 * (bq * G) * (p * ps) * 4
         return kv_bufs + softmax + qo + temps
 
-    while P > 1 and _vmem_bytes(P) > VMEM_BUDGET:
-        P //= 2
-    if _vmem_bytes(P) > VMEM_BUDGET:
+    # A chunk is also LOADED whole (``kv_bufs[slot]``) and copied once more
+    # by the per-head V select: values Mosaic keeps on its stack beside the
+    # scratch above, inside the 4 MB between the budget and its limit as
+    # long as a chunk is no more than 2 MiB (8 pages of 8 kv heads of 128: 2
+    # MiB; of 32 stored heads: 8, and a 16-row bucket ran out of VMEM on
+    # the chip, PR 34)
+    CHUNK_LIMIT = 2 * 1024 * 1024
+    while P0 > 1 and P0 * ps * ckv * hd * kv_itemsize > CHUNK_LIMIT:
+        P0 //= 2
+    while True:
+        P = P0
+        while P > 1 and _vmem_bytes(P, BQ) > VMEM_BUDGET:
+            P //= 2
+        if _vmem_bytes(P, BQ) <= VMEM_BUDGET or BQ <= 8:
+            break
+        BQ //= 2
+    if _vmem_bytes(P, BQ) > VMEM_BUDGET:
         raise ValueError(
             f"ragged_paged_attention VMEM budget exceeded even at "
-            f"pages_per_chunk=1: {_vmem_bytes(P)/2**20:.1f}MB > "
-            f"{VMEM_BUDGET/2**20:.0f}MB — reduce block_q ({block_q}), "
+            f"pages_per_chunk=1 and block_q=8: "
+            f"{_vmem_bytes(P, BQ)/2**20:.1f}MB > "
+            f"{VMEM_BUDGET/2**20:.0f}MB — reduce "
             f"page_size ({ps}), or kv heads x head_dim ({KV}x{hd})")
-
-    if alibi is not None:
-        import numpy as np
-
-        alibi = tuple(np.asarray(alibi, np.float32).tolist())   # static const
-        assert len(alibi) == H, "alibi slopes must be per query head"
+    T_pad = _cdiv(T, BQ) * BQ
+    if T_pad != T:
+        q = jnp.pad(q, ((0, T_pad - T), (0, 0), (0, 0)))
 
     interp = _interpret() if interpret is None else interpret
     kernel = functools.partial(
@@ -402,7 +441,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         name="ragged_prefill",
     )(kv_lens.astype(jnp.int32), page_table.astype(jnp.int32),
       cu_q_lens.astype(jnp.int32), q, kv_pages)
-    return out[:T]
+    return out[:T] if H == H_model else out[:T, :H_model]
 
 
 # ===================================================================== #
@@ -658,12 +697,18 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     how many ``lane_tiles`` a page lands (``hd // 128`` for the strided
     load, 1 otherwise).
     """
-    S, H, hd = q.shape
+    S, H_model, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
     assert hd == hd_k, f"head_dim mismatch {hd} vs {hd_k}"
-    KV = num_kv_heads
-    assert ckv == 2 * KV, f"kv_pages combined-head dim {ckv} != 2*{KV}"
-    assert H % KV == 0, "query heads must be a multiple of kv heads"
+    assert H_model % num_kv_heads == 0, \
+        "query heads must be a multiple of kv heads"
+    if alibi is not None:
+        import numpy as np
+
+        alibi = tuple(np.asarray(alibi, np.float32).tolist())
+        assert len(alibi) == H_model, "alibi slopes must be per query head"
+    q, KV, alibi = _stored_heads(q, kv_pages, num_kv_heads, alibi)
+    H = q.shape[1]
     G = H // KV
     S_t, NB = page_table.shape
     assert S_t == S and kv_lens.shape == (S,)
@@ -694,23 +739,17 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
             f"{VMEM_BUDGET/2**20:.0f}MB — reduce page_size ({ps}) or "
             f"kv heads x head_dim ({KV}x{hd})")
 
-    if alibi is not None:
-        import numpy as np
-
-        alibi = tuple(np.asarray(alibi, np.float32).tolist())
-        assert len(alibi) == H, "alibi slopes must be per query head"
-
     # trace time only: what a run says about the kernel it compiled
     get_tracer().record(
         "attn/decode_layout", time.perf_counter(), 0.0, load=load, P=P,
-        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=KV, group=G,
-        lane_tiles=LT)
+        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=num_kv_heads,
+        stored_kv_heads=KV, group=G, lane_tiles=LT)
 
     kernel = functools.partial(
         _decode_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, NB=NB,
         alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg)
     NG, R = KV // hpg, hpg * G
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -733,6 +772,7 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         interpret=_interpret() if interpret is None else interpret,
         name="paged_decode",
     )(kv_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, kv_pages)
+    return out if H == H_model else out[:, :H_model]
 
 
 def verify_window_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
@@ -785,9 +825,10 @@ def decode_attend_dense(q: jnp.ndarray, kv_pages: jnp.ndarray,
     even the interpreter-free CPU sim sees the decode win.  ``kv_lens == 0``
     rows (bucket padding) produce zeros.
     """
-    S, H, hd = q.shape
+    S, H_model, hd = q.shape
     _, ps, ckv, _ = kv_pages.shape
-    KV = num_kv_heads
+    q, KV, alibi = _stored_heads(q, kv_pages, num_kv_heads, alibi)
+    H = q.shape[1]
     G = H // KV
     NB = page_table.shape[1]
     C = NB * ps
@@ -825,7 +866,8 @@ def decode_attend_dense(q: jnp.ndarray, kv_pages: jnp.ndarray,
     # fully-masked (padding) rows: softmax over all -inf is uniform garbage
     probs = jnp.where(jnp.any(mask, axis=-1, keepdims=True), probs, 0.0)
     out = jnp.einsum("shc,schd->shd", probs, v_ctx.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = out.astype(q.dtype)
+    return out if H == H_model else out[:, :H_model]
 
 
 def decode_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
@@ -858,8 +900,9 @@ def paged_kv_append(kv_pages: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     off_of_token: jnp.ndarray, replicate=None) -> jnp.ndarray:
     """Scatter new K/V rows into their cache pages.
 
-    kv_pages: [num_pages_total, page_size, 2*KV, hd]; k/v: [T, KV, hd];
-    page_of_token/off_of_token: [T] (padded tokens target the trash page).
+    kv_pages: [num_pages_total, page_size, 2*KV, hd]; k/v: [T, KV, hd] (or
+    fewer heads than the pool stores); page_of_token/off_of_token: [T]
+    (padded tokens target the trash page).
     A row scatter into a donated / loop-carried buffer lowers to an
     in-place dynamic-update on TPU — the idiomatic equivalent of the
     reference's pointer-chasing CUDA append.  Writing the combined
@@ -873,6 +916,12 @@ def paged_kv_append(kv_pages: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     dp4×tp2 mesh — serving under a TP mesh produced garbage logits).  Pass
     it whenever any model param is non-trivially sharded.
     """
+    stored = kv_pages.shape[2] // 2
+    if stored != k.shape[1]:
+        # a pool that stores a token in more heads than the model has
+        # (_stored_heads): the heads past the model's are written as zeros
+        pad = ((0, 0), (0, stored - k.shape[1]), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     comb = jnp.concatenate([k, v], axis=1).astype(kv_pages.dtype)
     if replicate is not None:
         comb = jax.lax.with_sharding_constraint(comb, replicate)

@@ -101,6 +101,11 @@ def export_kv(engine, uid: int, tokens: List[int],
     row = engine.family.row
     rows = pages.reshape(engine.family.num_layers, n_pages * bs,
                          *row.token_shape)[:, :n]
+    if row.stored != row.num_kv_heads:
+        # canonical rows carry the model's heads, not the pool's padding
+        kv = row.num_kv_heads
+        rows = np.concatenate([rows[:, :, :kv],
+                               rows[:, :, row.stored:row.stored + kv]], axis=2)
     return KVShipment(tokens=[int(t) for t in tokens[:n]],
                       num_layers=engine.family.num_layers,
                       num_kv_heads=row.num_kv_heads, head_dim=row.head_dim,
@@ -137,6 +142,11 @@ def import_kv(engine, shipment: KVShipment, uid: int) -> bool:
     n_pages = -(-n // bs)
     pad = n_pages * bs - n
     rows = shipment.rows.astype(np.float32)
+    if row.stored != row.num_kv_heads:
+        kv, extra = row.num_kv_heads, row.stored - row.num_kv_heads
+        zeros = np.zeros(rows.shape[:2] + (extra, row.head_dim), np.float32)
+        rows = np.concatenate([rows[:, :, :kv], zeros, rows[:, :, kv:], zeros],
+                              axis=2)
     if pad:
         rows = np.pad(rows, ((0, 0), (0, pad), (0, 0), (0, 0)))
     pages = rows.reshape(shipment.num_layers, n_pages, bs,

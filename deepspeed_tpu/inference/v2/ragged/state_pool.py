@@ -11,6 +11,13 @@ rows read and write.  A live sequence owns one slot; ``DSStateManager``
 hands it out with the sequence's first pages and takes it back at flush.  A
 slot is never cleared: the first chunk of a sequence (position 0) starts
 from zeros ON THE DEVICE whatever the last owner left (``kernels/gdn_ops``).
+
+What the pool HOLDS is not what its values add up to: the device lays an
+array out in tiles of its two minor axes (a float32 ``[96, 192]`` state as
+``[96, 256]``, a bfloat16 ``[3, C]`` carry in tiles of more rows than 3), so
+``mem_bytes`` asks the arrays (``on_device_size_in_bytes``) and
+``pad_share`` says what share of that is padding.  How a state is stored is
+the state kind's (``GatedDeltaState.arrays``).
 """
 from __future__ import annotations
 
@@ -24,6 +31,10 @@ class StatePool:
         rows = kind.num_layers * self.slots + 1
         self.arrays = tuple(jnp.zeros((rows,) + shape, dt)
                             for shape, dt in kind.arrays(dtype))
+        #: bytes of the values, and bytes the device holds for them (shapes
+        #: never change, so both are read once)
+        self.value_bytes = sum(a.size * a.dtype.itemsize for a in self.arrays)
+        self.held_bytes = sum(held_bytes(a) for a in self.arrays)
 
     @property
     def pad_slot(self) -> int:
@@ -35,4 +46,28 @@ class StatePool:
         self.arrays = tuple(arrays)
 
     def mem_bytes(self) -> int:
-        return sum(a.size * a.dtype.itemsize for a in self.arrays)
+        """Bytes the device holds for the pool, tile padding included."""
+        return self.held_bytes
+
+    @property
+    def pad_share(self) -> float:
+        return 1.0 - self.value_bytes / self.held_bytes
+
+
+def held_bytes(array) -> int:
+    """What the device holds for ``array`` (its values' bytes where the
+    backend does not say)."""
+    try:
+        return int(array.on_device_size_in_bytes())
+    except Exception:  # noqa: BLE001 — a backend without the call
+        return array.size * array.dtype.itemsize
+
+
+def slot_held_bytes(kind, dtype=jnp.bfloat16) -> int:
+    """Bytes the device holds for ONE slot of ``kind`` over its state
+    layers, read from a one-row probe of each array (padding is of the two
+    minor axes, so a pool's rows cost this each): what a caller sizing the
+    pools BEFORE building them reckons with."""
+    return kind.num_layers * sum(
+        held_bytes(jnp.zeros((1,) + shape, dt))
+        for shape, dt in kind.arrays(dtype))

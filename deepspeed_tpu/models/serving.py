@@ -41,15 +41,43 @@ from typing import Any, Callable, Iterable, Optional, Tuple, Union
 
 @dataclasses.dataclass(frozen=True)
 class KVRow:
-    """A cached token of one layer is K and V for ``num_kv_heads`` heads."""
+    """A cached token of one layer is K and V for ``num_kv_heads`` heads.
+
+    ``stored_kv_heads`` (None: the model's) is how many heads a token is
+    STORED in: a page is ``[page_size, 2 * stored, head_dim]``, K heads first,
+    and the heads past the model's are zeros that no query head reads.  The
+    page operations take the count from the pool's shape (``kernels/
+    ragged_ops``), so a head count whose combined rows do not tile the
+    chip's sublanes (30 heads: 60 rows) is stored in one that does
+    (:meth:`tiled`) without the model's mathematics knowing.
+    ``read_values`` and ``attn_flops`` stay the model's: the padding is the
+    program's cost."""
 
     num_kv_heads: int
     head_dim: int
+    stored_kv_heads: Optional[int] = None
     latent = False
+
+    def __post_init__(self):
+        if self.stored_kv_heads is not None \
+                and self.stored_kv_heads < self.num_kv_heads:
+            raise ValueError(
+                f"stored_kv_heads {self.stored_kv_heads} < num_kv_heads "
+                f"{self.num_kv_heads}")
+
+    @classmethod
+    def tiled(cls, num_kv_heads: int, head_dim: int) -> "KVRow":
+        """The row kind of a model with ``num_kv_heads`` heads, stored in
+        :func:`tiling_kv_heads` of them."""
+        return cls(num_kv_heads, head_dim, tiling_kv_heads(num_kv_heads))
+
+    @property
+    def stored(self) -> int:
+        return self.stored_kv_heads or self.num_kv_heads
 
     @property
     def token_shape(self) -> Tuple[int, ...]:
-        return (2 * self.num_kv_heads, self.head_dim)
+        return (2 * self.stored, self.head_dim)
 
     @property
     def read_values(self) -> int:       # attention reads, a token a layer
@@ -58,6 +86,17 @@ class KVRow:
     @property
     def attn_flops(self) -> float:      # QK + PV a cached token a query head
         return 4.0 * self.head_dim
+
+
+def tiling_kv_heads(num_kv_heads: int) -> int:
+    """The least head count >= ``num_kv_heads`` whose 16-bit K/V rows tile
+    the sublanes of a page as they are: 1, 2, 4 or a multiple of 8 (a token's
+    combined rows 2, 4, 8 or a multiple of 16 — what the chip's compiler
+    lays out without padding and the decode kernel's pair load takes)."""
+    for n in (1, 2, 4):
+        if num_kv_heads <= n:
+            return n
+    return -(-num_kv_heads // 8) * 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +130,16 @@ class GatedDeltaState:
     """What a sequence owns in each of ``num_layers`` Gated DeltaNet layers:
     the delta-rule state ``[num_heads, key_dim, value_dim]`` (float32) and
     the causal convolution's last ``conv_kernel - 1`` inputs over its
-    ``conv_channels`` (in the serving dtype).  A state can be restored only
+    ``conv_channels`` (in the serving dtype).
+
+    The state is STORED with ``lane_heads`` heads side by side along the
+    minor axis (``arrays``): 1, the plain ``[H, dk, dv]``, where
+    ``value_dim`` is whole 128-lane tiles; 2 (``state_layout`` ``"pairs"``)
+    where a single head's values would be padded (192 -> 256 lanes, a third
+    more bytes held and moved) and a pair's are whole tiles (384).
+    ``kernels/gdn_ops`` reads the layout off the pool's shape.
+
+    A state can be restored only
     at the token it was saved at, so what re-reads or ships cached tokens is
     refused by name: the prefix cache, speculative verify windows, the host
     tier, ``kv_ship`` (ROADMAP R5)."""
@@ -102,21 +150,42 @@ class GatedDeltaState:
     key_dim: int
     value_dim: int
     conv_kernel: int
+    #: the delta rule's ``beta`` lies in (0, ``beta_max``): 1, or 2 where
+    #: ``I - beta k k^T`` may have eigenvalue -1.  The kernels take ``beta``
+    #: as it comes; the chunked form inverts ``I + A`` by squarings only
+    #: where ``beta`` stays within 1 (``kernels/gdn_ops._chunk_update``)
+    beta_max: float = 1.0
 
     @property
     def conv_channels(self) -> int:
         return 2 * self.num_key_heads * self.key_dim \
             + self.num_heads * self.value_dim
 
+    @property
+    def lane_heads(self) -> int:
+        """Heads stored side by side in a row of the state."""
+        if self.value_dim % 128 and self.num_heads % 2 == 0 \
+                and (2 * self.value_dim) % 128 == 0:
+            return 2
+        return 1
+
+    @property
+    def state_layout(self) -> str:
+        return "pairs" if self.lane_heads == 2 else "plain"
+
     def arrays(self, dtype) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
         """(shape, dtype) of what one slot holds in one layer."""
         import jax.numpy as jnp
 
-        return (((self.num_heads, self.key_dim, self.value_dim), jnp.float32),
+        P = self.lane_heads
+        return (((self.num_heads // P, self.key_dim, P * self.value_dim),
+                 jnp.float32),
                 ((self.conv_kernel - 1, self.conv_channels), dtype))
 
     def slot_bytes(self, dtype) -> int:
-        """Bytes a sequence owns, all state layers."""
+        """Bytes of the values a sequence owns, all state layers (what the
+        device HOLDS for them, tile padding included, is the pool's to say:
+        ``ragged/state_pool.StatePool.mem_bytes``)."""
         import math
 
         import jax.numpy as jnp

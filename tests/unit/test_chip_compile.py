@@ -353,6 +353,102 @@ def _qwen3next(decode):
     return build
 
 
+def _gdn_decode_olmo(dev):
+    """A layer's Gated DeltaNet decode call at the Olmo-Hybrid cell's shape:
+    128 rows x 30 heads x a [96, 192] float32 state, stored as the state
+    kind says (15 head pairs of [96, 384]: whole lane tiles, one grid step a
+    sequence), the pool of 6 x 128 + 1 slots aliased in place."""
+    from deepspeed_tpu.inference.v2.kernels.gdn_ops import gdn_decode
+    from deepspeed_tpu.models.serving import GatedDeltaState
+
+    f32 = jnp.float32
+    rows, heads = 128, 30
+    kind = GatedDeltaState(6, heads, heads, 96, 192, 4)
+    assert kind.state_layout == "pairs"
+    shape = kind.arrays(BF16)[0][0]
+    assert shape == (15, 96, 384)
+    key = _on(dev, (rows, heads, 96), f32)
+    gate = _on(dev, (rows, heads), f32)
+    return gdn_decode, (key, key, _on(dev, (rows, heads, 192), f32), gate,
+                        gate, _on(dev, (6 * rows + 1,) + shape, f32),
+                        _on(dev, (rows,), jnp.int32))
+
+
+def _paged_stored_heads(op, rows=512):
+    """The page operations with 30 query and K/V heads of 128 on a pool that
+    STORES a token in 32 (``KVRow.tiled(30, 128)``: 64 combined rows; 60 are
+    refused at the page DMA): the Olmo-Hybrid cell's two page layers of
+    3,900 pages, 36-page tables, 128 rows / a ``rows``-token chunk (the SMALL
+    prefill buckets too: at 16 and 32 rows the ragged kernel ran out of
+    scoped VMEM on the chip while 512 compiled, PR 34)."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
+            _decode_head_load, decode_paged_attention, paged_kv_append,
+            ragged_paged_attention)
+        from deepspeed_tpu.models.serving import KVRow
+
+        row = KVRow.tiled(30, HD)
+        assert row.token_shape == (64, HD)
+        assert _decode_head_load(BF16, 30, HD, PAGE) == "general"
+        assert _decode_head_load(BF16, row.stored, HD, PAGE) == "strided"
+        seqs, blocks = 128, 36
+        pool = _on(dev, (2 * 3900 + 1, PAGE) + row.token_shape)
+        lens = _on(dev, (seqs,), jnp.int32)
+        table = _on(dev, (seqs, blocks), jnp.int32)
+        if op == "decode":
+            return (lambda q, p, n, t: decode_paged_attention(
+                q, p, n, t, num_kv_heads=30)), \
+                (_on(dev, (seqs, 30, HD)), pool, lens, table)
+        if op == "ragged":
+            return (lambda q, p, n, t, cu: ragged_paged_attention(
+                q, p, n, t, cu, num_kv_heads=30)), \
+                (_on(dev, (rows, 30, HD)), pool, lens, table,
+                 _on(dev, (seqs + 1,), jnp.int32))
+        new = _on(dev, (512, 30, HD))
+        where = _on(dev, (512,), jnp.int32)
+        return paged_kv_append, (pool, new, new, where, where)
+    build.xla_only = op == "append"
+    return build
+
+
+def _olmo_hybrid(decode):
+    """The benchmark's Olmo-Hybrid configuration (two periods of 3 DeltaNet +
+    1 full-attention layer, published widths, whole vocabulary): a fused
+    decode window of 128 sequences x 2 steps (gdn_decode on head pairs, the
+    K/V page kernel on 32 stored heads, one scan of periods with both pools
+    in the carry), or a 512-token SplitFuse step."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                      OlmoHybridLM)
+
+        cfg = OlmoHybridConfig(num_layers=8)
+        model = OlmoHybridLM(cfg)
+        family = model.serving_family()
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
+        seqs, blocks, nb = 128, 2304 // PAGE, 3900
+        cache = (_on(dev, (2 * nb + 1, PAGE) + family.row.token_shape),
+                 tuple(_on(dev, (6 * seqs + 1,) + shape, dtype)
+                       for shape, dtype in cfg.state.arrays(BF16)))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            loop = build_decode_loop(family, max_q=seqs, block_size=PAGE,
+                                     steps=2, **kw)
+            meta = pack_layout(seqs, seqs, blocks, True)["_total"][0]
+            return loop, (params, cache, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(family, max_q=512, **kw)
+        meta = pack_layout(512, seqs, blocks, True)["_total"][0]
+        return step, (params, cache, _on(dev, (meta,), jnp.int32))
+    return build
+
+
 CASES = {
     "gdn_decode": _gdn_decode,
     "qwen3next_decode_window": _qwen3next(decode=True),
@@ -389,6 +485,19 @@ CASES = {
     "fused_adam_update": _adam,
     "train_step[1 chip]": _train_step(zero_stage=0),
     "train_step[zero3 x 4 chips]": _train_step(zero_stage=3),
+    # Olmo-Hybrid: head counts and widths that tile neither sublanes nor lanes
+    "gdn_decode[30 heads of 96 x 192, pairs]": _gdn_decode_olmo,
+    "decode_paged_attention[30 heads stored in 32]":
+        _paged_stored_heads("decode"),
+    "ragged_paged_attention[30 heads stored in 32]":
+        _paged_stored_heads("ragged"),
+    "ragged_paged_attention[30 heads stored in 32, 16 rows]":
+        _paged_stored_heads("ragged", rows=16),
+    "ragged_paged_attention[30 heads stored in 32, 32 rows]":
+        _paged_stored_heads("ragged", rows=32),
+    "paged_kv_append[30 heads stored in 32]": _paged_stored_heads("append"),
+    "olmo_hybrid_decode_window": _olmo_hybrid(decode=True),
+    "olmo_hybrid_prefill_step": _olmo_hybrid(decode=False),
 }
 
 
@@ -401,8 +510,10 @@ def test_compiles_for_v5e(v5e, for_the_chip, case):
         fn, args = build(SingleDeviceSharding(v5e.devices[0]))
     lowered = jax.jit(fn).lower(*args)
     # the device kernel is IN the program (an interpret-mode or XLA
-    # fall-back would compile too, and prove nothing)
-    assert "tpu_custom_call" in lowered.as_text(), \
+    # fall-back would compile too, and prove nothing); the append is an XLA
+    # scatter by design
+    assert getattr(build, "xla_only", False) \
+        or "tpu_custom_call" in lowered.as_text(), \
         f"{case}: no Mosaic kernel in the lowered program"
     lowered.compile()                   # raises what the chip would raise
 

@@ -264,18 +264,18 @@ class TestDecodeKernelParity:
 
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False)
         assert layout(q, pages, kvl, pt, 8) == dict(
-            load="strided", P=8, dtype="bfloat16", kv_heads=8, group=4,
-            lane_tiles=1)
+            load="strided", P=8, dtype="bfloat16", kv_heads=8,
+            stored_kv_heads=8, group=4, lane_tiles=1)
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
                                             geom=self.QWEN)
         assert layout(q, pages, kvl, pt, 2) == dict(
-            load="strided", P=8, dtype="bfloat16", kv_heads=2, group=8,
-            lane_tiles=2)
+            load="strided", P=8, dtype="bfloat16", kv_heads=2,
+            stored_kv_heads=2, group=8, lane_tiles=2)
         rng = np.random.default_rng(35)
         toy = _decode_case(rng, [9, 5], 1, 2, 16, 4, 3)
         assert layout(*toy, 1) == dict(
-            load="general", P=3, dtype="float32", kv_heads=1, group=2,
-            lane_tiles=1)
+            load="general", P=3, dtype="float32", kv_heads=1,
+            stored_kv_heads=1, group=2, lane_tiles=1)
         q4, p4, kvl4, pt4 = _decode_case(rng, [40], 4, 2, 128, 16, 4)
         rec = layout(q4.astype(jnp.bfloat16), p4.astype(jnp.bfloat16),
                      kvl4, pt4, 4)

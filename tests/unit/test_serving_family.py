@@ -19,6 +19,8 @@ from deepspeed_tpu.inference.v2.engine_v2 import (
     RaggedInferenceEngineConfig,
 )
 from deepspeed_tpu.models.families import ArchConfig, UniversalCausalLM
+from deepspeed_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridLM
+from deepspeed_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
 from deepspeed_tpu.models.serving import KVRow, LayerStack, ServingFamily
 from deepspeed_tpu.models.transformer import (
     CausalLM,
@@ -187,6 +189,10 @@ CASES = {
         **_UNIVERSAL, num_kv_heads=1, pos="rope", parallel_attn=True,
         qkv_bias=False, out_bias=False)), (2, 8), 32),
     "xing4": (lambda: Xing4LM(Xing4Config.tiny()), (128,), 80),
+    # 6 heads of 16 STORED in 8 (a page's rows tile): attention reads the
+    # model's 6; its 6 linear layers keep a state, 2 of 8 layers own pages
+    "olmo_hybrid": (lambda: OlmoHybridLM(OlmoHybridConfig.tiny()),
+                    (16, 16), 384),
 }
 
 
@@ -201,9 +207,11 @@ def test_the_pool_has_the_row_the_family_says(name):
     assert fam.row.token_shape == token_shape
     eng = _engine(model, model.init_params(jax.random.PRNGKey(0)),
                   dtype=jnp.bfloat16, num_blocks=6)
-    assert eng.kv.pages.shape == (fam.num_layers * 6 + 1, 8) + token_shape
+    assert eng.kv.pages.shape == (fam.page_layers * 6 + 1, 8) + token_shape
     assert eng.kv.pages.dtype == jnp.bfloat16
     assert eng.latent_kv == fam.row.latent == (name == "xing4")
+    assert (eng.state_pool is not None) == (fam.state is not None) \
+        == (name == "olmo_hybrid")
 
     logits = eng.put([0, 1], [[3, 5, 7], [11, 13, 17, 19, 23]])
     window = eng.decode_batch_async(
@@ -212,7 +220,7 @@ def test_the_pool_has_the_row_the_family_says(name):
     assert (window.moe_pairs is not None) == (fam.counts is not None)
     # 2 sequences x 2 steps append one row a layer each
     appended = eng.last_decode_roofline["kernels"]["kv_append"]["bytes"]
-    assert appended / (2 * 2 * fam.num_layers) == token_bytes
+    assert appended / (2 * 2 * fam.page_layers) == token_bytes
 
 
 # --------------------------------------------------------------------- #
@@ -351,3 +359,67 @@ def test_a_family_with_recurrent_state_is_served(impl):
     fresh = rng.integers(1, 88, size=11).tolist()
     same(eng.put([2], [fresh])[0], fresh)
     assert eng.state_manager.get_sequence(2).slot == slot
+
+
+#: the families that hold recurrent state: what re-reads, parks or ships
+#: cached tokens is refused for each BY NAME, the same way
+STATEFUL = {
+    "hybrid_in_this_file": lambda: HybridLM(RenamedConfig(depth=2)),
+    "qwen3_next": lambda: Qwen3NextLM(Qwen3NextConfig.tiny()),
+    "olmo_hybrid": lambda: OlmoHybridLM(OlmoHybridConfig.tiny()),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STATEFUL))
+def test_a_family_with_state_refuses_what_a_state_cannot_do(name):
+    from deepspeed_tpu.inference.v2 import kv_ship
+    from deepspeed_tpu.inference.v2.model_runner import build_verify_step
+
+    model = STATEFUL[name]()
+    params = model.init_params(jax.random.PRNGKey(0))
+    for kw, what in ((dict(prefix_cache=True), "prefix_cache"),
+                     (dict(host_tier_mb=1.0), "host_tier_mb")):
+        with pytest.raises(NotImplementedError,
+                           match=what + r".* recurrent state"):
+            _engine(model, params, **kw)
+    eng = _engine(model, params, max_tokens=16)
+    eng.put([0], [[3, 5, 7, 11]])
+    with pytest.raises(NotImplementedError, match="recurrent state"):
+        kv_ship.export_kv(eng, 0, [3, 5, 7, 11])
+    with pytest.raises(NotImplementedError, match="recurrent state"):
+        build_verify_step(model.serving_family(), max_q=8, num_blocks=4,
+                          max_seqs=2, max_blocks=4, jit=False)(
+            eng.params, eng._cache(), jnp.zeros((64,), jnp.int32))
+    fam = model.serving_family()
+    assert fam.page_layers < fam.num_layers and fam.state.num_layers \
+        == fam.num_layers - fam.page_layers
+
+
+def test_a_shipment_carries_the_models_heads_not_the_pools_padding():
+    """A K/V-row family whose row kind stores more heads than the model has
+    (``KVRow.tiled``: 3 heads in 4): the canonical rows of a shipment hold
+    the model's 3 + 3, and an engine that stores them another way continues
+    from the import with the same logits."""
+    from deepspeed_tpu.inference.v2 import kv_ship
+
+    config = RenamedConfig(depth=2, width=48, heads=6, kv_heads=3)
+    model = RenamedLM(config)
+    params = model.init_params(jax.random.PRNGKey(0))
+    base = model.serving_family()
+    hd = config.width // config.heads
+
+    class Stored(RenamedLM):
+        def serving_family(self):
+            return dataclasses.replace(base, row=KVRow.tiled(3, hd))
+
+    prompt = np.random.default_rng(0).integers(1, 88, size=11).tolist()
+    src = _engine(Stored(config), params, "gather", max_tokens=16)
+    assert src.kv.pages.shape[2:] == (8, hd)
+    want = np.asarray(src.put([0], [prompt])[0])
+    ship = kv_ship.export_kv(src, 0, prompt, n_tokens=10)
+    assert ship.rows.shape == (2, 10, 6, hd) and ship.num_kv_heads == 3
+    for target in (Stored(config), model):          # padded, and as it is
+        dst = _engine(target, params, "gather", max_tokens=16)
+        assert kv_ship.import_kv(dst, ship, 7)
+        got = np.asarray(dst.put([7], [prompt[10:]])[0])
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
