@@ -44,8 +44,15 @@ Three forms of the same recurrence (:func:`gdn_mix` dispatches):
 ``gdn_recurrent``      token by token over the flat batch: the numerics
                        oracle (``attn_impl="gather"``).
 
-The convolution with its carry (:func:`causal_conv_ragged`) is ``jax.numpy``
-in all three: 48-69 KB a sequence a layer against the state's 2 MB.
+The convolution with its carry has two forms, chosen with the recurrence's:
+a decode batch takes :func:`causal_conv_step`, ONE Pallas kernel a layer
+beside ``gdn_decode`` — a grid step a sequence, its ``[K - 1, C]`` carry (48-
+69 KB) read once at its pool row and written back shifted by one input, in
+place, the SiLU folded in.  The pool is pinned to HBM there: it is small
+enough (53-71 MB) that XLA would otherwise stage ALL of it through VMEM
+around every call.  Ragged batches and the oracle keep
+:func:`causal_conv_ragged` (``jax.numpy``: gathers by token and a row
+scatter), which is also the decode form's numerics reference.
 """
 from __future__ import annotations
 
@@ -127,6 +134,75 @@ def causal_conv_ragged(x, conv_w, carry_pool, rows, *, seq_of_token,
         carry, jnp.clip(rel + K - 1, 0, K - 2)[:, :, None], axis=1)
     new = jnp.where((rel >= 0)[:, :, None], from_x, from_old)
     return out, carry_pool.at[rows].set(new.astype(carry_pool.dtype))
+
+
+def _gdn_conv_step_kernel(rows_ref, keep_ref, x_ref, w_ref, c_ref, o_ref,
+                          c_out_ref, xf_ref, *, K: int, rb: int):
+    """One grid step = one sequence.  ``x`` / ``o`` blocks are ``[rb, C]``
+    (``rb`` consecutive rows share one: fetched, and written back, once
+    for all of them), the taps ``[K, C]`` (the same block every step), the
+    carry ``[1, K - 1, C]`` at the sequence's pool row; ``xf`` is the ``x``
+    block in float32."""
+    del rows_ref
+    r = pl.program_id(0)
+    i = r % rb
+
+    @pl.when(i == 0)
+    def _():
+        xf_ref[...] = x_ref[...].astype(jnp.float32)
+
+    x = xf_ref[pl.ds(i, 1), :]                                  # [1, C]
+    # a fresh or padded row starts from zeros whatever the slot holds
+    old = jnp.where(keep_ref[r] != 0, c_ref[0].astype(jnp.float32),
+                    0.0)                                        # [K-1, C]
+    out = w_ref[K - 1:K, :] * x
+    for d in range(1, K):            # causal_conv_ragged's order of summation
+        out = out + w_ref[K - 1 - d:K - d, :] * old[K - 1 - d:K - d]
+    o_ref[pl.ds(i, 1), :] = out * jax.nn.sigmoid(out)
+    c_out_ref[0] = jnp.concatenate([old[1:], x], axis=0).astype(
+        c_out_ref.dtype)
+
+
+def causal_conv_step(x, conv_w, carry_pool, rows, keep, *, interpret=None):
+    """The decode form of :func:`causal_conv_ragged` with the SiLU folded in:
+    one new input a sequence row.  ``x`` [R, C], ``conv_w`` [K, C],
+    ``carry_pool`` [N, K-1, C], ``rows`` [R] pool rows, ``keep`` [R] (False:
+    the carry is zeros whatever the slot holds — a sequence's first token, a
+    padded row) → (``silu(conv)`` [R, C] float32, carry_pool updated in
+    place: each row's ``[K-1, C]`` read once and written once, shifted by one
+    input).  Several rows may name the trash row: one after the other, and
+    never read."""
+    R, C = x.shape
+    K = conv_w.shape[0]
+    assert carry_pool.shape[1:] == (K - 1, C), \
+        f"carry pool {carry_pool.shape} does not hold [{K - 1}, {C}]"
+    # rows of x and of the output move a whole tile of the widest packing
+    # at a time (16 bf16 rows), not a [1, C] sliver a sequence
+    rb = min(R, 16)
+    row_block = pl.BlockSpec((rb, C), lambda r, rows, keep: (r // rb, 0))
+    carry_block = pl.BlockSpec((1, K - 1, C),
+                               lambda r, rows, keep: (rows[r], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_gdn_conv_step_kernel, K=K, rb=rb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(R,),
+            in_specs=[row_block,
+                      pl.BlockSpec((K, C), lambda r, rows, keep: (0, 0)),
+                      carry_block],
+            out_specs=[row_block, carry_block],
+            scratch_shapes=[pltpu.VMEM((rb, C), jnp.float32)]),
+        # the pool (output and, through the alias, operand) is pinned to
+        # HBM: left to itself XLA's memory-space assignment copies all of a
+        # pool that fits (53-71 MB) into VMEM before the call and back out
+        out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
+                   pltpu.HBM(carry_pool.shape, carry_pool.dtype)],
+        # operands count the scalar prefetches: the pool is operand 4
+        input_output_aliases={4: 1},
+        interpret=_interpret() if interpret is None else interpret,
+        # not "gdn_decode…": the benchmark reads that kernel by name
+        name="gdn_conv_step",
+    )(rows.astype(jnp.int32), keep.astype(jnp.int32), x,
+      conv_w.astype(jnp.float32), carry_pool)
 
 
 # --------------------------------------------------------------------- #
@@ -405,11 +481,21 @@ def gdn_mix(mixed, g, beta, conv_w, pool, rows, *, kind, mode: str, batch,
     q_len, ctx_len = batch["q_len"], batch["ctx_len"]
     fresh = ctx_len == q_len
     with jax.named_scope("attention/gdn_conv"):
-        x, carry_pool = causal_conv_ragged(
-            mixed, conv_w, carry_pool, rows,
-            seq_of_token=batch["seq_of_token"], q_offset=batch["q_offset"],
-            q_len=q_len, fresh=fresh)
-        x = jax.nn.silu(x)
+        if mode == "decode":
+            R = min(S, T)            # one token a row, row-major
+            # a fresh or padded row starts from zeros whatever its slot
+            # holds, in the convolution and in the recurrence
+            keep = (q_len[:R] > 0) & ~fresh[:R]
+            x, carry_pool = causal_conv_step(mixed[:R], conv_w, carry_pool,
+                                             rows[:R], keep)
+            if T > R:
+                x = jnp.pad(x, ((0, T - R), (0, 0)))
+        else:
+            x, carry_pool = causal_conv_ragged(
+                mixed, conv_w, carry_pool, rows,
+                seq_of_token=batch["seq_of_token"],
+                q_offset=batch["q_offset"], q_len=q_len, fresh=fresh)
+            x = jax.nn.silu(x)
     with jax.named_scope("attention/gdn_core"):
         Kd = Hk * dk
         q = l2norm(x[:, :Kd].reshape(T, Hk, dk)) / math.sqrt(dk)
@@ -418,19 +504,18 @@ def gdn_mix(mixed, g, beta, conv_w, pool, rows, *, kind, mode: str, batch,
         k = jnp.repeat(k, Hv // Hk, axis=1)
         v = x[:, 2 * Kd:].reshape(T, Hv, dv)
         g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
-        # trace time only: what a run says about the form it compiled
+        # trace time only: what a run says about the form it compiled (the
+        # recurrence's and the convolution's are chosen together)
+        impl = "kernel" if mode == "decode" else "xla"
         get_tracer().record(
             "attn/gdn_layout", time.perf_counter(), 0.0,
-            rows=min(S, T) if mode == "decode" else T, heads=Hv, chunk=CHUNK,
+            rows=R if mode == "decode" else T, heads=Hv, chunk=CHUNK,
             key_dim=dk, value_dim=dv, state_layout=kind.state_layout,
             state_dtype=jnp.dtype(state_pool.dtype).name, form=mode,
-            impl="kernel" if mode == "decode" else "xla")
+            impl=impl, conv_impl=impl)
         if mode == "decode":
-            R = min(S, T)
-            # a fresh or padded row starts from zeros whatever the slot
-            # holds: the kernel reads a decay of exactly 0 as "no state"
-            alpha = jnp.where((q_len[:R] > 0)[:, None] & ~fresh[:R, None],
-                              jnp.exp(g[:R]), 0.0)
+            # the kernel reads a decay of exactly 0 as "no state"
+            alpha = jnp.where(keep[:, None], jnp.exp(g[:R]), 0.0)
             live = (q_len[:R] > 0)[:, None]
             o, state_pool = gdn_decode(
                 q[:R], k[:R], v[:R], alpha, jnp.where(live, beta[:R], 0.0),

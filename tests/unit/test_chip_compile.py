@@ -374,6 +374,21 @@ def _gdn_decode_olmo(dev):
                         _on(dev, (rows,), jnp.int32))
 
 
+def _gdn_conv_step(rows, channels):
+    """A layer's decode-form convolution alone at a recurrent cell's shape:
+    ``rows`` sequences x ``channels``, the bf16 carry pool of 6 x rows + 1
+    slots of [3, channels] aliased in place, a row's block at its slot."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels.gdn_ops import \
+            causal_conv_step
+
+        return causal_conv_step, (
+            _on(dev, (rows, channels)), _on(dev, (4, channels)),
+            _on(dev, (6 * rows + 1, 3, channels)),
+            _on(dev, (rows,), jnp.int32), _on(dev, (rows,), jnp.bool_))
+    return build
+
+
 def _paged_stored_heads(op, rows=512):
     """The page operations with 30 query and K/V heads of 128 on a pool that
     STORES a token in 32 (``KVRow.tiled(30, 128)``: 64 combined rows; 60 are
@@ -498,6 +513,9 @@ CASES = {
     "paged_kv_append[30 heads stored in 32]": _paged_stored_heads("append"),
     "olmo_hybrid_decode_window": _olmo_hybrid(decode=True),
     "olmo_hybrid_prefill_step": _olmo_hybrid(decode=False),
+    # the decode-form convolution alone, at the two recurrent cells' shapes
+    "gdn_conv_step[128 rows x 11520]": _gdn_conv_step(128, 11520),
+    "gdn_conv_step[64 rows x 8192]": _gdn_conv_step(64, 8192),
 }
 
 

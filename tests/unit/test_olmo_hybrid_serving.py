@@ -339,12 +339,12 @@ def test_the_scheduler_serves_preempts_and_resumes(model_pairs):
     assert engine.state_manager.free_slots == 2
     assert sum(r.preempt_count for r in reqs) > 0
     records = tracer.records()[before:]
-    layouts = {(rec.attrs["form"], rec.attrs["impl"],
+    layouts = {(rec.attrs["form"], rec.attrs["impl"], rec.attrs["conv_impl"],
                 rec.attrs["state_layout"], rec.attrs["key_dim"],
                 rec.attrs["value_dim"])
                for rec in records if rec.name == "attn/gdn_layout"}
-    assert layouts == {("ragged", "xla", "pairs", 24, 64),
-                       ("decode", "kernel", "pairs", 24, 64)}
+    assert layouts == {("ragged", "xla", "xla", "pairs", 24, 64),
+                       ("decode", "kernel", "kernel", "pairs", 24, 64)}
     accounts = [rec for rec in records
                 if rec.name == "engine/window_account"]
     assert accounts and all(

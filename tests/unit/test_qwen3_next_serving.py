@@ -139,6 +139,10 @@ def got(model):
 
 @pytest.mark.parametrize("impl", ["paged", "gather"])
 def test_prefill_then_decode_through_slot_and_pages(model, impl):
+    from deepspeed_tpu.telemetry.trace import get_tracer
+
+    tracer = get_tracer()
+    before = len(tracer.records())
     prompt = prompt_tokens()
     engine = engine_for(model, attn_impl=impl)
     body = PROMPT - 4
@@ -154,6 +158,13 @@ def test_prefill_then_decode_through_slot_and_pages(model, impl):
     for i, tok in enumerate(more):
         out = int(engine.decode_batch([1], [tok], 1)[0, 0])
         assert out == int(np.argmax(ref[1 + i]))
+    # which convolution each compiled program took: the decode form's kernel
+    # in the windows, the ragged form everywhere else
+    convs = {(rec.attrs["form"], rec.attrs["conv_impl"])
+             for rec in tracer.records()[before:]
+             if rec.name == "attn/gdn_layout"}
+    assert convs == ({("ragged", "xla"), ("decode", "kernel")}
+                     if impl == "paged" else {("oracle", "xla")})
 
 
 @pytest.mark.parametrize("mutation", reference.MUTATIONS)
@@ -281,10 +292,11 @@ def test_the_scheduler_serves_preempts_and_resumes(model):
     reserves = [rec for rec in tracer.records()[before:]
                 if rec.name == "serve/reserve" and "slot" in rec.attrs]
     assert {rec.attrs["slot"] for rec in reserves} == {0, 1}
-    layouts = {(rec.attrs["form"], rec.attrs["impl"])
+    layouts = {(rec.attrs["form"], rec.attrs["impl"], rec.attrs["conv_impl"])
                for rec in tracer.records()[before:]
                if rec.name == "attn/gdn_layout"}
-    assert layouts == {("ragged", "xla"), ("decode", "kernel")}
+    assert layouts == {("ragged", "xla", "xla"),
+                       ("decode", "kernel", "kernel")}
     accounts = [rec for rec in tracer.records()[before:]
                 if rec.name == "engine/window_account"]
     assert accounts and all(rec.attrs["moe_pairs_dropped"] == 0
@@ -368,6 +380,78 @@ def test_the_convolution_carries_its_last_inputs():
             fresh=jnp.asarray([start == 0, True]))
         got.append(out)
         start += n
+    np.testing.assert_allclose(jnp.concatenate(got), want, atol=1e-5)
+    np.testing.assert_allclose(pool[1], x[-(K - 1):], atol=1e-6)
+
+
+@pytest.mark.parametrize("batch", ["shuffled", "fresh", "padded"])
+@pytest.mark.parametrize("C,K,dtype", [
+    (8192, 4, jnp.bfloat16),        # Qwen3-Next's channels
+    (11520, 4, jnp.bfloat16),       # Olmo-Hybrid's: 90 lane tiles
+    (12, 4, jnp.float32),
+    (384, 2, jnp.bfloat16)])
+def test_the_decode_convolution_is_the_ragged_one(C, K, dtype, batch):
+    """One token a row through ``causal_conv_step`` (the kernel, interpreted
+    here) and through the ragged form: same outputs, same pool outside the
+    trash row — rows in shuffled pool order; fresh rows over a slot full of
+    NaN; padded rows that all name the trash row."""
+    R, N = 6, 13
+    ks = jax.random.split(jax.random.PRNGKey(C + K), 3)
+    x = jax.random.normal(ks[0], (R, C)).astype(dtype)
+    w = jax.random.normal(ks[1], (K, C)).astype(dtype)
+    pool = jax.random.normal(ks[2], (N, K - 1, C)).astype(dtype)
+    rows = jnp.asarray([7, 2, 11, 0, 5, 9], jnp.int32)
+    q_len = jnp.ones((R,), jnp.int32)
+    fresh = jnp.zeros((R,), bool)
+    if batch == "fresh":
+        fresh = fresh.at[jnp.asarray([1, 4])].set(True)
+        pool = pool.at[rows[jnp.asarray([1, 4])]].set(jnp.nan)
+    if batch == "padded":
+        rows = rows.at[3:].set(N - 1)
+        q_len = q_len.at[3:].set(0)
+        fresh = fresh.at[3:].set(True)      # ctx_len == q_len == 0
+    live = np.asarray(q_len) > 0
+    want, want_pool = gdn_ops.causal_conv_ragged(
+        x, w, pool, rows, seq_of_token=jnp.arange(R),
+        q_offset=jnp.arange(R), q_len=q_len, fresh=fresh)
+    got, got_pool = gdn_ops.causal_conv_step(x, w, pool, rows,
+                                             (q_len > 0) & ~fresh)
+    assert got.dtype == jnp.float32 and got_pool.dtype == pool.dtype
+    np.testing.assert_allclose(np.asarray(got)[live],
+                               np.asarray(jax.nn.silu(want))[live],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(got_pool[:-1].astype(jnp.float32)),
+        np.asarray(want_pool[:-1].astype(jnp.float32)))
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_the_ragged_form_hands_its_carry_to_the_decode_form():
+    """A sequence prefilled in chunks by the ragged form and then decoded
+    five tokens, one a call, by the decode form: the one-piece convolution."""
+    T, C, K, steps = 23, 12, 4, 5
+    x = jax.random.normal(jax.random.PRNGKey(0), (T + steps, C))
+    w = jax.random.normal(jax.random.PRNGKey(1), (K, C))
+    padded = jnp.concatenate([jnp.zeros((K - 1, C)), x])
+    want = jax.nn.silu(sum(w[j][None] * padded[j:j + T + steps]
+                           for j in range(K)))
+    pool = jax.random.normal(jax.random.PRNGKey(2), (3, K - 1, C))
+    rows = jnp.asarray([1, 2], jnp.int32)               # row 1 unused: trash
+    got, start = [], 0
+    for n in (2, 1, 9, 11):
+        out, pool = gdn_ops.causal_conv_ragged(
+            x[start:start + n], w, pool, rows,
+            seq_of_token=jnp.zeros((n,), jnp.int32),
+            q_offset=jnp.asarray([0, n], jnp.int32),
+            q_len=jnp.asarray([n, 0], jnp.int32),
+            fresh=jnp.asarray([start == 0, True]))
+        got.append(jax.nn.silu(out))
+        start += n
+    for t in range(T, T + steps):
+        out, pool = gdn_ops.causal_conv_step(
+            jnp.stack([x[t], x[0]]), w, pool, rows,
+            jnp.asarray([True, False]))
+        got.append(out[:1])
     np.testing.assert_allclose(jnp.concatenate(got), want, atol=1e-5)
     np.testing.assert_allclose(pool[1], x[-(K - 1):], atol=1e-6)
 
