@@ -26,7 +26,7 @@ import numpy as np
 
 from ...models.transformer import CausalLM
 from ...runtime.fault.injection import InjectedNaN, inject
-from ...telemetry.trace import get_tracer
+from ...telemetry.trace import get_tracer, traced
 from ...utils.logging import log_dist, logger
 from .model_runner import build_ragged_step
 from .ragged.kv_cache import BlockedKVCache, KVCacheConfig
@@ -107,6 +107,7 @@ class RaggedInferenceEngineConfig:
 
 
 class InferenceEngineV2:
+    @traced("engine/init")      # the pools, the cast; its compiles inside
     def __init__(self, model: CausalLM, params,
                  config: Optional[RaggedInferenceEngineConfig] = None):
         from ...utils.compile_cache import configure_compile_cache

@@ -39,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..accelerator import get_accelerator
 from ..telemetry import emit_event
 from ..telemetry.goodput import get_goodput_ledger, record_goodput
-from ..telemetry.trace import NULL_SPAN, get_tracer
+from ..telemetry.trace import NULL_SPAN, get_tracer, traced
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from .config import DeepSpeedConfig
@@ -76,6 +76,7 @@ def _global_norm(tree):
 
 
 class DeepSpeedEngine:
+    @traced("engine/init")      # placing the state; its compiles inside
     def __init__(
         self,
         model: Any,

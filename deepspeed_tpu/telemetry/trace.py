@@ -28,6 +28,7 @@ its xplane.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -381,3 +382,16 @@ def get_tracer() -> Tracer:
     """The tracer every layer boundary records on; a ``Telemetry`` hub
     adopts it rather than building its own."""
     return _TRACER
+
+
+def traced(name: str):
+    """Decorator: each call of the function is one ``name`` span on the
+    process-global tracer (``engine/init`` around an engine's
+    construction)."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with _TRACER.span(name):
+                return fn(*args, **kwargs)
+        return call
+    return decorate
