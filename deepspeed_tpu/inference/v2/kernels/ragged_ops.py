@@ -65,6 +65,26 @@ shape of the chunk buffer follows the lane tile, not the head count: a
 page lands by ``hd // 128`` copies, one a 128-lane tile, and the pair
 load reads each tile's buffer (283 us, 77%; at 128-wide heads one copy a
 page as before).  ``_decode_head_load`` says which pools get it.
+
+PR 37 (PERF.md section 6; the kernel alone at Olmo-Hybrid's serving shape,
+128 sequences of 600-1,700 tokens, 30 multi-head-attention heads stored in
+32, 1 MiB pages: 2,741 us at the roof on the model's bytes).  At ONE query
+row a kv head the compute of a chunk, not the DMA, was what the walk
+waited for: 5,209 us (52.6%) with 4,911 us of compute on a resident chunk
+and 3,287 us (83.4%) of DMA starts and waits alone.  A pass is a serial
+chain whose latencies are paid once whatever its rows, and a pair pass
+filled 2 of the 8 sublanes of every score vreg, 16 passes a chunk.  So a
+pass scores as many pairs as fill the tile (``_pairs_per_pass``: 4 at one
+row a head, 1 from a group of 4 up, where the program is PR 29's): 4,030
+us (68.0%) at the 4 pages a chunk the VMEM budget gave.  What was left was
+a per-BYTE cost of the chunk's size: a pass costs ~0.15 us + 0.05 us a
+64 KB pair-page at 1 or 2 pages a chunk and 0.085 us at 4 (the same passes
+over half a 4-page chunk at a time get most of it back; the DMA alone
+takes the same 3.29 ms at 1, 2, 4 and 8 pages).  Mistral's pool (8 pages
+of 256 KB) and a ``KV`` 16 x group 2 pool (4 of 512 KB) agree: the best
+chunk is 2 MiB a buffer, which ``decode_paged_attention`` now holds a chunk
+to — 2 pages at Olmo's shape: 3,402 us, 80.6%, the DMA walk again.  (8
+pairs a pass, two sublane tiles of rows, bought 1 point more and stay out.)
 """
 from __future__ import annotations
 
@@ -81,6 +101,11 @@ from jax.experimental.pallas import tpu as pltpu
 from ....telemetry import get_tracer
 
 _NEG_INF = -1e30
+# The most a chunk (one of the two page buffers) may hold, in both kernels:
+# the ragged kernel loads a chunk whole onto Mosaic's stack (PR 34), and the
+# decode kernel's passes cost 1.7 x more a byte on a 4 MiB chunk than on a
+# 2 MiB one, in every pool measured (PR 37; module docstring)
+_CHUNK_LIMIT = 2 * 1024 * 1024
 
 
 def _interpret() -> bool:
@@ -392,8 +417,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     # long as a chunk is no more than 2 MiB (8 pages of 8 kv heads of 128: 2
     # MiB; of 32 stored heads: 8, and a 16-row bucket ran out of VMEM on
     # the chip, PR 34)
-    CHUNK_LIMIT = 2 * 1024 * 1024
-    while P0 > 1 and P0 * ps * ckv * hd * kv_itemsize > CHUNK_LIMIT:
+    while P0 > 1 and P0 * ps * ckv * hd * kv_itemsize > _CHUNK_LIMIT:
         P0 //= 2
     while True:
         P = P0
@@ -472,10 +496,26 @@ def _decode_head_load(dtype, KV: int, hd: int, ps: int) -> str:
     return "general"
 
 
+def _pairs_per_pass(KV: int, G: int) -> int:
+    """How many head PAIRS one pass of the strided load scores: the largest
+    divisor ``m`` of ``KV / 2`` whose ``2·m·G`` query rows fit the float32
+    sublane tile (8 rows), at least 1 — read off the stored head count and
+    the group size, nothing else.  A pass is a serial chain (strided load,
+    ``q·Kᵀ``, lane max, ``exp``, lane sum, ``p·V``) whose latencies are
+    paid once whatever its rows, and a ``[R <= 8, W]`` float32 tile costs
+    ``W / 128`` vregs whatever ``R`` is.  A group of 4 or more query heads
+    a kv head fills the tile with one pair (Mistral, Qwen3-Next: ``m`` = 1,
+    PR 29's pass); multi-head attention takes 4 pairs a pass (Olmo-Hybrid:
+    32 stored heads, 4 passes a chunk where one pair a pass made 16)."""
+    return max(m for m in range(1, max(KV // 2, 1) + 1)
+               if (KV // 2) % m == 0 and (m == 1 or 2 * m * G <= 8))
+
+
 def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                          q_ref, pages_ref, o_ref,        # VMEM block / HBM
                          kv_bufs, sems, acc, m_scr, l_scr, carry,
-                         *, scale, ps, P, KV, G, NB, alibi, alibi_scaled, hpg):
+                         *, scale, ps, P, KV, G, NB, alibi, alibi_scaled, hpg,
+                         pairs):
     """One grid step = ONE decoding sequence's single query token.
 
     The ragged kernel spends a ``[block_q·G, chunk]`` MXU tile per chunk even
@@ -502,6 +542,18 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
     one head a pass from a value slice of the chunk: the same body with no
     parity mask.
 
+    How many pairs a pass scores (PR 37).  ``pairs`` (``_pairs_per_pass``)
+    strided loads are laid one under the other, ``[pairs·2·CH, lanes]``,
+    and scored against the pass's ``R = 2·pairs·G`` query rows: one
+    ``[R, pairs·2·CH]`` score tile, one mask (a column's head is its load's
+    pair and its parity), ONE max / ``exp`` / sum chain, one ``p · V`` over
+    the V pairs laid the same way, one ``[R, hd]`` accumulator and ``m`` /
+    ``l`` block a pass, ``KV / (2·pairs)`` passes a chunk.  Another head's
+    probabilities are exactly 0, as the other parity's are, so the products
+    and their precision are those of one pair a pass; a row's ``sum(p)``
+    adds its terms in another order.  With ``pairs == 1`` the body is PR
+    29's, equation for equation.
+
     How a chunk's pages land (PR 33).  Mosaic lowers a strided load only
     from a base memref that is ONE lane tile (128 lanes) wide — then any
     sublane tiling of it is plain rows of 128 words.  That, and not the
@@ -517,7 +569,8 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
     kvl = kvl_ref[s]
     CH = P * ps                               # context tokens per chunk
     nch = _cdiv(kvl, CH)
-    NG, R, W = KV // hpg, hpg * G, hpg * CH   # passes, query rows, columns
+    HP, PW = hpg * pairs, hpg * CH            # heads a pass, columns a load
+    NG, R, W = KV // HP, HP * G, pairs * PW   # passes, query rows, columns
     dtype, LT, LW = kv_bufs.dtype, kv_bufs.shape[1], kv_bufs.shape[-1]
 
     def page_needed(seq, page_idx):
@@ -567,11 +620,18 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
 
         def compute(c, slot):
             col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
-            k_pos = c * CH + col // hpg        # column -> context position
+            # column -> context position: a load's PW columns are its CH
+            # tokens with the pair's two heads interleaved, and a pass lays
+            # its loads side by side
+            k_pos = c * CH + (col if pairs == 1 else col % PW) // hpg
             mask = k_pos < kvl                 # decode: attend all cached ctx
-            if hpg > 1:                        # ... of the row's own head
+            if hpg > 1:                        # ... of the row's own head:
                 row = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
-                mask = mask & (row // G == col % hpg)
+                row_head = row // G
+                col_head = col % hpg           # its parity in the pair
+                if pairs > 1:                  # and which pair of the pass
+                    col_head = col // PW * hpg + col_head
+                mask = mask & (row_head == col_head)
             # never-DMA'd tokens hold stale data: scores there are masked,
             # but V rows must be zeroed so 0·garbage(NaN) cannot poison the
             # accumulate (select-before-multiply — the
@@ -589,11 +649,16 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                         w = jnp.where(keep, w, jnp.uint32(0))
                     return pltpu.bitcast(w, dtype)        # [2·CH, 128]
 
+                def load(t, first, keep=None):  # a pass's pairs, one
+                    got = [pair(t, first + i, keep)         # under the other
+                           for i in range(pairs)]
+                    return got[0] if pairs == 1 else jnp.concatenate(got)
+
                 def load_k(g, t):
-                    return pair(t, g)
+                    return load(t, g * pairs)
 
                 def load_v(g, t):
-                    return pair(t, KV // 2 + g, tok_ok)
+                    return load(t, KV // 2 + g * pairs, tok_ok)
             else:
                 kv = kv_bufs[slot, 0]          # [P, ps, 2KV, hd]
 
@@ -695,7 +760,10 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     call leaves one ring-only ``attn/decode_layout`` record saying which
     head load the compiled kernel got (:func:`_decode_head_load`) and in
     how many ``lane_tiles`` a page lands (``hd // 128`` for the strided
-    load, 1 otherwise).
+    load, 1 otherwise), how many head pairs a pass scores and how many
+    passes a chunk takes (``pairs_per_pass``, ``passes_per_chunk``).
+    ``pages_per_chunk`` is an upper bound: a chunk is held to 2 MiB a
+    buffer and to the VMEM budget.
     """
     S, H_model, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
@@ -717,20 +785,24 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     P = min(pages_per_chunk, NB)
     load = _decode_head_load(kv_pages.dtype, KV, hd, ps)
     hpg, LT = (2, hd // 128) if load == "strided" else (1, 1)
+    pairs = _pairs_per_pass(KV, G) if load == "strided" else 1
+    NG, R = KV // (hpg * pairs), hpg * pairs * G   # passes a chunk, rows a pass
 
-    # same VMEM accounting as the ragged kernel, with the [hpg·G, hpg·chunk]
-    # score tile
+    # same VMEM accounting as the ragged kernel, with the
+    # [R, pairs·hpg·chunk] score tile, and the same limit on a chunk
     VMEM_BUDGET = 12 * 1024 * 1024
     kv_itemsize = jnp.dtype(kv_pages.dtype).itemsize
+    page_bytes = ps * ckv * hd * kv_itemsize
 
     def _vmem_bytes(p):
         kv_bufs = 2 * p * ps * ckv * hd * kv_itemsize
         softmax = KV * G * (hd + 2 * 128) * 4
         qo = 2 * 2 * H * hd * jnp.dtype(q.dtype).itemsize
-        temps = 3 * (hpg * G) * (hpg * p * ps) * 4
+        temps = 3 * R * (pairs * hpg * p * ps) * 4
         return kv_bufs + softmax + qo + temps
 
-    while P > 1 and _vmem_bytes(P) > VMEM_BUDGET:
+    while P > 1 and (P * page_bytes > _CHUNK_LIMIT
+                     or _vmem_bytes(P) > VMEM_BUDGET):
         P //= 2
     if _vmem_bytes(P) > VMEM_BUDGET:
         raise ValueError(
@@ -743,12 +815,12 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     get_tracer().record(
         "attn/decode_layout", time.perf_counter(), 0.0, load=load, P=P,
         dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=num_kv_heads,
-        stored_kv_heads=KV, group=G, lane_tiles=LT)
+        stored_kv_heads=KV, group=G, lane_tiles=LT, pairs_per_pass=pairs,
+        passes_per_chunk=NG)
 
     kernel = functools.partial(
         _decode_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, NB=NB,
-        alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg)
-    NG, R = KV // hpg, hpg * G
+        alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg, pairs=pairs)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

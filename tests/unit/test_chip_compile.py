@@ -120,18 +120,21 @@ def _paged(decode):
 
 
 def _paged_decode_pool(kv, hd, load, pages, heads=16, rows=64, blocks=64,
-                       dtype=BF16):
+                       dtype=BF16, pairs=1):
     """The K/V decode kernel on a pool ``_decode_head_load`` answers
     ``load`` for: the rule must promise only what Mosaic lowers (the
     strided pair load must lower for the chip, not only interpret), so the
     answer is asserted and then BOTH kinds are compiled.  (A bf16 pool of
     6 or 12 combined rows, or of 64-wide heads, is refused at the page DMA
-    whatever the load, at PR 32 too: PERF.md section 7.)"""
+    whatever the load, at PR 32 too: PERF.md section 7.)  ``pairs``: the
+    head pairs a pass scores (``_pairs_per_pass``, PR 37), asserted too —
+    the widened pass must fit the scoped VMEM limit on the chip."""
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
-            _decode_head_load, decode_paged_attention)
+            _decode_head_load, _pairs_per_pass, decode_paged_attention)
 
         assert _decode_head_load(dtype, kv, hd, PAGE) == load
+        assert load == "general" or _pairs_per_pass(kv, heads // kv) == pairs
         return (lambda q, p, n, t: decode_paged_attention(
             q, p, n, t, num_kv_heads=kv)), \
             (_on(dev, (rows, heads, hd), dtype),
@@ -398,14 +401,15 @@ def _paged_stored_heads(op, rows=512):
     scoped VMEM on the chip while 512 compiled, PR 34)."""
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
-            _decode_head_load, decode_paged_attention, paged_kv_append,
-            ragged_paged_attention)
+            _decode_head_load, _pairs_per_pass, decode_paged_attention,
+            paged_kv_append, ragged_paged_attention)
         from deepspeed_tpu.models.serving import KVRow
 
         row = KVRow.tiled(30, HD)
         assert row.token_shape == (64, HD)
         assert _decode_head_load(BF16, 30, HD, PAGE) == "general"
         assert _decode_head_load(BF16, row.stored, HD, PAGE) == "strided"
+        assert _pairs_per_pass(row.stored, 1) == 4     # 4 passes a chunk
         seqs, blocks = 128, 36
         pool = _on(dev, (2 * 3900 + 1, PAGE) + row.token_shape)
         lens = _on(dev, (seqs,), jnp.int32)
@@ -486,9 +490,18 @@ CASES = {
     "decode_paged_attention[qwen3next, 64 rows]": _paged_decode_pool(
         2, 256, "strided", 2 * 3200 + 1, blocks=50),
     "decode_paged_attention[KV 8, hd 256]": _paged_decode_pool(
-        8, 256, "strided", 16 * 400 + 1),              # Gemma-2-9B's heads
+        8, 256, "strided", 16 * 400 + 1, pairs=2),     # Gemma-2-9B's heads
     "decode_paged_attention[KV 4, hd 128]": _paged_decode_pool(
         4, 128, "strided", 16 * 1730 + 1),
+    # PR 37, several head pairs a pass: the Olmo-Hybrid cell's pool with
+    # every stored head a model head (multi-head attention, 4 pairs) and a
+    # group of 2 on 16 kv heads (2 pairs), 128 rows, 36-page tables
+    "decode_paged_attention[KV 32, group 1, 128 rows]": _paged_decode_pool(
+        32, 128, "strided", 2 * 3900 + 1, heads=32, rows=128, blocks=36,
+        pairs=4),
+    "decode_paged_attention[KV 16, group 2, 128 rows]": _paged_decode_pool(
+        16, 128, "strided", 2 * 3900 + 1, heads=32, rows=128, blocks=36,
+        pairs=2),
     "decode_paged_attention[KV 1, general]": _paged_decode_pool(
         1, 128, "general", 16 * 400 + 1),              # a K/V word row
     "decode_paged_attention[float32 KV 6, general]": _paged_decode_pool(

@@ -17,6 +17,7 @@ import pytest
 from deepspeed_tpu.inference.v2.kernels.page_ops import _attend_gather
 from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
     _decode_head_load,
+    _pairs_per_pass,
     decode_attend_dense,
     decode_attention,
     decode_paged_attention,
@@ -144,14 +145,31 @@ class TestDecodeKernelParity:
     # 16 / 2 heads of 256 (PR 33: 4 combined rows a token, two lane tiles)
     CELL = dict(KV=8, G=4, hd=128, ps=64, NB=10)
     QWEN = dict(KV=2, G=8, hd=256, ps=64, NB=10)
+    # PR 37, a pass of several head pairs (``_pairs_per_pass``): Olmo-
+    # Hybrid's pool of 32 stored heads at one query row a head (4 pairs a
+    # pass), the same pool serving the model's 30 heads (``heads``: the
+    # queries get two zero heads and the output is cut back), and a group
+    # of 2 on 16 kv heads (2 pairs a pass)
+    OLMO = dict(KV=32, G=1, hd=128, ps=64, NB=10)
+    OLMO30 = dict(OLMO, heads=30)
+    GROUP2 = dict(KV=16, G=2, hd=128, ps=64, NB=10)
     GEOMETRIES = [pytest.param(CELL, id="mistral"),
-                  pytest.param(QWEN, id="qwen3next")]
+                  pytest.param(QWEN, id="qwen3next"),
+                  pytest.param(OLMO, id="olmo"),
+                  pytest.param(OLMO30, id="olmo-30-in-32"),
+                  pytest.param(GROUP2, id="group2")]
+
+    @staticmethod
+    def _heads(geom):
+        """The MODEL's kv heads (the pool stores ``geom["KV"]``)."""
+        return geom.get("heads", geom["KV"])
 
     def _cell_case(self, seed, ctx, poison=True, geom=None):
         rng = np.random.default_rng(seed)
         g = geom or self.CELL
         q, pages, kvl, pt = _decode_case(rng, ctx, g["KV"], g["G"], g["hd"],
                                          g["ps"], g["NB"])
+        q = q[:, :self._heads(g) * g["G"]]
         q, pages = q.astype(jnp.bfloat16), pages.astype(jnp.bfloat16)
         if poison:        # every page the walk must not read: NaN
             for s, c in enumerate(ctx):
@@ -187,7 +205,7 @@ class TestDecodeKernelParity:
         NaN in every page past each context (never fetched, or fetched
         behind the context's end: masked AND zeroed)."""
         out = self._assert_matches_dense(
-            *self._cell_case(30, ctx, geom=geom), geom["KV"])
+            *self._cell_case(30, ctx, geom=geom), self._heads(geom))
         for s, c in enumerate(ctx):
             if c == 0:
                 np.testing.assert_array_equal(out[s], 0.0)
@@ -203,7 +221,7 @@ class TestDecodeKernelParity:
         for s, c in enumerate(ctx):
             pid = int(pt[s, c // ps])
             pages = pages.at[pid, c % ps:].set(jnp.nan)
-        self._assert_matches_dense(q, pages, kvl, pt, geom["KV"])
+        self._assert_matches_dense(q, pages, kvl, pt, self._heads(geom))
 
     @pytest.mark.parametrize("geom,ppc", [
         pytest.param(CELL, 1, id="mistral-1"),
@@ -213,13 +231,18 @@ class TestDecodeKernelParity:
         pytest.param(QWEN, 4, id="qwen3next-4"),
         pytest.param(QWEN, 8, id="qwen3next-8"),
         pytest.param(dict(QWEN, NB=17), 16, id="qwen3next-16"),
+        pytest.param(OLMO30, 1, id="olmo-30-in-32-1"),
+        pytest.param(OLMO, 2, id="olmo-2"),
+        pytest.param(OLMO30, 8, id="olmo-30-in-32-8"),     # a 2 MiB chunk: 2
+        pytest.param(GROUP2, 1, id="group2-1"),
+        pytest.param(GROUP2, 8, id="group2-8"),
     ])
     def test_bf16_pool_pages_per_chunk_invariance(self, geom, ppc):
         """1, 4, 8 and 16 pages a chunk walk the same context to the same
         answer (against the dense lowering, so each case stands alone)."""
         self._assert_matches_dense(
-            *self._cell_case(32, [577, 0, 256, 129], geom=geom), geom["KV"],
-            pages_per_chunk=ppc)
+            *self._cell_case(32, [577, 0, 256, 129], geom=geom),
+            self._heads(geom), pages_per_chunk=ppc)
 
     @pytest.mark.parametrize("geom", [
         pytest.param(dict(KV=8, G=2, hd=256, ps=64, NB=10), id="KV8-hd256"),
@@ -233,6 +256,15 @@ class TestDecodeKernelParity:
                                  geom["ps"]) == "strided"
         self._assert_matches_dense(
             *self._cell_case(37, [513, 0, 70, 600], geom=geom), geom["KV"])
+
+    @pytest.mark.parametrize("KV,G,pairs", [
+        (8, 4, 1), (2, 8, 1),              # Mistral, Qwen3-Next: PR 29's pass
+        (32, 1, 4), (16, 2, 2), (4, 1, 2), (2, 1, 1), (24, 1, 4), (40, 1, 4),
+    ])
+    def test_pairs_per_pass_fills_the_sublane_tile(self, KV, G, pairs):
+        """The largest divisor of ``KV / 2`` whose ``2·m·G`` query rows fit
+        8 sublanes, read off the stored head count and the group alone."""
+        assert _pairs_per_pass(KV, G) == pairs
 
     def test_bf16_pool_alibi_rides_the_pair_tile(self):
         """Per-head slopes in the two-heads-a-pass layout (MHA, so a pass
@@ -250,7 +282,10 @@ class TestDecodeKernelParity:
         at the cells' geometries (one lane tile a page at 128-wide heads,
         two at Qwen3-Next's 256) and at a bf16 pool of 8 combined rows a
         token; general at the float32 toy shapes, at an odd number of kv
-        heads, at narrow heads."""
+        heads, at narrow heads.  ``pairs_per_pass`` / ``passes_per_chunk``
+        (PR 37) say which pass the program got: 1 / 4 at Mistral's pool,
+        1 / 1 at Qwen3-Next's, 4 / 4 at Olmo-Hybrid's 30 heads stored in
+        32, 1 wherever the load is general."""
         from deepspeed_tpu.telemetry import get_tracer
 
         def layout(q, pages, kvl, pt, KV):
@@ -265,17 +300,26 @@ class TestDecodeKernelParity:
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False)
         assert layout(q, pages, kvl, pt, 8) == dict(
             load="strided", P=8, dtype="bfloat16", kv_heads=8,
-            stored_kv_heads=8, group=4, lane_tiles=1)
+            stored_kv_heads=8, group=4, lane_tiles=1, pairs_per_pass=1,
+            passes_per_chunk=4)
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
                                             geom=self.QWEN)
         assert layout(q, pages, kvl, pt, 2) == dict(
             load="strided", P=8, dtype="bfloat16", kv_heads=2,
-            stored_kv_heads=2, group=8, lane_tiles=2)
+            stored_kv_heads=2, group=8, lane_tiles=2, pairs_per_pass=1,
+            passes_per_chunk=1)
+        q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
+                                            geom=self.OLMO30)
+        assert layout(q, pages, kvl, pt, 30) == dict(
+            load="strided", P=2, dtype="bfloat16", kv_heads=30,
+            stored_kv_heads=32, group=1, lane_tiles=1, pairs_per_pass=4,
+            passes_per_chunk=4)
         rng = np.random.default_rng(35)
         toy = _decode_case(rng, [9, 5], 1, 2, 16, 4, 3)
         assert layout(*toy, 1) == dict(
             load="general", P=3, dtype="float32", kv_heads=1,
-            stored_kv_heads=1, group=2, lane_tiles=1)
+            stored_kv_heads=1, group=2, lane_tiles=1, pairs_per_pass=1,
+            passes_per_chunk=1)
         q4, p4, kvl4, pt4 = _decode_case(rng, [40], 4, 2, 128, 16, 4)
         rec = layout(q4.astype(jnp.bfloat16), p4.astype(jnp.bfloat16),
                      kvl4, pt4, 4)
