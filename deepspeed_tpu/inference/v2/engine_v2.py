@@ -1410,20 +1410,27 @@ class DecodeWindow:
         what the routing says it should have computed less what the
         experts' groups held: 0, asserted."""
         counts = self.engine.family.counts
-        held = self.moe_pairs[:counts.num_experts]
+        E = counts.num_experts
+        held = self.moe_pairs[:E]
         pairs = int(held.sum())
         # a chip's share: the pairs of experts held elsewhere are counted
-        # apart, neither computed nor dropped here
-        elsewhere = int(self.moe_pairs[counts.num_experts:].sum())
+        # apart, neither computed nor dropped here; so are the pairs of
+        # experts without weights (identity), which no chip owes
+        elsewhere = int(self.moe_pairs[E]) if counts.elsewhere else 0
+        identity = int(self.moe_pairs[-1]) if counts.identity else 0
         expected = self.n_seqs * self.steps * counts.per_token
-        dropped = expected - pairs - elsewhere
+        dropped = expected - pairs - elsewhere - identity
         assert dropped == 0, \
             f"dropless expert layer lost pairs: {expected} routed, " \
-            f"{pairs} computed, {elsewhere} held elsewhere"
+            f"{pairs} computed, {elsewhere} held elsewhere, " \
+            f"{identity} identity"
         sp.set(moe_pairs=pairs, moe_pairs_dropped=dropped,
                moe_load_max_share=float(held.max()) / max(pairs, 1))
         if counts.elsewhere:
             sp.set(moe_pairs_elsewhere=elsewhere)
+        if counts.identity:
+            sp.set(moe_pairs_identity=identity,
+                   moe_identity_pair_share=identity / max(expected, 1))
 
     def nonfinite_uids(self) -> List[int]:
         """uids whose logits went non-finite during this window (drains

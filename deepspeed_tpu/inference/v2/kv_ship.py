@@ -95,11 +95,11 @@ def export_kv(engine, uid: int, tokens: List[int],
     nb = engine.kv.config.num_blocks
     # one gather for all layers: [L * n_pages] physical page ids
     phys = np.asarray([b + layer * nb
-                       for layer in range(engine.family.num_layers)
+                       for layer in range(engine.family.page_layers)
                        for b in seq.blocks[:n_pages]], np.int64)
     pages = np.asarray(engine.kv.pages[jnp.asarray(phys)], np.float32)
     row = engine.family.row
-    rows = pages.reshape(engine.family.num_layers, n_pages * bs,
+    rows = pages.reshape(engine.family.page_layers, n_pages * bs,
                          *row.token_shape)[:, :n]
     if row.stored != row.num_kv_heads:
         # canonical rows carry the model's heads, not the pool's padding
@@ -107,7 +107,7 @@ def export_kv(engine, uid: int, tokens: List[int],
         rows = np.concatenate([rows[:, :, :kv],
                                rows[:, :, row.stored:row.stored + kv]], axis=2)
     return KVShipment(tokens=[int(t) for t in tokens[:n]],
-                      num_layers=engine.family.num_layers,
+                      num_layers=engine.family.page_layers,
                       num_kv_heads=row.num_kv_heads, head_dim=row.head_dim,
                       src_block_size=bs, wire="fp32", rows=rows)
 
@@ -122,13 +122,13 @@ def import_kv(engine, shipment: KVShipment, uid: int) -> bool:
 
     _refuse_latent(engine, "import_kv (kv_import requests)")
     row = engine.family.row
-    if (shipment.num_layers != engine.family.num_layers
+    if (shipment.num_layers != engine.family.page_layers
             or shipment.num_kv_heads != row.num_kv_heads
             or shipment.head_dim != row.head_dim):
         raise ValueError(
             f"KV shipment geometry mismatch: shipment "
             f"L{shipment.num_layers}/kv{shipment.num_kv_heads}"
-            f"/hd{shipment.head_dim} vs engine L{engine.family.num_layers}"
+            f"/hd{shipment.head_dim} vs engine L{engine.family.page_layers}"
             f"/kv{row.num_kv_heads}/hd{row.head_dim}")
     n = shipment.n_tokens
     sm = engine.state_manager

@@ -31,6 +31,7 @@ Two attention impls, for every cache kind (kernels/page_ops.py):
 from __future__ import annotations
 
 import contextlib
+import copy
 import math
 from functools import partial
 
@@ -65,7 +66,10 @@ class _LayerCache:
 
     ``pages`` is the FULL multi-layer page pool; ``layer`` (traced) picks
     this layer's pages via table arithmetic — no per-layer slice
-    materialization.  ``attend`` dispatches among the cache kind's
+    materialization.  ``at(page_layer)`` is the same pool seen as another
+    page layer, for a body that owns several: the views share the pool, so
+    an append through one is seen by the others and by the runner.
+    ``attend`` dispatches among the cache kind's
     operations (``ops``): the flat-token ragged kernel; the dense
     page-gather oracle; with ``decode_mode`` — the row-major decode layout
     (sequence i's single query token at flat index i, rows past n_seqs
@@ -79,11 +83,25 @@ class _LayerCache:
     def __init__(self, pages, layer, *, ops: PageOps, batch, attn_impl,
                  num_blocks, max_q, block_q, pages_per_chunk, decode_mode,
                  verify_mode):
-        self.ops, self.pages, self.batch, self.layer = ops, pages, batch, layer
+        self.ops, self.batch, self.layer = ops, batch, layer
+        self._pool = [pages]            # shared by every view (``at``)
         self.paged, self.num_blocks, self.max_q = \
             attn_impl == "paged", num_blocks, max_q
         self.tile = dict(block_q=block_q, pages_per_chunk=pages_per_chunk)
         self.decode_mode, self.verify_mode = decode_mode, verify_mode
+
+    @property
+    def pages(self):
+        return self._pool[0]
+
+    @pages.setter
+    def pages(self, pages) -> None:
+        self._pool[0] = pages
+
+    def at(self, page_layer) -> "_LayerCache":
+        view = copy.copy(self)          # the same ``_pool`` list
+        view.layer = page_layer
+        return view
 
     def append(self, *rows) -> None:
         b = self.batch

@@ -76,7 +76,7 @@ class KVSwapManager:
     def _page_row_bytes(self) -> int:
         """Bytes one logical page occupies in canonical row space (all
         layers, K+V, float32)."""
-        return (self.eng.family.num_layers * self.eng.config.block_size
+        return (self.eng.family.page_layers * self.eng.config.block_size
                 * self.eng.family.row.read_values * 4)
 
     # ------------------------------------------------------------------ #
@@ -175,7 +175,7 @@ class KVSwapManager:
             return 0
         row = self.eng.family.row
         ship = KVShipment(tokens=list(entry.tokens[:n]),
-                          num_layers=self.eng.family.num_layers,
+                          num_layers=self.eng.family.page_layers,
                           num_kv_heads=row.num_kv_heads,
                           head_dim=row.head_dim,
                           src_block_size=self.eng.config.block_size,
@@ -220,7 +220,7 @@ class KVSwapManager:
         c = self.eng.kv.config
         nb = c.num_blocks
         phys = np.asarray([node.block + layer * nb
-                           for layer in range(self.eng.family.num_layers)],
+                           for layer in range(self.eng.family.page_layers)],
                           np.int64)
         rows = np.asarray(self.eng.kv.pages[jnp.asarray(phys)], np.float32)
         try:

@@ -19,7 +19,11 @@ axis ``[T, ...]``:
     ``[T, H, d']``; ``cache.append`` / ``cache.attend`` are the halves, for
     a body that scopes them apart.  ``attn`` is the attention's own
     arithmetic (``scale``; K/V rows also ``alibi``, ``alibi_scaled``).  A
-    body never sees a page table.
+    body never sees a page table.  ``cache`` is page layer ``layer``; a
+    body that owns SEVERAL page layers (two attention blocks in one layer:
+    ``page_layer_count`` > ``num_layers``) takes each from the same handle,
+    ``cache.at(page_layer)``, and the pool is threaded through all their
+    appends.
 ``head(params, x, pick) -> logits``: the final norm, ``pick`` (the rows that
     need logits: each sequence's last, or all in a verify window), the head.
 
@@ -203,15 +207,20 @@ class ExpertPairs:
     step (expert layers × choices).  ``elsewhere``: the ``num_experts``
     experts are a chip's share of an expert-parallel layer; the pairs routed
     to the experts of other chips are neither computed nor dropped here, and
-    the vector has one more entry, at its end, that counts them."""
+    the vector has one more entry behind the experts' that counts them.
+    ``identity``: the router also scores experts WITHOUT weights (zero-
+    computation identity experts: the pair adds ``g·h``); their pairs cost
+    no matmul row and are held on no chip, and one more entry, the last,
+    counts them apart."""
 
     num_experts: int
     per_token: int
     elsewhere: bool = False
+    identity: bool = False
 
     @property
     def size(self) -> int:
-        return self.num_experts + int(self.elsewhere)
+        return self.num_experts + int(self.elsewhere) + int(self.identity)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,7 +246,8 @@ class ServingFamily:
     #: per-sequence recurrent state of some layers (None: every layer caches
     #: rows); bodies then take a ``state`` handle after ``ctx``
     state: Optional[GatedDeltaState] = None
-    #: layers that own PAGES (None: all ``num_layers``)
+    #: page layers of the pool (None: one a layer, ``num_layers``): fewer
+    #: where some layers keep state instead, more where a body owns several
     page_layer_count: Optional[int] = None
 
     @property

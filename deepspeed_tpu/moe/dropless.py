@@ -1,9 +1,12 @@
 """Dropless expert layer for serving: sigmoid scores with a selection bias
-(``noaux_tc``) or softmax scores, renormalised weights, a grouped matmul over
-the (token, choice) pairs sorted by expert, and a shared expert added to
-every token (behind a sigmoid gate where the model has one).  The layer can
-be told that it holds only a chip's share of the experts
-(:func:`dropless_experts`, ``offset``).
+(``noaux_tc``) or softmax scores (with one too: :func:`softmax_bias_topk_
+route`), renormalised weights or not, a grouped matmul over the (token,
+choice) pairs sorted by expert, and a shared expert added to every token
+(behind a sigmoid gate where the model has one).  The layer can be told that
+it holds only a chip's share of the experts (:func:`dropless_experts`,
+``offset``) and that the router's last outputs are experts WITHOUT weights
+(``identity_from``: zero-computation identity experts, whose pair adds
+``g·h`` and costs no matmul row).
 
 No capacity and no dropped pair: the pairs of one expert are a contiguous
 group of rows, the grouped matmul walks each group in row tiles (a tile that
@@ -22,10 +25,13 @@ the shapes per program (:func:`_tilings`).  On the TPU it is
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..telemetry import get_tracer
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -57,6 +63,20 @@ def softmax_topk_route(h, router: Dict, k: int, renormalise: bool = True):
     if renormalise:
         g = g / jnp.sum(g, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), g
+
+
+def softmax_bias_topk_route(h, router: Dict, k: int, scaling: float):
+    """``h`` [T, D] → (ids [T, k] int32, weights [T, k] float32) over ALL the
+    router's outputs, experts without weights among them: ``s = softmax(h·
+    W_r)`` in float32, the top ``k`` of ``s + b``, the weights ``s`` at those
+    WITHOUT ``b``, scaled and NOT renormalised (a token's weights sum to
+    what its picks' scores sum to)."""
+    logits = jnp.dot(h.astype(jnp.float32),
+                     router["kernel"].astype(jnp.float32), precision=_HI)
+    s = jax.nn.softmax(logits, axis=-1)
+    _, idx = jax.lax.top_k(s + router["bias"].astype(jnp.float32), k)
+    return idx.astype(jnp.int32), jnp.take_along_axis(s, idx, axis=-1) \
+        * scaling
 
 
 def _on_tpu() -> bool:
@@ -192,7 +212,8 @@ def grouped_matmul(x, w, group_sizes, impl: Optional[str] = None):
 
 def dropless_experts(h, idx, weights, experts: Dict,
                      valid=None, impl: Optional[str] = None, layer=None,
-                     offset: Optional[int] = None
+                     offset: Optional[int] = None,
+                     identity_from: Optional[int] = None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed experts' part of the layer: ``Σ_k g_k · E_idx_k(h)``.
 
@@ -213,26 +234,40 @@ def dropless_experts(h, idx, weights, experts: Dict,
     the last group, where the grouped matmul computes nothing, add nothing
     to the result, and are counted in one more entry at the end of the
     pairs: ``[E + 1]``.  They are not dropped: the chip that holds their
-    expert computes them (expert parallelism without its exchange)."""
+    expert computes them (expert parallelism without its exchange).
+
+    EXPERTS WITHOUT WEIGHTS (``identity_from`` given): ids from
+    ``identity_from`` on (of the whole layer, past every real expert) are
+    identity experts, ``E_id(h) = h``.  Such a pair is neither held here nor
+    elsewhere: it adds ``g·h`` where the token's residual lives (here, for
+    every token: ``moe/identity``), is sorted behind the last group like a
+    pair held elsewhere (no matmul row), and is counted in an entry of its
+    own, the last of the pairs."""
     T, D = h.shape
     k = idx.shape[1]
     E = experts["gate"].shape[-3]
     M = T * k
     flat = idx.reshape(M)
+    identity = None if identity_from is None else flat >= identity_from
     if offset is not None:
         flat = flat - offset
         flat = jnp.where((flat >= 0) & (flat < E), flat, E)
+    # buckets behind the E groups: held elsewhere, then identity
+    n = E + int(offset is not None) + int(identity is not None)
+    if identity is not None:
+        flat = jnp.where(identity, n - 1, flat)
     order = jnp.argsort(flat, stable=True)              # pairs by expert
     token_of = order // k
-    sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1) if offset is None \
-        else jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1)
+    if n > E:
+        sizes = sizes[:E]
     x = jnp.take(h, token_of, axis=0)                   # [M, D]
-    # rows to a whole tile: the extra rows are zeros in the last group (of a
-    # share: in no group, as the rows of the pairs held elsewhere)
+    # rows to a whole tile: the extra rows are zeros in the last group (where
+    # pairs lie behind it, held elsewhere or identity: in no group, as they)
     M_pad = whole_tiles(M, E)
     if M_pad != M:
         x = jnp.pad(x, ((0, M_pad - M), (0, 0)))
-        if offset is None:
+        if n == E:
             sizes = sizes.at[E - 1].add(M_pad - M)
     if layer is not None:
         L = experts["gate"].shape[0]
@@ -248,13 +283,20 @@ def dropless_experts(h, idx, weights, experts: Dict,
     with jax.named_scope("moe/combine"):
         inv = jnp.argsort(order)                        # back to pair order
         y = jnp.take(y, inv, axis=0).reshape(T, k, D)
-        if offset is not None:
+        if n > E:
             # a row in no group holds whatever memory held: select, then
             # weigh
             y = jnp.where((flat < E).reshape(T, k, 1), y, 0)
-        out = jnp.einsum("tk,tkd->td", weights,
-                         y.astype(jnp.float32)).astype(h.dtype)
-    n = E if offset is None else E + 1                  # + held elsewhere
+        out = jnp.einsum("tk,tkd->td", weights, y.astype(jnp.float32))
+        if identity is None:
+            out = out.astype(h.dtype)
+    if identity is not None:
+        with jax.named_scope("moe/identity"):
+            # Σ g over a token's identity picks, times its own input; added
+            # in float32 to the experts' sum before the one rounding
+            g_id = jnp.sum(jnp.where(identity.reshape(T, k), weights, 0.0),
+                           axis=-1, keepdims=True)
+            out = (out + g_id * h.astype(jnp.float32)).astype(h.dtype)
     counted = flat if valid is None else jnp.where(
         jnp.repeat(valid, k), flat, n)
     pairs = jnp.zeros((n + 1,), jnp.int32).at[counted].add(1)[:n]
@@ -306,3 +348,34 @@ def softmax_moe_block(h, lp: Dict, *, k: int, renormalise: bool = True,
         shared = (gate * shared.astype(jnp.float32)).astype(h.dtype)
     with jax.named_scope("moe/combine"):
         return routed + shared, pairs
+
+
+def zero_expert_moe_block(h, lp: Dict, *, k: int, scaling: float,
+                          identity_from: Optional[int],
+                          offset: Optional[int] = None, valid=None,
+                          impl: Optional[str] = None, experts=None,
+                          layer=None):
+    """The expert layer of a LongCat-Flash decoder layer: a softmax router
+    with a selection bias over real experts and identity experts alike
+    (:func:`softmax_bias_topk_route`; ids from ``identity_from`` on are the
+    identity ones, None: there are none), the real experts all held or with
+    ``offset`` a chip's share (:func:`dropless_experts`), no shared expert.
+    ``lp``: ``router`` (``kernel`` [D, E_all + Z], ``bias`` [E_all + Z]),
+    ``experts`` unless the stack and ``layer`` are given apart.  → ([T, D],
+    pairs: the experts held [E], of a share then those held elsewhere, then
+    the identity ones).  Every traced call leaves one ring-only
+    ``moe/serve_layout`` record."""
+    if experts is None:
+        experts = lp["experts"]
+    held = experts["gate"].shape[-3]
+    # trace time only: what a run says about the share it compiled
+    get_tracer().record(
+        "moe/serve_layout", time.perf_counter(), 0.0,
+        router_outputs=lp["router"]["kernel"].shape[-1], held=held,
+        offset=offset, identity_from=identity_from, k=k,
+        rows=whole_tiles(h.shape[0] * k, held))
+    with jax.named_scope("moe/route"):
+        idx, weights = softmax_bias_topk_route(h, lp["router"], k, scaling)
+    return dropless_experts(h, idx, weights, experts, valid=valid, impl=impl,
+                            layer=layer, offset=offset,
+                            identity_from=identity_from)

@@ -246,6 +246,71 @@ def _mla(decode):
     return build
 
 
+def _mla64(rows):
+    """LongCat-Flash's latent attention: the SAME 640-lane latent row as
+    Xing's, read by 64 heads (twice the query block and the float32
+    accumulators in VMEM), the cell's 8 page layers of ~6,400 blocks,
+    contexts to 4,224.  ``rows``: None the decode kernel on 128 sequences,
+    else the ragged kernel on a prefill bucket of that many tokens."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels import mla_ops
+
+        seqs, blocks, row, heads = 128, 4224 // PAGE, 640, 64
+        pool = _on(dev, (8 * 6400 + 1, PAGE, row))
+        lens = _on(dev, (seqs,), jnp.int32)
+        table = _on(dev, (seqs, blocks), jnp.int32)
+        kw = dict(rank=512, scale=192 ** -0.5)
+        if rows is None:
+            return (lambda q, p, n, t: mla_ops.mla_paged_decode(
+                q, p, n, t, **kw)), (_on(dev, (seqs, heads, row)), pool,
+                                     lens, table)
+        return (lambda q, p, n, t, cu: mla_ops.mla_ragged_prefill(
+            q, p, n, t, cu, **kw)), \
+            (_on(dev, (rows, heads, row)), pool, lens, table,
+             _on(dev, (seqs + 1,), jnp.int32))
+    return build
+
+
+def _longcat(decode):
+    """The benchmark's LongCat-Flash configuration (4 double layers = 8
+    latent page layers, published widths, 16 experts held of 512 + 256
+    identity outputs, an eighth of the vocabulary): a fused decode window of
+    128 sequences x 2 steps (two latent walks a scan step on one pool, the
+    768-way router, megablox over 1,536 pair rows of which 11 tiles are in
+    no group), or a 512-token SplitFuse step."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.longcat_flash import (LongCatFlashConfig,
+                                                        LongCatFlashLM)
+
+        cfg = LongCatFlashConfig(num_layers=4, vocab_size=16384,
+                                 experts_held=16)
+        model = LongCatFlashLM(cfg)
+        family = model.serving_family()
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda x: _on(dev, x.shape, jnp.float32 if x.dtype == jnp.float32
+                          else BF16), shapes)
+        seqs, blocks, nb = 128, 4224 // PAGE, 6400
+        pool = _on(dev, (family.page_layers * nb + 1, PAGE, cfg.latent_row))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            loop = build_decode_loop(family, max_q=seqs, block_size=PAGE,
+                                     steps=2, **kw)
+            meta = pack_layout(seqs, seqs, blocks)["_total"][0]
+            return loop, (params, pool, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(family, max_q=512, **kw)
+        meta = pack_layout(512, seqs, blocks)["_total"][0]
+        return step, (params, pool, _on(dev, (meta,), jnp.int32))
+    return build
+
+
 def _grouped_matmul(rows):
     """64 experts of width 1024 on hidden 3584: ``rows`` (token, choice)
     pairs sorted by expert (256 = a 64-wide decode step, 2048 = a 512-token
@@ -529,6 +594,14 @@ CASES = {
     # the decode-form convolution alone, at the two recurrent cells' shapes
     "gdn_conv_step[128 rows x 11520]": _gdn_conv_step(128, 11520),
     "gdn_conv_step[64 rows x 8192]": _gdn_conv_step(64, 8192),
+    # LongCat-Flash: the shared latent kernels at 64 heads (every prefill
+    # bucket's query tile, PR 34's lesson), and the double layer's programs
+    "mla_paged_decode[64 heads]": _mla64(None),
+    "mla_ragged_prefill[64 heads, 16 rows]": _mla64(16),
+    "mla_ragged_prefill[64 heads, 128 rows]": _mla64(128),
+    "mla_ragged_prefill[64 heads, 512 rows]": _mla64(512),
+    "longcat_decode_window": _longcat(decode=True),
+    "longcat_prefill_step": _longcat(decode=False),
 }
 
 

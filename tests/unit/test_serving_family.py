@@ -395,6 +395,80 @@ def test_a_family_with_state_refuses_what_a_state_cannot_do(name):
         == fam.num_layers - fam.page_layers
 
 
+class PairedLM(RenamedLM):
+    """``RenamedLM`` served two blocks a scan step: ONE layer body owns TWO
+    page layers (``page_layer_count`` > ``num_layers``) and takes both from
+    the handle it is given, ``cache.at``.  The dense forward is the
+    parent's."""
+
+    def serving_family(self) -> ServingFamily:
+        c = self.config
+        hd = c.width // c.heads
+        base = super().serving_family()
+
+        def pair(x, lp, l_idx, cache, ctx):
+            for i in (0, 1):
+                bp = jax.tree.map(lambda a: a[i], lp)
+                q, k, v = self._qkv(x, bp, *ctx)
+                o = cache.at(2 * l_idx + i)(
+                    q, k, v, scale=1.0 / math.sqrt(hd)).astype(x.dtype)
+                x = self._rest(x, o, bp)
+            return x
+
+        def stacks(params):
+            yield LayerStack(
+                jax.tree.map(lambda a: a.reshape((c.depth // 2, 2)
+                                                 + a.shape[1:]),
+                             params["blocks"]), range(c.depth // 2), pair)
+
+        return dataclasses.replace(base, num_layers=c.depth // 2,
+                                   stacks=stacks, page_layer_count=c.depth)
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_a_body_that_owns_two_page_layers_is_served(impl):
+    """Split prefill, put() steps, a fused window and a grafted prefix: the
+    pool holds ``page_layers`` = 2 x ``num_layers`` layers of blocks, both
+    appends of a step land in it, and what counts blocks (the graft's
+    copy-on-write, a shipment) walks every page layer."""
+    from deepspeed_tpu.inference.v2 import kv_ship
+
+    model = PairedLM(RenamedConfig(depth=4))
+    params = model.init_params(jax.random.PRNGKey(0))
+    fam = model.serving_family()
+    assert (fam.num_layers, fam.page_layers) == (2, 4)
+    eng = _engine(model, params, impl, max_tokens=16, prefix_cache=True)
+    assert eng.kv.pages.shape[0] == 4 * eng.kv.config.num_blocks + 1
+    rng = np.random.default_rng(0)
+    a, b = (rng.integers(1, 88, size=n).tolist() for n in (13, 9))
+
+    def same(got, seq):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(model(params, jnp.asarray(seq))[-1]),
+            atol=3e-4, rtol=3e-4)
+
+    eng.put([0], [a[:8]])
+    logits = eng.put([0, 1], [a[8:], b])        # chunks of two sequences
+    same(logits[0], a)
+    same(logits[1], b)
+    seeds = [int(jnp.argmax(logits[0])), int(jnp.argmax(logits[1]))]
+    window = eng.decode_batch([0, 1], seeds, 3)
+    for col, chain in enumerate((a + seeds[:1], b + seeds[1:])):
+        for tok in window[:, col].tolist():
+            assert tok == int(jnp.argmax(model(params, jnp.asarray(chain))[-1]))
+            chain.append(tok)
+    # a shipment carries every page layer's rows
+    ship = kv_ship.export_kv(eng, 0, a, n_tokens=12)
+    assert ship.rows.shape[:2] == (4, 12) and ship.num_layers == 4
+    # a re-asked prompt is grafted: one full page shared, the partial second
+    # copied in all four page layers before it is appended to
+    eng.commit_prefix(0, a, allow_partial=True)
+    eng.flush([0])
+    again = a + rng.integers(1, 88, size=5).tolist()
+    assert eng.graft_prefix(2, again) == 13
+    same(eng.put([2], [again[13:]])[0], again)
+
+
 def test_a_shipment_carries_the_models_heads_not_the_pools_padding():
     """A K/V-row family whose row kind stores more heads than the model has
     (``KVRow.tiled``: 3 heads in 4): the canonical rows of a shipment hold
