@@ -18,6 +18,20 @@ the v5e's ridge, so its roof is the HBM bandwidth.
 Both kernels follow ``ragged_ops.py`` (flat-token grid, in-kernel context
 walk, double-buffered page DMA steered by the scalar-prefetched page table);
 operands go to the MXU in the pool's dtype, accumulation is float32.
+
+Double-buffered page DMA: while a chunk of ``pages_per_chunk`` pages is
+scored out of one VMEM buffer, the next chunk's copies land in the other.
+The ragged kernel walks every sequence of a query block inside ONE grid
+step, so its prefetch crosses sequences by itself.  The decode kernel runs
+one grid step a sequence, and a step would begin with nothing in flight:
+so the walk's LAST compute has the next sequence's first chunk started
+behind it, into the buffer it has just left, and two SMEM words (``carry``:
+a flag and that buffer's index) tell the next grid step to wait on those
+copies and not to start its own.  A ``kv_lens == 0`` row is never handed a
+chunk and hands none on.  This rests on the grid running in order on one
+core (scratch and DMA semaphores persist from step to step), which is the
+default and what ``ragged_ops._decode_paged_kernel`` relies on too: the
+decode grid's axis is never ``parallel``.
 """
 from __future__ import annotations
 
@@ -44,43 +58,65 @@ def _dot_nt(a, b):
 # Decode: one query token a sequence
 # ===================================================================== #
 def _mla_decode_kernel(kvl_ref, pt_ref, q_ref, pages_ref, o_ref,
-                       bufs, sems, acc, m_scr, l_scr,
+                       bufs, sems, acc, m_scr, l_scr, carry,
                        *, scale, ps, P, NB, R):
     """One grid step = one decoding sequence: its H absorbed queries
-    against its latent pages, ``P`` pages a compute step."""
-    s = pl.program_id(0)
+    against its latent pages, ``P`` pages a compute step.
+
+    ``carry`` (two SMEM words) hands a sequence's FIRST chunk across grid
+    steps, as ``ragged_ops._decode_paged_kernel`` does: ``carry[0] == 1``
+    says the previous grid step already started THIS sequence's chunk 0,
+    into buffer ``carry[1]``, behind its own last compute; the walk then
+    starts in that buffer and waits on the very same copies (same page
+    ids, same buffer slot, same semaphores).  Grid step 0 clears it, every
+    step reads it and clears it.  A ``kv_lens == 0`` row (bucket padding)
+    starts nothing and is handed nothing: it writes zeros, and the row
+    after it fetches its own chunk 0.  Scratch and semaphores persist
+    over grid steps only because the grid runs IN ORDER on one core: never
+    mark its axis ``parallel``.
+    """
+    s, S = pl.program_id(0), pl.num_programs(0)
     kvl = kvl_ref[s]
     CH = P * ps
     nch = _cdiv(kvl, CH)
     H = q_ref.shape[1]
 
-    def page_needed(page_idx):
-        return page_idx * ps < kvl
+    def page_needed(seq, page_idx):
+        return page_idx * ps < kvl_ref[seq]
 
-    def chunk_dma(c, slot, p):
-        pid = pt_ref[s, jnp.minimum(c * P + p, NB - 1)]
+    def chunk_dma(seq, c, slot, p):
+        pid = pt_ref[seq, jnp.minimum(c * P + p, NB - 1)]
         return pltpu.make_async_copy(
             pages_ref.at[pid], bufs.at[slot, p], sems.at[slot, p])
 
-    def start_chunk(c, slot):
+    def start_chunk(seq, c, slot):
         for p in range(P):
-            @pl.when(page_needed(c * P + p))
+            @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(c, slot, p).start()
+                chunk_dma(seq, c, slot, p).start()
 
-    def wait_chunk(c, slot):
+    def wait_chunk(seq, c, slot):
         for p in range(P):
-            @pl.when(page_needed(c * P + p))
+            @pl.when(page_needed(seq, c * P + p))
             def _():
-                chunk_dma(c, slot, p).wait()
+                chunk_dma(seq, c, slot, p).wait()
 
     acc[:] = jnp.zeros_like(acc)
     m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
 
+    @pl.when(s == 0)
+    def _():
+        carry[0] = 0
+    fetched = carry[0] == 1
+    slot0 = jnp.where(fetched, carry[1], 0)
+    carry[0] = 0
+
     @pl.when(kvl > 0)
     def _walk():
-        start_chunk(0, 0)
+        @pl.when(jnp.logical_not(fetched))
+        def _():
+            start_chunk(s, 0, slot0)
 
         def compute(c, slot):
             k_pos = c * CH + jax.lax.broadcasted_iota(jnp.int32, (H, CH), 1)
@@ -110,14 +146,24 @@ def _mla_decode_kernel(kvl_ref, pt_ref, q_ref, pages_ref, o_ref,
 
             @pl.when(c + 1 < nch)
             def _prefetch():
-                start_chunk(c + 1, 1 - slot)
+                start_chunk(s, c + 1, 1 - slot)
 
-            wait_chunk(c, slot)
+            # behind this sequence's LAST compute: the next grid step's
+            # first chunk, into the buffer the walk has just left
+            nxt = jnp.minimum(s + 1, S - 1)
+
+            @pl.when((c + 1 == nch) & (s + 1 < S) & (kvl_ref[nxt] > 0))
+            def _next_seq():
+                start_chunk(nxt, 0, 1 - slot)
+                carry[0] = 1
+                carry[1] = 1 - slot
+
+            wait_chunk(s, c, slot)
             compute(c, slot)
             return c + 1, 1 - slot
 
         jax.lax.while_loop(lambda st: st[0] < nch, body,
-                           (jnp.int32(0), jnp.int32(0)))
+                           (jnp.int32(0), slot0))
 
     l = l_scr[:, :1]
     o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
@@ -152,6 +198,7 @@ def mla_paged_decode(q, pages, kv_lens, page_table, *, rank: int,
                 pltpu.VMEM((H, rank), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, H, rank), q.dtype),

@@ -246,27 +246,32 @@ def _mla(decode):
     return build
 
 
-def _mla64(rows):
+def _mla64(rows, lens=None):
     """LongCat-Flash's latent attention: the SAME 640-lane latent row as
     Xing's, read by 64 heads (twice the query block and the float32
     accumulators in VMEM), the cell's 8 page layers of ~6,400 blocks,
     contexts to 4,224.  ``rows``: None the decode kernel on 128 sequences,
-    else the ragged kernel on a prefill bucket of that many tokens."""
+    else the ragged kernel on a prefill bucket of that many tokens.
+    ``lens``: the decode kernel on a batch of these contexts, constants of
+    the program (a 0 between live rows: the row behind the empty one
+    starts its own first chunk, with the SMEM hand-over scratch)."""
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels import mla_ops
 
-        seqs, blocks, row, heads = 128, 4224 // PAGE, 640, 64
+        seqs = len(lens) if lens else 128
+        blocks, row, heads = 4224 // PAGE, 640, 64
         pool = _on(dev, (8 * 6400 + 1, PAGE, row))
-        lens = _on(dev, (seqs,), jnp.int32)
+        kv_lens = _on(dev, (seqs,), jnp.int32)
         table = _on(dev, (seqs, blocks), jnp.int32)
         kw = dict(rank=512, scale=192 ** -0.5)
         if rows is None:
             return (lambda q, p, n, t: mla_ops.mla_paged_decode(
-                q, p, n, t, **kw)), (_on(dev, (seqs, heads, row)), pool,
-                                     lens, table)
+                q, p, n if lens is None else jnp.asarray(lens, jnp.int32),
+                t, **kw)), (_on(dev, (seqs, heads, row)), pool, kv_lens,
+                            table)
         return (lambda q, p, n, t, cu: mla_ops.mla_ragged_prefill(
             q, p, n, t, cu, **kw)), \
-            (_on(dev, (rows, heads, row)), pool, lens, table,
+            (_on(dev, (rows, heads, row)), pool, kv_lens, table,
              _on(dev, (seqs + 1,), jnp.int32))
     return build
 
@@ -597,6 +602,8 @@ CASES = {
     # LongCat-Flash: the shared latent kernels at 64 heads (every prefill
     # bucket's query tile, PR 34's lesson), and the double layer's programs
     "mla_paged_decode[64 heads]": _mla64(None),
+    "mla_paged_decode[64 heads, middle row empty]":
+        _mla64(None, lens=[2130, 0, 577]),
     "mla_ragged_prefill[64 heads, 16 rows]": _mla64(16),
     "mla_ragged_prefill[64 heads, 128 rows]": _mla64(128),
     "mla_ragged_prefill[64 heads, 512 rows]": _mla64(512),
