@@ -134,6 +134,17 @@ class InferenceEngineV2:
             raise ValueError(
                 "host_tier_mb > 0 (the host KV tier, ragged/kv_swap.py) is "
                 "not supported with latent (MLA) pages: set host_tier_mb=0")
+        #: an index key beside every K/V row (learned sparse attention,
+        #: models/serving.IndexKey): the pool is the pair (rows, index keys)
+        self.index_kv = self.family.row.index
+        if self.index_kv is not None and c.host_tier_mb > 0:
+            # kv_swap parks K/V rows alone: a restored prefix would be
+            # scored against index keys of whatever held the block before
+            raise ValueError(
+                "host_tier_mb > 0 (the host KV tier, ragged/kv_swap.py) is "
+                "not supported with index keys beside the K/V rows (sparse "
+                "attention): the tier parks K/V rows without them — set "
+                "host_tier_mb=0")
         #: per-sequence recurrent state of some layers (a Gated DeltaNet
         #: family): a state can be restored only at the token it was saved
         #: at, so what re-reads, parks or ships cached tokens is refused
@@ -157,7 +168,8 @@ class InferenceEngineV2:
         self.kv = BlockedKVCache(KVCacheConfig(
             num_layers=self.family.page_layers, num_blocks=num_blocks,
             block_size=c.block_size, token_shape=self.family.row.token_shape,
-            dtype=c.dtype))
+            dtype=c.dtype,
+            index_dim=self.index_kv.dim if self.index_kv else 0))
         #: the state pool beside the page pool: a slot a live sequence
         #: (``max_seqs`` of them), handed out by the state manager
         self.state_pool = None
@@ -624,7 +636,9 @@ class InferenceEngineV2:
         src = jnp.asarray([src_block + layer * self._num_blocks
                            for layer in range(self.family.page_layers)])
         dst = src + (dst_block - src_block)
-        self.kv.update(self.kv.pages.at[dst].set(self.kv.pages[src]))
+        # (every array of the pool: a row's index key travels with it)
+        self.kv.update(jax.tree.map(lambda a: a.at[dst].set(a[src]),
+                                    self.kv.pages))
         if self.heat is not None:
             # the private copy inherits the shared page's heat — same
             # rows, same access history
@@ -783,6 +797,11 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 "verify_decode (speculative decoding) is not supported with "
                 "latent (MLA) pages: serve this model without a drafter")
+        if self.index_kv is not None:
+            raise NotImplementedError(
+                "verify_decode (speculative decoding) is not supported with "
+                "index keys beside the K/V rows: a verify window has no "
+                "sparse read (every candidate would need its own set)")
         if self.state_pool is not None:
             raise NotImplementedError(
                 "verify_decode (speculative decoding) is not supported with "
@@ -1116,6 +1135,7 @@ class InferenceEngineV2:
                               uids=list(uids), nonfinite_dev=nonfinite,
                               moe_pairs_dev=extra[0] if extra else None)
         window._state = self._decode_state
+        window._ctx_before = list(ctx_before)
         return window
 
     def _poison_kv(self, uid: int) -> None:
@@ -1143,7 +1163,9 @@ class InferenceEngineV2:
             return
         phys = [b + layer * self._num_blocks
                 for layer in range(self.family.page_layers) for b in own]
-        self.kv.update(self.kv.pages.at[jnp.asarray(phys)].set(jnp.nan))
+        phys = jnp.asarray(phys)
+        self.kv.update(jax.tree.map(lambda a: a.at[phys].set(jnp.nan),
+                                    self.kv.pages))
 
     @property
     def last_decode_roofline(self) -> Optional[Dict]:
@@ -1361,6 +1383,8 @@ class DecodeWindow:
         self.nonfinite: Optional[np.ndarray] = None
         self.duration_s: Optional[float] = None
         self._state: Optional[dict] = None
+        #: each row's cached tokens before the window (the launch sets it)
+        self._ctx_before: List[int] = []
 
     def tokens(self) -> np.ndarray:
         """Block for the generated tokens [steps, n_seqs]."""
@@ -1388,6 +1412,8 @@ class DecodeWindow:
             with _TRACER.span("engine/window_account") as asp:
                 if self.moe_pairs is not None:
                     self._account_moe(asp)
+                if self.engine.index_kv is not None:
+                    self._account_sparse(asp)
                 pool = self.engine.state_pool
                 if pool is not None:
                     used = pool.slots - self.engine.state_manager.free_slots
@@ -1431,6 +1457,22 @@ class DecodeWindow:
         if counts.identity:
             sp.set(moe_pairs_identity=identity,
                    moe_identity_pair_share=identity / max(expected, 1))
+
+    def _account_sparse(self, sp) -> None:
+        """What the window's queries scored and read, from the contexts the
+        host already knows (no device read): step ``i``'s query of a row
+        sees ``ctx_before + i + 1`` cached tokens, scores every one in each
+        page layer, and reads ``min(ctx, topk)`` rows of them."""
+        topk = self.engine.index_kv.topk
+        ctx = np.asarray(self._ctx_before, np.int64)[:, None] \
+            + np.arange(1, self.steps + 1)[None, :]
+        layers = self.engine.family.page_layers
+        scored = int(ctx.sum()) * layers
+        selected = int(np.minimum(ctx, topk).sum()) * layers
+        sp.set(sparse_tokens_scored=scored, sparse_tokens_selected=selected,
+               sparse_select_share=selected / max(scored, 1),
+               sparse_dense_queries=int((ctx <= topk).sum()),
+               sparse_queries=int(ctx.size))
 
     def nonfinite_uids(self) -> List[int]:
         """uids whose logits went non-finite during this window (drains

@@ -13,7 +13,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from . import gdn_ops, mla_ops
+from . import gdn_ops, mla_ops, sparse_ops
 from .ragged_ops import (_stored_heads, decode_attention, paged_kv_append,
                          ragged_paged_attention, verify_window_attention)
 
@@ -112,6 +112,16 @@ def page_ops(row, replicate=None) -> PageOps:
                 *a, block_q=min(block_q, 16), **kw, **k),
             verify=None,
             dense=partial(mla_ops.mla_attend_dense, **kw))
+    if row.index is not None:
+        # the pair (K/V pages, index-key pages): a body appends (k, v, ki)
+        # and attends (q, qi, w): score, select, read (kernels/sparse_ops)
+        kw = dict(num_kv_heads=row.num_kv_heads, index=row.index)
+        return PageOps(
+            append=partial(sparse_ops.indexed_append, replicate=replicate),
+            decode=partial(sparse_ops.sparse_decode_attention, **kw),
+            ragged=partial(sparse_ops.sparse_ragged_attention, **kw),
+            verify=None,
+            dense=partial(sparse_ops.sparse_attend_dense, **kw))
     # pages [ps, 2*stored, hd] (``row.stored`` heads, the model's unless the
     # row kind says otherwise: the operations read it off the pool): a body
     # appends (k, v) [T, KV, hd] and attends q [T, H, hd] → [T, H, hd]

@@ -66,6 +66,11 @@ def _refuse_latent(engine, what: str) -> None:
         raise NotImplementedError(
             f"{what} is not supported with latent (MLA) pages: the shipment "
             f"format holds K/V rows per head")
+    if getattr(engine, "index_kv", None) is not None:
+        raise NotImplementedError(
+            f"{what} is not supported with index keys beside the K/V rows "
+            f"(sparse attention): the shipment format holds K/V rows and "
+            f"not the keys the indexer scores")
     if getattr(engine, "state_pool", None) is not None:
         raise NotImplementedError(
             f"{what} is not supported with recurrent state: a shipment "

@@ -78,6 +78,12 @@ class _LayerCache:
     tile per decoding sequence; with ``verify_mode`` (mutually exclusive),
     the speculative-decoding seam — rows are short multi-token windows
     (seed + K draft candidates) — the kind's verify-window operation.
+
+    A row kind with an index key (``KVRow.index``): ``pages`` is the pair
+    (K/V array, index array), ``append`` takes the index key as a third
+    row, and ``attend`` takes, after ``q``, the indexer's queries and head
+    weights ``[T, ...]``; they ride with ``q`` as one tuple through the same
+    dispatch, and the kind's operations score, select and read.
     """
 
     def __init__(self, pages, layer, *, ops: PageOps, batch, attn_impl,
@@ -108,12 +114,16 @@ class _LayerCache:
         self.pages = self.ops.append(
             self.pages, *rows,
             _layer_pages(b["page_of_token"], self.layer, self.num_blocks,
-                         self.pages.shape[0] - 1), b["off_of_token"])
+                         jax.tree.leaves(self.pages)[0].shape[0] - 1),
+            b["off_of_token"])
 
-    def attend(self, q, **attn):
-        """q [T, H, d] → [T, H, d']."""
+    def attend(self, q, *index, **attn):
+        """q [T, H, d] → [T, H, d']; ``index``: what an indexed row kind's
+        queries bring beside ``q``, each ``[T, ...]``."""
         ops, pages, b, max_q = self.ops, self.pages, self.batch, self.max_q
         T = q.shape[0]
+        if index:
+            q = (q,) + index
         q_len, ctx_len = b["q_len"], b["ctx_len"]
         pt_l = b["block_table"] + self.layer * self.num_blocks     # [S, NB]
         if self.paged and self.verify_mode:
@@ -122,8 +132,9 @@ class _LayerCache:
         if self.paged and self.decode_mode:
             SW = min(q_len.shape[0], T)
             out = ops.decode(
-                q[:SW], pages, ctx_len[:SW], pt_l[:SW],
-                pages_per_chunk=self.tile["pages_per_chunk"], **attn)
+                jax.tree.map(lambda a: a[:SW], q), pages, ctx_len[:SW],
+                pt_l[:SW], pages_per_chunk=self.tile["pages_per_chunk"],
+                **attn)
             return jnp.pad(out, ((0, T - SW), (0, 0), (0, 0))) \
                 if T > SW else out
         if self.paged:
@@ -131,10 +142,11 @@ class _LayerCache:
                               **self.tile, **attn)
         q_idx = jnp.clip(b["q_offset"][:, None] + jnp.arange(max_q)[None, :],
                          0, T - 1)
-        q_seq = jnp.take(q, q_idx.reshape(-1), axis=0).reshape(
-            (-1, max_q) + q.shape[1:])                    # [S, mq, H, d]
+        q_seq = jax.tree.map(
+            lambda a: jnp.take(a, q_idx.reshape(-1), axis=0).reshape(
+                (-1, max_q) + a.shape[1:]), q)            # [S, mq, H, d]
         o_seq = ops.dense(q_seq, pages, pt_l, q_len, ctx_len,
-                          **attn).astype(q.dtype)
+                          **attn).astype(jax.tree.leaves(q)[0].dtype)
         within = jnp.clip(
             jnp.arange(T) - jnp.take(b["q_offset"], b["seq_of_token"]),
             0, max_q - 1)
@@ -199,7 +211,9 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
     if verify_mode and ops.verify is None:
         raise NotImplementedError(
             f"speculative verify windows are not supported with "
-            f"{type(family.row).__name__} pages")
+            f"{type(family.row).__name__} pages"
+            + (" that carry index keys (sparse attention)"
+               if family.row.index is not None else ""))
     state_pool = None
     if family.state is not None:
         if verify_mode:

@@ -11,6 +11,15 @@ heads' compute (see kernels/ragged_ops.py).  A latent pool
 (``KVCacheConfig.token_shape`` of one axis) keeps the same page arithmetic
 with pages of ``[block_size, width]``.
 
+A K/V row that carries an INDEX KEY (``KVCacheConfig.index_dim``: learned
+sparse attention, ``models/serving.IndexKey``) gets a second array under the
+same page ids, ``[total_pages, block_size // 2, 2 * index_dim]``: token ``t``
+of a page is row ``t % (block_size // 2)``, values ``(t // (block_size // 2))
+* index_dim`` onwards — two 64-value keys fill a row of 128 lanes, where one a
+row would be stored in twice its bytes.  ``pages`` is then the PAIR (K/V
+array, index array): what shares, frees or copies a block by id carries
+both.  A pool without index keys is the one array it always was.
+
 The FINAL page (index ``num_layers * num_blocks``) is a shared trash page
 that padded tokens write into, keeping the append a single dense scatter
 (no predication).
@@ -33,6 +42,8 @@ class KVCacheConfig:
     #: a token, no K/V pair and no heads; kernels/mla_ops.py)
     token_shape: tuple
     dtype: object = jnp.bfloat16
+    #: values of the index key a token holds beside its row (0: none)
+    index_dim: int = 0
 
     @property
     def total_pages(self) -> int:
@@ -52,9 +63,20 @@ class BlockedKVCache:
         c = config
         self.pages = jnp.zeros(
             (c.total_pages, c.block_size) + c.token_shape, c.dtype)
+        if c.index_dim:
+            if c.block_size % 2:
+                raise ValueError(
+                    f"index keys are stored two a row: block_size "
+                    f"{c.block_size} must be even")
+            self.pages = (self.pages, jnp.zeros(
+                (c.total_pages, c.block_size // 2, 2 * c.index_dim), c.dtype))
 
     def update(self, pages) -> None:
         self.pages = pages
 
+    def arrays(self) -> tuple:
+        """The pool's arrays: one, or (K/V rows, index keys)."""
+        return self.pages if isinstance(self.pages, tuple) else (self.pages,)
+
     def mem_bytes(self) -> int:
-        return self.pages.size * self.pages.dtype.itemsize
+        return sum(a.size * a.dtype.itemsize for a in self.arrays())

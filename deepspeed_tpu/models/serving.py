@@ -27,6 +27,12 @@ axis ``[T, ...]``:
 ``head(params, x, pick) -> logits``: the final norm, ``pick`` (the rows that
     need logits: each sequence's last, or all in a verify window), the head.
 
+A K/V row may carry an INDEX KEY beside it (``KVRow.index``,
+:class:`IndexKey`: learned sparse attention).  The body then appends
+``cache.append(k, v, k_index)`` and attends ``cache.attend(q, q_index,
+w_index, scale=...)``: the cache scores every cached index key of the
+query's sequence, keeps the ``topk`` best and attends to those rows only.
+
 A family whose layers (some of them) keep a per-sequence RECURRENT STATE
 instead of cached rows says so with ``state`` (:class:`GatedDeltaState`): the
 engine then holds a state pool beside the page pool, a sequence owns one slot
@@ -41,6 +47,24 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Any, Callable, Iterable, Optional, Tuple, Union
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexKey:
+    """What a cached token holds BESIDE its K/V row for a learned sparse-
+    attention indexer: one key of ``dim`` values.  A query brings ``heads``
+    index queries of ``dim`` and a weight a head; its score of cached token
+    ``s`` is ``sum_j w[j] * relu(q[j] . key[s])`` in float32, and it attends
+    to the ``topk`` cached tokens of largest score (all of them while there
+    are no more; ties to the lower position).  The keys live in a second
+    array of the page pool under the same block ids
+    (``ragged/kv_cache.py``), so what shares, frees or copies a block
+    carries both; what ships K/V rows WITHOUT them is refused by name:
+    ``kv_ship``, the host tier, speculative verify windows."""
+
+    dim: int
+    heads: int
+    topk: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +84,8 @@ class KVRow:
     num_kv_heads: int
     head_dim: int
     stored_kv_heads: Optional[int] = None
+    #: an index key beside the K/V row (None: attention is dense)
+    index: Optional[IndexKey] = None
     latent = False
 
     def __post_init__(self):
@@ -115,6 +141,7 @@ class LatentRow:
     dim: int
     rank: int
     latent = True
+    index = None
 
     @property
     def token_shape(self) -> Tuple[int, ...]:

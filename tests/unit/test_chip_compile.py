@@ -316,6 +316,48 @@ def _longcat(decode):
     return build
 
 
+def _keye(decode, bucket=512):
+    """The benchmark's Keye-VL configuration (6 layers, published widths, 16
+    experts held of 128, the whole vocabulary) on a pool of the cell's size
+    (K/V pages and index-key pages, ~850k tokens): a fused decode window of
+    20 sequences x 2 steps — every layer scores up to 66,688 index keys a
+    sequence, selects 2,048 and reads their rows, or takes the K/V decode
+    kernel (both are in the program: which runs follows the contexts) — or
+    a SplitFuse step of ``bucket`` tokens (the masked walk, or the ragged
+    K/V kernel)."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.keye_vl import KeyeVLConfig, KeyeVLLM
+
+        cfg = KeyeVLConfig(num_layers=6, experts_held=16)
+        model = KeyeVLLM(cfg)
+        family = model.serving_family()
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda x: _on(dev, x.shape, jnp.float32 if x.dtype == jnp.float32
+                          else BF16), shapes)
+        seqs, blocks, nb = 20, 66688 // PAGE, 13300
+        pages = family.page_layers * nb + 1
+        pool = (_on(dev, (pages, PAGE, 2 * cfg.num_kv_heads, cfg.head_dim)),
+                _on(dev, (pages, PAGE // 2, 2 * cfg.indexer_head_dim)))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            loop = build_decode_loop(family, max_q=seqs, block_size=PAGE,
+                                     steps=2, **kw)
+            meta = pack_layout(seqs, seqs, blocks)["_total"][0]
+            return loop, (params, pool, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(family, max_q=bucket, **kw)
+        meta = pack_layout(bucket, seqs, blocks)["_total"][0]
+        return step, (params, pool, _on(dev, (meta,), jnp.int32))
+    return build
+
+
 def _grouped_matmul(rows):
     """64 experts of width 1024 on hidden 3584: ``rows`` (token, choice)
     pairs sorted by expert (256 = a 64-wide decode step, 2048 = a 512-token
@@ -609,6 +651,9 @@ CASES = {
     "mla_ragged_prefill[64 heads, 512 rows]": _mla64(512),
     "longcat_decode_window": _longcat(decode=True),
     "longcat_prefill_step": _longcat(decode=False),
+    "keye_decode_window": _keye(decode=True),
+    "keye_prefill_step[512]": _keye(decode=False),
+    "keye_prefill_step[16]": _keye(decode=False, bucket=16),
 }
 
 

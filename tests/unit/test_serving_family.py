@@ -21,7 +21,8 @@ from deepspeed_tpu.inference.v2.engine_v2 import (
 from deepspeed_tpu.models.families import ArchConfig, UniversalCausalLM
 from deepspeed_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridLM
 from deepspeed_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
-from deepspeed_tpu.models.serving import KVRow, LayerStack, ServingFamily
+from deepspeed_tpu.models.serving import (IndexKey, KVRow, LayerStack,
+                                          ServingFamily)
 from deepspeed_tpu.models.transformer import (
     CausalLM,
     TransformerConfig,
@@ -467,6 +468,118 @@ def test_a_body_that_owns_two_page_layers_is_served(impl):
     again = a + rng.integers(1, 88, size=5).tolist()
     assert eng.graft_prefix(2, again) == 13
     same(eng.put([2], [again[13:]])[0], again)
+
+
+class IndexedLM(RenamedLM):
+    """``RenamedLM`` with a learned sparse-attention indexer written HERE: a
+    cached token also holds an index key (``KVRow.index``), a query scores
+    every cached key of its sequence and attends to the ``TOPK`` best.  The
+    body hands the indexer's queries and weights to the cache handle and
+    never sees a page table; the dense forward below masks to the set."""
+
+    TOPK, IH, ID = 6, 2, 8
+
+    def init_params(self, key, dtype=jnp.float32):
+        c = self.config
+        params = super().init_params(key, dtype)
+        ks = jax.random.split(jax.random.fold_in(key, 7), 3)
+        shapes = dict(iq=(c.width, self.IH * self.ID), ik=(c.width, self.ID),
+                      iw=(c.width, self.IH))
+        for k, (name, shape) in zip(ks, shapes.items()):
+            params["blocks"][name] = (jax.random.normal(
+                k, (c.depth,) + shape) / math.sqrt(c.width)).astype(dtype)
+        return params
+
+    def _index(self, x, bp):
+        h = rms_norm(x, bp["n1"], self.config.eps)
+        return ((h @ bp["iq"]).reshape(-1, self.IH, self.ID), h @ bp["ik"],
+                h @ bp["iw"])
+
+    def __call__(self, params, tokens):
+        c = self.config
+        S, hd = tokens.shape[0], c.width // c.heads
+        cos, sin = rope_at(jnp.arange(S), hd, c.theta)
+        causal = jnp.tril(jnp.ones((S, S), bool))
+        x = params["wte"][tokens]
+        for i in range(c.depth):
+            bp = jax.tree.map(lambda a: a[i], params["blocks"])
+            q, k, v = self._qkv(x, bp, cos, sin)
+            qi, ki, w = self._index(x, bp)
+            score = jnp.sum(w[..., None] * jax.nn.relu(
+                jnp.einsum("tjd,sd->tjs", qi, ki)), axis=1)
+            _, best = jax.lax.top_k(jnp.where(causal, score, -jnp.inf),
+                                    min(self.TOPK, S))
+            chosen = jnp.zeros((S, S), bool).at[
+                jnp.arange(S)[:, None], best].set(True) & causal
+            k = jnp.repeat(k, c.heads // c.kv_heads, axis=1)
+            v = jnp.repeat(v, c.heads // c.kv_heads, axis=1)
+            s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(hd)
+            s = jnp.where(chosen[None], s, -1e30)
+            o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+            x = self._rest(x, o, bp)
+        return rms_norm(x, params["nf"], c.eps) @ params["out"]
+
+    def serving_family(self) -> ServingFamily:
+        c = self.config
+        hd = c.width // c.heads
+        base = super().serving_family()
+
+        def layer(x, bp, l_idx, cache, ctx):
+            q, k, v = self._qkv(x, bp, *ctx)
+            qi, ki, w = self._index(x, bp)
+            cache.append(k, v, ki)
+            o = cache.attend(q, qi, w, scale=1.0 / math.sqrt(hd))
+            return self._rest(x, o.astype(x.dtype), bp)
+
+        def stacks(params):
+            yield LayerStack(params["blocks"], range(c.depth), layer)
+
+        return dataclasses.replace(
+            base, stacks=stacks,
+            row=KVRow(c.kv_heads, hd,
+                      index=IndexKey(self.ID, self.IH, self.TOPK)))
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_a_family_whose_row_carries_an_index_key_is_served(impl):
+    """The pool is the pair (K/V pages, index-key pages) under one set of
+    block ids; split prefill, a batch of chunks, a fused window and a
+    grafted prefix (its partial page copied in BOTH arrays) give the dense
+    forward's logits; what ships K/V rows without the keys is refused."""
+    from deepspeed_tpu.inference.v2 import kv_ship
+
+    model = IndexedLM(RenamedConfig(depth=2))
+    params = model.init_params(jax.random.PRNGKey(0))
+    eng = _engine(model, params, impl, max_tokens=16, prefix_cache=True)
+    kv, ix = eng.kv.pages
+    pages = 2 * eng.kv.config.num_blocks + 1
+    assert kv.shape == (pages, 8, 4, 8) and ix.shape == (pages, 4, 16)
+    assert eng.kv.mem_bytes() == (kv.size + ix.size) * 4
+    rng = np.random.default_rng(0)
+    a, b = (rng.integers(1, 88, size=n).tolist() for n in (19, 5))
+
+    def same(got, seq):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(model(params, jnp.asarray(seq))[-1]),
+            atol=3e-4, rtol=3e-4)
+
+    eng.put([0], [a[:8]])
+    logits = eng.put([0, 1], [a[8:], b])    # beyond TOPK, and within it
+    same(logits[0], a)
+    same(logits[1], b)
+    seeds = [int(jnp.argmax(logits[0])), int(jnp.argmax(logits[1]))]
+    window = eng.decode_batch([0, 1], seeds, 3)
+    for col, chain in enumerate((a + seeds[:1], b + seeds[1:])):
+        for tok in window[:, col].tolist():
+            assert tok == int(jnp.argmax(model(params, jnp.asarray(chain))[-1]))
+            chain.append(tok)
+    with pytest.raises(NotImplementedError, match="index keys"):
+        kv_ship.export_kv(eng, 0, a, n_tokens=12)
+    eng.commit_prefix(0, a, allow_partial=True)
+    eng.flush([0])
+    again = a + rng.integers(1, 88, size=5).tolist()
+    assert eng.graft_prefix(2, again) == 19
+    same(eng.put([2], [again[19:]])[0], again)
 
 
 def test_a_shipment_carries_the_models_heads_not_the_pools_padding():
