@@ -17,8 +17,15 @@ page of index keys is scored as it lies, against the block-diagonal ``[2·di,
 
 The four steps, each under its own name scope:
 
-``attention/index_score``  every cached index key of the sequence, a chunk of
-    pages a pass, bounded by the longest REAL context of the batch;
+``attention/index_score``  every cached index key of the sequence.  Decode,
+    on the chip: a Pallas walk (``index_score_paged``) over each sequence's
+    OWN pages by its page table — a page is one contiguous copy into VMEM,
+    a chunk of pages is scored while the next one lands, and only the
+    float32 scores are written.  A sequence's chunk of prefill queries, and
+    decode wherever the kernel does not serve (``_walk_serves``: off the
+    chip, or a pool whose rows do not tile): XLA's gather of a ``[S, pages]``
+    rectangle a pass (``_index_scores``), bounded by the longest REAL
+    context of the batch;
 ``attention/index_select`` the exact set.  ``lax.top_k`` of 2,048 from 66k
     sorts the row; here the ``topk``-th largest score is found by a radix
     select on the order-preserving unsigned image of the float32 scores
@@ -52,9 +59,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ....telemetry.trace import get_tracer
-from .ragged_ops import (decode_attention, paged_kv_append,
+from .mla_ops import _dot_nt
+from .ragged_ops import (_interpret, decode_attention, paged_kv_append,
                          ragged_paged_attention)
 
 _RADIX_BITS = 4         # bits settled a counting pass of the radix select
@@ -63,9 +73,11 @@ _SCORE_PAGES = 64       # pages of index keys scored a pass
 #: which axis of the score tile the cached tokens lie along, ``"lanes"`` or
 #: ``"rows"``, for decode batches and for a sequence's chunk of queries
 #: (read on the chip, PERF.md section 6, PR 46: a 20-wide decode call 877
-#: against 1,001 us, a 512-query chunk 8.5 against 4.4 ms)
+#: against 1,001 us, a 512-query chunk 8.5 against 4.4 ms; since PR 47 the
+#: chip's decode batches take ``index_score_paged``)
 _SCORE_FORM = {"decode": "lanes", "chunk": "rows"}
 _WALK_PAGES = 16        # pages of K/V rows a pass of the masked walk
+_KERNEL_PAGES = 32      # pages of index keys a chunk of the score kernel
 _MASKED = -1e30
 
 
@@ -160,6 +172,198 @@ def _index_scores(qi, w, ix, page_table, ctx_max):
     out = jnp.zeros((rows, _cdiv(n_pass * P * ps, _LANES) * _LANES),
                     jnp.float32)
     return lax.fori_loop(0, _cdiv(ctx_max, P * ps), one, out)
+
+
+# --------------------------------------------------------------------- #
+# Score, decode: a page walk (Pallas)
+# --------------------------------------------------------------------- #
+def _score_chunk(q, w, keys, Hi: int):
+    """``q [2·Hi, 2·di]`` (the transposed block-diagonal: row ``h·Hi + j`` is
+    head ``j`` against a row's key ``h``), ``w [2·Hi, 1]``, ``keys [rows,
+    2·di]`` → ``[2, rows]`` float32: ``I`` of each row's two keys, the rows
+    along the lanes.  The arithmetic of ``_index_scores``."""
+    t = jnp.maximum(_dot_nt(q, keys), 0.0) * w
+    return (jnp.sum(t[:Hi], axis=0, keepdims=True),
+            jnp.sum(t[Hi:], axis=0, keepdims=True))
+
+
+def _index_score_kernel(kvl_ref, pt_ref, q_ref, w_ref, ix_ref, o_ref,
+                        bufs, sems, rows, carry, *, ps, P, NB, Hi):
+    """One grid step = one decoding sequence: its indexer query against its
+    own pages of index keys, ``P`` pages a chunk, two chunks' buffers; the
+    walk and the ``carry`` of ``mla_ops._mla_decode_kernel`` (the first
+    chunk of the NEXT sequence is started behind this one's last compute;
+    the grid runs in order on one core: never ``parallel``).
+
+    The copies' issue bounds the walk (~35 ns a page for a start and a wait
+    behind their ``pl.when``), so a chunk that lies WHOLE below the context
+    — all but a sequence's last — starts its pages unguarded and waits
+    once: a buffer's copies signal one semaphore, and one descriptor of the
+    buffer's size waits for all of their bytes.
+
+    A page's rows hold tokens ``r`` and ``half + r``, so 128 rows (``G``
+    pages) score into two 128-lane rows of the output, a page's two halves
+    side by side.  The pages of a group are PLACED in the buffer so that no
+    lane moves further than ``half``: the group's first ``G/2`` pages (the
+    first output row) at even places, the others at odd ones; then the first
+    row is key 0 where it lies and key 1 rolled up by ``half``, the second
+    key 1 where it lies and key 0 rolled down."""
+    s, S = pl.program_id(0), pl.num_programs(0)
+    kvl = kvl_ref[s]
+    half = ps // 2
+    G = _LANES // half                  # pages a group: 128 rows of keys
+    CH = P * ps
+    R = CH // _LANES                    # output rows a chunk
+    nch = _cdiv(kvl, CH)
+
+    def place(p):
+        g, i = divmod(p, G)
+        return g * G + (2 * i if i < G // 2 else 2 * (i - G // 2) + 1)
+
+    def whole(seq, c):
+        return (c + 1) * CH <= kvl_ref[seq]
+
+    def page_dma(seq, c, slot, p):
+        pid = pt_ref[seq, jnp.minimum(c * P + p, NB - 1)]
+        return pltpu.make_async_copy(
+            ix_ref.at[pid], bufs.at[slot, place(p)], sems.at[slot])
+
+    def pages_below_context(seq, c, do):
+        for p in range(P):
+            pl.when((c * P + p) * ps < kvl_ref[seq])(partial(do, p))
+
+    def start_chunk(seq, c, slot):
+        def start(p):
+            page_dma(seq, c, slot, p).start()
+
+        @pl.when(whole(seq, c))
+        def _():
+            for p in range(P):
+                start(p)
+
+        @pl.when(jnp.logical_not(whole(seq, c)))
+        def _():
+            pages_below_context(seq, c, start)
+
+    def wait_chunk(seq, c, slot):
+        @pl.when(whole(seq, c))
+        def _():                        # the bytes of all P copies at once
+            pltpu.make_async_copy(ix_ref.at[pl.ds(0, P)], bufs.at[slot],
+                                  sems.at[slot]).wait()
+
+        @pl.when(jnp.logical_not(whole(seq, c)))
+        def _():
+            pages_below_context(
+                seq, c, lambda p: page_dma(seq, c, slot, p).wait())
+
+    o_ref[...] = jnp.zeros_like(o_ref)      # chunks past the context: zeros
+
+    @pl.when(s == 0)
+    def _():
+        carry[0] = 0
+    fetched = carry[0] == 1
+    slot0 = jnp.where(fetched, carry[1], 0)
+    carry[0] = 0
+
+    @pl.when(kvl > 0)
+    def _walk():
+        @pl.when(jnp.logical_not(fetched))
+        def _():
+            start_chunk(s, 0, slot0)
+
+        lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+        even = (lane // half) % 2 == 0
+        token = lax.broadcasted_iota(jnp.int32, (R, _LANES), 0) * _LANES \
+            + lax.broadcasted_iota(jnp.int32, (R, _LANES), 1)
+
+        def compute(c, slot):
+            keys = bufs[slot].reshape(P * half, bufs.shape[-1])
+            k0, k1 = _score_chunk(q_ref[0], w_ref[0], keys, Hi)
+            for g in range(P // G):
+                a = k0[:, g * _LANES:(g + 1) * _LANES]
+                b = k1[:, g * _LANES:(g + 1) * _LANES]
+                rows[2 * g:2 * g + 1] = jnp.where(
+                    even, a, pltpu.roll(b, half, 1))
+                rows[2 * g + 1:2 * g + 2] = jnp.where(
+                    even, pltpu.roll(a, _LANES - half, 1), b)
+            # pages past the context were never fetched, and a page's tail
+            # holds whatever the pool does: zeros
+            o_ref[0, pl.ds(pl.multiple_of(c * R, R), R), :] = jnp.where(
+                c * CH + token < kvl, rows[...], 0.0)
+
+        def body(state):
+            c, slot = state
+
+            @pl.when(c + 1 < nch)
+            def _prefetch():
+                start_chunk(s, c + 1, 1 - slot)
+
+            # behind this sequence's LAST compute: the next grid step's
+            # first chunk, into the buffer the walk has just left
+            nxt = jnp.minimum(s + 1, S - 1)
+
+            @pl.when((c + 1 == nch) & (s + 1 < S) & (kvl_ref[nxt] > 0))
+            def _next_seq():
+                start_chunk(nxt, 0, 1 - slot)
+                carry[0] = 1
+                carry[1] = 1 - slot
+
+            wait_chunk(s, c, slot)
+            compute(c, slot)
+            return c + 1, 1 - slot
+
+        lax.while_loop(lambda st: st[0] < nch, body, (jnp.int32(0), slot0))
+
+
+def _walk_serves(ix, Hi: int) -> bool:
+    """Whether the page walk is compiled for this pool: the chip, whole
+    groups of 128 rows, whole sublane tiles of heads."""
+    half = ix.shape[1]
+    return not _interpret() and _LANES % (2 * half) == 0 and Hi % 8 == 0 \
+        and ix.shape[2] % _LANES == 0
+
+
+def index_score_paged(qi, w, ix, ctx_len, page_table):
+    """``_index_scores``' decode form as a walk over each sequence's OWN
+    pages: ``qi [S, Hi, di]``, ``w [S, Hi]``, ``ix`` left in HBM, ``ctx_len
+    [S]`` (0: a padding row, no page visited), ``page_table [S, NB]`` → ``[S,
+    C]`` float32 in token order, ``C`` whole chunks; zeros from ``ctx_len``
+    on.  A chunk's pages are copied one ``make_async_copy`` each, the next
+    chunk in flight while one is scored; only the scores leave VMEM."""
+    S, Hi, _ = qi.shape
+    _, half, L = ix.shape
+    ps = 2 * half
+    NB = page_table.shape[1]
+    G = _LANES // half
+    P = G * max(1, _KERNEL_PAGES // G)
+    C = _cdiv(NB, P) * P * ps
+    q2, w2 = _query_blocks(qi, w, ix.dtype)
+    out = pl.pallas_call(
+        partial(_index_score_kernel, ps=ps, P=P, NB=NB, Hi=Hi),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, 2 * Hi, L), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, 2 * Hi, 1), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, C // _LANES, _LANES),
+                                   lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, P, half, L), ix.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((P * ps // _LANES, _LANES), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, C // _LANES, _LANES),
+                                       jnp.float32),
+        interpret=_interpret(),
+        name="index_score_paged",
+    )(ctx_len.astype(jnp.int32), page_table.astype(jnp.int32),
+      jnp.swapaxes(q2, 1, 2), w2[:, :, None], ix)
+    return out.reshape(S, C)
 
 
 # --------------------------------------------------------------------- #
@@ -268,7 +472,7 @@ def _compact(chosen, k: int):
 # --------------------------------------------------------------------- #
 # Decode: one query a sequence
 # --------------------------------------------------------------------- #
-def _layout_record(index, pools, select: str, read: str) -> None:
+def _layout_record(index, pools, score: str, select: str, read: str) -> None:
     """Trace time only: what the compiled sparse path is made of."""
     kv, ix = pools
     item = jnp.dtype(kv.dtype).itemsize
@@ -276,8 +480,8 @@ def _layout_record(index, pools, select: str, read: str) -> None:
         "attn/sparse_layout", time.perf_counter(), 0.0, topk=index.topk,
         index_heads=index.heads, index_dim=index.dim,
         index_row_bytes=index.dim * jnp.dtype(ix.dtype).itemsize,
-        kv_row_bytes=kv.shape[2] * kv.shape[3] * item, select=select,
-        read=read, page_size=kv.shape[1])
+        kv_row_bytes=kv.shape[2] * kv.shape[3] * item, score=score,
+        select=select, read=read, page_size=kv.shape[1])
 
 
 def _stored(kv, num_kv_heads):
@@ -291,7 +495,10 @@ def _decode_sparse(q, qi, w, kv, ix, ctx_len, page_table, *, scale,
     ps = kv.shape[1]
     stored, KV = _stored(kv, num_kv_heads)
     with jax.named_scope("attention/index_score"):
-        scores = _index_scores(qi, w, ix, page_table, jnp.max(ctx_len))
+        if _walk_serves(ix, qi.shape[1]):
+            scores = index_score_paged(qi, w, ix, ctx_len, page_table)
+        else:
+            scores = _index_scores(qi, w, ix, page_table, jnp.max(ctx_len))
     with jax.named_scope("attention/index_select"):
         C = scores.shape[-1]
         live = jnp.arange(C)[None, :] < ctx_len[:, None]
@@ -329,8 +536,9 @@ def sparse_decode_attention(qs, pools, ctx_len, page_table, *, index,
     kv, ix = pools
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    _layout_record(index, pools, select=f"radix{_RADIX_BITS}+onehot",
-                   read="xla_gather")
+    _layout_record(
+        index, pools, select=f"radix{_RADIX_BITS}+onehot", read="xla_gather",
+        score="pallas_walk" if _walk_serves(ix, qi.shape[1]) else "xla_gather")
     dense = partial(decode_attention, q, kv, ctx_len, page_table,
                     num_kv_heads=num_kv_heads, scale=scale,
                     pages_per_chunk=pages_per_chunk)
@@ -446,8 +654,8 @@ def sparse_ragged_attention(qs, pools, ctx_len, page_table, cu_q_lens, *,
     kv, ix = pools
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    _layout_record(index, pools, select=f"radix{_RADIX_BITS}+mask",
-                   read="masked_walk")
+    _layout_record(index, pools, score="xla_gather",
+                   select=f"radix{_RADIX_BITS}+mask", read="masked_walk")
     dense = partial(ragged_paged_attention, q, kv, ctx_len, page_table,
                     cu_q_lens, num_kv_heads=num_kv_heads, scale=scale,
                     block_q=block_q, pages_per_chunk=pages_per_chunk)

@@ -58,13 +58,14 @@ def for_the_chip(monkeypatch):
     from deepspeed_tpu.accelerator import real_accelerator
     from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
     from deepspeed_tpu.inference.v2.kernels import (gdn_ops, mla_ops,
-                                                    ragged_ops)
+                                                    ragged_ops, sparse_ops)
     from deepspeed_tpu.kernels import fused_collective_matmul as fcm
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.adam import fused_adam
     from deepspeed_tpu.ops.transformer import flash_attention as fa
 
-    for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, fused_adam):
+    for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, sparse_ops,
+                fused_adam):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     monkeypatch.setattr(fcm, "resolve_impl",
@@ -355,6 +356,22 @@ def _keye(decode, bucket=512):
         step = build_ragged_step(family, max_q=bucket, **kw)
         meta = pack_layout(bucket, seqs, blocks)["_total"][0]
         return step, (params, pool, _on(dev, (meta,), jnp.int32))
+    return build
+
+
+def _index_score(seqs=20, blocks=66688 // PAGE, nb=13300):
+    """The indexer's decode score alone at the Keye cell's shape: 16 index
+    heads of 64 against a sequence's own pages of index keys (two 64-value
+    keys a 128-lane row), the pool of the cell's six page layers."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels import sparse_ops
+
+        assert sparse_ops._walk_serves(
+            jax.ShapeDtypeStruct((1, PAGE // 2, 128), BF16), 16)
+        return sparse_ops.index_score_paged, (
+            _on(dev, (seqs, 16, 64)), _on(dev, (seqs, 16)),
+            _on(dev, (6 * nb + 1, PAGE // 2, 128)),
+            _on(dev, (seqs,), jnp.int32), _on(dev, (seqs, blocks), jnp.int32))
     return build
 
 
@@ -651,6 +668,7 @@ CASES = {
     "mla_ragged_prefill[64 heads, 512 rows]": _mla64(512),
     "longcat_decode_window": _longcat(decode=True),
     "longcat_prefill_step": _longcat(decode=False),
+    "index_score_paged[20 rows, 1042-page tables]": _index_score(),
     "keye_decode_window": _keye(decode=True),
     "keye_prefill_step[512]": _keye(decode=False),
     "keye_prefill_step[16]": _keye(decode=False, bucket=16),

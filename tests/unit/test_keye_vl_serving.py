@@ -167,6 +167,8 @@ def test_prefill_single_tokens_and_fused_windows(model, impl):
             and a["kv_row_bytes"] == 2 * 2 * 32 * 4 and a["page_size"] == 8
             for a in layouts)
         assert {a["read"] for a in layouts} == {"xla_gather", "masked_walk"}
+        # (off the chip the decode score is the XLA gather too)
+        assert {a["score"] for a in layouts} == {"xla_gather"}
     accounts = [r.attrs for r in records if r.name == "engine/window_account"]
     assert accounts
     for i, a in enumerate(accounts):
@@ -192,10 +194,28 @@ def test_each_piece_of_the_mathematics_is_noticed(model, got, mutation):
     assert rel_l2(logits, ref) > 20 * TOL, mutation
 
 
-def test_contexts_below_at_and_above_topk_in_one_batch(model):
+@pytest.fixture
+def score(request, monkeypatch):
+    """Which form of the decode score a test's programs are traced with:
+    ``pallas_walk`` steers ``sparse_ops._walk_serves`` on (the kernel then
+    runs in interpret mode here), in the test and not through an option."""
+    if request.param == "pallas_walk":
+        monkeypatch.setattr(sparse_ops, "_walk_serves", lambda ix, Hi: True)
+    return request.param
+
+
+both_scores = pytest.mark.parametrize(
+    "score", ["xla_gather", "pallas_walk"], indirect=True)
+
+
+@both_scores
+def test_contexts_below_at_and_above_topk_in_one_batch(model, score):
     """SplitFuse: chunks of sequences whose contexts end below, at and above
     ``topk``, and a decode row, in ONE flat batch; then one fused window of
     all of them."""
+    from deepspeed_tpu.telemetry.trace import get_tracer
+
+    before = len(get_tracer().records())
     a, b, c, d = (prompt_tokens(2, 41), prompt_tokens(3, TOPK + 1),
                   prompt_tokens(4, 10), prompt_tokens(5, 60))
     engine = engine_for(model, max_tokens=64)
@@ -210,6 +230,10 @@ def test_contexts_below_at_and_above_topk_in_one_batch(model):
                                           [a[40], b[TOPK], c[9]], 3))
     for row, seq in enumerate((a, b, c)):
         greedy_is_the_references(model, seq, toks[:, row])
+    decode = [r.attrs["score"] for r in get_tracer().records()[before:]
+              if r.name == "attn/sparse_layout"
+              and r.attrs["read"] == "xla_gather"]
+    assert decode and set(decode) == {score}
 
 
 def test_a_grafted_prefix_brings_its_index_keys(model):
@@ -340,6 +364,71 @@ def test_index_keys_are_appended_two_a_row():
 
 
 # --------------------------------------------------------------------- #
+# The decode score as a page walk
+# --------------------------------------------------------------------- #
+#: pages of 8 (4 rows of two keys): 32 pages fill 128 rows, a chunk is 32
+#: pages = 256 tokens; tables of 70 pages (two chunks and 6 pages)
+WALK = dict(ps=8, NB=70, chunk=256)
+WALK_CASES = {
+    # contexts of one batch, a row each
+    "a_padding_row_among_real_ones": [0, 300, 0, 41],
+    "under_one_page": [5, 1, 7, 8],
+    "exactly_a_chunk": [256, 512, 256, 3],
+    "a_chunk_and_one_token": [257, 513, 255, 249],
+    "the_longest_the_table_holds": [560, 559, 553, 2],
+    "every_row_is_padding": [0, 0, 0, 0],
+    "one_sequence": [400],
+    "a_grafted_prefix_shared_by_two": [300, 420, 130, 77],
+    "unvisited_pages_and_the_trash_page_hold_nan": [0, 300, 9, 256],
+}
+
+
+# (jitted once: the cases of one batch width share a trace of the kernel)
+_walk_scores = jax.jit(sparse_ops.index_score_paged)
+_gather_scores = jax.jit(sparse_ops._index_scores)
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_the_page_walk_scores_as_the_gather(case):
+    """``index_score_paged`` (interpret mode) against ``_index_scores``:
+    page ids out of order, a table that is no multiple of the chunk; the
+    scores equal to float32 rounding wherever a token is cached, zero from
+    there on, and the ``_select`` sets equal."""
+    ps, NB = WALK["ps"], WALK["NB"]
+    ctx = np.asarray(WALK_CASES[case], np.int32)
+    S, Hi, di, half = len(ctx), 4, 16, ps // 2
+    rng = np.random.default_rng(31)
+    pages = S * NB + 1                      # the last: the trash page
+    table = rng.permutation(S * NB).reshape(S, NB).astype(np.int32)
+    if case == "a_grafted_prefix_shared_by_two":
+        table[1, :16] = table[0, :16]       # 128 tokens under the same ids
+        table[3, :9] = table[0, :9]
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    ix = jax.random.normal(keys[0], (pages, half, 2 * di))
+    qi = jax.random.normal(keys[1], (S, Hi, di))
+    w = jax.random.normal(keys[2], (S, Hi))
+    want = np.asarray(_gather_scores(
+        qi, w, ix, jnp.asarray(table), jnp.max(jnp.asarray(ctx))))
+    if case == "unvisited_pages_and_the_trash_page_hold_nan":
+        used = np.zeros(pages, bool)
+        for s, n in enumerate(ctx):
+            used[table[s, :-(-int(n) // ps)]] = True
+        ix = jnp.where(jnp.asarray(used)[:, None, None], ix, jnp.nan)
+    got = np.asarray(_walk_scores(
+        qi, w, ix, jnp.asarray(ctx), jnp.asarray(table)))
+    C = got.shape[1]
+    assert C % 128 == 0 and NB * ps <= C < NB * ps + WALK["chunk"]
+    live = np.arange(C)[None, :] < ctx[:, None]
+    assert (got[~live] == 0).all()
+    np.testing.assert_allclose(got[live], want[:, :C][live], rtol=1e-6,
+                               atol=1e-6)
+    sets = [np.asarray(sparse_ops._select(sparse_ops._ordered(
+        jnp.asarray(x), jnp.asarray(live)), TOPK)) for x in (got, want[:, :C])]
+    assert (sets[0] == sets[1]).all()
+    assert (sets[0].sum(-1) == np.minimum(ctx, TOPK)).all()
+
+
+# --------------------------------------------------------------------- #
 # The model's own forward: M-RoPE sections, the shares
 # --------------------------------------------------------------------- #
 def _unequal_streams(n, seed=12):
@@ -388,10 +477,12 @@ def test_the_shares_add_up():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_long_tables_take_every_width_and_several_passes():
+@both_scores
+def test_long_tables_take_every_width_and_several_passes(score):
     """The operations alone over tables longer than a scoring pass (200 pages
     of 8, three sequences whose contexts fall into the three widths of the
-    prefill form): the ragged form and the decode form against the oracle
+    prefill form): the ragged form and the decode form (its score by the XLA
+    gather, and by the page walk: 7 chunks of 32 pages) against the oracle
     (the padded context, ``lax.top_k``)."""
     from deepspeed_tpu.models.serving import IndexKey
 

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """The sparse-attention path of ``kernels/sparse_ops.py`` alone on the chip at
-the Keye cell's shapes, step by step: score, select (threshold, then the
-positions), read, attend; the whole decode call; a prefill chunk; the in-place
-append.
+the Keye cell's shapes, step by step: score (XLA's gather form, the page walk
+with its body left out, the walk), select (threshold, then the positions),
+read, attend; the whole decode call; a prefill chunk; the in-place append.
 
     chiprun --chips 1 -- python3 tools/sparse_decode_split.py
     python3 tools/sparse_decode_split.py --cpu-rehearsal     # toy sizes
@@ -10,8 +10,12 @@ append.
 
 A decode step's call (``--seqs`` sequences of log-uniform 16k-64k tokens, 32
 query / 4 K/V heads of 128, 16 index heads of 64, ``topk`` 2,048, 64-token
-pages, a pool of ``--layers`` page layers) runs ``--iters`` times; every
-form is one jitted function of the same pools.  Bytes a form must move
+pages, a pool of ``--layers`` page layers, random page ids) runs ``--iters``
+times; every form is one jitted function of the same pools.  ``score`` is
+``_index_scores`` (what the chip ran until PR 47 and the CPU still does),
+``score_kernel`` is ``index_score_paged``, ``score_copies`` the same walk
+with every copy and no arithmetic (what a one-page descriptor costs);
+``whole`` is ``_decode_sparse`` as the chip runs it.  Bytes a form must move
 (``lib/flops_sparse``) over its time is its share of the HBM roof.  One JSON
 line a form.
 """
@@ -35,7 +39,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--forms", default="")
     ap.add_argument("--score-pages", type=int, default=0)
-    ap.add_argument("--score-form", default="")
+    ap.add_argument("--kernel-pages", type=int, default=0)
     ap.add_argument("--radix-bits", type=int, default=0)
     args = ap.parse_args()
     if args.cpu_rehearsal or args.compile_only:
@@ -51,8 +55,8 @@ def main():
 
     if args.score_pages:
         so._SCORE_PAGES = args.score_pages
-    if args.score_form:
-        so._SCORE_FORM = dict.fromkeys(so._SCORE_FORM, args.score_form)
+    if args.kernel_pages:
+        so._KERNEL_PAGES = args.kernel_pages
     if args.radix_bits:
         so._RADIX_BITS = args.radix_bits
     toy = args.cpu_rehearsal
@@ -87,6 +91,21 @@ def main():
     def score(a):
         return so._index_scores(a["qi"], a["w"], a["ix"], a["table"],
                                 jnp.max(a["ctx"]))
+
+    def score_kernel(a):
+        return so.index_score_paged(a["qi"], a["w"], a["ix"], a["ctx"],
+                                    a["table"])
+
+    def score_copies(a):
+        """The walk with its body left out: every copy, no arithmetic."""
+        def no_body(q, w, keys, Hi):
+            zero = jnp.zeros((1, keys.shape[0]), jnp.float32)
+            return zero, zero
+        body, so._score_chunk = so._score_chunk, no_body
+        try:
+            return score_kernel(a)
+        finally:
+            so._score_chunk = body
 
     def ordered(a):
         sc = score(a)
@@ -147,8 +166,9 @@ def main():
     big = int(ctx.max())
     mid = int(np.sort(ctx)[S // 2])
     forms = {
-        "score": score, "ordered": ordered, "threshold": threshold,
-        "select": select, "compact": compact, "read": read, "whole": whole,
+        "score": score, "score_copies": score_copies,
+        "score_kernel": score_kernel, "ordered": ordered,
+        "threshold": threshold, "select": select, "compact": compact, "read": read, "whole": whole,
         "topk_sort": topk_sort,
         "prefill_score@mid": lambda a: prefill_score(a, mid),
         "prefill_select@mid": lambda a: prefill_select(a, mid),
@@ -161,10 +181,13 @@ def main():
 
     total_ctx = int(ctx.sum())
     sel = int(np.minimum(ctx, topk).sum())
-    need = {"score": total_ctx * di * 2, "read": sel * 2 * KV * hd * 2,
-            "whole": total_ctx * di * 2 + sel * 2 * KV * hd * 2}
+    need = dict.fromkeys(("score", "score_copies", "score_kernel"),
+                         total_ctx * di * 2)
+    need.update(read=sel * 2 * KV * hd * 2,
+                whole=total_ctx * di * 2 + sel * 2 * KV * hd * 2)
 
     if args.compile_only:
+        so._interpret = lambda: False       # the chip's branch, its kernel
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
 
@@ -199,6 +222,16 @@ def main():
         print(json.dumps({"compact_equals_topk_sort": bool(
             (np.asarray(pos) == best).all()),
             "count_min": int(np.asarray(count).min())}), flush=True)
+    if "score" in forms and "score_kernel" in forms:
+        want = np.asarray(jax.jit(score)(arrays))
+        got = np.asarray(jax.jit(score_kernel)(arrays))
+        live = np.arange(got.shape[1])[None, :] < ctx[:, None]
+        print(json.dumps({"score_kernel_max_abs_diff": float(np.abs(np.where(
+            live, got - want[:, :got.shape[1]], 0)).max()),
+            "score_max_abs": float(np.abs(np.where(
+                live, want[:, :got.shape[1]], 0)).max()),
+            "beyond_ctx_all_zero": bool((got[~live] == 0).all())}),
+            flush=True)
     for name, fn in forms.items():
         if name == "append_loop":
             compiled = jax.jit(fn, donate_argnums=0).lower(arrays).compile()
