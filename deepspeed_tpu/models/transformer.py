@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..runtime.topology import (DATA, DATA_OUTER, EXPERT, SEQ, TENSOR,
@@ -52,10 +53,15 @@ class TransformerConfig:
     #: bias on q/k/v projections (qwen2-family); o_proj stays bias-free
     attn_bias: bool = False
     remat: bool = False
-    #: jax.checkpoint_policies name: "nothing_saveable" = full recompute
-    #: (min memory); "dots_with_no_batch_dims_saveable" keeps matmul outputs
-    #: (≈no recompute flops — the MFU-vs-memory dial)
-    remat_policy: str = "nothing_saveable"
+    #: what a checkpointed layer keeps for its backward pass.  "auto": the
+    #: named outputs of its kernels and matmuls, as many as the device's
+    #: memory holds beside the engine's state and the step's transients
+    #: (``_remat_layout``; with no memory report, or outside an engine's
+    #: step, none: full recompute).  A
+    #: jax.checkpoint_policies name overrides it: "nothing_saveable" = full
+    #: recompute (min memory); "dots_with_no_batch_dims_saveable" keeps
+    #: matmul outputs whatever they take
+    remat_policy: str = "auto"
     use_flash: bool = True          # pallas flash attention on TPU
     attn_impl: str = "auto"         # auto | flash | xla | ring | ulysses
     #: flash kernel tile sizes.  The defaults have no valid on-chip
@@ -272,15 +278,21 @@ def _xla_attention(q, k, v, causal=True, seq_offset=0):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def attention(q, k, v, cfg: TransformerConfig, causal=True):
-    """Dispatch to the Pallas flash kernel on TPU, XLA math elsewhere."""
+def _attn_impl(cfg: TransformerConfig, seq_len: int) -> str:
+    """``cfg.attn_impl`` with "auto" resolved: flash on a TPU from 128
+    tokens up, XLA math elsewhere."""
     impl = cfg.attn_impl
     if impl == "auto":
         from ..accelerator import get_accelerator
 
         impl = "flash" if (cfg.use_flash and get_accelerator().supports_pallas()
-                           and q.shape[1] >= 128) else "xla"
-    if impl == "flash":
+                           and seq_len >= 128) else "xla"
+    return impl
+
+
+def attention(q, k, v, cfg: TransformerConfig, causal=True):
+    """Dispatch to the Pallas flash kernel on TPU, XLA math elsewhere."""
+    if _attn_impl(cfg, q.shape[1]) == "flash":
         from ..ops.transformer.flash_attention import flash_attention
 
         # the kernel clamps blocks to the (128-aligned) sequence itself —
@@ -311,6 +323,58 @@ def _norm_matmul(x, scale, w, eps):
 # --------------------------------------------------------------------- #
 def _activation_spec():
     return P(_BATCH_AXES, SEQ, None)
+
+
+def _remat_layout(cfg: TransformerConfig, batch: int, seq_len: int,
+                  itemsize: int):
+    """What ``checkpointing.layer_policy`` chooses from, for ONE device, from
+    the trace's shapes: the layer's named values (bytes held a layer, FLOPs
+    its backward spends making them again) and the bytes the step needs
+    beside them — the head's float32 ``[rows, vocab]`` logits, log-softmax
+    and cotangent, or one layer's backward pass (every named value made
+    again, and a cotangent for each), whichever is more: the two never live
+    together.  Rows are a device's share over the batch and sequence axes;
+    a tensor axis, which would divide the widths, is left out (it saves
+    less than it could there)."""
+    from ..ops.transformer.flash_attention import (F32_DOT_PASSES, LSE_NAME,
+                                                   OUT_NAME)
+    from ..runtime import topology as _topo
+    from ..runtime.activation_checkpointing.checkpointing import Saveable
+
+    topo = _topo._TOPOLOGY
+    shards = 1
+    if topo is not None:
+        over_batch = math.prod(topo.dims[a] for a in _BATCH_AXES)
+        shards = (over_batch if batch % over_batch == 0 else 1) * (
+            topo.dims[SEQ] if seq_len % topo.dims[SEQ] == 0 else 1)
+    rows = batch * seq_len // shards
+    D, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    q_w, kv_w = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+
+    def matmul(name, width, contract=D):
+        return Saveable((name,), rows * width * itemsize,
+                        2.0 * rows * contract * width)
+
+    tensors = []
+    if cfg.num_experts == 1:    # the expert block names nothing (ROADMAP S5)
+        tensors += [matmul("gate_proj", F), matmul("up_proj", F)]
+    if _attn_impl(cfg, seq_len) == "flash":
+        # causal: half of the two [S, S, hd] products a head, in float32
+        tensors.append(Saveable(
+            (OUT_NAME, LSE_NAME), rows * q_w * itemsize + rows * cfg.num_heads * 4,
+            F32_DOT_PASSES * 2.0 * rows * seq_len * q_w))
+    tensors += [matmul("q_proj", q_w), matmul("k_proj", kv_w),
+                matmul("v_proj", kv_w), matmul("attn_residual", D, q_w)]
+    head = 3 * rows * V * 4
+    layer_backward = 2 * sum(t.bytes for t in tensors)
+    if cfg.num_experts > 1:     # the experts' unnamed gate and up rows
+        layer_backward += 4 * rows * cfg.moe_top_k * F * itemsize
+    if topo is not None and topo.mesh.size > 1:
+        # a sharded (ZeRO-3) layer is gathered whole, and its gradient is
+        # whole before it is scattered
+        layer_backward += 2 * itemsize * (
+            D * (q_w + 2 * kv_w) + q_w * D + cfg.num_experts * 3 * D * F)
+    return tensors, max(head, layer_backward)
 
 
 def _constrain(x, spec):
@@ -351,48 +415,55 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             # fused path: h is the UN-normalized residual; the norm is
             # folded into the gate/up projection kernels (down has no
             # norm in front and stays a plain matmul)
-            gate = jax.nn.silu(_norm_matmul(
-                h, fused_scale, lp["gate_proj"]["kernel"], cfg.norm_eps))
+            gate = _norm_matmul(h, fused_scale, lp["gate_proj"]["kernel"],
+                                cfg.norm_eps)
             up = _norm_matmul(h, fused_scale, lp["up_proj"]["kernel"],
                               cfg.norm_eps)
         else:
-            gate = jax.nn.silu(h @ lp["gate_proj"]["kernel"])
+            gate = h @ lp["gate_proj"]["kernel"]
             up = h @ lp["up_proj"]["kernel"]
+        gate = jax.nn.silu(checkpoint_name(gate, "gate_proj"))
+        up = checkpoint_name(up, "up_proj")
         return (gate * up) @ lp["down_proj"]["kernel"], jnp.zeros((), jnp.float32)
 
-    def proj(h, p, B, n_heads):
+    def proj(h, p, B, n_heads, name):
         y = h @ p["kernel"]
         if "bias" in p:
             y = y + p["bias"]
-        return y.reshape(B, S, n_heads, cfg.head_dim)
+        return checkpoint_name(y.reshape(B, S, n_heads, cfg.head_dim), name)
 
     fused_norm = _fused_rmsnorm_active(cfg)
 
-    def norm_proj(x, norm_scale, p, B, n_heads):
+    def norm_proj(x, norm_scale, p, B, n_heads, name):
         """rms_norm folded into the projection kernel (the fused path's
         per-tile recompute of the norm is free VPU work; the normalized
         activations never hit HBM)."""
         y = _norm_matmul(x, norm_scale, p["kernel"], cfg.norm_eps)
         if "bias" in p:
             y = y + p["bias"]
-        return y.reshape(B, S, n_heads, cfg.head_dim)
+        return checkpoint_name(y.reshape(B, S, n_heads, cfg.head_dim), name)
 
+    # Under ``jax.checkpoint`` the policy selects by these names what a
+    # layer keeps for its backward pass (``_remat_layout``): the
+    # projections before RoPE and the GQA repeat, the flash kernel's own
+    # (ops/transformer/flash_attention.py), the residual stream.  Anywhere
+    # else a name is the identity.
     def layer(carry, lp):
-        from jax.ad_checkpoint import checkpoint_name
-
         x, aux = carry
         B = x.shape[0]
         with jax.named_scope("attention"):
             if fused_norm:
                 ns = lp["attn_norm"]["scale"]
-                q = norm_proj(x, ns, lp["q_proj"], B, cfg.num_heads)
-                k = norm_proj(x, ns, lp["k_proj"], B, cfg.num_kv_heads)
-                v = norm_proj(x, ns, lp["v_proj"], B, cfg.num_kv_heads)
+                q = norm_proj(x, ns, lp["q_proj"], B, cfg.num_heads, "q_proj")
+                k = norm_proj(x, ns, lp["k_proj"], B, cfg.num_kv_heads,
+                              "k_proj")
+                v = norm_proj(x, ns, lp["v_proj"], B, cfg.num_kv_heads,
+                              "v_proj")
             else:
                 h = rms_norm(x, lp["attn_norm"]["scale"], cfg.norm_eps)
-                q = proj(h, lp["q_proj"], B, cfg.num_heads)
-                k = proj(h, lp["k_proj"], B, cfg.num_kv_heads)
-                v = proj(h, lp["v_proj"], B, cfg.num_kv_heads)
+                q = proj(h, lp["q_proj"], B, cfg.num_heads, "q_proj")
+                k = proj(h, lp["k_proj"], B, cfg.num_kv_heads, "k_proj")
+                v = proj(h, lp["v_proj"], B, cfg.num_kv_heads, "v_proj")
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
             o = attention(q, k, v, cfg, causal=True)
@@ -425,11 +496,15 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             # cpu_checkpointing) overrides the model's own remat policy —
             # the config toggle must change execution
             policy = ac.get_policy()
+        elif cfg.remat_policy == "auto":
+            policy = ac.layer_policy(
+                *_remat_layout(cfg, *tokens.shape, jnp.dtype(dtype).itemsize),
+                layers=cfg.num_layers)
         else:
             policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
             if not callable(policy):
-                valid = [n for n in dir(jax.checkpoint_policies)
-                         if not n.startswith("_")]
+                valid = ["auto"] + [n for n in dir(jax.checkpoint_policies)
+                                    if not n.startswith("_")]
                 raise ValueError(
                     f"remat_policy={cfg.remat_policy!r} is not a "
                     f"jax.checkpoint_policies member; valid: {valid}")

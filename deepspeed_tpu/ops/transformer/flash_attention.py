@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -314,8 +315,22 @@ def _flash_bhsd(q, k, v, scale, causal, block_q, block_k):
     return out
 
 
+#: ``checkpoint_name`` tags of the forward kernel's two outputs.  A
+#: ``jax.checkpoint`` policy that saves both leaves its backward pass no use
+#: for the forward ``pallas_call``; outside a checkpoint a name is the identity
+OUT_NAME, LSE_NAME = "flash_out", "flash_lse"
+#: bf16 passes of the MXU that one product of the kernels' float32 operands
+#: takes at the least (the casts in ``_fwd_kernel``; ROADMAP S4): what a
+#: caller multiplies this kernel's FLOPs by to weigh them against a bf16
+#: matmul's.  On the v5e the forward call reaches 17% of the bf16 peak
+#: where the fused matmul kernel reaches 73% (PERF.md section 6, PR 48).
+F32_DOT_PASSES = 3
+
+
 def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k):
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k)
+    out = checkpoint_name(out, OUT_NAME)
+    lse = checkpoint_name(lse, LSE_NAME)
     return out, (q, k, v, out, lse)
 
 

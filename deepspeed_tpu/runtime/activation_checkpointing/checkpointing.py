@@ -18,14 +18,24 @@ On TPU every reference feature maps onto a ``jax.checkpoint`` policy:
   ``CudaRNGStatesTracker``              functional PRNG keys — dropout keys are
                                         split per call, replayed exactly under
                                         remat (no tracker needed)
+  (none: the default since PR 48)       a checkpointed layer SAVES the named
+                                        outputs of its kernels and matmuls, as
+                                        many as the device's free memory holds
+                                        (:func:`select_saved`,
+                                        :func:`layer_policy`); with no memory
+                                        report, or outside an engine's step:
+                                        ``nothing_saveable``, full recompute
   ====================================  =======================================
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import contextlib
+import time
+from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 
+from ...telemetry.trace import get_tracer
 from ...utils.logging import logger
 
 _CONFIG = {
@@ -68,6 +78,74 @@ def is_configured() -> bool:
 #: per-layer residual streams — the values the save/offload policies below
 #: select by name (models/transformer.py layer()).
 RESIDUAL_NAMES = ("attn_residual", "mlp_residual")
+
+
+class Saveable(NamedTuple):
+    """Named values of one checkpointed layer that pay off only together
+    (a kernel's output and its row statistics), the bytes a device holds
+    for them a layer, and the FLOPs the backward spends making them again
+    when they are not saved."""
+    names: Tuple[str, ...]
+    bytes: int
+    flops: float
+
+
+def select_saved(tensors: Sequence[Saveable], layers: int,
+                 budget_bytes: int) -> Tuple[str, ...]:
+    """The names a checkpointed layer saves under ``budget_bytes`` for all
+    ``layers``: the entries taken by FLOPs a byte (a tie keeps the order
+    given) up to the first that no longer fits — all of them where all
+    fit, ``()`` where the best does not, which is ``nothing_saveable``."""
+    saved, left = [], budget_bytes
+    for t in sorted(tensors, key=lambda t: -t.flops / max(t.bytes, 1)):
+        left -= layers * t.bytes
+        if left < 0:
+            break
+        saved.extend(t.names)
+    return tuple(saved)
+
+
+#: (the device's ``bytes_limit``, the engine's state on it) while an
+#: engine traces its model's loss; ``None`` anywhere else
+_ENGINE_MEMORY: Optional[Tuple[int, int]] = None
+
+
+@contextlib.contextmanager
+def engine_memory(limit_bytes: int, state_bytes: int):
+    """Trace-time scope the engine opens around its model's loss: what one
+    device's memory holds at most (0 where the backend reports none) and
+    what the engine itself keeps there through a step.  Both are fixed by
+    shapes and shardings, so a model's saved set is the same on every run."""
+    global _ENGINE_MEMORY
+    outer, _ENGINE_MEMORY = _ENGINE_MEMORY, (limit_bytes, state_bytes)
+    try:
+        yield
+    finally:
+        _ENGINE_MEMORY = outer
+
+
+def layer_policy(tensors: Sequence[Saveable], reserve_bytes: int,
+                 layers: int):
+    """The ``jax.checkpoint`` policy of a model's layer whose named values
+    are ``tensors``: save what fits in the device's memory less the
+    engine's state less ``reserve_bytes`` (the step's transients, from the
+    caller's shapes).  A saved value is held once a layer and once more:
+    the backward pass slices the layer it differentiates out of the
+    ``[layers, ...]`` stacks into a copy (the TPU compiler's own account,
+    PERF.md section 6, PR 48).  Leaves one ``train/remat_layout`` record a
+    trace."""
+    limit, state = _ENGINE_MEMORY or (0, 0)
+    budget = max(0, limit - state - reserve_bytes)
+    saved = select_saved(tensors, layers + 1, budget)
+    get_tracer().record(
+        "train/remat_layout", time.perf_counter(), 0.0, saved=saved,
+        bytes_per_layer=sum(t.bytes for t in tensors
+                            if set(t.names) <= set(saved)),
+        layers=layers, budget_bytes=budget, state_bytes=state,
+        reserve_bytes=reserve_bytes)
+    if not saved:
+        return jax.checkpoint_policies.nothing_saveable
+    return jax.checkpoint_policies.save_only_these_names(*saved)
 
 
 def active() -> bool:

@@ -24,6 +24,7 @@ forward entry, grads accumulated in fp32.
 """
 from __future__ import annotations
 
+import math
 import os
 import time
 import weakref
@@ -42,6 +43,7 @@ from ..telemetry.goodput import get_goodput_ledger, record_goodput
 from ..telemetry.trace import NULL_SPAN, get_tracer, traced
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+from .activation_checkpointing import checkpointing as _act_ckpt
 from .config import DeepSpeedConfig
 from .fault import injection as fault_injection
 from .fp16.loss_scaler import LossScaler, LossScalerState, create_loss_scaler
@@ -300,6 +302,8 @@ class DeepSpeedEngine:
             comm_error=comm_error,
         )
 
+        self._device_memory = self._account_device_memory()
+
         # ---- data ---------------------------------------------------- #
         self.training_dataloader = None
         if training_data is not None:
@@ -313,8 +317,6 @@ class DeepSpeedEngine:
         # block applies — engines without one must not clobber another
         # engine's or a manual configure() call's policy.
         if getattr(config, "activation_checkpointing_explicit", False):
-            from .activation_checkpointing import checkpointing as _act_ckpt
-
             _act_ckpt.configure(deepspeed_config=config)
 
         # ---- compiled steps ------------------------------------------ #
@@ -364,6 +366,30 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------ #
     # Resolution helpers
     # ------------------------------------------------------------------ #
+    def _account_device_memory(self) -> Tuple[int, int]:
+        """``(bytes_limit, state bytes)`` of one device, for the model's
+        choice of what a checkpointed layer saves
+        (``activation_checkpointing.checkpointing.engine_memory``): what the
+        backend says the device holds at most (0 where it reports nothing:
+        the CPU) and what this engine keeps there through a step — the
+        placed state, the compute-dtype copy of the parameters a step casts
+        and the gradients its backward pass returns in that type.  From
+        shapes and shardings alone, so the same on every run."""
+        def shard_bytes(x, itemsize=None):
+            if getattr(x.sharding, "memory_kind", None) == "pinned_host":
+                return 0
+            return math.prod(x.sharding.shard_shape(x.shape)) * (
+                itemsize or x.dtype.itemsize)
+
+        device = next(d for d in self.mesh.devices.flat
+                      if d.process_index == jax.process_index())
+        limit = (device.memory_stats() or {}).get("bytes_limit", 0)
+        placed = sum(shard_bytes(x) for x in jax.tree.leaves(self.state))
+        per_step = 2 * sum(
+            shard_bytes(x, jnp.dtype(self.compute_dtype).itemsize)
+            for x in jax.tree.leaves(self.state.params))
+        return limit, placed + per_step
+
     def _resolve_loss_fn(self, model) -> Callable:
         """Accept a loss callable, or a flax-like module with .apply.
 
@@ -746,7 +772,8 @@ class DeepSpeedEngine:
             # the masters' cast: under ZeRO-3 what follows it is the gather
             with jax.named_scope("zero/gather_params"):
                 p = jax.tree.map(lambda x: x.astype(self.compute_dtype), p32)
-            out = self.loss_fn(p, batch, rng)
+            with _act_ckpt.engine_memory(*self._device_memory):
+                out = self.loss_fn(p, batch, rng)
             loss = out[0] if isinstance(out, tuple) else out
             return self.loss_scaler.scale_loss(loss.astype(jnp.float32), scaler_state), loss
 

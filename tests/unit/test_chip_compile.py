@@ -173,30 +173,38 @@ def _adam(dev):
         (t, t, t, t, _on(dev, (), jnp.int32))
 
 
-def _train_step(zero_stage):
+def _train_step(zero_stage, layers=1, device_bytes=0):
     """The default model config (flash + fused RMSNorm both "auto" = on for
     a TPU, remat) at hidden 4096 under the engine's step recipe: bf16 cast
     of fp32 masters, ``value_and_grad`` of the LM loss, AdamW.  Depth 1 and
     a short batch keep the compile quick; the kernels' tiles are the real
     ones (rows >= 256, full widths).  ``zero_stage`` 3 lays the state out
-    with the engine's own ZeRO-3 plan over all four chips."""
+    with the engine's own ZeRO-3 plan over all four chips.  ``device_bytes``
+    is the memory the checkpointed layer is told the device has, as the
+    engine tells it: 0 saves nothing, a terabyte every named value (the
+    flash kernel's, named inside its VJP rule, must lower)."""
     def build(topology):
         import optax
 
         from deepspeed_tpu.models.transformer import (CausalLM,
                                                       TransformerConfig)
+        from deepspeed_tpu.runtime.activation_checkpointing import \
+            checkpointing as ac
         from deepspeed_tpu.runtime.topology import (TopologyConfig,
                                                     initialize_mesh)
         from deepspeed_tpu.runtime.zero.sharding import ZeroShardingPlan
+        from deepspeed_tpu.telemetry import get_tracer
 
         n = 4 if zero_stage else 1
         topo = initialize_mesh(TopologyConfig(),
                                devices=list(topology.devices[:n]),
                                force=True)
         cfg = TransformerConfig(
-            vocab_size=V, hidden_size=D, intermediate_size=F, num_layers=1,
-            num_heads=H, num_kv_heads=KV, max_seq_len=512, remat=True)
+            vocab_size=V, hidden_size=D, intermediate_size=F,
+            num_layers=layers, num_heads=H, num_kv_heads=KV, max_seq_len=512,
+            remat=True)
         assert cfg.use_flash and cfg.fused_rmsnorm == "auto"   # defaults
+        assert cfg.remat_policy == "auto"
         model = CausalLM(cfg)
         tx = optax.adamw(3e-4, weight_decay=0.1)
         p_abs = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
@@ -211,9 +219,13 @@ def _train_step(zero_stage):
         def step(params, opt, tokens):
             def loss_fn(p32):
                 p = jax.tree.map(lambda x: x.astype(BF16), p32)
-                return model.loss_fn(p, {"input_ids": tokens}, None)
+                with ac.engine_memory(device_bytes, 0):
+                    return model.loss_fn(p, {"input_ids": tokens}, None)
 
             loss, grads = jax.value_and_grad(loss_fn)(params)
+            saved = get_tracer().records()[-1]
+            assert saved.name == "train/remat_layout" and \
+                len(saved.attrs["saved"]) == (8 if device_bytes else 0)
             updates, opt = tx.update(grads, opt, params)
             return optax.apply_updates(params, updates), opt, loss
 
@@ -642,6 +654,8 @@ CASES = {
     "fused_adam_update": _adam,
     "train_step[1 chip]": _train_step(zero_stage=0),
     "train_step[zero3 x 4 chips]": _train_step(zero_stage=3),
+    "train_step[1 chip, 2 layers, every name saved]":
+        _train_step(zero_stage=0, layers=2, device_bytes=1 << 40),
     # Olmo-Hybrid: head counts and widths that tile neither sublanes nor lanes
     "gdn_decode[30 heads of 96 x 192, pairs]": _gdn_decode_olmo,
     "decode_paged_attention[30 heads stored in 32]":
