@@ -243,6 +243,9 @@ class InferenceEngineV2:
         #: window with NO host repack / H2D upload (see decode_batch_async)
         self._decode_state: Optional[Dict] = None
         self.decode_resume_hits = 0
+        #: the last window dispatched: one dispatched while it is undrained
+        #: takes its own time from this one's drain (``DecodeWindow``)
+        self._last_window: Optional["DecodeWindow"] = None
         #: monotonically increasing fused-window index — the ``step``
         #: passed to the ``decode_window`` fault-injection site (verify
         #: windows share the counter and the site, so the chaos harness
@@ -971,21 +974,44 @@ class InferenceEngineV2:
         metadata (next seed token, positions, ctx lengths) and the engine
         caches it; when the next window targets the same uid set with
         unchanged KV block tables, the cached device array is reused —
-        no host repack, no H2D upload.  If the previous window was already
-        drained its last tokens are known on the host, and ``seed_tokens``
-        are honored: seeds matching the cached stream resume device-side,
-        different seeds (stop-token rewrites, guided decoding) force a
-        repack.  For a window dispatched BEFORE the previous one was
-        drained the seeds are unknowable and therefore advisory — the
-        on-device state already holds them.  Combined with JAX async
-        dispatch this lets the host schedule window i+1 while window i is
-        still executing: dispatch the next window first, THEN drain the
-        previous handle's ``tokens()``.
+        no host repack, no H2D upload (``decode_resume_hits``).  If the
+        previous window was already drained its last tokens are known on
+        the host, and ``seed_tokens`` are honored: seeds matching the
+        cached stream resume device-side, different seeds (stop-token
+        rewrites, guided decoding) force a repack.
+
+        One window ahead: a window may be dispatched over the uid set of
+        the previous window BEFORE that one is drained — dispatch the next
+        window first, THEN drain the previous handle's ``tokens()``, so the
+        host's fetch, bookkeeping, packing and launch run while the device
+        decodes.  The seeds are then unknowable on the host and
+        ``seed_tokens`` are advisory: the undrained window's advanced
+        metadata holds them ON THE DEVICE.  Where no block table grew (a
+        scheduler that reserved the riders' whole lifetime at admission:
+        always) the window resumes as above and nothing syncs.  Where one
+        did (a caller that allocates a window at a time) the metadata must
+        be packed anew and the true seeds are read back from the device: a
+        sync with the undrained window, i.e. its drain — correct, and no
+        window ahead.  At most two windows are so committed to the device:
+        the one being drained and the one dispatched ahead of that drain.
         """
         with _TRACER.span("engine/decode_dispatch", n_seqs=len(uids),
                           steps=steps) as sp:
             return self._dispatch_decode_window(
                 sp, uids, seed_tokens, steps, temperature, rng, top_k)
+
+    def decode_chains(self, uids: Sequence[int]) -> bool:
+        """Does the last window's advanced metadata still describe exactly
+        ``uids``, in this order, with nothing cached since?  Then a window
+        over them may be dispatched before that one is drained (see
+        :meth:`decode_batch_async`); a host forward, a flush or a poisoned
+        window in between says no, and the caller drains first."""
+        st = self._decode_state
+        if st is None or st["uids"] != tuple(uids):
+            return False
+        seqs = map(self.state_manager.get_sequence, uids)
+        return all(seq is not None and st["seen"][seq.uid] == seq.seen_tokens
+                   for seq in seqs)
 
     def _dispatch_decode_window(self, sp, uids, seed_tokens, steps,
                                 temperature, rng, top_k) -> "DecodeWindow":
@@ -1012,13 +1038,12 @@ class InferenceEngineV2:
                 grew |= seq.cur_allocated_blocks != prev
 
             st = self._decode_state
-            uids_t = tuple(uids)
-            resume = (not grew and st is not None
-                      and st["uids"] == uids_t and st["bucket"] == bucket
-                      and all(st["seen"][u] ==
-                              self.state_manager.get_sequence(u).seen_tokens
-                              for u in uids))
-            if resume and "last_tokens" in st:
+            chained = self.decode_chains(uids)
+            # off an UNDRAINED window: its last tokens are not on the host
+            # and the caller's seeds are advisory
+            undrained = chained and "last_tokens" not in st
+            resume = chained and not grew and st["bucket"] == bucket
+            if resume and not undrained:
                 # the previous window was drained, so the caller KNOWS the
                 # stream — a seed differing from the cached on-device token
                 # (stop-token rewrite, guided decoding) must win over resume
@@ -1028,19 +1053,17 @@ class InferenceEngineV2:
             self.decode_resume_hits += 1
             meta_dev = st["meta"]
         else:
-            if (st is not None and st["uids"] == uids_t
-                    and "last_tokens" not in st
-                    and all(st["seen"][u] ==
-                            self.state_manager.get_sequence(u).seen_tokens
-                            for u in uids)):
+            if undrained:
                 # chaining off an UNDRAINED window that cannot resume
                 # (block growth crossed a page boundary): the caller's
                 # seeds are advisory and unknowable, so packing them would
                 # silently corrupt the stream — the true next tokens are
                 # the advanced meta's tokens field.  Reading it syncs with
                 # the previous window, the price of a growth-boundary
-                # repack.  (Same uids ⟹ same n ⟹ same bucket, so the
-                # slice below is the previous window's seq rows.)
+                # repack; a scheduler that reserves its riders' whole
+                # lifetime never pays it.  (Same uids ⟹ same n ⟹ same
+                # bucket, so the slice below is the previous window's seq
+                # rows.)
                 seed_tokens = [int(t) for t in np.asarray(st["meta"][:n])]
             with _TRACER.span("engine/decode_pack"):
                 wrapper = self._wrapper_for(bucket)
@@ -1136,6 +1159,9 @@ class InferenceEngineV2:
                               moe_pairs_dev=extra[0] if extra else None)
         window._state = self._decode_state
         window._ctx_before = list(ctx_before)
+        last, self._last_window = self._last_window, window
+        if last is not None and last._drained_t is None:
+            window._after = last        # queued behind an undrained window
         return window
 
     def _poison_kv(self, uid: int) -> None:
@@ -1349,10 +1375,16 @@ class DecodeWindow:
     ``duration_s`` is dispatch→drain WALL time (JAX exposes no per-dispatch
     device time): host work done between dispatch and :meth:`tokens`
     inflates it and understates the published tok/s / HBM %-of-peak gauges.
-    Drain promptly when the roofline numbers matter — the benches do; in
-    the dispatch-next-then-drain-previous pipeline the drain happens right
-    after the next dispatch, so the overstatement is one dispatch's host
-    cost, not a window.
+    Drain promptly when the roofline numbers matter — the benches do.  A
+    window dispatched while its predecessor was undrained started on the
+    device when that one ended, not when it was dispatched: its time runs
+    from the predecessor's drain, or a chained window would read as two.
+
+    :meth:`tokens` launches NO device program: it waits for the window's own
+    outputs, copies them whole and cuts them to ``n_seqs`` on the host.  A
+    slice taken on the device would queue behind whatever was dispatched
+    since — behind the NEXT window, when the caller runs one ahead — and the
+    drain of window i would wait for window i+1.
     """
 
     def __init__(self, engine: "InferenceEngineV2", toks_dev, n_seqs: int,
@@ -1385,27 +1417,31 @@ class DecodeWindow:
         self._state: Optional[dict] = None
         #: each row's cached tokens before the window (the launch sets it)
         self._ctx_before: List[int] = []
+        #: the window this one was queued behind, while that one is undrained
+        self._after: Optional["DecodeWindow"] = None
+        self._drained_t: Optional[float] = None
 
     def tokens(self) -> np.ndarray:
         """Block for the generated tokens [steps, n_seqs]."""
         if self._toks is None:
             # the sync the copies below would make anyway, timed apart: the
-            # wait is the device's, the fetch is the host's.  The slices
-            # are queued behind the window BEFORE the wait, so the device
-            # never idles between the window and them.
+            # wait is the device's, the fetch is the host's
             with _TRACER.span("engine/window_wait", steps=self.steps):
-                toks_dev = self._toks_dev[:, :self.n_seqs]
-                bad_dev = None if self._nonfinite_dev is None \
-                    else self._nonfinite_dev[:self.n_seqs]
-                jax.block_until_ready((toks_dev, bad_dev,
+                jax.block_until_ready((self._toks_dev, self._nonfinite_dev,
                                        self._moe_pairs_dev))
             with _TRACER.span("engine/window_fetch"):
-                self._toks = np.asarray(toks_dev)
-                self.nonfinite = np.zeros(self.n_seqs, bool) \
-                    if bad_dev is None else np.asarray(bad_dev)
+                n = self.n_seqs     # (the bucket's pad rows are cut HERE)
+                self._toks = np.asarray(self._toks_dev)[:, :n]
+                self.nonfinite = np.zeros(n, bool) \
+                    if self._nonfinite_dev is None \
+                    else np.asarray(self._nonfinite_dev)[:n]
                 if self._moe_pairs_dev is not None:
                     self.moe_pairs = np.asarray(self._moe_pairs_dev)
-            self.duration_s = time.perf_counter() - self._t0
+            self._drained_t = time.perf_counter()
+            after, self._after = self._after, None
+            began = self._t0 if after is None or after._drained_t is None \
+                else max(self._t0, after._drained_t)
+            self.duration_s = self._drained_t - began
             self._toks_dev = None
             self._nonfinite_dev = None
             self._moe_pairs_dev = None

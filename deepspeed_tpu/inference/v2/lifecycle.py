@@ -17,6 +17,28 @@ survivability layer on top of the engine primitives:
     long: an expired request is flushed and its KV blocks reclaimed at the
     next window boundary — mid-stream, never "after it finishes"
     (``serving/deadline_expired``, ``serving/ttft_timeout``).
+  * **One decode window ahead** — at most TWO windows are ever committed to
+    the device: the one whose tokens the host is about to drain and, where
+    the scheduler observes that it may (``_may_run_ahead``), the next one,
+    dispatched BEFORE that drain so that the fetch, the bookkeeping, the
+    lifecycle passes and the next dispatch all run while the device decodes.
+    It may where the next window's riders are exactly the ones in flight
+    (nobody reaches ``max_new_tokens`` inside the window in flight — the
+    host knows by count — nothing queued or prefilling, no drafter, no
+    cancellation or deadline due) and every row of the decode batch is
+    taken (``max_seqs`` live sequences; with recurrent state, every state
+    slot).  Everything else — a free row, a queued request, a finisher, a
+    drain, an error — drains first, and the schedule is the one of a
+    scheduler that drains every window.  **What it costs**: with recurrent
+    state a full pool admits nobody, so nothing; a paged family admits on
+    free BLOCKS, so a request that arrives while the batch is full and
+    blocks are free is started after the window in flight — one window (at
+    most ``window_steps`` steps) later than it would have been, never two.
+    A queue that is not empty keeps every window drained: a server with a
+    backlog runs the drained schedule.  A rider that ends where the host
+    could not foresee it (EOS, a non-finite row) is found at the drain of
+    window i while window i+1 carries it: that row of i+1 is dropped and
+    its blocks go back when i+1 has drained.
   * **Cancellation** — ``cancel(uid)`` (client disconnect) flushes the
     sequence and returns its blocks to the pool; the freed blocks are
     immediately re-admittable (``serving/cancelled``).
@@ -198,13 +220,29 @@ class AdmissionVerdict:
     retry_after_s: Optional[float] = None
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A fused decode window dispatched and not yet drained."""
+
+    window: object                   # the engine's DecodeWindow
+    uids: List[int]                  # its riders, in row order
+    steps: int
+    index: int                       # engine.decode_windows_dispatched
+    #: riders retired since the dispatch (EOS or a non-finite row found at
+    #: the previous window's drain, a deadline): their rows are dropped at
+    #: this window's drain and their blocks released after it
+    dropped: set = dataclasses.field(default_factory=set)
+
+
 class LifecycleScheduler:
     """Open-world serving scheduler over :class:`InferenceEngineV2`.
 
     One ``step()`` runs either a mixed prefill/admission forward (``put``)
-    or one bounded fused decode window, after processing cancellations and
-    deadline expiries — so no request ever waits more than one window for
-    its lifecycle events to take effect.
+    or dispatches one bounded fused decode window, after processing
+    cancellations and deadline expiries — so no request ever waits more than
+    one window for its lifecycle events to take effect.  A window is drained
+    in the step that dispatched it, unless the next step may dispatch its
+    successor first (the module docstring's "one decode window ahead").
     """
 
     def __init__(self, engine: InferenceEngineV2, max_queue: int = 64,
@@ -262,6 +300,8 @@ class LifecycleScheduler:
         self._decodes: "collections.OrderedDict[int, int]" = \
             collections.OrderedDict()          # uid -> next seed token
         self._cancel_requested: set = set()
+        #: the decode window dispatched and not yet drained, if any
+        self._inflight: Optional[_InFlight] = None
         self._admit_seq = 0
         self.draining = False
         self.counters: "collections.Counter[str]" = collections.Counter()
@@ -435,7 +475,12 @@ class LifecycleScheduler:
 
     def _release(self, uid: int, flush: bool = True) -> None:
         """A request is over: its KV blocks, drafter state, parked rows."""
-        if flush:
+        fl = self._inflight
+        if flush and fl is not None and uid in fl.uids:
+            # its row of the window in flight is still decoding into those
+            # blocks: they go back when that window has drained (_drain)
+            fl.dropped.add(uid)
+        elif flush:
             self.eng.flush([uid])
         if self.drafter is not None:
             self.drafter.flush(uid)
@@ -890,29 +935,105 @@ class LifecycleScheduler:
             return []
         if self.drafter is not None and \
                 any(self._spec_k_for(self._reqs[u]) > 0 for u in uids):
-            sp.set(n_seqs=len(uids), steps=1, verify=True)
+            sp.set(n_seqs=len(uids), steps=1, verify=True, ahead=0)
             return self._run_verify_window(uids, room)
+        # the window in flight (its riders are these: _settle saw to it)
+        # has not given its tokens yet; what it will give is known by count
+        prev = self._inflight
+        owed = prev.steps if prev is not None else 0
         steps = min(self.window_steps,
-                    min(self._reqs[u].remaining for u in uids),
+                    min(self._reqs[u].remaining for u in uids) - owed,
                     min(room[u] for u in uids))
         if steps > 2:       # pow2 quantize: one compiled loop per window size
             steps = 1 << (steps.bit_length() - 1)
-        sp.set(n_seqs=len(uids), steps=steps)
+        sp.set(n_seqs=len(uids), steps=steps, ahead=int(prev is not None))
+        # (ahead of an undrained window these seeds are stale and advisory:
+        # the true ones are on the device, decode_batch_async)
         seeds = [self._decodes[u] for u in uids]
         window = self.eng.decode_batch_async(uids, seeds, steps)
-        toks = window.tokens()
+        self._inflight = _InFlight(window, uids, steps,
+                                   self.eng.decode_windows_dispatched)
+        finished = [] if prev is None else self._drain(prev)
+        return finished + self._settle()
+
+    def _free_slots(self) -> int:
+        """Rows of the decode batch not taken: the engine's ``max_seqs`` less
+        the live sequences and, with recurrent state, the state pool's free
+        slots if fewer.  (Only the state pool GATES admission; a paged
+        family admits beyond ``max_seqs`` while blocks are free, and its
+        decode set then rotates.)"""
+        free = self.eng.config.max_seqs - len(self._prefilling) \
+            - len(self._decodes)
+        slots = self.eng.state_manager.free_slots
+        return free if slots is None else min(free, slots)
+
+    def _may_run_ahead(self) -> bool:
+        """May the next decode window be dispatched BEFORE the one in flight
+        is drained?  From what the host can observe: (a) the next window's
+        riders are exactly the ones in flight — nobody reaches
+        ``max_new_tokens`` or the context cap inside that window (known by
+        count), nothing queued, prefilling, cancelled, due or draining, no
+        drafter (it needs the tokens), the engine's device state still
+        describes them; and (b) no row of the decode batch is free
+        (``_free_slots``): with a free row an arrival is the common case and
+        would wait a second window for its first token.  With recurrent
+        state (b) means nobody could be admitted anyway.  A paged family
+        still admits an arrival on free blocks: it is started when the
+        window in flight has drained, one window later than under a
+        scheduler that drains every window (the module docstring's "what it
+        costs")."""
+        fl = self._inflight
+        if (fl.dropped or self.drafter is not None
+                or self.draining or self._waiting or self._prefilling
+                or self._cancel_requested or self._free_slots() > 0
+                or list(self._decodes) != fl.uids
+                or not self.eng.decode_chains(fl.uids)):
+            return False
+        now = self.clock()
+        cap = self.eng.config.max_ctx
+        sm = self.eng.state_manager
+        for uid in fl.uids:
+            req = self._reqs[uid]
+            if (req.remaining <= fl.steps
+                    or sm.get_sequence(uid).seen_tokens >= cap
+                    or (req.deadline_t is not None
+                        and now >= req.deadline_t)):
+                return False
+        return True
+
+    def _settle(self) -> List[int]:
+        """Drain the window in flight unless the next may run ahead of it:
+        whatever touches its riders or could start a request finds the
+        schedule as it was before windows ran ahead."""
+        if self._inflight is None or self._may_run_ahead():
+            return []
+        fl, self._inflight = self._inflight, None
+        return self._drain(fl)
+
+    def _drain(self, fl: _InFlight) -> List[int]:
+        """Wait for a window's tokens and apply them (watchdog + NaN
+        isolation); the rows of riders retired since its dispatch are
+        dropped and their blocks released, now that nothing writes them."""
+        toks = fl.window.tokens()
         with _TRACER.span("serve/window_apply"):
-            streams = [[int(t) for t in toks[:, col]]
-                       for col in range(len(uids))]
-            return self._apply_window_results(
-                uids, streams, set(window.nonfinite_uids()),
-                wall_s=window.duration_s, compiled=window.compiled)
+            columns = toks.T.tolist()
+            rows = [(u, columns[col]) for col, u in enumerate(fl.uids)
+                    if u not in fl.dropped]
+            finished = self._apply_window_results(
+                [u for u, _ in rows], [stream for _, stream in rows],
+                set(fl.window.nonfinite_uids()) - fl.dropped,
+                wall_s=fl.window.duration_s, compiled=fl.window.compiled,
+                window_index=fl.index)
+            if fl.dropped:
+                self.eng.flush(sorted(fl.dropped))
+        return finished
 
     def _apply_window_results(self, uids: List[int],
                               streams: List[List[int]], poisoned: set,
                               wall_s: float, compiled: bool,
                               span_kind: str = "decode_window",
-                              span_wall_s: Optional[float] = None
+                              span_wall_s: Optional[float] = None,
+                              window_index: Optional[int] = None
                               ) -> List[int]:
         """Shared tail of fused-decode and verify windows: post-hoc hang
         detection, per-request NaN isolation, eos truncation, finish /
@@ -931,10 +1052,12 @@ class LifecycleScheduler:
         span_s = wall_s if span_wall_s is None else span_wall_s
         t0 = time.perf_counter() - span_s
         kind = "compile" if compiled else span_kind
+        if window_index is None:
+            window_index = self.eng.decode_windows_dispatched
         for uid, stream in zip(uids, streams):
             self._tspan(self._reqs[uid], kind, t0=t0, dur_s=span_s,
                         n_seqs=len(uids), tokens=len(stream),
-                        window=self.eng.decode_windows_dispatched)
+                        window=window_index)
         if not compiled and wall_s > self.hang_deadline_s:
             # post-hoc hang detection: the window drained, but took longer
             # than the deadline — a stuck DMA / pathological host stall.
@@ -1051,9 +1174,14 @@ class LifecycleScheduler:
                 "serve/step", waiting=len(self._waiting),
                 prefilling=len(self._prefilling),
                 decoding=len(self._decodes)) as sp:
+            # a window in flight is drained first, unless this step may
+            # dispatch its successor ahead of that: the passes below, and
+            # admission, see its riders drained (a finisher's slot free)
+            done = self._settle()
             with _TRACER.span("serve/lifecycle"):
-                done = self._process_cancellations()
+                done += self._process_cancellations()
                 done += self._process_expiries()
+            done += self._settle()      # a deadline that passed in between
             # prefill/admission first — finishing prefills frees the decode
             # path to run fused windows over the full live set.  A BLOCKED
             # queue head (no reservation, no preemption victim) yields an
@@ -1073,8 +1201,23 @@ class LifecycleScheduler:
                         done += self._run_decode_window(wsp)
             except Exception as e:  # said, then the caller's: requests stay
                 self._say_why(None, "error", kind, 0, error=type(e).__name__)
+                self._settle_safely()
                 raise
             return done
+
+    def _settle_safely(self) -> None:
+        """Drain what is in flight whatever may come next, and never raise:
+        for a step that has already failed (its riders get their tokens if
+        the device still answers) and for the drain's mop-up."""
+        fl, self._inflight = self._inflight, None
+        if fl is None:
+            return
+        try:
+            self._drain(fl)
+        except Exception as e:  # noqa: BLE001 — the first error is the caller's
+            logger.error(f"window in flight lost after a failed step: {e!r}")
+            if fl.dropped:
+                self.eng.flush(sorted(fl.dropped))
 
     def run_until_idle(self, max_iters: int = 10_000) -> None:
         """Drive until no live work remains (tests / batch mode)."""
@@ -1137,6 +1280,7 @@ class LifecycleScheduler:
                         completed += 1
             expired = 0
             with self._lock:
+                self._settle_safely()      # nothing stays in flight
                 for req in list(self._reqs.values()):
                     if req.state not in TERMINAL_STATES:
                         self._retire(req, RequestState.EXPIRED,
