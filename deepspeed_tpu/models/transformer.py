@@ -64,10 +64,14 @@ class TransformerConfig:
     remat_policy: str = "auto"
     use_flash: bool = True          # pallas flash attention on TPU
     attn_impl: str = "auto"         # auto | flash | xla | ring | ulysses
-    #: flash kernel tile sizes.  The defaults have no valid on-chip
-    #: measurement behind them (ROADMAP S4): re-derive from the trace
-    flash_block_q: int = 256
-    flash_block_k: int = 512
+    #: flash kernel tile sizes (clamped to the sequence): the best pair of
+    #: {128, 256, 512} x {256, 512, 1024} on the v5e at [4 | 2, 32, 2048,
+    #: 128] bf16, forward, dq and dkv summed: 6.78 ms against 10.94 at 256
+    #: x 512 (tools/flash_split.py; PERF.md section 6, PR 54).  It compiles
+    #: for float32 and for 64- and 256-wide heads too, which 1024 x 1024
+    #: (6.56 ms) does not
+    flash_block_q: int = 512
+    flash_block_k: int = 1024
     #: fold rms_norm into the consuming projections' Pallas kernels
     #: (``kernels/fused_collective_matmul.rmsnorm_matmul`` — the norm's
     #: variance/rsqrt recomputed per output tile, normalized activations
@@ -336,8 +340,7 @@ def _remat_layout(cfg: TransformerConfig, batch: int, seq_len: int,
     together.  Rows are a device's share over the batch and sequence axes;
     a tensor axis, which would divide the widths, is left out (it saves
     less than it could there)."""
-    from ..ops.transformer.flash_attention import (F32_DOT_PASSES, LSE_NAME,
-                                                   OUT_NAME)
+    from ..ops.transformer.flash_attention import LSE_NAME, OUT_NAME
     from ..runtime import topology as _topo
     from ..runtime.activation_checkpointing.checkpointing import Saveable
 
@@ -359,10 +362,10 @@ def _remat_layout(cfg: TransformerConfig, batch: int, seq_len: int,
     if cfg.num_experts == 1:    # the expert block names nothing (ROADMAP S5)
         tensors += [matmul("gate_proj", F), matmul("up_proj", F)]
     if _attn_impl(cfg, seq_len) == "flash":
-        # causal: half of the two [S, S, hd] products a head, in float32
+        # causal: half of the two [S, S, hd] products a head
         tensors.append(Saveable(
             (OUT_NAME, LSE_NAME), rows * q_w * itemsize + rows * cfg.num_heads * 4,
-            F32_DOT_PASSES * 2.0 * rows * seq_len * q_w))
+            2.0 * rows * seq_len * q_w))
     tensors += [matmul("q_proj", q_w), matmul("k_proj", kv_w),
                 matmul("v_proj", kv_w), matmul("attn_residual", D, q_w)]
     head = 3 * rows * V * 4

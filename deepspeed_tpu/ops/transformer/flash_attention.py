@@ -10,6 +10,15 @@ recompute-based backward (dq and dkv kernels), exposed through
 Layout: inputs [B, S, H, hd] (GQA allowed: KV heads = H // group).  The kernel
 operates per (batch, head, q-block) with kv-blocks as the innermost grid dim,
 accumulating in VMEM scratch (f32).  Causal masking skips fully-masked blocks.
+
+Precision: every dot takes its operands in the dtype the inputs arrive in
+and accumulates in float32.  The scores, the probabilities, ``ds`` and the
+row statistics (``m``, ``l``, ``lse``, ``delta``) are float32 whatever the
+inputs are; ``p`` and ``ds`` are rounded to the operand dtype only where they
+enter a dot as its left operand.  On the TPU a float32 dot at default
+precision is one bf16 pass of the MXU too, so there the dtype moves neither
+the result nor the time (PERF.md section 6, PR 54); in interpret mode and on
+the CPU bf16 inputs now compute what the chip does.
 """
 from __future__ import annotations
 
@@ -42,6 +51,14 @@ def _interpret() -> bool:
 
 def _cdiv(a, b):
     return (a + b - 1) // b
+
+
+def _dot_tn(a, b):
+    """``a.T @ b`` with float32 accumulation: [K, M] x [K, N] -> [M, N].
+    Mosaic lowers the contraction over dimension 0 and ``jnp.dot(a.T, b)``
+    alike (equal times on the v5e: ``tools/flash_split.py``, ``tree+T``)."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _causal_kv_index(causal: bool, block_q: int, block_k: int):
@@ -93,9 +110,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [BQ, hd]
-        k = k_ref[0, 0].astype(jnp.float32)            # [BK, hd]
-        v = v_ref[0, 0].astype(jnp.float32)            # [BK, hd]
+        q = q_ref[0, 0]                                 # [BQ, hd]
+        k = k_ref[0, 0]                                 # [BK, hd]
+        v = v_ref[0, 0]                                 # [BK, hd]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
         q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -111,7 +128,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         alpha = jnp.exp(m_prev - m_new)                 # rescale factor
         p = jnp.exp(s - m_new)                          # [BQ, BK]
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc[:] = acc[:] * alpha + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        acc[:] = acc[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -185,12 +203,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]
         delta = delta_ref[0, 0][:, :1]
+        # dp before s: the same values, and on the v5e 10% off this kernel
+        # with bf16 operands (PERF.md section 6, PR 54)
+        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -198,9 +216,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
     def _write():
@@ -224,10 +242,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]
         delta = delta_ref[0, 0][:, :1]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
@@ -237,10 +252,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dv_acc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+        dv_acc[:] += _dot_tn(p.astype(do.dtype), do)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_acc[:] += _dot_tn(ds.astype(q.dtype), q)
 
     @pl.when(iq == nq - 1)
     def _write():
@@ -319,12 +334,6 @@ def _flash_bhsd(q, k, v, scale, causal, block_q, block_k):
 #: ``jax.checkpoint`` policy that saves both leaves its backward pass no use
 #: for the forward ``pallas_call``; outside a checkpoint a name is the identity
 OUT_NAME, LSE_NAME = "flash_out", "flash_lse"
-#: bf16 passes of the MXU that one product of the kernels' float32 operands
-#: takes at the least (the casts in ``_fwd_kernel``; ROADMAP S4): what a
-#: caller multiplies this kernel's FLOPs by to weigh them against a bf16
-#: matmul's.  On the v5e the forward call reaches 17% of the bf16 peak
-#: where the fused matmul kernel reaches 73% (PERF.md section 6, PR 48).
-F32_DOT_PASSES = 3
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k):

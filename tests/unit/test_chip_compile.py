@@ -17,6 +17,7 @@ its CPU-rehearsal size, and the compile-cache placement.
 import importlib.util
 import json
 import os
+from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
 
@@ -85,17 +86,25 @@ def _on(sharding, shape, dtype=BF16):
 
 
 # ---- one builder per case: (fn, abstract args) ---------------------------
-def _flash(grad):
+def _flash(grad, model_blocks=False):
+    """bf16 inputs, so bf16 operands of every dot (PR 54), at the kernel's
+    own default blocks or at the model's ``flash_block_q`` x
+    ``flash_block_k``, the tiles both train cells run."""
     def build(dev):
+        from deepspeed_tpu.models.transformer import TransformerConfig
         from deepspeed_tpu.ops.transformer.flash_attention import \
             flash_attention
 
+        blocks = {}
+        if model_blocks:
+            cfg = TransformerConfig.tiny()
+            blocks = dict(block_q=cfg.flash_block_q, block_k=cfg.flash_block_k)
         q = _on(dev, (2, 2048, H, HD))
         kv = _on(dev, (2, 2048, KV, HD))
         if not grad:
-            return flash_attention, (q, kv, kv)
+            return partial(flash_attention, **blocks), (q, kv, kv)
         loss = lambda q, k, v: flash_attention(  # noqa: E731
-            q, k, v).astype(jnp.float32).sum()
+            q, k, v, **blocks).astype(jnp.float32).sum()
         return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv)
     return build
 
@@ -621,6 +630,8 @@ CASES = {
     "xing4_decode_window": _xing4_decode_window,
     "flash_fwd": _flash(grad=False),
     "flash_bwd": _flash(grad=True),
+    "flash_fwd[the model's blocks]": _flash(grad=False, model_blocks=True),
+    "flash_bwd[the model's blocks]": _flash(grad=True, model_blocks=True),
     "decode_paged_attention": _paged(decode=True),
     "ragged_paged_attention": _paged(decode=False),
     "decode_paged_attention[4 rows]": _paged_decode_cell(4),
