@@ -148,7 +148,9 @@ class InferenceEngineV2:
         #: per-sequence recurrent state of some layers (a Gated DeltaNet
         #: family): a state can be restored only at the token it was saved
         #: at, so what re-reads, parks or ships cached tokens is refused
-        state = self.family.state
+        #: (a window ring in the slot is the same: the ring at an earlier
+        #: token is gone)
+        state = self.family.state or self.family.window
         if state is not None and (c.prefix_cache or c.host_tier_mb > 0):
             raise NotImplementedError(
                 f"{'prefix_cache' if c.prefix_cache else 'host_tier_mb > 0'} "
@@ -176,7 +178,8 @@ class InferenceEngineV2:
         if state is not None:
             from .ragged.state_pool import StatePool
 
-            self.state_pool = StatePool(state, c.max_seqs, c.dtype)
+            self.state_pool = StatePool(self.family.slot_kinds, c.max_seqs,
+                                        c.dtype)
         #: page-heat tracker (None = tracking off): observes the allocator
         #: so its live set mirrors the free list, ticked per forward below
         self.heat = None
@@ -1456,6 +1459,16 @@ class DecodeWindow:
                     asp.set(state_slots=used, state_bytes=pool.mem_bytes(),
                             state_fill=used / pool.slots,
                             state_pad_share=pool.pad_share)
+                if self.engine.family.window is not None:
+                    self._account_window(asp)
+                if self.engine.family.page_readers is not None:
+                    # rows ONE reader of a page layer reads in the window:
+                    # every rider's context at every step
+                    asp.set(shared_read_layers=max(
+                        self.engine.family.page_readers),
+                        page_rows_read=int(sum(
+                            c * self.steps + self.steps * (self.steps + 1) // 2
+                            for c in self._ctx_before)))
                 if self._state is not None and \
                         self.engine._decode_state is self._state:
                     # the last sampled token is the next window's seed: once
@@ -1465,6 +1478,20 @@ class DecodeWindow:
                         int(t) for t in self._toks[-1])
                 self.engine._account_decode_window(self)
         return self._toks
+
+    def _account_window(self, sp) -> None:
+        """What the window layers hold and read: a sequence's ring has as
+        many live rows as the sequence has tokens, up to the window
+        (``window_rows_held``: mean over the riders at the window's end),
+        and a step reads them once a window layer (``window_rows_read``:
+        summed over riders and steps, ONE layer's)."""
+        ring = self.engine.family.window
+        ctx = np.asarray(self._ctx_before, np.int64)[:, None] \
+            + np.arange(1, self.steps + 1)[None, :]     # [riders, steps]
+        held = np.minimum(ctx, ring.window)
+        sp.set(window_layers=ring.num_layers, window_rows=ring.window,
+               window_rows_held=float(held[:, -1].mean()) if ctx.size else 0.0,
+               window_rows_read=int(held.sum()))
 
     def _account_moe(self, sp) -> None:
         """Dropless by construction: every (token, choice) pair of the

@@ -13,7 +13,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from . import gdn_ops, mla_ops, sparse_ops
+from . import gdn_ops, mla_ops, sparse_ops, ssm_ops, window_ops
 from .ragged_ops import (_stored_heads, decode_attention, paged_kv_append,
                          ragged_paged_attention, verify_window_attention)
 
@@ -126,10 +126,24 @@ def page_ops(row, replicate=None) -> PageOps:
     # row kind says otherwise: the operations read it off the pool): a body
     # appends (k, v) [T, KV, hd] and attends q [T, H, hd] → [T, H, hd]
     kw = dict(num_kv_heads=row.num_kv_heads)
+
+    def ragged(q, *a, pages_per_chunk, **k):
+        # the ragged kernel's VMEM estimate does not see what Mosaic keeps
+        # on its stack (a chunk loaded whole and copied by the V select),
+        # which grows with the query rows a kv head brings: from 64 padded
+        # query heads of 128 up (16 stored kv heads under a group of 4) a
+        # 32-token bucket of 4-page chunks ran out of scoped VMEM on the
+        # chip (PR 55) where every bucket of 2-page chunks compiles; pools
+        # of fewer padded heads keep their chunks
+        if row.stored * (q.shape[1] // row.num_kv_heads) > 32:
+            pages_per_chunk = min(pages_per_chunk, 2)
+        return ragged_paged_attention(q, *a, pages_per_chunk=pages_per_chunk,
+                                      **kw, **k)
+
     return PageOps(
         append=partial(paged_kv_append, replicate=replicate),
         decode=partial(decode_attention, **kw),
-        ragged=partial(ragged_paged_attention, **kw),
+        ragged=ragged,
         verify=partial(verify_window_attention, **kw),
         dense=partial(_attend_gather, **kw))
 
@@ -139,5 +153,47 @@ def state_ops(state) -> Callable:
     serving.py``): ``(*inputs, pool, rows, mode=, batch=, valid=) → (out,
     pool)``, ``mode`` one of ``"decode"``, ``"ragged"``, ``"oracle"``
     (``model_runner._LayerState`` picks it as ``_LayerCache`` picks among a
-    cache kind's operations)."""
-    return partial(gdn_ops.gdn_mix, kind=state)
+    cache kind's operations).  The inputs are the recurrence's own
+    (``gdn_ops.gdn_mix``, ``ssm_ops.ssm_mix``)."""
+    mix = {"gated_delta": gdn_ops.gdn_mix,
+           "selective": ssm_ops.ssm_mix}[state.recurrence]
+    return partial(mix, kind=state)
+
+
+def window_op(row, ring) -> Callable:
+    """The windowed read of K/V rows ``row`` kept in a ring (``models/
+    serving.WindowRing``): ``(q, k, v, ring_pool, rows, *, mode, batch,
+    valid, scale, pages_per_chunk) → (out, ring_pool)``."""
+    if row.latent or row.index is not None:
+        raise NotImplementedError(
+            f"a window ring of {type(row).__name__} rows"
+            + (" with index keys" if not row.latent else ""))
+    return partial(window_ops.window_attention,
+                   num_kv_heads=row.num_kv_heads, page=ring.page)
+
+
+def pair_queries(q1, q2, pairs: int):
+    """The DIFFERENTIAL read as the K/V operations take it.  A cached K row
+    of pair ``j`` is ``[k1_j | k2_j]`` and its V row ``[v1_j | v2_j]``, each
+    ``hd`` wide; ``q1``, ``q2`` [T, Hp, hd / 2] are the two query sets,
+    head ``i`` of either reading pair ``i // (Hp / pairs)``.  ``q1`` padded
+    with zeros behind and ``q2`` in front score ``q1 . k1`` and ``q2 . k2``
+    against the SAME row, so a pair's group of query heads is ``[q1 heads |
+    q2 heads]``, each with its own softmax over the one value pair → q [T,
+    2 Hp, hd] grouped by pair (:func:`pair_outputs` takes it apart)."""
+    T, Hp, half = q1.shape
+
+    def grouped(q, pad):
+        return jnp.pad(q, ((0, 0), (0, 0), pad)).reshape(
+            T, pairs, Hp // pairs, 2 * half)
+
+    return jnp.concatenate([grouped(q1, (0, half)), grouped(q2, (half, 0))],
+                           axis=2).reshape(T, 2 * Hp, 2 * half)
+
+
+def pair_outputs(out, pairs: int):
+    """[T, 2 Hp, hd] grouped by pair → (A1, A2), each [T, Hp, hd]."""
+    T, H2, hd = out.shape
+    out = out.reshape(T, pairs, 2, H2 // (2 * pairs), hd)
+    return (out[:, :, 0].reshape(T, H2 // 2, hd),
+            out[:, :, 1].reshape(T, H2 // 2, hd))

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ...models.serving import ServingFamily
-from .kernels.page_ops import PageOps, page_ops, state_ops
+from .kernels.page_ops import (PageOps, page_ops, pair_outputs, pair_queries,
+                               state_ops, window_op)
 from .ragged.ragged_wrapper import pack_layout
 
 
@@ -57,6 +58,14 @@ def _layer_pages(page_of_token, layer, num_blocks, trash_page):
     pad sentinel (>= num_blocks) routes to the shared trash page."""
     return jnp.where(page_of_token < num_blocks,
                      page_of_token + layer * num_blocks, trash_page)
+
+
+def _slot_rows(batch, layer, slots, trash_row):
+    """Each sequence row's row of a slot pool ``[layers * slots + 1, ...]``
+    in ``layer``; the wrapper's pad sentinel (>= slots) routes to the
+    trailing trash row."""
+    slot = batch["state_slot"]
+    return jnp.where(slot < slots, slot + layer * slots, trash_row)
 
 
 class _LayerCache:
@@ -84,13 +93,21 @@ class _LayerCache:
     row, and ``attend`` takes, after ``q``, the indexer's queries and head
     weights ``[T, ...]``; they ride with ``q`` as one tuple through the same
     dispatch, and the kind's operations score, select and read.
+
+    ``attend_pair(q1, q2, pairs=)`` is the differential read of a row kind
+    whose heads are pairs (``page_ops.pair_queries``); it appends nothing,
+    like ``attend``, so a body that only reads another layer's page layer
+    calls it on ``at(that layer)``.  ``window(window_layer)`` is a layer
+    that keeps a ring of rows in the sequence's slot instead of pages
+    (:class:`_WindowView`; families with ``ServingFamily.window``).
     """
 
     def __init__(self, pages, layer, *, ops: PageOps, batch, attn_impl,
                  num_blocks, max_q, block_q, pages_per_chunk, decode_mode,
-                 verify_mode):
+                 verify_mode, rings=None):
         self.ops, self.batch, self.layer = ops, batch, layer
         self._pool = [pages]            # shared by every view (``at``)
+        self.rings = rings
         self.paged, self.num_blocks, self.max_q = \
             attn_impl == "paged", num_blocks, max_q
         self.tile = dict(block_q=block_q, pages_per_chunk=pages_per_chunk)
@@ -156,6 +173,50 @@ class _LayerCache:
         self.append(*rows)
         return self.attend(q, **attn)
 
+    def attend_pair(self, q1, q2, *, pairs: int, **attn):
+        """→ (A1, A2) [T, Hp, hd]: ``q1 . k1`` and ``q2 . k2`` over the one
+        value pair, each with its own softmax."""
+        return pair_outputs(
+            self.attend(pair_queries(q1, q2, pairs), **attn), pairs)
+
+    def window(self, window_layer) -> "_WindowView":
+        return _WindowView(self.rings, window_layer,
+                           self.tile["pages_per_chunk"])
+
+
+class _Rings:
+    """The window layers' ring pool (``kernels/window_ops``) for one layer
+    step: ``[window_layers * slots + 1, W, *row]``, a sequence's ring of
+    window layer ``w`` at row ``w * slots + slot``, the last row the trash
+    ring.  Shared by the step's views as the page pool is."""
+
+    def __init__(self, pool, *, op, batch, slots, mode, valid):
+        self.pool, self.op, self.batch = pool, op, batch
+        self.slots, self.mode, self.valid = slots, mode, valid
+
+
+class _WindowView:
+    """One window layer as a body gets it: called with ``(q, k, v,
+    **attn)`` it appends the new tokens' rows to their rings and attends
+    each query to its window (the halves cannot be had apart: a prefill
+    chunk reads the ring as it was BEFORE the chunk); ``pair`` is the
+    differential form of the same (``_LayerCache.attend_pair``)."""
+
+    def __init__(self, rings: _Rings, window_layer, pages_per_chunk):
+        self.rings, self.layer, self.ppc = rings, window_layer, \
+            pages_per_chunk
+
+    def __call__(self, q, k, v, **attn):
+        r = self.rings
+        rows = _slot_rows(r.batch, self.layer, r.slots, r.pool.shape[0] - 1)
+        out, r.pool = r.op(q, k, v, r.pool, rows, mode=r.mode, batch=r.batch,
+                           valid=r.valid, pages_per_chunk=self.ppc, **attn)
+        return out.astype(q.dtype)
+
+    def pair(self, q1, q2, k, v, *, pairs: int, **attn):
+        return pair_outputs(self(pair_queries(q1, q2, pairs), k, v, **attn),
+                            pairs)
+
 
 class _LayerState:
     """The state pool as a layer body gets it: ``state(state_layer,
@@ -174,9 +235,8 @@ class _LayerState:
         self.slots, self.mode, self.valid = slots, mode, valid
 
     def __call__(self, state_layer, *inputs):
-        slot = self.batch["state_slot"]
-        rows = jnp.where(slot < self.slots, slot + state_layer * self.slots,
-                         self.pool[0].shape[0] - 1)
+        rows = _slot_rows(self.batch, state_layer, self.slots,
+                          self.pool[0].shape[0] - 1)
         out, self.pool = self.update(*inputs, self.pool, rows,
                                      mode=self.mode, batch=self.batch,
                                      valid=self.valid)
@@ -214,16 +274,19 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
             f"{type(family.row).__name__} pages"
             + (" that carry index keys (sparse attention)"
                if family.row.index is not None else ""))
-    state_pool = None
-    if family.state is not None:
+    state_pool = rings = None
+    has_slot = bool(family.slot_kinds)
+    if has_slot:
         if verify_mode:
             raise NotImplementedError(
                 "speculative verify windows are not supported with "
                 "recurrent state: a rejected candidate cannot be taken back "
                 "out of a state (ROADMAP R5)")
         kv_pages, state_pool = kv_pages
-    batch = _unpack_batch(batch, max_q, max_seqs, max_blocks,
-                          family.state is not None)
+        if family.window is not None:
+            # the window layers' rings lie behind the state's arrays
+            state_pool, rings = tuple(state_pool[:-1]), state_pool[-1]
+    batch = _unpack_batch(batch, max_q, max_seqs, max_blocks, has_slot)
     layer_cache = partial(
         _LayerCache, ops=ops, batch=batch, attn_impl=attn_impl,
         num_blocks=num_blocks, max_q=max_q, block_q=block_q,
@@ -235,12 +298,19 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
         lambda: batch["page_of_token"] < num_blocks)
     counts = jnp.zeros((family.counts.size,), jnp.int32) \
         if family.counts else None
-    layer_state = None if state_pool is None else partial(
-        _LayerState, update=state_ops(family.state), batch=batch,
-        slots=(state_pool[0].shape[0] - 1) // family.state.num_layers,
-        valid=batch["page_of_token"] < num_blocks,
+    # (made only for a family with a slot: a program without one keeps the
+    # parent's text, operation for operation)
+    slot_mode = dict(
+        batch=batch, valid=batch["page_of_token"] < num_blocks,
         mode="oracle" if attn_impl != "paged"
-        else "decode" if decode_mode else "ragged")
+        else "decode" if decode_mode else "ragged") if has_slot else {}
+    layer_state = None if not state_pool else partial(
+        _LayerState, update=state_ops(family.state),
+        slots=(state_pool[0].shape[0] - 1) // family.state.num_layers,
+        **slot_mode)
+    layer_rings = None if rings is None else partial(
+        _Rings, op=window_op(family.row, family.window),
+        slots=(rings.shape[0] - 1) // family.window.num_layers, **slot_mode)
 
     for stack in family.stacks(params):
         def layer_step(carry, inputs, body=stack.body):
@@ -249,9 +319,11 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
             # from the pool.  Scanning the cache as xs/ys instead would
             # slice-copy one full layer per iteration AND restack the whole
             # cache per forward — O(cache) HBM per decode step.
-            x, pages, counts, state_pool = carry
+            x, pages, counts, state_pool, rings = carry
             lp, l_idx = inputs
-            cache = layer_cache(pages, l_idx)
+            # the rings ride the carry too, shared by the step's views
+            cache = layer_cache(pages, l_idx, rings=layer_rings
+                                and layer_rings(rings))
             if layer_state is None:
                 out = body(x, lp, l_idx, cache, ctx)
             else:
@@ -262,12 +334,13 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
             x, c = out if family.counts else (out, None)
             if c is not None:
                 counts = counts + c
-            return (x, cache.pages, counts, state_pool), None
+            return (x, cache.pages, counts, state_pool,
+                    cache.rings and cache.rings.pool), None
 
         with jax.named_scope(stack.scope) if stack.scope \
                 else contextlib.nullcontext():
-            (x, kv_pages, counts, state_pool), _ = jax.lax.scan(
-                layer_step, (x, kv_pages, counts, state_pool),
+            (x, kv_pages, counts, state_pool, rings), _ = jax.lax.scan(
+                layer_step, (x, kv_pages, counts, state_pool, rings),
                 (stack.params, jnp.arange(
                     stack.layers.start, stack.layers.stop, dtype=jnp.int32)))
 
@@ -277,8 +350,9 @@ def ragged_forward(params, kv_pages: jnp.ndarray, batch,
         params, x, (lambda x: x) if verify_mode
         else (lambda x: jnp.take(x, batch["logit_idx"], axis=0)))    # [S, V]
     logits = logits.astype(jnp.float32)
-    if state_pool is not None:
-        kv_pages = (kv_pages, state_pool)
+    if has_slot:
+        kv_pages = (kv_pages, tuple(state_pool or ())
+                    + (() if rings is None else (rings,)))
     return (logits, kv_pages, counts) if family.counts \
         else (logits, kv_pages)
 
@@ -393,7 +467,7 @@ def build_decode_loop(family: ServingFamily, *, max_q: int, max_seqs: int,
     # (a family with state: its rows' slots ride behind the block table and
     # do not advance; ``kv_pages`` below is the pair of pools)
     layout = pack_layout(max_q, max_seqs, max_blocks,
-                         family.state is not None)
+                         bool(family.slot_kinds))
     NB, bs = max_blocks, block_size
     S = max_seqs
     # A decode row costs one flat token, so at most min(max_seqs, max_q)

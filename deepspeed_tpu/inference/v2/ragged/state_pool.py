@@ -18,6 +18,11 @@ array out in tiles of its two minor axes (a float32 ``[96, 192]`` state as
 ``mem_bytes`` asks the arrays (``on_device_size_in_bytes``) and
 ``pad_share`` says what share of that is padding.  How a state is stored is
 the state kind's (``GatedDeltaState.arrays``).
+
+A slot may hold SEVERAL kinds of thing, each over its own number of layers
+(a selective-scan state in 9 layers and a window ring in 8:
+``ServingFamily.slot_kinds``): the pool is then the kinds' arrays one after
+the other, each ``[its layers * slots + 1, ...]``, under the one slot id.
 """
 from __future__ import annotations
 
@@ -25,12 +30,14 @@ import jax.numpy as jnp
 
 
 class StatePool:
-    def __init__(self, kind, slots: int, dtype=jnp.bfloat16):
-        self.kind = kind
+    def __init__(self, kinds, slots: int, dtype=jnp.bfloat16):
+        """``kinds``: what a slot holds, in the pool's order
+        (``ServingFamily.slot_kinds``)."""
+        self.kinds = tuple(kinds)
         self.slots = int(slots)
-        rows = kind.num_layers * self.slots + 1
-        self.arrays = tuple(jnp.zeros((rows,) + shape, dt)
-                            for shape, dt in kind.arrays(dtype))
+        self.arrays = tuple(
+            jnp.zeros((k.num_layers * self.slots + 1,) + shape, dt)
+            for k in self.kinds for shape, dt in k.arrays(dtype))
         #: bytes of the values, and bytes the device holds for them (shapes
         #: never change, so both are read once)
         self.value_bytes = sum(a.size * a.dtype.itemsize for a in self.arrays)

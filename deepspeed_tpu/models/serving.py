@@ -41,7 +41,24 @@ of it, and a body is called ``body(x, lp, layer, cache, ctx, state)``.
 tokens through every sequence's slot (``kernels/gdn_ops.gdn_mix``) and
 returns its output; ``state_layer`` counts the state-holding layers only, as
 ``layer`` given to ``cache`` counts the page-owning ones (``page_layers``).
-A body never sees a slot.
+A body never sees a slot.  The state kind names its recurrence
+(``recurrence``): ``"gated_delta"`` (:class:`GatedDeltaState`) or
+``"selective"`` (:class:`SelectiveScanState`, ``kernels/ssm_ops.ssm_mix``).
+
+A family some of whose attention layers read only the last ``window`` tokens
+says so with ``window`` (:class:`WindowRing`): those layers own NO page
+layer; a sequence holds a ring of ``window`` rows of the family's row kind
+in each of them, in its slot of the state pool, whatever its length.  A body
+takes such a layer from the same handle, ``cache.window(window_layer)``,
+which appends and attends like a page layer's view
+(``kernels/window_ops``).
+
+Layers may SHARE a page layer: a body that calls ``cache.at(page_layer)
+.attend(q, ...)`` and appends nothing reads what another layer's body wrote
+(cross-layer K/V sharing).  ``attend_pair(q1, q2, ...)`` of either view is
+the DIFFERENTIAL read of a K/V row kind whose heads are pairs (``K`` row
+``[k1 | k2]``, ``V`` row ``[v1 | v2]``): two score sets over one value pair,
+each with its own softmax, returned apart.
 """
 from __future__ import annotations
 
@@ -181,6 +198,7 @@ class GatedDeltaState:
     key_dim: int
     value_dim: int
     conv_kernel: int
+    recurrence = "gated_delta"
     #: the delta rule's ``beta`` lies in (0, ``beta_max``): 1, or 2 where
     #: ``I - beta k k^T`` may have eigenvalue -1.  The kernels take ``beta``
     #: as it comes; the chunked form inverts ``I + A`` by squarings only
@@ -224,6 +242,77 @@ class GatedDeltaState:
         return self.num_layers * sum(
             math.prod(shape) * jnp.dtype(dt).itemsize
             for shape, dt in self.arrays(dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class SelectiveScanState:
+    """What a sequence owns in each of ``num_layers`` selective-scan
+    (Mamba-1) layers: the state ``[state_dim, channels]`` (float32; every
+    channel has ``state_dim`` values, each with its own decay) and the
+    causal convolution's last ``conv_kernel - 1`` inputs over the channels
+    (in the serving dtype).  The state is STORED channels-minor: ``[16,
+    5120]`` float32 is whole (8, 128) tiles, ``[5120, 16]`` would be padded
+    eightfold along the lanes.
+
+    As a :class:`GatedDeltaState`, it can be restored only at the token it
+    was saved at: the prefix cache, speculative verify windows, the host
+    tier and ``kv_ship`` are refused by name (ROADMAP R5)."""
+
+    num_layers: int
+    channels: int
+    state_dim: int
+    conv_kernel: int
+    recurrence = "selective"
+
+    def arrays(self, dtype) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
+        """(shape, dtype) of what one slot holds in one layer."""
+        import jax.numpy as jnp
+
+        return (((self.state_dim, self.channels), jnp.float32),
+                ((self.conv_kernel - 1, self.channels), dtype))
+
+    slot_bytes = GatedDeltaState.slot_bytes
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowRing:
+    """``num_layers`` attention layers read the last ``window`` tokens only
+    (a token attends itself and the ``window - 1`` before it).  A sequence
+    holds, in each, a RING of ``window`` rows of the family's row kind in
+    its state-pool slot: the row of position ``p`` lies at ``p % window``,
+    so a new token's row replaces the one that just left the window and the
+    ring never holds more, however long the sequence.  Valid only where
+    attention is a function of the SET of rows (no positional term inside
+    the scores): the ring forgets which row is which position.  What ships
+    or re-reads cached tokens is refused as for a recurrent state: the ring
+    at an earlier token is gone."""
+
+    num_layers: int
+    window: int
+    #: rows of a page of the ring as the decode kernel walks it (the ring is
+    #: ``window // page`` pages of its own; no table, no allocator)
+    page: int = 64
+
+    def __post_init__(self):
+        if self.window % self.page:
+            raise ValueError(f"window {self.window} is not whole pages of "
+                             f"{self.page} rows")
+
+
+@dataclasses.dataclass(frozen=True)
+class _RingOf:
+    """A :class:`WindowRing` of a row kind, as the state pool takes a slot
+    holder: ``num_layers`` and ``arrays(dtype)``."""
+
+    ring: WindowRing
+    row: Any
+
+    @property
+    def num_layers(self) -> int:
+        return self.ring.num_layers
+
+    def arrays(self, dtype):
+        return (((self.ring.window,) + self.row.token_shape, dtype),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,7 +361,14 @@ class ServingFamily:
     counts: Optional[ExpertPairs] = None
     #: per-sequence recurrent state of some layers (None: every layer caches
     #: rows); bodies then take a ``state`` handle after ``ctx``
-    state: Optional[GatedDeltaState] = None
+    state: Union[GatedDeltaState, SelectiveScanState, None] = None
+    #: attention layers that read a bounded window and keep a ring of rows
+    #: in the sequence's slot instead of pages (None: none)
+    window: Optional[WindowRing] = None
+    #: layer bodies that READ each page layer a forward (None: one each, the
+    #: body that appends to it): ``(8,)`` where seven later layers attend
+    #: the rows one layer wrote.  What the engine accounts, not what it runs
+    page_readers: Optional[Tuple[int, ...]] = None
     #: page layers of the pool (None: one a layer, ``num_layers``): fewer
     #: where some layers keep state instead, more where a body owns several
     page_layer_count: Optional[int] = None
@@ -281,3 +377,12 @@ class ServingFamily:
     def page_layers(self) -> int:
         return self.num_layers if self.page_layer_count is None \
             else self.page_layer_count
+
+    @property
+    def slot_kinds(self) -> Tuple[Any, ...]:
+        """What a sequence's slot of the state pool holds, in the pool's
+        order: the recurrent state's arrays, then the window ring."""
+        kinds = () if self.state is None else (self.state,)
+        if self.window is not None:
+            kinds += (_RingOf(self.window, self.row),)
+        return kinds

@@ -618,7 +618,55 @@ def _olmo_hybrid(decode):
     return build
 
 
+def _phi4_flash(decode, bucket=512):
+    """The benchmark's Phi-4-mini-flash configuration, whole (32 layers,
+    published widths, the whole vocabulary): a fused decode window of 64
+    sequences x 2 steps (the selective scan's one-token form, the K/V decode
+    kernel on 16 stored row pairs over the window layers' rings AND over
+    the one page layer, eight readers), or a SplitFuse step of ``bucket``
+    tokens (the blocked scan, the ragged window form, the ragged page
+    kernel); page pool, state pool and rings in the carry."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.phi4_flash import (Phi4FlashConfig,
+                                                     Phi4FlashLM)
+
+        model = Phi4FlashLM(Phi4FlashConfig())
+        family = model.serving_family()
+        assert family.row.token_shape == (32, 128)
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
+        seqs, blocks, nb = 64, 4800 // PAGE, 4800
+        cache = (_on(dev, (nb + 1, PAGE) + family.row.token_shape),
+                 tuple(_on(dev, (kind.num_layers * seqs + 1,) + shape, dtype)
+                       for kind in family.slot_kinds
+                       for shape, dtype in kind.arrays(BF16)))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            loop = build_decode_loop(family, max_q=seqs, block_size=PAGE,
+                                     steps=2, **kw)
+            meta = pack_layout(seqs, seqs, blocks, True)["_total"][0]
+            return loop, (params, cache, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(family, max_q=bucket, **kw)
+        meta = pack_layout(bucket, seqs, blocks, True)["_total"][0]
+        return step, (params, cache, _on(dev, (meta,), jnp.int32))
+    return build
+
+
 CASES = {
+    "phi4flash_decode_window": _phi4_flash(decode=True),
+    "phi4flash_prefill_step": _phi4_flash(decode=False),
+    # EVERY prefill bucket (PR 34's lesson, learnt again in PR 55: of these
+    # only the 32-token bucket ran out of scoped VMEM on the chip)
+    **{f"phi4flash_prefill_step[{rows} rows]":
+       _phi4_flash(decode=False, bucket=rows)
+       for rows in (16, 32, 64, 128, 256)},
     "gdn_decode": _gdn_decode,
     "qwen3next_decode_window": _qwen3next(decode=True),
     "qwen3next_prefill_step": _qwen3next(decode=False),

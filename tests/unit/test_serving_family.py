@@ -20,6 +20,7 @@ from deepspeed_tpu.inference.v2.engine_v2 import (
 )
 from deepspeed_tpu.models.families import ArchConfig, UniversalCausalLM
 from deepspeed_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridLM
+from deepspeed_tpu.models.phi4_flash import Phi4FlashConfig, Phi4FlashLM
 from deepspeed_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
 from deepspeed_tpu.models.serving import (IndexKey, KVRow, LayerStack,
                                           ServingFamily)
@@ -362,12 +363,180 @@ def test_a_family_with_recurrent_state_is_served(impl):
     assert eng.state_manager.get_sequence(2).slot == slot
 
 
+# --------------------------------------------------------------------- #
+# A family with a SELECTIVE-SCAN state and WINDOW layers, written here
+# --------------------------------------------------------------------- #
+class WindowedLM(RenamedLM):
+    """``RenamedLM`` without rotary (a ring of rows forgets their order)
+    whose every period is a selective-scan mixer (``SelectiveScanState``),
+    an attention layer over the last ``W`` tokens (``WindowRing``: rows in
+    the sequence's slot, no page layer) and the full-attention block.  Its
+    dense forward runs the scan token by token and a banded mask."""
+
+    C, N, K, W = 16, 4, 3, 8
+
+    def init_params(self, key, dtype=jnp.float32):
+        c = self.config
+        params = super().init_params(key, dtype)
+        D, kv = c.width, c.kv_heads * (c.width // c.heads)
+        keys = iter(jax.random.split(jax.random.fold_in(key, 2), 12))
+        n = lambda *s: jax.random.normal(next(keys), s).astype(dtype)  # noqa: E731
+        params["scan"] = {
+            "inp": n(c.depth, D, self.C) / math.sqrt(D),
+            "conv": n(c.depth, self.K, self.C) / math.sqrt(self.K),
+            "bias": n(c.depth, self.C) / 3,
+            "proj": n(c.depth, self.C, self.C + 2 * self.N) / 4,
+            "A": -jax.random.uniform(next(keys), (c.depth, self.N, self.C),
+                                     minval=0.05, maxval=1.0).astype(dtype),
+            "skip": n(c.depth, self.C),
+            "out": n(c.depth, self.C, D) / 4}
+        params["win"] = {"wq": n(c.depth, D, D) / math.sqrt(D),
+                         "wk": n(c.depth, D, kv) / math.sqrt(D),
+                         "wv": n(c.depth, D, kv) / math.sqrt(D),
+                         "wo": n(c.depth, D, D) / math.sqrt(D)}
+        return params
+
+    def _scan_proj(self, sp):
+        def proj(x):
+            r = x @ sp["proj"]
+            return (jax.nn.softplus(r[:, :self.C]),
+                    r[:, self.C:self.C + self.N], r[:, self.C + self.N:])
+        return proj
+
+    def _win_qkv(self, x, wp):
+        c, T = self.config, x.shape[0]
+        h = rms_norm(x, jnp.ones((c.width,), x.dtype), c.eps)
+        return ((h @ wp[w]).reshape(T, n, -1) for w, n in
+                (("wq", c.heads), ("wk", c.kv_heads), ("wv", c.kv_heads)))
+
+    def __call__(self, params, tokens):
+        c = self.config
+        S, hd = tokens.shape[0], c.width // c.heads
+        cos, sin = rope_at(jnp.zeros((S,), jnp.int32), hd, c.theta)
+        x = params["wte"][tokens]
+        causal = jnp.tril(jnp.ones((S, S), bool))
+        band = causal & ~jnp.tril(jnp.ones((S, S), bool), -self.W)
+
+        def attend(q, k, v, mask):
+            k = jnp.repeat(k, c.heads // c.kv_heads, axis=1)
+            v = jnp.repeat(v, c.heads // c.kv_heads, axis=1)
+            s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(hd)
+            s = jnp.where(mask[None], s, -1e30)
+            return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+
+        for i in range(c.depth):
+            sp = jax.tree.map(lambda a: a[i], params["scan"])
+            u = x @ sp["inp"]
+            padded = jnp.concatenate([jnp.zeros((self.K - 1, self.C)), u])
+            xs = jax.nn.silu(sum(sp["conv"][j][None] * padded[j:j + S]
+                                 for j in range(self.K)) + sp["bias"])
+            delta, B, Cm = self._scan_proj(sp)(xs)
+            state, ys = jnp.zeros((self.N, self.C)), []
+            for t in range(S):
+                state = jnp.exp(delta[t][None] * sp["A"]) * state \
+                    + (delta[t] * xs[t])[None] * B[t][:, None]
+                ys.append(jnp.sum(state * Cm[t][:, None], axis=0)
+                          + sp["skip"] * xs[t])
+            x = x + jnp.stack(ys) @ sp["out"]
+            wp = jax.tree.map(lambda a: a[i], params["win"])
+            q, k, v = self._win_qkv(x, wp)
+            x = x + attend(q, k, v, band).reshape(S, -1) @ wp["wo"]
+            bp = jax.tree.map(lambda a: a[i], params["blocks"])
+            q, k, v = self._qkv(x, bp, cos, sin)
+            x = self._rest(x, attend(q, k, v, causal), bp)
+        return rms_norm(x, params["nf"], c.eps) @ params["out"]
+
+    def serving_family(self) -> ServingFamily:
+        from deepspeed_tpu.models.serving import (SelectiveScanState,
+                                                  WindowRing)
+
+        c = self.config
+        hd = c.width // c.heads
+        base = super().serving_family()
+        scale = 1.0 / math.sqrt(hd)
+
+        def embed(params, ids, pos, valid):
+            return params["wte"][ids], rope_at(jnp.zeros_like(pos), hd,
+                                               c.theta)
+
+        def period(x, lp, p_idx, cache, ctx, state):
+            bp, sp, wp = lp
+            y = state(p_idx, x @ sp["inp"], sp["conv"], sp["bias"],
+                      self._scan_proj(sp), sp["A"], sp["skip"])
+            x = x + y.astype(x.dtype) @ sp["out"]
+            q, k, v = self._win_qkv(x, wp)
+            o = cache.window(p_idx)(q, k, v, scale=scale)
+            x = x + o.reshape(x.shape[0], -1) @ wp["wo"]
+            q, k, v = self._qkv(x, bp, *ctx)
+            o = cache(q, k, v, scale=scale).astype(x.dtype)
+            return self._rest(x, o, bp)
+
+        def stacks(params):
+            yield LayerStack((params["blocks"], params["scan"],
+                              params["win"]), range(c.depth), period)
+
+        return dataclasses.replace(
+            base, num_layers=3 * c.depth, embed=embed, stacks=stacks,
+            page_layer_count=c.depth,
+            state=SelectiveScanState(num_layers=c.depth, channels=self.C,
+                                     state_dim=self.N, conv_kernel=self.K),
+            window=WindowRing(num_layers=c.depth, window=self.W, page=4))
+
+
+@pytest.mark.parametrize("impl", ["paged", "gather"])
+def test_a_family_with_a_scan_state_and_window_layers_is_served(impl):
+    """A second state kind and a window, both named by a family written
+    HERE: chunks that cross the window and wrap the ring, put() steps, a
+    fused window, a reused slot — against the dense forward.  The engine
+    sized the state pool and the rings from the family's descriptors alone,
+    and a long sequence holds the rows a short one does."""
+    model = WindowedLM(RenamedConfig(depth=2))
+    params = model.init_params(jax.random.PRNGKey(0))
+    eng = _engine(model, params, impl, max_tokens=16)
+    assert eng.kv.pages.shape[0] == 2 * eng.kv.config.num_blocks + 1
+    state, carry, ring = eng.state_pool.arrays
+    assert state.shape == (2 * 2 + 1, 4, 16) and state.dtype == jnp.float32
+    assert carry.shape == (2 * 2 + 1, 2, 16)
+    assert ring.shape == (2 * 2 + 1, 8, 2 * 2, 8)
+    rng = np.random.default_rng(0)
+    a, b = (rng.integers(1, 88, size=n).tolist() for n in (29, 11))
+
+    def same(got, seq):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(model(params, jnp.asarray(seq))[-1]),
+            atol=3e-4, rtol=3e-4)
+
+    eng.put([0], [a[:9]])                       # past the window already
+    eng.put([0], [a[9:18]])                     # a chunk over a ring wrap
+    logits = eng.put([0, 1], [a[18:], b[:5]])   # two sequences' chunks
+    same(logits[0], a)
+    same(logits[1], b[:5])
+    logits = eng.put([1, 0], [b[5:], [int(jnp.argmax(logits[0]))]])
+    a = a + [int(jnp.argmax(model(params, jnp.asarray(a))[-1]))]
+    same(logits[0], b)
+    same(logits[1], a)
+    seeds = [int(jnp.argmax(logits[1])), int(jnp.argmax(logits[0]))]
+    window = eng.decode_batch([0, 1], seeds, 3)
+    for col, chain in enumerate((a + seeds[:1], b + seeds[1:])):
+        for tok in window[:, col].tolist():
+            assert tok == int(jnp.argmax(model(params, jnp.asarray(chain))[-1]))
+            chain.append(tok)
+    slot = eng.state_manager.get_sequence(1).slot
+    eng.flush([1])
+    fresh = rng.integers(1, 88, size=5).tolist()    # shorter than the window
+    same(eng.put([2], [fresh])[0], fresh)
+    assert eng.state_manager.get_sequence(2).slot == slot
+    assert eng.state_pool.arrays[2].shape == ring.shape
+
+
 #: the families that hold recurrent state: what re-reads, parks or ships
 #: cached tokens is refused for each BY NAME, the same way
 STATEFUL = {
     "hybrid_in_this_file": lambda: HybridLM(RenamedConfig(depth=2)),
     "qwen3_next": lambda: Qwen3NextLM(Qwen3NextConfig.tiny()),
     "olmo_hybrid": lambda: OlmoHybridLM(OlmoHybridConfig.tiny()),
+    "windowed_in_this_file": lambda: WindowedLM(RenamedConfig(depth=2)),
+    "phi4_flash": lambda: Phi4FlashLM(Phi4FlashConfig.tiny()),
 }
 
 
@@ -392,8 +561,11 @@ def test_a_family_with_state_refuses_what_a_state_cannot_do(name):
                           max_seqs=2, max_blocks=4, jit=False)(
             eng.params, eng._cache(), jnp.zeros((64,), jnp.int32))
     fam = model.serving_family()
-    assert fam.page_layers < fam.num_layers and fam.state.num_layers \
-        == fam.num_layers - fam.page_layers
+    # the layers that keep a state or a ring are among those without pages
+    held = fam.state.num_layers + (fam.window.num_layers if fam.window else 0)
+    assert fam.page_layers < fam.num_layers \
+        and held <= fam.num_layers - fam.page_layers
+    assert fam.window is not None or held == fam.num_layers - fam.page_layers
 
 
 class PairedLM(RenamedLM):
