@@ -52,7 +52,9 @@ place, the SiLU folded in.  The pool is pinned to HBM there: it is small
 enough (53-71 MB) that XLA would otherwise stage ALL of it through VMEM
 around every call.  Ragged batches and the oracle keep
 :func:`causal_conv_ragged` (``jax.numpy``: gathers by token and a row
-scatter), which is also the decode form's numerics reference.
+scatter), which is also the decode form's numerics reference.  The selective
+scan's convolution (``ssm_ops``) is the same two forms with a ``bias``
+handed to the kernel.
 """
 from __future__ import annotations
 
@@ -136,14 +138,16 @@ def causal_conv_ragged(x, conv_w, carry_pool, rows, *, seq_of_token,
     return out, carry_pool.at[rows].set(new.astype(carry_pool.dtype))
 
 
-def _gdn_conv_step_kernel(rows_ref, keep_ref, x_ref, w_ref, c_ref, o_ref,
-                          c_out_ref, xf_ref, *, K: int, rb: int):
+def _gdn_conv_step_kernel(rows_ref, keep_ref, x_ref, w_ref, *refs, K: int,
+                          rb: int):
     """One grid step = one sequence.  ``x`` / ``o`` blocks are ``[rb, C]``
     (``rb`` consecutive rows share one: fetched, and written back, once
     for all of them), the taps ``[K, C]`` (the same block every step), the
     carry ``[1, K - 1, C]`` at the sequence's pool row; ``xf`` is the ``x``
-    block in float32."""
+    block in float32.  ``refs`` opens with the bias ``[1, C]`` where the
+    convolution has one."""
     del rows_ref
+    *b_ref, c_ref, o_ref, c_out_ref, xf_ref = refs
     r = pl.program_id(0)
     i = r % rb
 
@@ -158,20 +162,24 @@ def _gdn_conv_step_kernel(rows_ref, keep_ref, x_ref, w_ref, c_ref, o_ref,
     out = w_ref[K - 1:K, :] * x
     for d in range(1, K):            # causal_conv_ragged's order of summation
         out = out + w_ref[K - 1 - d:K - d, :] * old[K - 1 - d:K - d]
+    for ref in b_ref:
+        out = out + ref[...]
     o_ref[pl.ds(i, 1), :] = out * jax.nn.sigmoid(out)
     c_out_ref[0] = jnp.concatenate([old[1:], x], axis=0).astype(
         c_out_ref.dtype)
 
 
-def causal_conv_step(x, conv_w, carry_pool, rows, keep, *, interpret=None):
+def causal_conv_step(x, conv_w, carry_pool, rows, keep, bias=None, *,
+                     interpret=None):
     """The decode form of :func:`causal_conv_ragged` with the SiLU folded in:
     one new input a sequence row.  ``x`` [R, C], ``conv_w`` [K, C],
     ``carry_pool`` [N, K-1, C], ``rows`` [R] pool rows, ``keep`` [R] (False:
     the carry is zeros whatever the slot holds — a sequence's first token, a
-    padded row) → (``silu(conv)`` [R, C] float32, carry_pool updated in
-    place: each row's ``[K-1, C]`` read once and written once, shifted by one
-    input).  Several rows may name the trash row: one after the other, and
-    never read."""
+    padded row), ``bias`` [C] or None (added before the SiLU: the selective
+    scan's convolution has one, DeltaNet's has none) → (``silu(conv +
+    bias)`` [R, C] float32, carry_pool updated in place: each row's ``[K-1,
+    C]`` read once and written once, shifted by one input).  Several rows
+    may name the trash row: one after the other, and never read."""
     R, C = x.shape
     K = conv_w.shape[0]
     assert carry_pool.shape[1:] == (K - 1, C), \
@@ -182,13 +190,14 @@ def causal_conv_step(x, conv_w, carry_pool, rows, keep, *, interpret=None):
     row_block = pl.BlockSpec((rb, C), lambda r, rows, keep: (r // rb, 0))
     carry_block = pl.BlockSpec((1, K - 1, C),
                                lambda r, rows, keep: (rows[r], 0, 0))
+    whole = lambda n: pl.BlockSpec((n, C), lambda r, rows, keep: (0, 0))  # noqa: E731
+    biases = [] if bias is None else [bias.astype(jnp.float32)[None]]
     return pl.pallas_call(
         functools.partial(_gdn_conv_step_kernel, K=K, rb=rb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(R,),
-            in_specs=[row_block,
-                      pl.BlockSpec((K, C), lambda r, rows, keep: (0, 0)),
-                      carry_block],
+            in_specs=[row_block, whole(K)] + [whole(1) for _ in biases]
+            + [carry_block],
             out_specs=[row_block, carry_block],
             scratch_shapes=[pltpu.VMEM((rb, C), jnp.float32)]),
         # the pool (output and, through the alias, operand) is pinned to
@@ -196,13 +205,13 @@ def causal_conv_step(x, conv_w, carry_pool, rows, keep, *, interpret=None):
         # pool that fits (53-71 MB) into VMEM before the call and back out
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
                    pltpu.HBM(carry_pool.shape, carry_pool.dtype)],
-        # operands count the scalar prefetches: the pool is operand 4
-        input_output_aliases={4: 1},
+        # operands count the scalar prefetches: the pool is the last one
+        input_output_aliases={4 + len(biases): 1},
         interpret=_interpret() if interpret is None else interpret,
         # not "gdn_decode…": the benchmark reads that kernel by name
         name="gdn_conv_step",
     )(rows.astype(jnp.int32), keep.astype(jnp.int32), x,
-      conv_w.astype(jnp.float32), carry_pool)
+      conv_w.astype(jnp.float32), *biases, carry_pool)
 
 
 # --------------------------------------------------------------------- #

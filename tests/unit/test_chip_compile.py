@@ -59,13 +59,14 @@ def for_the_chip(monkeypatch):
     from deepspeed_tpu.accelerator import real_accelerator
     from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
     from deepspeed_tpu.inference.v2.kernels import (gdn_ops, mla_ops,
-                                                    ragged_ops, sparse_ops)
+                                                    ragged_ops, sparse_ops,
+                                                    ssm_ops)
     from deepspeed_tpu.kernels import fused_collective_matmul as fcm
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.adam import fused_adam
     from deepspeed_tpu.ops.transformer import flash_attention as fa
 
-    for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, sparse_ops,
+    for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, sparse_ops, ssm_ops,
                 fused_adam):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
@@ -527,10 +528,11 @@ def _gdn_decode_olmo(dev):
                         _on(dev, (rows,), jnp.int32))
 
 
-def _gdn_conv_step(rows, channels):
+def _gdn_conv_step(rows, channels, bias=False):
     """A layer's decode-form convolution alone at a recurrent cell's shape:
     ``rows`` sequences x ``channels``, the bf16 carry pool of 6 x rows + 1
-    slots of [3, channels] aliased in place, a row's block at its slot."""
+    slots of [3, channels] aliased in place, a row's block at its slot;
+    ``bias``: the selective scan's, added before the SiLU."""
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels.gdn_ops import \
             causal_conv_step
@@ -538,8 +540,25 @@ def _gdn_conv_step(rows, channels):
         return causal_conv_step, (
             _on(dev, (rows, channels)), _on(dev, (4, channels)),
             _on(dev, (6 * rows + 1, 3, channels)),
-            _on(dev, (rows,), jnp.int32), _on(dev, (rows,), jnp.bool_))
+            _on(dev, (rows,), jnp.int32), _on(dev, (rows,), jnp.bool_)) \
+            + ((_on(dev, (channels,)),) if bias else ())
     return build
+
+
+def _ssm_decode(dev):
+    """A scan layer's one-token update alone at the Phi-4-mini-flash cell's
+    shape: 64 rows x a [16, 5120] float32 state, the pool of 9 x 64 + 1
+    slots aliased in place."""
+    from deepspeed_tpu.inference.v2.kernels.ssm_ops import ssm_decode
+
+    f32 = jnp.float32
+    rows, N, C = 64, 16, 5120
+    vec, col = _on(dev, (rows, C), f32), _on(dev, (rows, N), f32)
+    return ssm_decode, (vec, vec, col, col, _on(dev, (N, C), f32),
+                        _on(dev, (C,), f32),
+                        _on(dev, (9 * rows + 1, N, C), f32),
+                        _on(dev, (rows,), jnp.int32),
+                        _on(dev, (rows,), jnp.bool_))
 
 
 def _paged_stored_heads(op, rows=512):
@@ -656,6 +675,19 @@ def _phi4_flash(decode, bucket=512):
         step = build_ragged_step(family, max_q=bucket, **kw)
         meta = pack_layout(bucket, seqs, blocks, True)["_total"][0]
         return step, (params, cache, _on(dev, (meta,), jnp.int32))
+
+    def scan_layers_run_two_kernels_in_place(compiled):
+        """A scan layer's decode form is two Mosaic calls on the pools
+        where they lie: no gathered ``[rows, 16, 5120]`` states, and no
+        more temporaries than the XLA form's window had (0.09 GiB, PR 55)."""
+        text = compiled.as_text()
+        for kernel in ("ssm_decode", "gdn_conv_step"):
+            assert f"/{kernel}/pallas_call" in text, kernel
+        assert "f32[64,16,5120]" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.09 * 2 ** 30
+
+    if decode:
+        build.check = scan_layers_run_two_kernels_in_place
     return build
 
 
@@ -731,6 +763,9 @@ CASES = {
     # the decode-form convolution alone, at the two recurrent cells' shapes
     "gdn_conv_step[128 rows x 11520]": _gdn_conv_step(128, 11520),
     "gdn_conv_step[64 rows x 8192]": _gdn_conv_step(64, 8192),
+    # the selective scan's decode form alone, at the Phi-4-mini-flash cell's
+    "gdn_conv_step[64 rows x 5120, bias]": _gdn_conv_step(64, 5120, True),
+    "ssm_decode[64 rows x 16 x 5120]": _ssm_decode,
     # LongCat-Flash: the shared latent kernels at 64 heads (every prefill
     # bucket's query tile, PR 34's lesson), and the double layer's programs
     "mla_paged_decode[64 heads]": _mla64(None),
@@ -762,7 +797,33 @@ def test_compiles_for_v5e(v5e, for_the_chip, case):
     assert getattr(build, "xla_only", False) \
         or "tpu_custom_call" in lowered.as_text(), \
         f"{case}: no Mosaic kernel in the lowered program"
-    lowered.compile()                   # raises what the chip would raise
+    compiled = lowered.compile()        # raises what the chip would raise
+    getattr(build, "check", lambda compiled: None)(compiled)
+
+
+#: ``causal_conv_step``'s kernel as the DeltaNet cells' programs held it
+#: before the convolution could take a bias (PR 35's, at [20, 256] bf16)
+CONV_STEP_OPERANDS = (
+    "j:Ref<smem>{i32[20]} k:Ref<smem>{i32[20]} l:Ref{bf16[16,256]} "
+    "m:Ref{f32[4,256]} n:Ref{bf16[1,3,256]} o:Ref{f32[16,256]} "
+    "p:Ref{bf16[1,3,256]} q:Ref<vmem>{f32[16,256]}")
+
+
+def test_the_deltanet_convolution_traces_as_before_the_bias():
+    """With no bias the shared kernel is the parent's program: the same
+    operands, the pool aliased at the same place, and not one equation
+    more (the bias is one ``get`` and one ``add``)."""
+    from deepspeed_tpu.inference.v2.kernels.gdn_ops import causal_conv_step
+
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((20, 256), BF16), ((4, 256), BF16), ((41, 3, 256), BF16),
+        ((20,), jnp.int32), ((20,), jnp.bool_), ((256,), BF16))]
+    text = " ".join(str(jax.make_jaxpr(causal_conv_step)(*args[:5])).split())
+    assert f"jaxpr={{ lambda ; {CONV_STEP_OPERANDS}. let" in text
+    assert "input_output_aliases=((4, 1),)" in text and len(text) == 3435
+    biased = " ".join(str(jax.make_jaxpr(causal_conv_step)(*args)).split())
+    assert "input_output_aliases=((5, 1),)" in biased
+    assert biased.count(" add ") == text.count(" add ") + 1
 
 
 @pytest.mark.parametrize("rows", [256, 512, 1024, 2048])

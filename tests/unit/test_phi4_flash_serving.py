@@ -403,6 +403,149 @@ def _scan_case(seed=0):
     return run, S
 
 
+#: a decode batch: (pool row, tokens behind it) a sequence row — ``None``
+#: = a padded row (it names the trash row); ``T`` tokens in the flat batch
+DECODE_BATCHES = {
+    "a kept row": ([(3, 7)], 1),
+    "a fresh row whose slot holds NaN": ([(1, 0), (3, 7)], 2),
+    "padded rows that all name the trash row":
+        ([(3, 7), None, None, None], 4),
+    "fewer rows than tokens": ([(3, 7), (0, 2)], 8),
+}
+
+
+def _decode_case(C, seqs, T, seed=0):
+    """One token a sequence through ``ssm_mix``: → (run(mode, pool, behind)
+    → (y, pool), the pool with NaN in slot 1 and in the trash row, the
+    tokens behind each sequence)."""
+    N, K, S = 16, 4, len(seqs)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    kind = SelectiveScanState(1, C, N, K)
+    trash = 5
+    pool = tuple(jax.random.normal(k, (trash + 1, n, C)).astype(dt)
+                 .at[jnp.asarray([1, trash])].set(jnp.nan)
+                 for k, n, dt in ((ks[0], N, jnp.float32),
+                                  (ks[1], K - 1, jnp.float32)))
+    live = jnp.asarray([s is not None for s in seqs])
+    rows = jnp.asarray([trash if s is None else s[0] for s in seqs],
+                       jnp.int32)
+    q_len = live.astype(jnp.int32)
+    offset = jnp.minimum(jnp.arange(S), T).astype(jnp.int32)
+    seq_of = jnp.minimum(jnp.arange(T), S - 1).astype(jnp.int32)
+    u = jax.random.normal(ks[2], (T, C))
+    wx = jax.random.normal(ks[3], (C, 3 * N)) / C ** 0.5
+    wdt = jax.random.normal(ks[3], (N, C)) / N ** 0.5
+
+    def proj(x):         # delta through a low rank, as the model's
+        r = x @ wx
+        return (jax.nn.softplus(r[:, 2 * N:] @ wdt - 1.0), r[:, :N],
+                r[:, N:2 * N])
+
+    args = (jax.random.normal(ks[5], (K, C)) / 2,
+            jax.random.normal(ks[6], (C,)) / 3, proj,
+            -jax.random.uniform(ks[4], (N, C), minval=0.05, maxval=4.0),
+            jax.random.normal(ks[7], (C,)))
+
+    def run(mode, pool, behind, u=u):
+        behind = jnp.asarray(behind, jnp.int32)
+        batch = dict(q_len=q_len, ctx_len=q_len + behind, q_offset=offset,
+                     seq_of_token=seq_of, pos_of_token=behind[seq_of])
+        return ssm_ops.ssm_mix(u, *args, pool, rows, kind=kind, mode=mode,
+                               batch=batch, valid=jnp.arange(T) < live.sum())
+
+    return run, pool, [0 if s is None else s[1] for s in seqs]
+
+
+@pytest.mark.parametrize("case", list(DECODE_BATCHES) + [
+    "two consecutive steps through a donated pool"])
+@pytest.mark.parametrize("C", [128, 5120])
+def test_the_decode_kernels_agree_with_the_oracle_and_the_ragged_form(
+        C, case):
+    """``ssm_decode`` and the convolution step (Pallas, interpreted here)
+    against the token-by-token form and the blocked scan on one-token
+    chunks: outputs and BOTH pools to float32 round-off, at a tiny width
+    and at the model's (a ``[16, 5120]`` state, four taps).  A fresh row
+    starts from zeros though its slot holds NaN; padded rows only ever
+    touch the trash row; a slot no row names is left as it was."""
+    seqs, T = DECODE_BATCHES.get(case, DECODE_BATCHES[
+        "a fresh row whose slot holds NaN"])
+    run, pool, behind = _decode_case(C, seqs, T)
+    steps = 1 + (case not in DECODE_BATCHES)
+    step = jax.jit(run, static_argnums=0, donate_argnums=1)
+    got = {}
+    for mode in ("decode", "ragged", "oracle"):
+        p, ys = jax.tree.map(jnp.copy, pool), []
+        for i in range(steps):
+            u = jax.random.normal(jax.random.PRNGKey(10 + i), (T, C))
+            y, p = step(mode, p, [b + i for b in behind], u)
+            ys.append(y)
+        got[mode] = ys, p
+    live = np.asarray([i for i, s in enumerate(seqs) if s is not None])
+    named = np.asarray(sorted(seqs[i][0] for i in live))
+    others = np.asarray([r for r in range(5) if r not in named])
+    ys, (state, carry) = got["decode"]
+    assert np.isfinite(np.asarray(state[named])).all()
+    for mode in ("oracle", "ragged"):
+        ys0, (state0, carry0) = got[mode]
+        for y, y0 in zip(ys, ys0):
+            np.testing.assert_allclose(y[live], y0[live], atol=2e-5,
+                                       rtol=2e-5, err_msg=mode)
+        np.testing.assert_allclose(state[named], state0[named], atol=2e-5,
+                                   rtol=2e-5, err_msg=mode)
+        np.testing.assert_allclose(carry[named], carry0[named], atol=1e-6,
+                                   err_msg=mode)
+    for new, old in zip((state, carry), pool):
+        np.testing.assert_array_equal(new[others], old[others])
+
+
+def test_the_decode_kernel_takes_its_channels_in_blocks(monkeypatch):
+    """All of a row's channels a grid step where the blocks fit the VMEM
+    they may take (the model's [16, 5120]: 1.9 MB), whole lane tiles that
+    divide them where they do not — and the blocked walk is the same
+    update."""
+    assert ssm_ops._channel_block(16, 5120) == 5120
+    assert ssm_ops._channel_block(16, 1 << 20) == 16384
+    seqs, T = DECODE_BATCHES["fewer rows than tokens"]
+    run, pool, behind = _decode_case(256, seqs, T)
+    whole = run("decode", pool, behind)
+    monkeypatch.setattr(ssm_ops, "_STATE_VMEM", 6 * 4 * 16 * 128)
+    assert ssm_ops._channel_block(16, 256) == 128
+    blocked = run("decode", pool, behind)
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(blocked)):
+        np.testing.assert_array_equal(np.asarray(a)[:5], np.asarray(b)[:5])
+
+
+def test_every_traced_scan_says_which_form_it_compiled(model):
+    """``attn/ssm_layout``, one ring record a traced scan layer: a decode
+    window took both kernels, a prefill step the blocked scan, the oracle
+    engine the token-by-token form."""
+    from deepspeed_tpu.telemetry.trace import get_tracer
+
+    def layouts(**kw):
+        before = len(get_tracer().records())
+        engine = engine_for(model, **kw)
+        engine.put([1], [prompt_tokens(5, 9)])
+        engine.decode_batch([1], [7], 2)
+        return [rec.attrs for rec in get_tracer().records()[before:]
+                if rec.name == "attn/ssm_layout"]
+
+    recs = layouts()
+    forms = {(a["form"], a["impl"], a["conv_impl"]) for a in recs}
+    assert forms == {("ragged", "xla", "xla"), ("decode", "kernel", "kernel")}
+    for a in recs:
+        assert (a["channels"], a["state_dim"], a["conv_kernel"],
+                a["state_dtype"]) == (128, 16, 4, "float32")
+        decode = a["form"] == "decode"
+        # a window's rows are its riders, a step's its token bucket
+        assert a["rows"] == (1 if decode else 16)
+        assert a["channel_block"] == (128 if decode else 0)
+    # the three scan layers lie in two stacks: a program traces two bodies
+    by_form = [sum(a["form"] == f for a in recs) for f in ("ragged", "decode")]
+    assert by_form[0] % 2 == 0 and by_form[1] % 2 == 0 and min(by_form) >= 2
+    assert {(a["form"], a["impl"], a["conv_impl"])
+            for a in layouts(attn_impl="gather")} == {("oracle", "xla", "xla")}
+
+
 def test_the_blocked_scan_agrees_with_the_token_by_token_oracle():
     run, S = _scan_case()
     (y, (state, carry)), (y0, (state0, carry0)) = run("ragged"), run("oracle")
