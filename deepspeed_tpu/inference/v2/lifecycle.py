@@ -302,6 +302,13 @@ class LifecycleScheduler:
         self._cancel_requested: set = set()
         #: the decode window dispatched and not yet drained, if any
         self._inflight: Optional[_InFlight] = None
+        #: what ``_may_run_ahead`` last said held the next window ("" = go)
+        self._held = ""
+        #: why the next window finds nothing in flight (its ``held_by``):
+        #: the reason of the FIRST drain since the last window went out,
+        #: ``first`` where none was in flight since the start or an idle
+        #: scheduler, "" while the last window is still in flight
+        self._boundary = "first"
         self._admit_seq = 0
         self.draining = False
         self.counters: "collections.Counter[str]" = collections.Counter()
@@ -935,7 +942,8 @@ class LifecycleScheduler:
             return []
         if self.drafter is not None and \
                 any(self._spec_k_for(self._reqs[u]) > 0 for u in uids):
-            sp.set(n_seqs=len(uids), steps=1, verify=True, ahead=0)
+            sp.set(n_seqs=len(uids), steps=1, verify=True, ahead=0,
+                   held_by="drafter")      # it needs the tokens, every time
             return self._run_verify_window(uids, room)
         # the window in flight (its riders are these: _settle saw to it)
         # has not given its tokens yet; what it will give is known by count
@@ -946,7 +954,9 @@ class LifecycleScheduler:
                     min(room[u] for u in uids))
         if steps > 2:       # pow2 quantize: one compiled loop per window size
             steps = 1 << (steps.bit_length() - 1)
-        sp.set(n_seqs=len(uids), steps=steps, ahead=int(prev is not None))
+        sp.set(n_seqs=len(uids), steps=steps, ahead=int(prev is not None),
+               held_by="" if prev is not None else self._boundary)
+        self._boundary = ""
         # (ahead of an undrained window these seeds are stale and advisory:
         # the true ones are on the device, decode_batch_async)
         seeds = [self._decodes[u] for u in uids]
@@ -981,25 +991,43 @@ class LifecycleScheduler:
         still admits an arrival on free blocks: it is started when the
         window in flight has drained, one window later than under a
         scheduler that drains every window (the module docstring's "what it
-        costs")."""
+        costs").  ``_held`` says which condition said no, the first in
+        the order they are tested ("" = it may)."""
+        self._held = self._holds_next_window()
+        return not self._held
+
+    def _holds_next_window(self) -> str:
         fl = self._inflight
-        if (fl.dropped or self.drafter is not None
-                or self.draining or self._waiting or self._prefilling
-                or self._cancel_requested or self._free_slots() > 0
-                or list(self._decodes) != fl.uids
-                or not self.eng.decode_chains(fl.uids)):
-            return False
+        if fl.dropped:
+            return "dropped"
+        if self.drafter is not None:
+            return "drafter"
+        if self.draining:
+            return "draining"
+        if self._waiting:
+            return "queued"
+        if self._prefilling:
+            return "prefilling"
+        if self._cancel_requested:
+            return "cancel"
+        if self._free_slots() > 0:
+            return "free_row"
+        if list(self._decodes) != fl.uids:
+            return "rotation"
+        if not self.eng.decode_chains(fl.uids):
+            return "chains"
         now = self.clock()
         cap = self.eng.config.max_ctx
         sm = self.eng.state_manager
         for uid in fl.uids:
             req = self._reqs[uid]
-            if (req.remaining <= fl.steps
-                    or sm.get_sequence(uid).seen_tokens >= cap
-                    or (req.deadline_t is not None
-                        and now >= req.deadline_t)):
-                return False
-        return True
+            if req.remaining <= fl.steps:
+                return "finisher"
+            if sm.get_sequence(uid).seen_tokens >= cap:
+                return "ctx_cap"
+            if req.deadline_t is not None and now >= req.deadline_t:
+                return "deadline"
+        return ""
 
     def _settle(self) -> List[int]:
         """Drain the window in flight unless the next may run ahead of it:
@@ -1008,7 +1036,16 @@ class LifecycleScheduler:
         if self._inflight is None or self._may_run_ahead():
             return []
         fl, self._inflight = self._inflight, None
-        return self._drain(fl)
+        return self._drained(fl, self._held)
+
+    def _drained(self, fl: _InFlight, why: str) -> List[int]:
+        """Drain ``fl`` with nothing left in flight, and keep ``why`` for
+        the next ``serve/window`` if it is the root of this boundary."""
+        self._boundary = self._boundary or why
+        finished = self._drain(fl)
+        if not self.pending:            # idle: the next window is a first
+            self._boundary = "first"
+        return finished
 
     def _drain(self, fl: _InFlight) -> List[int]:
         """Wait for a window's tokens and apply them (watchdog + NaN
@@ -1170,8 +1207,8 @@ class LifecycleScheduler:
         """One scheduler iteration; returns uids that reached a terminal
         state.  Lifecycle passes (cancel, expiry) run FIRST, so no request
         outlives its deadline by more than one bounded window."""
-        with self._lock, _TRACER.span(
-                "serve/step", waiting=len(self._waiting),
+        with self._lock, _TRACER.step_span(
+                name="serve/step", waiting=len(self._waiting),
                 prefilling=len(self._prefilling),
                 decoding=len(self._decodes)) as sp:
             # a window in flight is drained first, unless this step may
@@ -1213,7 +1250,7 @@ class LifecycleScheduler:
         if fl is None:
             return
         try:
-            self._drain(fl)
+            self._drained(fl, "draining" if self.draining else "error")
         except Exception as e:  # noqa: BLE001 — the first error is the caller's
             logger.error(f"window in flight lost after a failed step: {e!r}")
             if fl.dropped:

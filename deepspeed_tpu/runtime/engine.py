@@ -1057,8 +1057,10 @@ class DeepSpeedEngine:
                 log_dist(f"comms_logger: xprof trace for step {cl.xprof_step} "
                          f"→ {cl.xprof_dir}", ranks=[0])
             # the fence inside the step span makes it cover device time, not
-            # just Python dispatch
-            self.tput_timer.stop(sync=loss)
+            # just Python dispatch; it is the step's one wait for the device,
+            # so what is left of ``engine/train_batch`` is the host's own
+            with tracer.span("engine/step_wait"):
+                self.tput_timer.stop(sync=loss)
         if self.config.wall_clock_breakdown:
             self._timers("step").stop(sync=loss)
         if getattr(self.config, "debug_nan_check", False) and \

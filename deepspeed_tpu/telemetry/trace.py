@@ -24,14 +24,35 @@ runs, a host event on the device trace's clock while one does) and one
 append to the bounded ring — no file, socket, thread or sync.  A hub adopts
 this tracer and adds its exporters; a profiler session sees the spans in
 its xplane.
+
+The account of a step.  The two spans that delimit a step (``step_span``:
+``serve/step``, ``engine/train_batch``) also say what the host did with the
+thread meanwhile, from ONE ``getrusage(RUSAGE_THREAD)`` call an edge —
+``cpu_s`` (the thread's CPU time, user + system), ``nvcsw`` / ``nivcsw``
+(its voluntary / involuntary context switches) — so that a step that took
+seconds can be told apart afterwards: descheduled (wall far over CPU,
+involuntary switches), blocked below Python (no CPU) or running Python that
+long (CPU = wall).  Under gVisor, the chip's host, the switches read 0 and
+``cpu_s`` has a 10 ms grain: "ran" against "did not run" is what it tells
+there.  And every pause of Python's
+collector is an ``engine/host_gc`` span (``generation``, ``collected``)
+under whatever span it interrupted.  No other span pays for either.
 """
 from __future__ import annotations
 
 import collections
 import functools
+import gc
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+try:
+    import resource
+
+    _RUSAGE_THREAD: Optional[int] = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):       # not Linux: no host facts
+    _RUSAGE_THREAD = None
 
 #: ring capacity of a tracer nobody configured (the hub's ``max_spans``
 #: default is the same number)
@@ -193,6 +214,35 @@ class _Span:
         return False  # never swallow the exception
 
 
+def _host_facts() -> Optional[Tuple[float, int, int]]:
+    """The calling thread's CPU seconds (user + system) and its voluntary /
+    involuntary context switches so far, from ONE ``getrusage`` call; None
+    where the platform does not count them by thread."""
+    if _RUSAGE_THREAD is None:
+        return None
+    usage = resource.getrusage(_RUSAGE_THREAD)
+    return usage.ru_utime + usage.ru_stime, usage.ru_nvcsw, usage.ru_nivcsw
+
+
+class _StepSpan(_Span):
+    """A span that delimits one step: besides its wall time, what the
+    thread got of the host inside it (``cpu_s``, ``nvcsw``, ``nivcsw``)."""
+
+    __slots__ = ("_facts",)
+
+    def __enter__(self) -> "_StepSpan":
+        super().__enter__()
+        self._facts = _host_facts()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        then, now = self._facts, _host_facts()
+        if then is not None and now is not None:
+            self.set(cpu_s=now[0] - then[0], nvcsw=now[1] - then[1],
+                     nivcsw=now[2] - then[2])
+        return super().__exit__(exc_type, exc, tb)
+
+
 class Tracer:
     """Records nested spans; exports Chrome-trace JSON.
 
@@ -214,7 +264,9 @@ class Tracer:
         self.total_recorded = 0   # monotonic; never decreases on eviction
         self._epoch = time.perf_counter()
         self._epoch_unix = time.time()
-        self._lock = threading.Lock()
+        # re-entrant: the collector's hook (``_on_gc``) records a span at
+        # whatever bytecode the collection ran, ``_record``'s own included
+        self._lock = threading.RLock()
         self._spans: "collections.deque[SpanRecord]" = collections.deque(
             maxlen=self.max_spans)
         self._tls = threading.local()
@@ -272,14 +324,17 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, sync, attrs or None)
 
-    def step_span(self, step_num: int, name: str = "train_step",
-                  sync: Any = None):
-        """Step-delimiting span; also emits ``StepTraceAnnotation`` so an
-        active JAX profile groups device ops per training step."""
+    def step_span(self, step_num: Optional[int] = None,
+                  name: str = "train_step", sync: Any = None, **attrs):
+        """Step-delimiting span: it also records what the thread got of the
+        host inside it (``cpu_s``, ``nvcsw``, ``nivcsw``; Linux).  With a
+        ``step_num`` it carries ``step`` and emits ``StepTraceAnnotation``,
+        so an active JAX profile groups device ops per training step."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, sync, {"step": int(step_num)},
-                     step_num=int(step_num))
+        if step_num is not None:
+            attrs["step"] = step_num = int(step_num)
+        return _StepSpan(self, name, sync, attrs or None, step_num=step_num)
 
     def record(self, name: str, start: float, dur_s: float,
                **attrs) -> None:
@@ -293,13 +348,6 @@ class Tracer:
             name=name, start_s=start - self._epoch, dur_s=dur_s,
             depth=len(stack), parent=stack[-1].name if stack else None,
             tid=threading.get_ident(), attrs=attrs or None, error=None))
-
-    def current_span(self) -> Optional[str]:
-        stack = self._stack()
-        return stack[-1].name if stack else None
-
-    def depth(self) -> int:
-        return len(self._stack())
 
     # ---------------------------------------------------------------- #
     def records(self) -> List[SpanRecord]:
@@ -376,6 +424,24 @@ def own_times(records: Iterable[SpanRecord]) -> Dict[str, float]:
 # The process-global tracer
 # --------------------------------------------------------------------- #
 _TRACER = Tracer()
+_GC_SPAN = NULL_SPAN    # the collection under way (one at a time, per process)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` hook: a pause of Python's collector is one
+    ``engine/host_gc`` span under whatever span the thread has open."""
+    global _GC_SPAN
+    if phase == "start":
+        _GC_SPAN = _TRACER.span("engine/host_gc",
+                                generation=info["generation"])
+        _GC_SPAN.__enter__()
+    else:
+        span, _GC_SPAN = _GC_SPAN, NULL_SPAN
+        span.set(collected=info["collected"])
+        span.__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def get_tracer() -> Tracer:

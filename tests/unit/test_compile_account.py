@@ -49,6 +49,12 @@ def named(name, fn=lambda x: (x * 2 + 1).sum()):
     return jax.jit(call)
 
 
+def recorded(tracer):
+    """Records but the collector's pauses (``engine/host_gc``), which come
+    whenever Python collects."""
+    return sum(1 for r in tracer.records() if r.name != "engine/host_gc")
+
+
 def of(tracer, program):
     return [r for r in tracer.records() if r.name in PHASES
             and r.attrs["program"] == program]
@@ -64,9 +70,9 @@ def test_one_record_a_phase_and_none_on_the_fast_path(tracer):
     starts = [r.start_s for r in records]
     assert starts == sorted(starts)
     assert records[2].attrs["cache"] in ("compiled", "off")
-    before = tracer.total_recorded
+    before = recorded(tracer)
     step(X)
-    assert tracer.total_recorded == before
+    assert recorded(tracer) == before
 
 
 def test_parent_is_the_span_that_paid(tracer):
@@ -106,9 +112,9 @@ def test_a_thousand_numpy_calls_are_three_records(tracer):
 
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
-        before = tracer.total_recorded
+        before = recorded(tracer)
         named("thousand_calls", thousand)(X)
-        assert tracer.total_recorded == before + 3
+        assert recorded(tracer) == before + 3
     finally:
         jax.monitoring.unregister_event_duration_listener(listener)
     trace, lower, _ = of(tracer, "jit_thousand_calls")

@@ -34,7 +34,9 @@ class TestSpanNesting:
         by_name = {r.name: r for r in tr.records()}
         assert by_name["a"].parent == "outer"
         assert by_name["b"].parent == "outer"
-        assert tr.depth() == 0  # stack fully unwound
+        with tr.span("next"):   # stack fully unwound: a top-level span again
+            pass
+        assert (tr.records()[-1].depth, tr.records()[-1].parent) == (0, None)
 
     def test_duration_measured(self):
         tr = Tracer()
@@ -66,7 +68,7 @@ class TestSpanNesting:
             try:
                 with tr.span(f"t{i}"):
                     time.sleep(0.01)
-                    assert tr.current_span() == f"t{i}"
+                    tr.record(f"in_t{i}", time.perf_counter(), 0.0)
             except Exception as e:  # surfaced below
                 errs.append(e)
 
@@ -76,8 +78,13 @@ class TestSpanNesting:
         for t in threads:
             t.join()
         assert not errs
-        assert len(tr.records()) == 4
-        assert all(r.depth == 0 for r in tr.records())
+        # what each thread had open was its own span, whatever the others did
+        inner = [r for r in tr.records() if r.name.startswith("in_")]
+        assert sorted((r.name, r.parent, r.depth) for r in inner) == [
+            (f"in_t{i}", f"t{i}", 1) for i in range(4)]
+        outer = [r for r in tr.records() if r not in inner]
+        assert len(outer) == 4
+        assert all(r.depth == 0 for r in outer)
 
 
 class TestExceptionSafety:
@@ -88,7 +95,10 @@ class TestExceptionSafety:
                 raise ValueError("x")
         (rec,) = tr.records()
         assert rec.error == "ValueError"
-        assert tr.depth() == 0
+        assert (rec.depth, rec.parent) == (0, None)
+        with tr.span("after"):
+            pass
+        assert tr.records()[-1].depth == 0
 
     def test_exception_in_nested_span_unwinds_stack(self):
         tr = Tracer()
@@ -99,7 +109,8 @@ class TestExceptionSafety:
         by_name = {r.name: r for r in tr.records()}
         assert by_name["inner"].error == "RuntimeError"
         assert by_name["outer"].error == "RuntimeError"
-        assert tr.depth() == 0
+        assert (by_name["inner"].depth, by_name["inner"].parent) == (1, "outer")
+        assert (by_name["outer"].depth, by_name["outer"].parent) == (0, None)
         # a fresh span after the exception nests at top level again
         with tr.span("after"):
             pass
