@@ -14,8 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from . import gdn_ops, mla_ops, sparse_ops, ssm_ops, window_ops
-from .ragged_ops import (_stored_heads, decode_attention, paged_kv_append,
-                         ragged_paged_attention, verify_window_attention)
+from .ragged_ops import (_stored_heads, _token_heads, decode_attention,
+                         paged_kv_append, ragged_paged_attention,
+                         verify_window_attention)
 
 
 def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
@@ -27,14 +28,16 @@ def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
     position bias (bloom semantics; the falcon ``alibi_scaled`` variant
     computes bf16(slope·pos) pre-scaling).
 
-    q_seq: [S, mq, H, hd]; kv_pages: [NP_total, ps, 2KV, hd];
-    page_table: [S, NB] → output [S, mq, H, hd] (f32).  ``num_kv_heads``:
-    the model's, where the pool stores a token in more (None: the pool's).
+    q_seq: [S, mq, H, hd]; kv_pages: [NP_total, ps, 2KV, hd] (or several
+    heads a row: ``ragged_ops._stored_heads``); page_table: [S, NB] →
+    output [S, mq, H, hd] (f32).  ``num_kv_heads``: the model's, where the
+    pool stores a token in more (None: the pool's).
     """
     H_model = q_seq.shape[2]
-    _, ps, ckv, _ = kv_pages.shape
-    q_seq, KV, alibi = _stored_heads(q_seq, kv_pages, num_kv_heads or ckv // 2,
-                                     alibi)
+    _, ps, ckv, hd_k = kv_pages.shape
+    q_seq, KV, alibi = _stored_heads(
+        q_seq, kv_pages,
+        num_kv_heads or ckv // 2 * (hd_k // q_seq.shape[-1]), alibi)
     S, mq, H, hd = q_seq.shape
     NB = page_table.shape[1]
     C = NB * ps
@@ -42,7 +45,7 @@ def _attend_gather(q_seq, kv_pages, page_table, q_len, ctx_len,
     pg = jnp.take_along_axis(
         page_table, (ctx_pos // ps)[None, :].repeat(S, 0), axis=1)   # [S, C]
     off = jnp.broadcast_to((ctx_pos % ps)[None, :], (S, C))
-    ctx = kv_pages[pg, off]                           # [S, C, 2KV, hd]
+    ctx = _token_heads(kv_pages[pg, off], hd)         # [S, C, 2KV, hd]
     k_ctx, v_ctx = ctx[..., :KV, :], ctx[..., KV:, :]
     # zero V at out-of-context columns: masked scores become -1e30 (so K
     # garbage can't leak) but probs*V still multiplies 0-weight columns —
@@ -122,8 +125,10 @@ def page_ops(row, replicate=None) -> PageOps:
             ragged=partial(sparse_ops.sparse_ragged_attention, **kw),
             verify=None,
             dense=partial(sparse_ops.sparse_attend_dense, **kw))
-    # pages [ps, 2*stored, hd] (``row.stored`` heads, the model's unless the
-    # row kind says otherwise: the operations read it off the pool): a body
+    # pages [ps, *row.token_shape]: ``row.stored`` heads (the model's, or
+    # more where the row kind pads them), one a row [2*stored, hd] or
+    # ``row.lane_heads`` side by side [2*stored/HL, HL*hd] — the operations
+    # read the form off the pool (``ragged_ops._stored_heads``); a body
     # appends (k, v) [T, KV, hd] and attends q [T, H, hd] → [T, H, hd]
     kw = dict(num_kv_heads=row.num_kv_heads)
 

@@ -116,17 +116,40 @@ def _cdiv(a, b):
     return (a + b - 1) // b
 
 
+def _lane_heads(hd: int, kv_pages) -> int:
+    """Heads side by side along the lanes of a stored row
+    (``models/serving.KVRow.lane_heads``), read off the pool: its rows are
+    ``lane_heads * hd`` wide where the queries' heads are ``hd``."""
+    HL = kv_pages.shape[-1] // hd
+    assert kv_pages.shape[-1] == HL * hd, \
+        f"head_dim mismatch {hd} vs {kv_pages.shape[-1]}"
+    return HL
+
+
+def _token_heads(rows, hd: int):
+    """Cached rows ``[..., 2 * stored / HL, HL * hd]`` as ``[..., 2 * stored,
+    hd]``, K heads first (a row's heads are consecutive: a reshape; a pool
+    of one head a row comes back as it came)."""
+    if rows.shape[-1] == hd:
+        return rows
+    return rows.reshape(rows.shape[:-2] + (-1, hd))
+
+
 def _stored_heads(q, kv_pages, num_kv_heads: int, alibi=None):
-    """A pool may STORE a token in more heads than the model has
-    (``models/serving.KVRow.stored_kv_heads``), and its shape says how many:
-    ``[pages, page_size, 2 * stored, hd]``, the K heads first.  The queries
-    get zero heads behind their own (one group a padded kv head) and the
-    operation runs on the stored count; its caller cuts the output back to
-    the model's heads, so no padded head's output reaches the model, and a
-    padded head's rows are zeros (:func:`paged_kv_append`).  → (q, stored,
-    alibi); a pool of the model's own count comes back as it came."""
-    stored = kv_pages.shape[2] // 2
-    assert kv_pages.shape[2] == 2 * stored and stored >= num_kv_heads, \
+    """How many heads a pool STORES a token in, which its shape says
+    (``models/serving.KVRow``): ``[pages, page_size, 2 * stored / HL, HL *
+    hd]``, the K heads first, ``HL`` heads along the lanes of a row
+    (:func:`_lane_heads`; 1 in most pools).  A pool may store MORE heads
+    than the model has (``KVRow.tiled``): the queries then get zero heads
+    behind their own (one group a padded kv head) and the operation runs on
+    the stored count; its caller cuts the output back to the model's heads,
+    so no padded head's output reaches the model, and a padded head's rows
+    are zeros (:func:`paged_kv_append`).  A pool with heads along its lanes
+    (``KVRow.packed``) stores the model's own count and pads nothing.  →
+    (q, stored, alibi); a pool of the model's own count comes back as it
+    came."""
+    stored = kv_pages.shape[2] // 2 * _lane_heads(q.shape[-1], kv_pages)
+    assert kv_pages.shape[2] % 2 == 0 and stored >= num_kv_heads, \
         f"kv_pages combined-head dim {kv_pages.shape[2]} is not 2 x " \
         f"(>= {num_kv_heads}) heads"
     if stored == num_kv_heads:
@@ -138,11 +161,20 @@ def _stored_heads(q, kv_pages, num_kv_heads: int, alibi=None):
     return q, stored, alibi
 
 
+def _row_bytes(kv_pages, num_kv_heads: int, hd: int) -> dict:
+    """What a layout record says of the stored form: the bytes a token
+    takes in the pool and the bytes of it the model reads (equal but for a
+    padded head count)."""
+    itemsize = jnp.dtype(kv_pages.dtype).itemsize
+    return dict(row_bytes=math.prod(kv_pages.shape[2:]) * itemsize,
+                read_bytes=2 * num_kv_heads * hd * itemsize)
+
+
 def _ragged_paged_kernel(kvl_ref, pt_ref, cu_ref,        # scalar prefetch
                          q_ref, pages_ref, o_ref,        # VMEM block / HBM
                          kv_bufs, sems, acc, m_scr, l_scr,
                          *, scale, ps, P, KV, G, BQ, S, NB,
-                         alibi, alibi_scaled, use_refs=True):
+                         alibi, alibi_scaled, use_refs=True, HL=1):
     """One grid step = one BQ-token block of the flat query axis.
 
     Walks the sequences whose tokens fall in this block; per sequence,
@@ -155,6 +187,9 @@ def _ragged_paged_kernel(kvl_ref, pt_ref, cu_ref,        # scalar prefetch
     needs every control-flow decision made on VALUES.  On TPU the per-
     element SMEM reads stay (whole-array SMEM loads are not a Mosaic
     vector op).
+
+    ``HL`` heads lie along the lanes of a stored row (``_lane_heads``):
+    combined head ``h`` is row ``h // HL``, lanes ``h % HL`` of ``hd``.
     """
     qb = pl.program_id(0)
     blk_start = qb * BQ
@@ -265,7 +300,14 @@ def _ragged_paged_kernel(kvl_ref, pt_ref, cu_ref,        # scalar prefetch
         # NaN-isolation contract the dense/decode lowerings already
         # enforce by construction).
         row_ok = (t[:, :1] >= q0) & (t[:, :1] < q1)  # [rows, 1]
-        kv = kv_bufs[slot]                           # [P, ps, 2KV, hd]
+        kv = kv_bufs[slot]                    # [P, ps, 2KV / HL, HL·hd]
+
+        def head(h):                          # combined head h, [CH, hd]
+            if HL == 1:
+                return kv[:, :, h, :].reshape(CH, -1)
+            hd = kv.shape[-1] // HL
+            return kv[:, :, h // HL, h % HL * hd:(h % HL + 1) * hd] \
+                .reshape(CH, -1)
         # pages past this block's CAUSAL bound (eff_kvl <= kv_len) are never
         # DMA'd — their buffer rows hold stale / uninitialized data.  Scores
         # there are masked, but V must be zeroed too: softmax weights for
@@ -276,9 +318,8 @@ def _ragged_paged_kernel(kvl_ref, pt_ref, cu_ref,        # scalar prefetch
         for h in range(KV):
             qh = q_ref[:, h * G:(h + 1) * G, :].reshape(rows, -1) \
                 .astype(jnp.float32)
-            kh = kv[:, :, h, :].reshape(CH, -1).astype(jnp.float32)
-            vh = jnp.where(col_ok, kv[:, :, KV + h, :].reshape(CH, -1), 0.0) \
-                .astype(jnp.float32)
+            kh = head(h).astype(jnp.float32)
+            vh = jnp.where(col_ok, head(KV + h), 0.0).astype(jnp.float32)
             s_mat = jnp.dot(qh, kh.T,
                             preferred_element_type=jnp.float32) * scale
             if alibi is not None:
@@ -361,7 +402,8 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
       q:          [T, H, hd] flat query tokens, sequence-major (sequence
                   s's tokens at [cu_q_lens[s], cu_q_lens[s+1])).
       kv_pages:   [num_pages_total, page_size, 2*KV, hd] combined page pool
-                  (K heads at [:KV], V heads at [KV:]).  For stacked
+                  (K heads at [:KV], V heads at [KV:]; or ``HL`` heads a
+                  row, [.., 2*KV/HL, HL*hd]: ``_stored_heads``).  For stacked
                   multi-layer caches pass the full buffer and a per-layer
                   ``page_table + layer*pages`` — no in-kernel layer index.
       kv_lens:    [S] total context span per sequence (seen + in-flight).
@@ -371,7 +413,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     """
     T, H_model, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
-    assert hd == hd_k, f"head_dim mismatch {hd} vs {hd_k}"
+    HL = _lane_heads(hd, kv_pages)
     assert H_model % num_kv_heads == 0, \
         "query heads must be a multiple of kv heads"
     if alibi is not None:
@@ -402,7 +444,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     kv_itemsize = jnp.dtype(kv_pages.dtype).itemsize
 
     def _vmem_bytes(p, bq):
-        kv_bufs = 2 * p * ps * ckv * hd * kv_itemsize
+        kv_bufs = 2 * p * ps * ckv * hd_k * kv_itemsize
         softmax = KV * (bq * G) * (hd + 2 * 128) * 4
         # Pallas double-buffers the streamed q/o blocks across grid steps
         qo = 2 * 2 * bq * H * hd * jnp.dtype(q.dtype).itemsize
@@ -417,7 +459,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     # long as a chunk is no more than 2 MiB (8 pages of 8 kv heads of 128: 2
     # MiB; of 32 stored heads: 8, and a 16-row bucket ran out of VMEM on
     # the chip, PR 34)
-    while P0 > 1 and P0 * ps * ckv * hd * kv_itemsize > _CHUNK_LIMIT:
+    while P0 > 1 and P0 * ps * ckv * hd_k * kv_itemsize > _CHUNK_LIMIT:
         P0 //= 2
     while True:
         P = P0
@@ -437,11 +479,18 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     if T_pad != T:
         q = jnp.pad(q, ((0, T_pad - T), (0, 0), (0, 0)))
 
+    # trace time only: what a run says about the kernel it compiled
+    get_tracer().record(
+        "attn/ragged_layout", time.perf_counter(), 0.0, P=P, block_q=BQ,
+        dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=num_kv_heads,
+        stored_kv_heads=KV, group=G, lane_heads=HL,
+        **_row_bytes(kv_pages, num_kv_heads, hd))
+
     interp = _interpret() if interpret is None else interpret
     kernel = functools.partial(
         _ragged_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, BQ=BQ,
         S=S, NB=NB, alibi=alibi, alibi_scaled=alibi_scaled,
-        use_refs=not interp)
+        use_refs=not interp, HL=HL)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -453,7 +502,7 @@ def ragged_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
             ],
             out_specs=pl.BlockSpec((BQ, H, hd), lambda qb, *_: (qb, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, P, ps, ckv, hd), kv_pages.dtype),
+                pltpu.VMEM((2, P, ps, ckv, hd_k), kv_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, P)),
                 pltpu.VMEM((KV, BQ * G, hd), jnp.float32),
                 pltpu.VMEM((KV, BQ * G, 128), jnp.float32),
@@ -487,7 +536,10 @@ def _decode_head_load(dtype, KV: int, hd: int, ps: int) -> str:
     kernel lands a page one 128-lane tile at a time, because the base
     memref of a sublane-strided load must be one lane tile wide.  Anything
     else — float32 pools, ``KV`` 1, 3 or 6, narrow heads — is
-    ``"general"``.
+    ``"general"``.  ``KV`` counts the K ROWS a token is stored in: the
+    heads, or fewer where several heads lie along the lanes of a row
+    (``_lane_heads``: 10 heads in 2 rows of five, which tile where 10 rows
+    do not).
     """
     packing = 4 // jnp.dtype(dtype).itemsize
     if packing == 2 and (KV in (2, 4) or KV % 8 == 0) and hd % 128 == 0 \
@@ -515,7 +567,7 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                          q_ref, pages_ref, o_ref,        # VMEM block / HBM
                          kv_bufs, sems, acc, m_scr, l_scr, carry,
                          *, scale, ps, P, KV, G, NB, alibi, alibi_scaled, hpg,
-                         pairs):
+                         pairs, HL=1):
     """One grid step = ONE decoding sequence's single query token.
 
     The ragged kernel spends a ``[block_q·G, chunk]`` MXU tile per chunk even
@@ -564,14 +616,30 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
     whole page for the general load).  A pass sums ``q_t · K_tᵀ`` over the
     lane tiles in float32 and writes ``p · V_t`` into the accumulator's own
     columns: the same products in the same precision.
+
+    Heads along the lanes (PR 59; ``HL``, ``_lane_heads``).  A pool whose
+    head count tiles no sublane tile stores ``HL`` heads side by side in a
+    row, ``KV / HL`` K rows and as many V rows a token (10 heads of 128: 2
+    + 2 rows of 640), so that a token takes its own bytes and no more.  The
+    page lands a lane tile at a time as above, and the pair load of word
+    row ``j`` from the tiles of lane slot ``c`` is the pair of heads ``2j ·
+    HL + c`` and ``(2j + 1) · HL + c``: a lane slot is a pair OF ITS OWN —
+    its own scores, softmax state and accumulator, nothing summed across
+    slots — and a chunk takes ``HL`` passes for each one a row pair takes
+    (5 where the same heads padded to 16 rows took 8).  The wrapper hands
+    the queries over in pass order and takes the outputs back.  ``KV``
+    stays the number of heads; with ``HL == 1`` the body is the parent's,
+    equation for equation.
     """
     s, S = pl.program_id(0), pl.num_programs(0)
     kvl = kvl_ref[s]
     CH = P * ps                               # context tokens per chunk
     nch = _cdiv(kvl, CH)
-    HP, PW = hpg * pairs, hpg * CH            # heads a pass, columns a load
-    NG, R, W = KV // HP, HP * G, pairs * PW   # passes, query rows, columns
+    KVr = KV // HL                            # K rows a token
+    HP, PW = hpg * pairs, hpg * CH            # rows a pass, columns a load
+    NG, R, W = KVr // HP, HP * G, pairs * PW  # row passes, query rows, columns
     dtype, LT, LW = kv_bufs.dtype, kv_bufs.shape[1], kv_bufs.shape[-1]
+    QW = LW // HL if hpg == 1 else LW         # columns a dot takes of a head
 
     def page_needed(seq, page_idx):
         return page_idx * ps < kvl_ref[seq]
@@ -639,12 +707,12 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
             tok_ok = jax.lax.broadcasted_iota(
                 jnp.int32, (CH, 1), 0) + c * CH < kvl
             if hpg == 2:
-                words = [kv_bufs.at[slot, t].reshape(CH * 2 * KV, LW)
-                         .bitcast(jnp.uint32)  # [CH·KV, 128] a lane tile
+                words = [kv_bufs.at[slot, t].reshape(CH * 2 * KVr, LW)
+                         .bitcast(jnp.uint32)  # [CH·KVr, 128] a lane tile
                          for t in range(LT)]
 
-                def pair(t, j, keep=None):     # combined heads 2j, 2j+1
-                    w = words[t][pl.ds(j, CH, stride=KV), :]
+                def pair(t, j, keep=None):     # combined rows 2j, 2j+1
+                    w = words[t][pl.ds(j, CH, stride=KVr), :]
                     if keep is not None:       # a word row is one token
                         w = jnp.where(keep, w, jnp.uint32(0))
                     return pltpu.bitcast(w, dtype)        # [2·CH, 128]
@@ -658,23 +726,28 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                     return load(t, g * pairs)
 
                 def load_v(g, t):
-                    return load(t, KV // 2 + g * pairs, tok_ok)
+                    return load(t, KVr // 2 + g * pairs, tok_ok)
             else:
-                kv = kv_bufs[slot, 0]          # [P, ps, 2KV, hd]
+                kv = kv_bufs[slot, 0]          # [P, ps, 2KVr, HL·hd]
 
-                def load_k(g, t):
-                    return kv[:, :, g, :].reshape(CH, LW)
+                def load_k(row, t):            # lane slot t of a row
+                    lanes = slice(None) if HL == 1 \
+                        else slice(t * QW, (t + 1) * QW)
+                    return kv[:, :, row, lanes].reshape(CH, QW)
 
                 def load_v(g, t):
-                    return jnp.where(
-                        tok_ok, kv[:, :, KV + g, :].reshape(CH, LW), 0.0)
+                    return jnp.where(tok_ok, load_k(KVr + g, t), 0.0)
 
-            for g in range(NG):
+            # pass g = (row pass rp, lane slot c), over the slot's own tiles
+            for g in range(NG * HL):
+                rp, c = divmod(g, HL)
+                tiles = range(c * LT // HL, (c + 1) * LT // HL) \
+                    if hpg == 2 else [c]
                 s_mat = functools.reduce(jnp.add, [jax.lax.dot_general(
-                    q_ref[0, g * R:(g + 1) * R, t * LW:(t + 1) * LW]
-                    .astype(dtype), load_k(g, t), (((1,), (1,)), ((), ())),
+                    q_ref[0, g * R:(g + 1) * R, i * QW:(i + 1) * QW]
+                    .astype(dtype), load_k(rp, t), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                    for t in range(LT)]) * scale
+                    for i, t in enumerate(tiles)]) * scale
                 if alibi is not None:
                     r = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
                     slope = jnp.zeros((R, W), jnp.float32)
@@ -699,10 +772,10 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
                 l_scr[g] = jnp.broadcast_to(
                     alpha * l_scr[g][:, :1] +
                     jnp.sum(p_mat, axis=1, keepdims=True), l_scr[g].shape)
-                for t in range(LT):            # the tile's own columns
-                    cols = slice(t * LW, (t + 1) * LW)
+                for i, t in enumerate(tiles):  # the tile's own columns
+                    cols = slice(i * QW, (i + 1) * QW)
                     acc[g, :, cols] = acc[g, :, cols] * alpha + \
-                        jnp.dot(p_mat.astype(dtype), load_v(g, t),
+                        jnp.dot(p_mat.astype(dtype), load_v(rp, t),
                                 preferred_element_type=jnp.float32)
                 m_scr[g] = jnp.broadcast_to(m_new, m_scr[g].shape)
 
@@ -731,7 +804,7 @@ def _decode_paged_kernel(kvl_ref, pt_ref,                # scalar prefetch
         jax.lax.while_loop(lambda st: st[0] < nch, body,
                            (jnp.int32(0), slot0))
 
-    for g in range(NG):
+    for g in range(NG * HL):
         l = l_scr[g][:, :1]
         o = acc[g] / jnp.where(l == 0.0, 1.0, l)
         o_ref[0, g * R:(g + 1) * R, :] = o.astype(o_ref.dtype)
@@ -748,7 +821,8 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     Args:
       q:          [S, H, hd] — sequence s's single new-token query at row s.
       kv_pages:   [num_pages_total, page_size, 2*KV, hd] page pool (the
-                  multi-layer layout of :func:`ragged_paged_attention`).
+                  multi-layer layout of :func:`ragged_paged_attention`; or
+                  ``HL`` heads a row, [.., 2*KV/HL, HL*hd]).
       kv_lens:    [S] context length per sequence (seen + the in-flight
                   token, i.e. the query's own position is kv_lens-1).
                   Rows with kv_lens == 0 are padding and yield zeros.
@@ -761,13 +835,16 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     head load the compiled kernel got (:func:`_decode_head_load`) and in
     how many ``lane_tiles`` a page lands (``hd // 128`` for the strided
     load, 1 otherwise), how many head pairs a pass scores and how many
-    passes a chunk takes (``pairs_per_pass``, ``passes_per_chunk``).
+    passes a chunk takes (``pairs_per_pass``, ``passes_per_chunk``), and
+    the STORED form: ``lane_heads`` (heads along the lanes of a row),
+    ``row_bytes`` (what a token takes in the pool) and ``read_bytes`` (what
+    the model reads of it: less only where a head count is padded).
     ``pages_per_chunk`` is an upper bound: a chunk is held to 2 MiB a
     buffer and to the VMEM budget.
     """
     S, H_model, hd = q.shape
     _, ps, ckv, hd_k = kv_pages.shape
-    assert hd == hd_k, f"head_dim mismatch {hd} vs {hd_k}"
+    HL = _lane_heads(hd, kv_pages)
     assert H_model % num_kv_heads == 0, \
         "query heads must be a multiple of kv heads"
     if alibi is not None:
@@ -783,19 +860,36 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     P = min(pages_per_chunk, NB)
-    load = _decode_head_load(kv_pages.dtype, KV, hd, ps)
-    hpg, LT = (2, hd // 128) if load == "strided" else (1, 1)
-    pairs = _pairs_per_pass(KV, G) if load == "strided" else 1
+    # the load, the pairs and the passes follow the ROWS a token is stored
+    # in: ``KV`` of them, or ``KV / HL`` with ``HL`` heads a row
+    load = _decode_head_load(kv_pages.dtype, KV // HL, hd, ps)
+    hpg, LT = (2, hd_k // 128) if load == "strided" else (1, 1)
+    pairs = _pairs_per_pass(KV // HL, G) if load == "strided" else 1
     NG, R = KV // (hpg * pairs), hpg * pairs * G   # passes a chunk, rows a pass
+    by_pass = HL > 1 and hpg * pairs > 1
+    if by_pass:
+        # a pass of the strided load scores lane slot c of ``hpg · pairs``
+        # rows, heads ``row · HL + c``: the queries (and the slopes) go in
+        # pass order, the outputs come back in the model's
+        def swap(x, a, b):          # [.., a, b, G, ..] -> [.., b, a, G, ..]
+            return x.reshape(x.shape[:1] + (NG // HL, a, b, G)
+                             + x.shape[2:]).swapaxes(2, 3).reshape(x.shape)
+
+        q = swap(q, R // G, HL)
+        if alibi is not None:
+            import numpy as np
+
+            alibi = tuple(swap(np.asarray(alibi)[None], R // G, HL)[0]
+                          .tolist())
 
     # same VMEM accounting as the ragged kernel, with the
     # [R, pairs·hpg·chunk] score tile, and the same limit on a chunk
     VMEM_BUDGET = 12 * 1024 * 1024
     kv_itemsize = jnp.dtype(kv_pages.dtype).itemsize
-    page_bytes = ps * ckv * hd * kv_itemsize
+    page_bytes = ps * ckv * hd_k * kv_itemsize
 
     def _vmem_bytes(p):
-        kv_bufs = 2 * p * ps * ckv * hd * kv_itemsize
+        kv_bufs = 2 * p * ps * ckv * hd_k * kv_itemsize
         softmax = KV * G * (hd + 2 * 128) * 4
         qo = 2 * 2 * H * hd * jnp.dtype(q.dtype).itemsize
         temps = 3 * R * (pairs * hpg * p * ps) * 4
@@ -816,11 +910,12 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         "attn/decode_layout", time.perf_counter(), 0.0, load=load, P=P,
         dtype=jnp.dtype(kv_pages.dtype).name, kv_heads=num_kv_heads,
         stored_kv_heads=KV, group=G, lane_tiles=LT, pairs_per_pass=pairs,
-        passes_per_chunk=NG)
+        passes_per_chunk=NG, lane_heads=HL,
+        **_row_bytes(kv_pages, num_kv_heads, hd))
 
     kernel = functools.partial(
         _decode_paged_kernel, scale=scale, ps=ps, P=P, KV=KV, G=G, NB=NB,
-        alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg, pairs=pairs)
+        alibi=alibi, alibi_scaled=alibi_scaled, hpg=hpg, pairs=pairs, HL=HL)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -832,7 +927,7 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
             ],
             out_specs=pl.BlockSpec((1, H, hd), lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, LT, P, ps, ckv, hd // LT), kv_pages.dtype),
+                pltpu.VMEM((2, LT, P, ps, ckv, hd_k // LT), kv_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, LT, P)),
                 pltpu.VMEM((NG, R, hd), jnp.float32),
                 pltpu.VMEM((NG, R, 128), jnp.float32),
@@ -844,6 +939,8 @@ def decode_paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
         interpret=_interpret() if interpret is None else interpret,
         name="paged_decode",
     )(kv_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, kv_pages)
+    if by_pass:
+        out = swap(out, HL, R // G)
     return out if H == H_model else out[:, :H_model]
 
 
@@ -910,7 +1007,7 @@ def decode_attend_dense(q: jnp.ndarray, kv_pages: jnp.ndarray,
     pg = jnp.take_along_axis(page_table,
                              (ctx_pos // ps)[None, :].repeat(S, 0), axis=1)
     off = jnp.broadcast_to((ctx_pos % ps)[None, :], (S, C))
-    ctx = kv_pages[pg, off]                              # [S, C, 2KV, hd]
+    ctx = _token_heads(kv_pages[pg, off], hd)            # [S, C, 2KV, hd]
     k_ctx, v_ctx = ctx[..., :KV, :], ctx[..., KV:, :]
     # out-of-context columns may hold never-written garbage: scores there
     # are masked to -inf, but V must be zeroed too so 0·garbage(NaN)
@@ -972,7 +1069,8 @@ def paged_kv_append(kv_pages: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     off_of_token: jnp.ndarray, replicate=None) -> jnp.ndarray:
     """Scatter new K/V rows into their cache pages.
 
-    kv_pages: [num_pages_total, page_size, 2*KV, hd]; k/v: [T, KV, hd] (or
+    kv_pages: [num_pages_total, page_size, 2*KV, hd] (or ``HL`` heads a
+    row, [.., 2*KV/HL, HL*hd]: ``_stored_heads``); k/v: [T, KV, hd] (or
     fewer heads than the pool stores); page_of_token/off_of_token: [T]
     (padded tokens target the trash page).
     A row scatter into a donated / loop-carried buffer lowers to an
@@ -988,13 +1086,16 @@ def paged_kv_append(kv_pages: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     dp4×tp2 mesh — serving under a TP mesh produced garbage logits).  Pass
     it whenever any model param is non-trivially sharded.
     """
-    stored = kv_pages.shape[2] // 2
+    HL = _lane_heads(k.shape[2], kv_pages)
+    stored = kv_pages.shape[2] // 2 * HL
     if stored != k.shape[1]:
         # a pool that stores a token in more heads than the model has
         # (_stored_heads): the heads past the model's are written as zeros
         pad = ((0, 0), (0, stored - k.shape[1]), (0, 0))
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     comb = jnp.concatenate([k, v], axis=1).astype(kv_pages.dtype)
+    if HL > 1:              # HL heads a row, as they follow one another
+        comb = comb.reshape((-1,) + kv_pages.shape[2:])
     if replicate is not None:
         comb = jax.lax.with_sharding_constraint(comb, replicate)
         kv_pages = jax.lax.with_sharding_constraint(kv_pages, replicate)

@@ -3,9 +3,13 @@
 tokens before it, and a sequence holds ``W`` rows a window layer in its slot
 of the state pool, whatever its length.
 
-The ring pool is ``[window_layers * slots + 1, W, 2 * stored, hd]`` (the last
-row the trash ring of padded rows), a row laid out as a page's: K heads
-first, then V, ``stored`` of each (``KVRow.stored``).  The row of position
+The ring pool is ``[window_layers * slots + 1, W, *token_shape]`` (the last
+row the trash ring of padded rows), a row laid out as a page's, in whichever
+form the family's row kind stores a token (``KVRow.token_shape``: K heads
+first, then V, ``stored`` of each, one head a row ``[2 * stored, hd]`` or
+``lane_heads`` of them side by side; the decode kernel and the append read
+the form off the pool, the ragged and oracle forms see a ring's rows through
+``ragged_ops._token_heads``, one head a row either way).  The row of position
 ``p`` lies at ``p % W``: a new token's row replaces the one that has just
 left the window.  Attention has no positional term inside the scores here,
 so it is a function of the SET of rows and the ring need not know their
@@ -30,10 +34,14 @@ Three forms (:func:`window_attention` dispatches, as the page kinds do):
 """
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 
-from .ragged_ops import decode_attention, paged_kv_append
+from ....telemetry import get_tracer
+from .ragged_ops import (_row_bytes, _token_heads, decode_attention,
+                         paged_kv_append)
 
 #: queries a step of the ragged form
 TILE = 128
@@ -71,7 +79,7 @@ def _decode(q, k, v, ring, rows, ctx_len, *, page, pages_per_chunk, **attn):
 def _ragged(q, k, v, ring, rows, *, cu_q_lens, q_len, ctx_len, num_kv_heads,
             scale, tile: int = TILE):
     T, H, hd = q.shape
-    W, stored = ring.shape[1], ring.shape[2] // 2
+    W = ring.shape[1]
     KV, G = num_kv_heads, q.shape[1] // num_kv_heads
     dtype = ring.dtype
     per_seq = -(-q_len // tile)                                   # [S]
@@ -101,7 +109,8 @@ def _ragged(q, k, v, ring, rows, *, cu_q_lens, q_len, ctx_len, num_kv_heads,
         # congruent to r below the chunk's first (none: below 0)
         p0 = first_pos[s]
         held = p0 - 1 - jnp.mod(p0 - 1 - ring_at, W)
-        hist = ring[rows[s]]                       # [W, 2 stored, hd]
+        hist = _token_heads(ring[rows[s]], hd)     # [W, 2 stored, hd]
+        stored = hist.shape[1] // 2
         q_pos = p0 + i0 + lane
         ok_h = (held >= 0)[None, :] & (q_pos[:, None] - held[None, :] < W)
         keys = jnp.concatenate([hist[:, :KV], kb], axis=0)
@@ -128,7 +137,7 @@ def _ragged(q, k, v, ring, rows, *, cu_q_lens, q_len, ctx_len, num_kv_heads,
 
 def _oracle(q, k, v, ring, ring_row, pos, *, num_kv_heads, scale, page):
     T, H, hd = q.shape
-    W, stored = ring.shape[1], ring.shape[2] // 2
+    W = ring.shape[1]
     KV, G = num_kv_heads, H // num_kv_heads
     qf = q.astype(jnp.float32).reshape(T, KV, G, hd)
 
@@ -136,7 +145,8 @@ def _oracle(q, k, v, ring, ring_row, pos, *, num_kv_heads, scale, page):
         out, ring = carry
         ring = _write(ring, k[t][None], v[t][None], ring_row[t][None],
                       pos[t][None], page=page)
-        held = ring[ring_row[t]].astype(jnp.float32)
+        held = _token_heads(ring[ring_row[t]], hd).astype(jnp.float32)
+        stored = held.shape[1] // 2
         ok = jnp.arange(W) <= pos[t]            # every slot once p >= W - 1
         sc = jnp.einsum("kgd,ckd->kgc", qf[t], held[:, :KV]) * scale
         pr = jax.nn.softmax(jnp.where(ok[None, None], sc, _NEG), axis=-1)
@@ -159,6 +169,12 @@ def window_attention(q, k, v, ring, rows, *, mode: str, batch, valid,
     T = q.shape[0]
     q_len, ctx_len = batch["q_len"], batch["ctx_len"]
     trash = ring.shape[0] - 1
+    # trace time only: the form a ring's rows are stored and read in
+    get_tracer().record(
+        "attn/window_layout", time.perf_counter(), 0.0, form=mode,
+        window=ring.shape[1], page=page, kv_heads=num_kv_heads,
+        dtype=jnp.dtype(ring.dtype).name,
+        **_row_bytes(ring, num_kv_heads, q.shape[-1]))
     if mode == "decode":
         R = min(rows.shape[0], T)
         out, ring = _decode(q[:R], k[:R], v[:R], ring, rows[:R], ctx_len[:R],

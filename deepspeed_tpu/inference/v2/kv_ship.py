@@ -104,8 +104,9 @@ def export_kv(engine, uid: int, tokens: List[int],
                        for b in seq.blocks[:n_pages]], np.int64)
     pages = np.asarray(engine.kv.pages[jnp.asarray(phys)], np.float32)
     row = engine.family.row
+    # one head a row, whatever form the pool stores a token in
     rows = pages.reshape(engine.family.page_layers, n_pages * bs,
-                         *row.token_shape)[:, :n]
+                         2 * row.stored, row.head_dim)[:, :n]
     if row.stored != row.num_kv_heads:
         # canonical rows carry the model's heads, not the pool's padding
         kv = row.num_kv_heads

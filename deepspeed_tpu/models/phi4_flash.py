@@ -26,7 +26,7 @@ softmax(q1_i k1_j^T / sqrt(hd)) V_j``, ``A2_i`` likewise from ``q2``,
 ``lambda_init = 0.8 - 0.6 exp(-0.3 l)``; ``o_i = (1 - lambda_init)
 RMSNorm(A1_i - lambda A2_i)``.  A cached token is therefore ``KV / 2`` K
 rows ``[k1 | k2]`` and as many V rows ``[v1 | v2]`` of ``2 hd``
-(:func:`serving_family`: ``KVRow.tiled(KV / 2, 2 hd)``), and the two score
+(:func:`serving_family`: ``KVRow.packed(KV / 2, 2 hd)``), and the two score
 sets are the cache's differential read (``attend_pair``).
 
 This module holds the configuration (from the published ``config.json``
@@ -345,7 +345,11 @@ def memory_unit(x, m, lp: Dict, cfg: Phi4FlashConfig):
 # --------------------------------------------------------------------- #
 def serving_family(cfg: Phi4FlashConfig) -> ServingFamily:
     """ONE page layer (layer ``M + 1``) of ``KV / 2`` row pairs ``2 hd``
-    wide, read by itself and every cross-attention layer; a selective-scan
+    wide, read by itself and every cross-attention layer — a token STORED in
+    its own bytes (``KVRow.packed``: the published 10 pairs of 128 tile no
+    sublane tile, so 5 lie along the lanes of a row, 2 K rows and 2 V rows
+    of 640 = 5,120 B in bf16, in the page layer and in every ring; a pair
+    count that tiles is stored as it is); a selective-scan
     state in ``M / 2 + 1`` layers; a ring of ``sliding_window`` rows in
     ``M / 2`` window layers.  Three stacks: (scan, window attention) pairs,
     the hand-over pair, (memory unit, cross attention) pairs.  The residual
@@ -415,7 +419,7 @@ def serving_family(cfg: Phi4FlashConfig) -> ServingFamily:
 
     return ServingFamily(
         num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-        row=KVRow.tiled(cfg.pairs, 2 * cfg.head_dim),
+        row=KVRow.packed(cfg.pairs, 2 * cfg.head_dim),
         embed=embed, stacks=stacks, head=head,
         state=SelectiveScanState(num_layers=P + 1, channels=cfg.d_inner,
                                  state_dim=cfg.d_state,

@@ -88,14 +88,26 @@ class IndexKey:
 class KVRow:
     """A cached token of one layer is K and V for ``num_kv_heads`` heads.
 
-    ``stored_kv_heads`` (None: the model's) is how many heads a token is
-    STORED in: a page is ``[page_size, 2 * stored, head_dim]``, K heads first,
-    and the heads past the model's are zeros that no query head reads.  The
-    page operations take the count from the pool's shape (``kernels/
-    ragged_ops``), so a head count whose combined rows do not tile the
-    chip's sublanes (30 heads: 60 rows) is stored in one that does
-    (:meth:`tiled`) without the model's mathematics knowing.
-    ``read_values`` and ``attn_flops`` stay the model's: the padding is the
+    How a token is STORED is the row kind's to say, and the page operations
+    read it off the pool's shape (``kernels/ragged_ops``), so the model's
+    mathematics never knows.  Three forms:
+
+    - as it is: a page is ``[page_size, 2 * num_kv_heads, head_dim]``, K
+      heads first.  Right wherever the combined 16-bit rows tile the chip's
+      sublanes (2, 4, 8 or a multiple of 16 of them);
+    - padded (:meth:`tiled`; ``stored_kv_heads``): a head count whose
+      combined rows do not tile (30 heads: 60 rows) is stored in one that
+      does (32), and the heads past the model's are zeros that no query
+      head reads — and that every read streams;
+    - heads along the lanes (:meth:`packed`; ``lane_heads``): ``lane_heads``
+      heads lie side by side in one row, a page is ``[page_size, 2 *
+      num_kv_heads / lane_heads, lane_heads * head_dim]`` (10 heads of 128:
+      2 K rows and 2 V rows of 640, heads 0-4 in the first of each and 5-9
+      in the second — a reshape of ``k [T, 10, 128]``), which tiles as
+      stored and pads nothing: a token takes exactly ``read_values``
+      values.
+
+    ``read_values`` and ``attn_flops`` stay the model's: a padding is the
     program's cost."""
 
     num_kv_heads: int
@@ -103,6 +115,8 @@ class KVRow:
     stored_kv_heads: Optional[int] = None
     #: an index key beside the K/V row (None: attention is dense)
     index: Optional[IndexKey] = None
+    #: heads side by side along the lanes of one stored row
+    lane_heads: int = 1
     latent = False
 
     def __post_init__(self):
@@ -111,6 +125,13 @@ class KVRow:
             raise ValueError(
                 f"stored_kv_heads {self.stored_kv_heads} < num_kv_heads "
                 f"{self.num_kv_heads}")
+        if self.lane_heads > 1 and (
+                self.stored != self.num_kv_heads or self.index is not None
+                or self.num_kv_heads % self.lane_heads):
+            raise ValueError(
+                f"lane_heads {self.lane_heads}: the heads of a row must "
+                f"divide num_kv_heads {self.num_kv_heads}, with no padded "
+                f"head and no index key")
 
     @classmethod
     def tiled(cls, num_kv_heads: int, head_dim: int) -> "KVRow":
@@ -118,13 +139,26 @@ class KVRow:
         :func:`tiling_kv_heads` of them."""
         return cls(num_kv_heads, head_dim, tiling_kv_heads(num_kv_heads))
 
+    @classmethod
+    def packed(cls, num_kv_heads: int, head_dim: int) -> "KVRow":
+        """The row kind of a model with ``num_kv_heads`` heads, stored in
+        its own bytes: as it is where the head count tiles, else with the
+        fewest heads along the lanes whose row count does
+        (:func:`tiling_kv_heads`; 10 heads: 5 a row, 2 + 2 rows)."""
+        lanes = min(n for n in range(1, num_kv_heads + 1)
+                    if num_kv_heads % n == 0
+                    and tiling_kv_heads(num_kv_heads // n)
+                    == num_kv_heads // n)
+        return cls(num_kv_heads, head_dim, lane_heads=lanes)
+
     @property
     def stored(self) -> int:
         return self.stored_kv_heads or self.num_kv_heads
 
     @property
     def token_shape(self) -> Tuple[int, ...]:
-        return (2 * self.stored, self.head_dim)
+        return (2 * self.stored // self.lane_heads,
+                self.lane_heads * self.head_dim)
 
     @property
     def read_values(self) -> int:       # attention reads, a token a layer
@@ -136,10 +170,13 @@ class KVRow:
 
 
 def tiling_kv_heads(num_kv_heads: int) -> int:
-    """The least head count >= ``num_kv_heads`` whose 16-bit K/V rows tile
-    the sublanes of a page as they are: 1, 2, 4 or a multiple of 8 (a token's
-    combined rows 2, 4, 8 or a multiple of 16 — what the chip's compiler
-    lays out without padding and the decode kernel's pair load takes)."""
+    """The least count >= ``num_kv_heads`` of 16-bit K (and as many V) rows
+    a token that tile the sublanes of a page as they are: 1, 2, 4 or a
+    multiple of 8 (a token's combined rows 2, 4, 8 or a multiple of 16 —
+    what the chip's compiler lays out without padding and the decode
+    kernel's pair load takes).  :meth:`KVRow.tiled` stores a token in that
+    many heads; :meth:`KVRow.packed` lays heads along the lanes until the
+    rows that are left are such a count."""
     for n in (1, 2, 4):
         if num_kv_heads <= n:
             return n

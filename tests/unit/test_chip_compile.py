@@ -155,6 +155,46 @@ def _paged_decode_pool(kv, hd, load, pages, heads=16, rows=64, blocks=64,
     return build
 
 
+def _paged_lane_heads(op, rows=64, ring=False):
+    """The page operations on a pool that stores a token with heads ALONG
+    THE LANES (``KVRow.packed(10, 128)``: 2 K rows and 2 V rows of 640, 5
+    heads each; 20 combined rows of 128 are refused at the page DMA and
+    padded by the chip's layout all the same): the Phi-4-mini-flash cell's
+    page layer of 4,800 pages with 75-page tables, or its eight rings of
+    512 rows seen as 8 pages of 64 (``ring``), 40 query heads in groups of
+    4, 64 rows / a ``rows``-token chunk."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
+            _decode_head_load, _lane_heads, _pairs_per_pass,
+            decode_paged_attention, paged_kv_append, ragged_paged_attention)
+        from deepspeed_tpu.models.serving import KVRow
+
+        row = KVRow.packed(10, HD)
+        assert row.token_shape == (4, 5 * HD) and row.lane_heads == 5
+        seqs, blocks, pages = (64, 8, 8 * 65 * 8 + 8) if ring \
+            else (64, 75, 4801)
+        pool = _on(dev, (pages, PAGE) + row.token_shape)
+        assert _lane_heads(HD, pool) == 5
+        assert _decode_head_load(BF16, 2, HD, PAGE) == "strided"
+        assert _pairs_per_pass(2, 4) == 1              # 5 passes a chunk
+        lens = _on(dev, (seqs,), jnp.int32)
+        table = _on(dev, (seqs, blocks), jnp.int32)
+        if op == "decode":
+            return (lambda q, p, n, t: decode_paged_attention(
+                q, p, n, t, num_kv_heads=10)), \
+                (_on(dev, (seqs, 40, HD)), pool, lens, table)
+        if op == "ragged":
+            return (lambda q, p, n, t, cu: ragged_paged_attention(
+                q, p, n, t, cu, num_kv_heads=10, pages_per_chunk=2)), \
+                (_on(dev, (rows, 40, HD)), pool, lens, table,
+                 _on(dev, (seqs + 1,), jnp.int32))
+        new = _on(dev, (512, 10, HD))
+        where = _on(dev, (512,), jnp.int32)
+        return paged_kv_append, (pool, new, new, where, where)
+    build.xla_only = op == "append"
+    return build
+
+
 def _paged_decode_cell(rows):
     """The K/V decode kernel as the Mistral serving cells run it: a
     16-layer pool of 1,730 pages a layer, 64-page tables, bf16, 4 / 32 / 64
@@ -638,14 +678,15 @@ def _olmo_hybrid(decode):
     return build
 
 
-def _phi4_flash(decode, bucket=512):
+def _phi4_flash(decode, bucket=512, wide=64, steps=2):
     """The benchmark's Phi-4-mini-flash configuration, whole (32 layers,
-    published widths, the whole vocabulary): a fused decode window of 64
-    sequences x 2 steps (the selective scan's one-token form, the K/V decode
-    kernel on 16 stored row pairs over the window layers' rings AND over
-    the one page layer, eight readers), or a SplitFuse step of ``bucket``
-    tokens (the blocked scan, the ragged window form, the ragged page
-    kernel); page pool, state pool and rings in the carry."""
+    published widths, the whole vocabulary): a fused decode window of
+    ``wide`` sequences x ``steps`` steps (the selective scan's one-token
+    form, the K/V decode kernel on 10 row pairs stored five along the lanes
+    of a row, over the window layers' rings AND over the one page layer,
+    eight readers; 1 x 1: the reference check's windows), or a SplitFuse
+    step of ``bucket`` tokens (the blocked scan, the ragged window form, the
+    ragged page kernel); page pool, state pool and rings in the carry."""
     def build(dev):
         from deepspeed_tpu.inference.v2.model_runner import (
             build_decode_loop, build_ragged_step)
@@ -656,7 +697,7 @@ def _phi4_flash(decode, bucket=512):
 
         model = Phi4FlashLM(Phi4FlashConfig())
         family = model.serving_family()
-        assert family.row.token_shape == (32, 128)
+        assert family.row.token_shape == (4, 640)      # 5,120 B a token
         shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
                                 jax.random.PRNGKey(0))
         params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
@@ -668,9 +709,10 @@ def _phi4_flash(decode, bucket=512):
         kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
                   attn_impl="paged", jit=False)
         if decode:
-            loop = build_decode_loop(family, max_q=seqs, block_size=PAGE,
-                                     steps=2, **kw)
-            meta = pack_layout(seqs, seqs, blocks, True)["_total"][0]
+            kw.update(max_seqs=wide)
+            loop = build_decode_loop(family, max_q=wide, block_size=PAGE,
+                                     steps=steps, **kw)
+            meta = pack_layout(wide, wide, blocks, True)["_total"][0]
             return loop, (params, cache, _on(dev, (meta,), jnp.int32),
                           _on(dev, (2,), jnp.uint32))
         step = build_ragged_step(family, max_q=bucket, **kw)
@@ -680,20 +722,31 @@ def _phi4_flash(decode, bucket=512):
     def scan_layers_run_two_kernels_in_place(compiled):
         """A scan layer's decode form is two Mosaic calls on the pools
         where they lie: no gathered ``[rows, 16, 5120]`` states, and no
-        more temporaries than the XLA form's window had (0.09 GiB, PR 55)."""
+        more temporaries than the XLA form's window had (0.09 GiB, PR 55).
+        And the pools are held at 5,120 B a token AS THE CHIP LAYS THEM
+        OUT: the arguments (7.17 GiB of weights, 4,801 pages, 8 x 65 + 1
+        rings of 512 rows, the scan states) are 10.09 GiB where the padded
+        form's were 11.72 (PR 55) — 0.89 GiB of pages and 0.74 of rings."""
         text = compiled.as_text()
         for kernel in ("ssm_decode", "gdn_conv_step"):
             assert f"/{kernel}/pallas_call" in text, kernel
         assert "f32[64,16,5120]" not in text
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.09 * 2 ** 30
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < 0.09 * 2 ** 30
+        assert 10.0 < memory.argument_size_in_bytes / 2 ** 30 < 10.15
 
-    if decode:
+    if decode and wide == 64:
         build.check = scan_layers_run_two_kernels_in_place
     return build
 
 
 CASES = {
     "phi4flash_decode_window": _phi4_flash(decode=True),
+    # PR 59: the other window lengths and the reference check's one-wide
+    # windows, on the pool that stores a token in its own bytes
+    "phi4flash_decode_window[8 steps]": _phi4_flash(decode=True, steps=8),
+    "phi4flash_decode_window[1 wide, 1 step]":
+        _phi4_flash(decode=True, wide=1, steps=1),
     "phi4flash_prefill_step": _phi4_flash(decode=False),
     # EVERY prefill bucket (PR 34's lesson, learnt again in PR 55: of these
     # only the 32-token bucket ran out of scoped VMEM on the chip)
@@ -739,6 +792,17 @@ CASES = {
         1, 128, "general", 16 * 400 + 1),              # a K/V word row
     "decode_paged_attention[float32 KV 6, general]": _paged_decode_pool(
         6, 128, "general", 16 * 400 + 1, heads=12, dtype=jnp.float32),
+    # PR 59, heads along the lanes: Phi-4-mini-flash's 10 row pairs of 128
+    "decode_paged_attention[10 heads, 5 a row]": _paged_lane_heads("decode"),
+    "decode_paged_attention[10 heads, 5 a row, a ring]":
+        _paged_lane_heads("decode", ring=True),
+    "ragged_paged_attention[10 heads, 5 a row]":
+        _paged_lane_heads("ragged", rows=512),
+    "ragged_paged_attention[10 heads, 5 a row, 16 rows]":
+        _paged_lane_heads("ragged", rows=16),
+    "ragged_paged_attention[10 heads, 5 a row, 32 rows]":
+        _paged_lane_heads("ragged", rows=32),
+    "paged_kv_append[10 heads, 5 a row]": _paged_lane_heads("append"),
     "rmsnorm_matmul[4096x14336]": _rmsnorm(D, F),      # gate / up
     "rmsnorm_matmul[4096x6144]": _rmsnorm(D, 6144),    # fused qkv width
     "rmsnorm_matmul[4096x1024]": _rmsnorm(D, 1024),    # k / v

@@ -165,7 +165,7 @@ def got(model):
 
 def test_the_family_says_what_it_holds(model):
     fam = model[0].serving_family()
-    assert fam.row == KVRow(2, 16, stored_kv_heads=2)
+    assert fam.row == KVRow(2, 16) and fam.row.token_shape == (4, 16)
     assert fam.page_layers == 1 and fam.page_readers == (2,)
     assert fam.state == SelectiveScanState(3, 128, 16, 4)
     assert fam.state.arrays(jnp.bfloat16) == (
@@ -173,13 +173,17 @@ def test_the_family_says_what_it_holds(model):
     assert fam.window == WindowRing(2, 8, page=4)
     state, ring = fam.slot_kinds
     assert ring.arrays(jnp.bfloat16) == (((8, 4, 16), jnp.bfloat16),)
-    # the published widths: 10 row pairs of 128 stored in 16, nothing cut
+    # the published widths: 10 row pairs of 128, five along the lanes of a
+    # row, a token in its own 5,120 B (bf16); nothing cut
     full = P.Phi4FlashLM.from_hf_config(dict(
         HF, vocab_size=200064, hidden_size=2560, intermediate_size=10240,
         num_hidden_layers=32, num_attention_heads=40, num_key_value_heads=20,
         sliding_window=512))
     fam = full.serving_family()
-    assert fam.row == KVRow(10, 128, stored_kv_heads=16)
+    assert fam.row == KVRow(10, 128, lane_heads=5) == KVRow.packed(10, 128)
+    assert fam.row.token_shape == (4, 640)
+    assert fam.slot_kinds[1].arrays(jnp.bfloat16) == (
+        ((512, 4, 640), jnp.bfloat16),)
     assert (fam.state.num_layers, fam.window.num_layers, fam.page_layers,
             fam.page_readers) == (9, 8, 1, (8,))
     assert fam.state.slot_bytes(jnp.bfloat16) == 9 * (16 * 5120 * 4
@@ -189,6 +193,36 @@ def test_the_family_says_what_it_holds(model):
         model[0].loss_fn(model[1], None, None)
     with pytest.raises(NotImplementedError, match="whole .* pairs"):
         P.Phi4FlashConfig.from_hf(dict(HF, num_hidden_layers=6))
+
+
+def test_a_pair_count_that_tiles_no_sublanes_lies_along_the_lanes():
+    """Six row pairs (12 combined rows tile nothing) are stored three to a
+    row, ``[4, 3 * 2 hd]``, in the page layer and in the rings, and the
+    system is the reference's through chunks, the window, two ring wraps
+    and a fused window: the engine, the append, the ragged kernel, the
+    window's three forms and the decode lowering read the stored form off
+    the pools."""
+    hf = dict(HF, hidden_size=96, num_attention_heads=24,
+              num_key_value_heads=12)
+    m = P.Phi4FlashLM.from_hf_config(hf, ring_page=4)
+    params = m.init_params(jax.random.PRNGKey(1), jnp.float32)
+    fam = m.serving_family()
+    assert fam.row == KVRow(6, 8, lane_heads=3)
+    assert fam.row.token_shape == (4, 24)
+    seq = prompt_tokens(seed=5) + prompt_tokens(6, 4)
+    ref = np.asarray(reference.Reference(hf).logits(
+        [jnp.asarray(seq + [0] * (PADDED - len(seq)), jnp.int32)],
+        ref_weights(params), positions=[list(range(BODY - 1, len(seq)))])[0])
+    for impl in ("paged", "gather"):
+        engine = engine_for((m, params), attn_impl=impl)
+        assert engine.kv.pages.shape[2:] == (4, 24)
+        assert engine.state_pool.arrays[-1].shape[1:] == (W, 4, 24)
+        logits = system_logits(engine, seq[:PROMPT], BODY)
+        assert rel_l2(logits, ref[:PROMPT - BODY + 1]) < TOL, impl
+        # fused one-step windows, teacher-forced: the reference's greedy
+        for i, tok in enumerate(seq[PROMPT:]):
+            out = int(engine.decode_batch([1], [tok], 1)[0, 0])
+            assert out == int(np.argmax(ref[PROMPT - BODY + 1 + i])), impl
 
 
 def test_chunked_prefill_then_single_tokens_across_window_and_wrap(model,

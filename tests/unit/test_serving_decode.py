@@ -285,7 +285,10 @@ class TestDecodeKernelParity:
         heads, at narrow heads.  ``pairs_per_pass`` / ``passes_per_chunk``
         (PR 37) say which pass the program got: 1 / 4 at Mistral's pool,
         1 / 1 at Qwen3-Next's, 4 / 4 at Olmo-Hybrid's 30 heads stored in
-        32, 1 wherever the load is general."""
+        32, 1 wherever the load is general.  ``row_bytes`` / ``read_bytes``
+        (PR 59): what a token takes in the pool and what the model reads
+        of it — equal but for Olmo's two padded heads; ``lane_heads`` 1
+        (heads along the lanes: ``test_kv_row_forms.py``)."""
         from deepspeed_tpu.telemetry import get_tracer
 
         def layout(q, pages, kvl, pt, KV):
@@ -301,25 +304,26 @@ class TestDecodeKernelParity:
         assert layout(q, pages, kvl, pt, 8) == dict(
             load="strided", P=8, dtype="bfloat16", kv_heads=8,
             stored_kv_heads=8, group=4, lane_tiles=1, pairs_per_pass=1,
-            passes_per_chunk=4)
+            passes_per_chunk=4, lane_heads=1, row_bytes=4096, read_bytes=4096)
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
                                             geom=self.QWEN)
         assert layout(q, pages, kvl, pt, 2) == dict(
             load="strided", P=8, dtype="bfloat16", kv_heads=2,
             stored_kv_heads=2, group=8, lane_tiles=2, pairs_per_pass=1,
-            passes_per_chunk=1)
+            passes_per_chunk=1, lane_heads=1, row_bytes=2048, read_bytes=2048)
         q, pages, kvl, pt = self._cell_case(34, [70], poison=False,
                                             geom=self.OLMO30)
         assert layout(q, pages, kvl, pt, 30) == dict(
             load="strided", P=2, dtype="bfloat16", kv_heads=30,
             stored_kv_heads=32, group=1, lane_tiles=1, pairs_per_pass=4,
-            passes_per_chunk=4)
+            passes_per_chunk=4, lane_heads=1, row_bytes=16384,
+            read_bytes=15360)
         rng = np.random.default_rng(35)
         toy = _decode_case(rng, [9, 5], 1, 2, 16, 4, 3)
         assert layout(*toy, 1) == dict(
             load="general", P=3, dtype="float32", kv_heads=1,
             stored_kv_heads=1, group=2, lane_tiles=1, pairs_per_pass=1,
-            passes_per_chunk=1)
+            passes_per_chunk=1, lane_heads=1, row_bytes=128, read_bytes=128)
         q4, p4, kvl4, pt4 = _decode_case(rng, [40], 4, 2, 128, 16, 4)
         rec = layout(q4.astype(jnp.bfloat16), p4.astype(jnp.bfloat16),
                      kvl4, pt4, 4)

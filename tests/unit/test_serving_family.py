@@ -754,11 +754,14 @@ def test_a_family_whose_row_carries_an_index_key_is_served(impl):
     same(eng.put([2], [again[19:]])[0], again)
 
 
-def test_a_shipment_carries_the_models_heads_not_the_pools_padding():
-    """A K/V-row family whose row kind stores more heads than the model has
-    (``KVRow.tiled``: 3 heads in 4): the canonical rows of a shipment hold
-    the model's 3 + 3, and an engine that stores them another way continues
-    from the import with the same logits."""
+@pytest.mark.parametrize("stored", ["tiled", "packed"])
+def test_a_shipment_carries_the_models_heads_not_the_pools_form(stored):
+    """A K/V-row family whose row kind stores a token another way than the
+    model's heads lie — padded (``KVRow.tiled``: 3 heads in 4) or along the
+    lanes (``KVRow.packed``: 3 heads in one K row and one V row): the
+    canonical rows of a shipment hold the model's 3 + 3, one head a row, and
+    an engine that stores them in any form continues from the import with
+    the same logits."""
     from deepspeed_tpu.inference.v2 import kv_ship
 
     config = RenamedConfig(depth=2, width=48, heads=6, kv_heads=3)
@@ -767,17 +770,23 @@ def test_a_shipment_carries_the_models_heads_not_the_pools_padding():
     base = model.serving_family()
     hd = config.width // config.heads
 
-    class Stored(RenamedLM):
-        def serving_family(self):
-            return dataclasses.replace(base, row=KVRow.tiled(3, hd))
+    def storing(row):
+        class Stored(RenamedLM):
+            def serving_family(self):
+                return dataclasses.replace(base, row=row)
+        return Stored(config)
 
+    forms = {"tiled": (KVRow.tiled(3, hd), (8, hd)),
+             "packed": (KVRow.packed(3, hd), (2, 3 * hd))}
     prompt = np.random.default_rng(0).integers(1, 88, size=11).tolist()
-    src = _engine(Stored(config), params, "gather", max_tokens=16)
-    assert src.kv.pages.shape[2:] == (8, hd)
+    row, token_shape = forms[stored]
+    src = _engine(storing(row), params, "gather", max_tokens=16)
+    assert src.kv.pages.shape[2:] == token_shape
     want = np.asarray(src.put([0], [prompt])[0])
     ship = kv_ship.export_kv(src, 0, prompt, n_tokens=10)
     assert ship.rows.shape == (2, 10, 6, hd) and ship.num_kv_heads == 3
-    for target in (Stored(config), model):          # padded, and as it is
+    for target in (storing(forms["tiled"][0]), storing(forms["packed"][0]),
+                   model):                  # padded, along the lanes, as it is
         dst = _engine(target, params, "gather", max_tokens=16)
         assert kv_ship.import_kv(dst, ship, 7)
         got = np.asarray(dst.put([7], [prompt[10:]])[0])
