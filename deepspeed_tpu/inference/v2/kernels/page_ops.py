@@ -13,7 +13,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from . import gdn_ops, mla_ops, sparse_ops, ssm_ops, window_ops
+from . import gdn_ops, mla_ops, sparse_ops, ssd_ops, ssm_ops, window_ops
 from .ragged_ops import (_stored_heads, _token_heads, decode_attention,
                          paged_kv_append, ragged_paged_attention,
                          verify_window_attention)
@@ -158,10 +158,14 @@ def state_ops(state) -> Callable:
     serving.py``): ``(*inputs, pool, rows, mode=, batch=, valid=) → (out,
     pool)``, ``mode`` one of ``"decode"``, ``"ragged"``, ``"oracle"``
     (``model_runner._LayerState`` picks it as ``_LayerCache`` picks among a
-    cache kind's operations).  The inputs are the recurrence's own
-    (``gdn_ops.gdn_mix``, ``ssm_ops.ssm_mix``)."""
+    cache kind's operations).  The inputs are the recurrence's own, one of
+    three kinds: the gated delta rule (``gdn_ops.gdn_mix``: a ``[dk, dv]``
+    matrix a head, rank-one corrected), the selective scan (``ssm_ops.
+    ssm_mix``: ``[N, C]``, a decay a value) and the state-space dual
+    (``ssd_ops.ssd_mix``: a ``[hd, N]`` matrix a head, one decay a head)."""
     mix = {"gated_delta": gdn_ops.gdn_mix,
-           "selective": ssm_ops.ssm_mix}[state.recurrence]
+           "selective": ssm_ops.ssm_mix,
+           "ssd": ssd_ops.ssd_mix}[state.recurrence]
     return partial(mix, kind=state)
 
 

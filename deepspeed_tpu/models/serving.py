@@ -42,8 +42,9 @@ tokens through every sequence's slot (``kernels/gdn_ops.gdn_mix``) and
 returns its output; ``state_layer`` counts the state-holding layers only, as
 ``layer`` given to ``cache`` counts the page-owning ones (``page_layers``).
 A body never sees a slot.  The state kind names its recurrence
-(``recurrence``): ``"gated_delta"`` (:class:`GatedDeltaState`) or
-``"selective"`` (:class:`SelectiveScanState`, ``kernels/ssm_ops.ssm_mix``).
+(``recurrence``): ``"gated_delta"`` (:class:`GatedDeltaState`),
+``"selective"`` (:class:`SelectiveScanState`, ``kernels/ssm_ops.ssm_mix``) or
+``"ssd"`` (:class:`SSDState`, ``kernels/ssd_ops.ssd_mix``).
 
 A family some of whose attention layers read only the last ``window`` tokens
 says so with ``window`` (:class:`WindowRing`): those layers own NO page
@@ -312,6 +313,70 @@ class SelectiveScanState:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSDState:
+    """What a sequence owns in each of ``num_layers`` Mamba-2 (state-space
+    dual) layers: a matrix A HEAD, ``[heads, head_dim, state_dim]`` values in
+    float32 — ONE scalar decay a head a token, and the input and output
+    maps ``B`` / ``C`` ``[groups, state_dim]`` shared by the ``heads /
+    groups`` heads of a group — and the causal convolution's last
+    ``conv_kernel - 1`` inputs over ``x | B | C`` (in the serving dtype).
+
+    The state is STORED with the state values along the sublanes and
+    ``lane_heads`` heads side by side along the lanes (``arrays``:
+    ``[heads / P, state_dim, P * head_dim]``): a head of 64 alone would be
+    padded to 128 lanes, twice the bytes held and moved; two fill a tile,
+    and a head's input ``x`` and output ``y`` are then ROWS of the update,
+    ``B`` / ``C`` columns (``kernels/ssd_ops`` reads the layout off the
+    pool's shape).  The heads of a stored row share a group.
+
+    ``chunk``: tokens the chunked prefill form takes at a time (the
+    published ``chunk_size``).  As the other state kinds, it can be restored
+    only at the token it was saved at: the prefix cache, speculative verify
+    windows, the host tier and ``kv_ship`` are refused by name (ROADMAP
+    R5)."""
+
+    num_layers: int
+    heads: int
+    head_dim: int
+    state_dim: int
+    groups: int
+    conv_kernel: int
+    chunk: int = 128
+    recurrence = "ssd"
+
+    def __post_init__(self):
+        if self.heads % self.groups:
+            raise ValueError(f"heads {self.heads} are not whole groups of "
+                             f"{self.groups}")
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        return self.inner + 2 * self.groups * self.state_dim
+
+    @property
+    def lane_heads(self) -> int:
+        """Heads stored side by side in a row of the state: as many as fill
+        a 128-lane tile, where a group's heads are whole such rows."""
+        P = 128 // self.head_dim if 128 % self.head_dim == 0 else 1
+        return P if P > 1 and (self.heads // self.groups) % P == 0 else 1
+
+    def arrays(self, dtype) -> Tuple[Tuple[Tuple[int, ...], Any], ...]:
+        """(shape, dtype) of what one slot holds in one layer."""
+        import jax.numpy as jnp
+
+        P = self.lane_heads
+        return (((self.heads // P, self.state_dim, P * self.head_dim),
+                 jnp.float32),
+                ((self.conv_kernel - 1, self.conv_channels), dtype))
+
+    slot_bytes = GatedDeltaState.slot_bytes
+
+
+@dataclasses.dataclass(frozen=True)
 class WindowRing:
     """``num_layers`` attention layers read the last ``window`` tokens only
     (a token attends itself and the ``window - 1`` before it).  A sequence
@@ -398,7 +463,7 @@ class ServingFamily:
     counts: Optional[ExpertPairs] = None
     #: per-sequence recurrent state of some layers (None: every layer caches
     #: rows); bodies then take a ``state`` handle after ``ctx``
-    state: Union[GatedDeltaState, SelectiveScanState, None] = None
+    state: Union[GatedDeltaState, SelectiveScanState, SSDState, None] = None
     #: attention layers that read a bounded window and keep a ring of rows
     #: in the sequence's slot instead of pages (None: none)
     window: Optional[WindowRing] = None

@@ -2,8 +2,11 @@
 (``noaux_tc``) or softmax scores (with one too: :func:`softmax_bias_topk_
 route`), renormalised weights or not, a grouped matmul over the (token,
 choice) pairs sorted by expert, and a shared expert added to every token
-(behind a sigmoid gate where the model has one).  The layer can be told that
-it holds only a chip's share of the experts (:func:`dropless_experts`,
+(behind a sigmoid gate where the model has one).  An expert is a gated unit
+at the residual's width (``gate``/``up``/``down``) or a plain one at
+whatever width the layer hands in (``up``/``down``: Nemotron-H's squared-
+ReLU experts in a latent, :func:`latent_moe_block`).  The layer can be told
+that it holds only a chip's share of the experts (:func:`dropless_experts`,
 ``offset``) and that the router's last outputs are experts WITHOUT weights
 (``identity_from``: zero-computation identity experts, whose pair adds
 ``g·h`` and costs no matmul row).
@@ -213,12 +216,19 @@ def grouped_matmul(x, w, group_sizes, impl: Optional[str] = None):
 def dropless_experts(h, idx, weights, experts: Dict,
                      valid=None, impl: Optional[str] = None, layer=None,
                      offset: Optional[int] = None,
-                     identity_from: Optional[int] = None
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     identity_from: Optional[int] = None,
+                     act=jax.nn.silu) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed experts' part of the layer: ``Σ_k g_k · E_idx_k(h)``.
 
-    ``h`` [T, D]; ``idx``/``weights`` [T, k]; ``experts``: ``gate``/``up``
-    [E, D, F], ``down`` [E, F, D] — or, with ``layer`` (a traced index),
+    ``h`` [T, D]; ``idx``/``weights`` [T, k]; ``experts`` says the expert's
+    FORM by the matrices it holds: ``gate``/``up`` [E, D, F] and ``down``
+    [E, F, D] is the gated unit ``(act(h W_gate) * h W_up) W_down``;
+    ``up``/``down`` alone the plain one ``act(h W_up) W_down`` (``act`` a
+    function: SiLU, or a squared ReLU).  ``E``, ``D`` and ``F`` are read
+    off ``up``: ``D`` is whatever width the layer hands in (the residual's,
+    or a latent's) and ``F`` need not be a power of two (2,688 = 21 x 128
+    runs in tiles of 384 and 896: :func:`_tile`).  With ``layer`` (a traced
+    index) ``experts`` is
     the whole stack ``[L, E, ...]``: the grouped matmul then takes the
     stack as ``L·E`` groups of which only this layer's are non-empty.  A
     layer's slice of the stack handed to a Mosaic call is first COPIED by
@@ -245,7 +255,7 @@ def dropless_experts(h, idx, weights, experts: Dict,
     own, the last of the pairs."""
     T, D = h.shape
     k = idx.shape[1]
-    E = experts["gate"].shape[-3]
+    E = experts["up"].shape[-3]
     M = T * k
     flat = idx.reshape(M)
     identity = None if identity_from is None else flat >= identity_from
@@ -270,16 +280,20 @@ def dropless_experts(h, idx, weights, experts: Dict,
         if n == E:
             sizes = sizes.at[E - 1].add(M_pad - M)
     if layer is not None:
-        L = experts["gate"].shape[0]
+        L = experts["up"].shape[0]
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((L * E,), jnp.int32), sizes, (layer * E,))
         experts = {n: w.reshape((L * E,) + w.shape[2:])
                    for n, w in experts.items()}
     with jax.named_scope("moe/experts"):
-        g = grouped_matmul(x, experts["gate"], sizes, impl)
-        u = grouped_matmul(x, experts["up"], sizes, impl)
-        y = grouped_matmul((jax.nn.silu(g) * u).astype(h.dtype),
-                           experts["down"], sizes, impl)[:M]
+        if "gate" in experts:
+            g = grouped_matmul(x, experts["gate"], sizes, impl)
+            u = grouped_matmul(x, experts["up"], sizes, impl)
+            u = act(g) * u
+        else:
+            u = act(grouped_matmul(x, experts["up"], sizes, impl))
+        y = grouped_matmul(u.astype(h.dtype), experts["down"], sizes,
+                           impl)[:M]
     with jax.named_scope("moe/combine"):
         inv = jnp.argsort(order)                        # back to pair order
         y = jnp.take(y, inv, axis=0).reshape(T, k, D)
@@ -379,3 +393,43 @@ def zero_expert_moe_block(h, lp: Dict, *, k: int, scaling: float,
     return dropless_experts(h, idx, weights, experts, valid=valid, impl=impl,
                             layer=layer, offset=offset,
                             identity_from=identity_from)
+
+
+def squared_relu(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def latent_moe_block(h, lp: Dict, *, k: int, scaling: float,
+                     renormalise: bool = True, offset: Optional[int] = None,
+                     valid=None, impl: Optional[str] = None, experts=None,
+                     layer=None, act=squared_relu):
+    """The expert layer of a Nemotron-H decoder layer: sigmoid scores with a
+    selection bias over ALL the router's experts (:func:`sigmoid_topk_route`,
+    on the layer's input at the residual's width), routed experts that live
+    in a LATENT — ``l = h W_down`` (``moe/latent_down``), ungated experts
+    ``act(l W1_e) W2_e`` at the latent's width (:func:`dropless_experts`'s
+    plain form), their weighted sum up-projected (``moe/latent_up``) — and a
+    shared expert of the same plain form on the full width (``moe/shared``).
+    With ``offset`` the experts are a chip's share: the sum over the experts
+    HELD is formed in the latent and up-projected, and the up-projection is
+    linear, so the shares' parts plus the shared expert once are the uncut
+    layer.  ``lp``: ``router`` (``kernel`` [D, E_all], ``bias`` [E_all]),
+    ``latent_down`` [D, R], ``latent_up`` [R, D], ``shared`` (``up`` [D,
+    Fs], ``down`` [Fs, D]), ``experts`` (``up`` [E, R, F], ``down`` [E, F,
+    R]) unless the stack and ``layer`` are given apart.  → ([T, D], pairs per
+    expert held [E] or, of a share, [E + 1])."""
+    with jax.named_scope("moe/route"):
+        idx, weights = sigmoid_topk_route(h, lp["router"], k, scaling,
+                                          renormalise)
+    with jax.named_scope("moe/latent_down"):
+        latent = h @ lp["latent_down"]["kernel"]
+    routed, pairs = dropless_experts(
+        latent, idx, weights, lp["experts"] if experts is None else experts,
+        valid=valid, impl=impl, layer=layer, offset=offset, act=act)
+    with jax.named_scope("moe/latent_up"):
+        routed = routed @ lp["latent_up"]["kernel"]
+    with jax.named_scope("moe/shared"):
+        sh = lp["shared"]
+        shared = act(h @ sh["up"]) @ sh["down"]
+    with jax.named_scope("moe/combine"):
+        return routed + shared, pairs

@@ -60,14 +60,14 @@ def for_the_chip(monkeypatch):
     from deepspeed_tpu.accelerator.tpu_accelerator import TPUAccelerator
     from deepspeed_tpu.inference.v2.kernels import (gdn_ops, mla_ops,
                                                     ragged_ops, sparse_ops,
-                                                    ssm_ops)
+                                                    ssd_ops, ssm_ops)
     from deepspeed_tpu.kernels import fused_collective_matmul as fcm
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.adam import fused_adam
     from deepspeed_tpu.ops.transformer import flash_attention as fa
 
     for mod in (fa, fcm, ragged_ops, mla_ops, gdn_ops, sparse_ops, ssm_ops,
-                fused_adam):
+                ssd_ops, fused_adam):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(dropless, "_on_tpu", lambda: True)
     monkeypatch.setattr(fcm, "resolve_impl",
@@ -602,6 +602,84 @@ def _ssm_decode(dev):
                         _on(dev, (rows,), jnp.bool_))
 
 
+def _ssd_decode(dev):
+    """A Mamba-2 layer's one-token update alone at the Nemotron-3-Super
+    cell's shape: 128 rows x 128 heads of a [64, 128] float32 state stored
+    two heads along the lanes, the pool of 5 x 128 + 1 slots aliased in
+    place."""
+    from deepspeed_tpu.inference.v2.kernels.ssd_ops import ssd_decode
+
+    f32 = jnp.float32
+    rows, heads, hd, groups, N = 128, 128, 64, 8, 128
+    head = _on(dev, (rows, heads), f32)
+    group = _on(dev, (rows, groups, N), f32)
+    return ssd_decode, (_on(dev, (rows, heads, hd), f32), head, head, group,
+                        group, _on(dev, (5 * rows + 1, heads // 2, N, 2 * hd),
+                                   f32),
+                        _on(dev, (rows,), jnp.int32))
+
+
+def _nemotron_h(decode, bucket=512, wide=128, steps=2):
+    """The benchmark's Nemotron-3-Super configuration (the first period
+    ``MEMEMEM*EME`` at published widths, 128 of 512 experts held, a quarter
+    of the vocabulary): a fused decode window of ``wide`` sequences x
+    ``steps`` steps (``ssd_decode`` and the convolution step in place, the
+    K/V decode kernel on the one page layer, the grouped matmul over 22
+    picks a token in the latent), or a SplitFuse step of ``bucket`` tokens
+    (the chunked state-space-dual form); both pools in the carry."""
+    def build(dev):
+        from deepspeed_tpu.inference.v2.model_runner import (
+            build_decode_loop, build_ragged_step)
+        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import \
+            pack_layout
+        from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                                     NemotronHLM)
+
+        model = NemotronHLM(NemotronHConfig(
+            vocab_size=32768, experts_held=128, max_seq_len=2240))
+        family = model.serving_family()
+        shapes = jax.eval_shape(lambda k: model.init_params(k, BF16),
+                                jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda x: _on(dev, x.shape, x.dtype), shapes)
+        seqs, blocks, nb = 128, 2240 // PAGE, 4480
+        cache = (_on(dev, (nb + 1, PAGE) + family.row.token_shape),
+                 tuple(_on(dev, (5 * seqs + 1,) + shape, dtype)
+                       for shape, dtype in family.state.arrays(BF16)))
+        kw = dict(max_seqs=seqs, max_blocks=blocks, num_blocks=nb,
+                  attn_impl="paged", jit=False)
+        if decode:
+            kw.update(max_seqs=wide)
+            loop = build_decode_loop(family, max_q=wide, block_size=PAGE,
+                                     steps=steps, **kw)
+            meta = pack_layout(wide, wide, blocks, True)["_total"][0]
+            return loop, (params, cache, _on(dev, (meta,), jnp.int32),
+                          _on(dev, (2,), jnp.uint32))
+        step = build_ragged_step(family, max_q=bucket, **kw)
+        meta = pack_layout(bucket, seqs, blocks, True)["_total"][0]
+        return step, (params, cache, _on(dev, (meta,), jnp.int32))
+
+    def mamba_layers_run_two_kernels_in_place(compiled):
+        """A Mamba-2 layer's decode form is two Mosaic calls on the pools
+        where they lie: no gathered ``[rows, 64, 128, 128]`` states."""
+        text = compiled.as_text()
+        for kernel in ("ssd_decode", "gdn_conv_step"):
+            assert f"/{kernel}/pallas_call" in text, kernel
+        assert "f32[128,64,128,128]" not in text
+
+    def the_chunked_form_leaves_the_pool_where_it_lies(compiled):
+        """The chunk loop carries the state pool in the layout it is stored
+        in: unpinned, the compiler laid the carried pool out state-values-
+        minor for the chunk's products and copied all 2.5 GiB of it at the
+        step's entry and back at its end (temporaries 2.84 GiB, PR 60)."""
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2 ** 30
+
+    if decode and wide == 128:
+        build.check = mamba_layers_run_two_kernels_in_place
+    if not decode:
+        build.check = the_chunked_form_leaves_the_pool_where_it_lies
+    return build
+
+
 def _paged_stored_heads(op, rows=512):
     """The page operations with 30 query and K/V heads of 128 on a pool that
     STORES a token in 32 (``KVRow.tiled(30, 128)``: 64 combined rows; 60 are
@@ -831,6 +909,16 @@ CASES = {
     # the selective scan's decode form alone, at the Phi-4-mini-flash cell's
     "gdn_conv_step[64 rows x 5120, bias]": _gdn_conv_step(64, 5120, True),
     "ssm_decode[64 rows x 16 x 5120]": _ssm_decode,
+    # Nemotron-3-Super: the state-space-dual kind and the latent experts
+    "ssd_decode[128 rows x 128 heads x 64 x 128]": _ssd_decode,
+    "nemotronh_decode_window": _nemotron_h(decode=True),
+    # the width the cell runs at (ISSUE 60's fall-back, taken)
+    "nemotronh_decode_window[64 wide, 8 steps]":
+        _nemotron_h(decode=True, wide=64, steps=8),
+    "nemotronh_decode_window[1 wide, 1 step]":
+        _nemotron_h(decode=True, wide=1, steps=1),
+    "nemotronh_prefill_step": _nemotron_h(decode=False),
+    "nemotronh_prefill_step[16 rows]": _nemotron_h(decode=False, bucket=16),
     # LongCat-Flash: the shared latent kernels at 64 heads (every prefill
     # bucket's query tile, PR 34's lesson), and the double layer's programs
     "mla_paged_decode[64 heads]": _mla64(None),

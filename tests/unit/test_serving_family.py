@@ -19,6 +19,7 @@ from deepspeed_tpu.inference.v2.engine_v2 import (
     RaggedInferenceEngineConfig,
 )
 from deepspeed_tpu.models.families import ArchConfig, UniversalCausalLM
+from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHLM
 from deepspeed_tpu.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridLM
 from deepspeed_tpu.models.phi4_flash import Phi4FlashConfig, Phi4FlashLM
 from deepspeed_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
@@ -195,6 +196,9 @@ CASES = {
     # model's 6; its 6 linear layers keep a state, 2 of 8 layers own pages
     "olmo_hybrid": (lambda: OlmoHybridLM(OlmoHybridConfig.tiny()),
                     (16, 16), 384),
+    # 2 K/V heads of 16 in its ONE attention layer of 11; its 5 Mamba-2
+    # layers keep a state, its 5 expert layers nothing
+    "nemotron_h": (lambda: NemotronHLM(NemotronHConfig.tiny()), (4, 16), 128),
 }
 
 
@@ -213,7 +217,7 @@ def test_the_pool_has_the_row_the_family_says(name):
     assert eng.kv.pages.dtype == jnp.bfloat16
     assert eng.latent_kv == fam.row.latent == (name == "xing4")
     assert (eng.state_pool is not None) == (fam.state is not None) \
-        == (name == "olmo_hybrid")
+        == (name in ("olmo_hybrid", "nemotron_h"))
 
     logits = eng.put([0, 1], [[3, 5, 7], [11, 13, 17, 19, 23]])
     window = eng.decode_batch_async(
@@ -537,6 +541,7 @@ STATEFUL = {
     "olmo_hybrid": lambda: OlmoHybridLM(OlmoHybridConfig.tiny()),
     "windowed_in_this_file": lambda: WindowedLM(RenamedConfig(depth=2)),
     "phi4_flash": lambda: Phi4FlashLM(Phi4FlashConfig.tiny()),
+    "nemotron_h": lambda: NemotronHLM(NemotronHConfig.tiny()),
 }
 
 
@@ -565,7 +570,10 @@ def test_a_family_with_state_refuses_what_a_state_cannot_do(name):
     held = fam.state.num_layers + (fam.window.num_layers if fam.window else 0)
     assert fam.page_layers < fam.num_layers \
         and held <= fam.num_layers - fam.page_layers
-    assert fam.window is not None or held == fam.num_layers - fam.page_layers
+    # (a layer that is the experts alone holds neither: Nemotron-H's five)
+    alone = 5 if name == "nemotron_h" else 0
+    assert fam.window is not None \
+        or held == fam.num_layers - fam.page_layers - alone
 
 
 class PairedLM(RenamedLM):
