@@ -332,51 +332,78 @@ def _activation_spec():
 def _remat_layout(cfg: TransformerConfig, batch: int, seq_len: int,
                   itemsize: int):
     """What ``checkpointing.layer_policy`` chooses from, for ONE device, from
-    the trace's shapes: the layer's named values (bytes held a layer, FLOPs
-    its backward spends making them again) and the bytes the step needs
-    beside them — the head's float32 ``[rows, vocab]`` logits, log-softmax
-    and cotangent, or one layer's backward pass (every named value made
-    again, and a cotangent for each), whichever is more: the two never live
-    together.  Rows are a device's share over the batch and sequence axes;
-    a tensor axis, which would divide the widths, is left out (it saves
-    less than it could there)."""
+    the trace's shapes: the layer's named values (bytes held a layer, seconds
+    its backward spends making them again: a kernel's or a matmul's FLOPs at
+    the MXU's peak, a gathered weight's bytes at the links' rate) and the
+    bytes the step needs beside them — the head's float32 ``[rows, vocab]``
+    logits, log-softmax and cotangent, or one layer's backward pass (every
+    named activation made again, and a cotangent for each), whichever is
+    more: the two never live together.  Rows are a device's share over the
+    batch and sequence axes; a tensor axis, which would divide the widths,
+    is left out (it saves less than it could there)."""
+    from ..moe.sharded_moe import ROW_NAMES, STACK_NAMES, own_pair_rows
     from ..ops.transformer.flash_attention import LSE_NAME, OUT_NAME
+    from ..profiling.roofline import device_spec
     from ..runtime import topology as _topo
     from ..runtime.activation_checkpointing.checkpointing import Saveable
 
     topo = _topo._TOPOLOGY
-    shards = 1
+    shards = devices = 1
     if topo is not None:
+        devices = topo.mesh.size
         over_batch = math.prod(topo.dims[a] for a in _BATCH_AXES)
         shards = (over_batch if batch % over_batch == 0 else 1) * (
             topo.dims[SEQ] if seq_len % topo.dims[SEQ] == 0 else 1)
+    spec = device_spec(None if topo is None else topo.mesh.devices.flat[0])
     rows = batch * seq_len // shards
     D, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     q_w, kv_w = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
 
-    def matmul(name, width, contract=D):
+    def matmul(name, width, contract=D, rows=rows):
         return Saveable((name,), rows * width * itemsize,
-                        2.0 * rows * contract * width)
+                        2.0 * rows * contract * width / spec.peak_flops)
 
-    tensors = []
-    if cfg.num_experts == 1:    # the expert block names nothing (ROADMAP S5)
+    tensors, pair_rows = [], 0
+    if cfg.num_experts == 1:
         tensors += [matmul("gate_proj", F), matmul("up_proj", F)]
+    elif cfg.moe_dispatch == "sparse":
+        # a shard's grouped matmuls name the rows they make; the padded
+        # einsums (one device, other axes) name nothing
+        pair_rows = own_pair_rows(batch * seq_len, cfg.num_experts,
+                                  cfg.moe_top_k, cfg.moe_capacity_factor)
+        if pair_rows:
+            gate, up, down = ROW_NAMES
+            tensors += [matmul(gate, F, rows=pair_rows),
+                        matmul(up, F, rows=pair_rows),
+                        matmul(down, D, F, rows=pair_rows)]
     if _attn_impl(cfg, seq_len) == "flash":
         # causal: half of the two [S, S, hd] products a head
         tensors.append(Saveable(
             (OUT_NAME, LSE_NAME), rows * q_w * itemsize + rows * cfg.num_heads * 4,
-            2.0 * rows * seq_len * q_w))
+            2.0 * rows * seq_len * q_w / spec.peak_flops))
     tensors += [matmul("q_proj", q_w), matmul("k_proj", kv_w),
                 matmul("v_proj", kv_w), matmul("attn_residual", D, q_w)]
     head = 3 * rows * V * 4
     layer_backward = 2 * sum(t.bytes for t in tensors)
-    if cfg.num_experts > 1:     # the experts' unnamed gate and up rows
+    if cfg.num_experts > 1 and not pair_rows:   # the einsums' gate and up
         layer_backward += 4 * rows * cfg.moe_top_k * F * itemsize
-    if topo is not None and topo.mesh.size > 1:
-        # a sharded (ZeRO-3) layer is gathered whole, and its gradient is
-        # whole before it is scattered
-        layer_backward += 2 * itemsize * (
-            D * (q_w + 2 * kv_w) + q_w * D + cfg.num_experts * 3 * D * F)
+    if devices > 1:
+        # a sharded (ZeRO-3) layer is gathered whole; a weight's gradient is
+        # whole until it is scattered, one weight at a time (the compiler's
+        # account, PERF.md section 6, PR 63)
+        stack = cfg.num_experts * D * F * itemsize
+        layer_backward += itemsize * (
+            D * (q_w + 2 * kv_w) + q_w * D) + 3 * stack + max(
+            itemsize * D * q_w, stack)
+        if pair_rows:
+            # the gather lands at the region's boundary (moe/sharded_moe.py):
+            # a stack kept whole costs the other devices' shares over the
+            # links to make again, and kept, the backward's gathered copy of
+            # it above is the one it slices out of the layers' stacks
+            freed = max(0, min(layer_backward - head, 3 * stack)) // 3
+            tensors += [Saveable((n,), stack, stack * (devices - 1) / devices
+                                 / spec.ici_bandwidth, freed)
+                        for n in STACK_NAMES]
     return tensors, max(head, layer_backward)
 
 
@@ -449,8 +476,9 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     # Under ``jax.checkpoint`` the policy selects by these names what a
     # layer keeps for its backward pass (``_remat_layout``): the
     # projections before RoPE and the GQA repeat, the flash kernel's own
-    # (ops/transformer/flash_attention.py), the residual stream.  Anywhere
-    # else a name is the identity.
+    # (ops/transformer/flash_attention.py), the residual stream, and on the
+    # experts' grouped path the matmuls' rows and the stacks ZeRO-3 gathered
+    # (moe/sharded_moe.py).  Anywhere else a name is the identity.
     def layer(carry, lp):
         x, aux = carry
         B = x.shape[0]

@@ -556,6 +556,15 @@ def moe_mlp_block(lp: Dict, tokens: jnp.ndarray, k: int = 2,
                   lp["down_proj"]["kernel"])
 
 
+#: ``checkpoint_name`` tags in :func:`_experts_on_own_pairs`: the rows out of
+#: the three grouped matmuls, and the three expert stacks as a shard holds
+#: them WHOLE — under ZeRO-3 what the gather at the region's boundary made.
+#: A checkpointed layer that saves them (``models/transformer.py``
+#: ``_remat_layout``) runs neither the matmuls nor the gathers twice.
+ROW_NAMES = ("expert_gate_rows", "expert_up_rows", "expert_down_rows")
+STACK_NAMES = ("expert_gate_whole", "expert_up_whole", "expert_down_whole")
+
+
 def _experts_on_own_pairs(gate_out: SparseGateOutput, tokens, rows: int,
                           w_gate, w_up, w_down):
     """One shard's kept (token, choice) pairs through their experts, sorted
@@ -563,6 +572,8 @@ def _experts_on_own_pairs(gate_out: SparseGateOutput, tokens, rows: int,
     whole row tile); nothing leaves the shard.  A pair the gate dropped or
     masked sorts behind the last expert and is in no group: its rows are
     not computed, and hold whatever memory held."""
+    name = jax.ad_checkpoint.checkpoint_name
+    w_gate, w_up, w_down = map(name, (w_gate, w_up, w_down), STACK_NAMES)
     dtype = w_gate.dtype
     (S, k), E, D = gate_out.slot.shape, w_gate.shape[0], tokens.shape[1]
     with jax.named_scope("moe/dispatch"):
@@ -578,14 +589,26 @@ def _experts_on_own_pairs(gate_out: SparseGateOutput, tokens, rows: int,
         x = jnp.where(live, jnp.take(tokens.astype(dtype), token_of, axis=0),
                       0)
     with jax.named_scope("moe/experts"):
-        act = jax.nn.silu(grouped_matmul(x, w_gate, sizes))
-        up = grouped_matmul(x, w_up, sizes)
-        y = grouped_matmul(act * up, w_down, sizes)
+        act = jax.nn.silu(name(grouped_matmul(x, w_gate, sizes),
+                               ROW_NAMES[0]))
+        up = name(grouped_matmul(x, w_up, sizes), ROW_NAMES[1])
+        y = name(grouped_matmul(act * up, w_down, sizes), ROW_NAMES[2])
     with jax.named_scope("moe/combine"):
         y = jnp.where(live, y, 0)
         y = jnp.take(y, jnp.argsort(order), axis=0).reshape(S, k, D)
         return jnp.sum(gate_out.gate_val[:, :, None].astype(dtype) * y,
                        axis=1)
+
+
+def own_pair_rows(num_tokens: int, num_experts: int, k: int,
+                  capacity_factor: float) -> int:
+    """Rows of a shard's grouped matmuls where :func:`moe_mlp_block` (sparse
+    dispatch) takes :func:`_experts_on_own_pairs` at these sizes on the mesh
+    of this trace; 0 where it does not."""
+    grouped = _routing_groups(num_tokens, _capacity(
+        num_tokens * k, num_experts, capacity_factor, MIN_CAPACITY))
+    return whole_tiles(num_tokens // grouped[0].size * k,
+                       num_experts) if grouped else 0
 
 
 def moe_layer(params: Dict, x: jnp.ndarray, k: int = 1,

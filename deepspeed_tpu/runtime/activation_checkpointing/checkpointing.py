@@ -19,8 +19,12 @@ On TPU every reference feature maps onto a ``jax.checkpoint`` policy:
                                         split per call, replayed exactly under
                                         remat (no tracker needed)
   (none: the default since PR 48)       a checkpointed layer SAVES the named
-                                        outputs of its kernels and matmuls, as
-                                        many as the device's free memory holds
+                                        outputs of its kernels and matmuls and
+                                        (PR 63) the expert weights ZeRO-3
+                                        gathered for it, as many as the
+                                        device's free memory holds, the
+                                        dearest to make again first — FLOPs
+                                        and a gather's bytes both in seconds
                                         (:func:`select_saved`,
                                         :func:`layer_policy`); with no memory
                                         report, or outside an engine's step:
@@ -83,22 +87,26 @@ RESIDUAL_NAMES = ("attn_residual", "mlp_residual")
 class Saveable(NamedTuple):
     """Named values of one checkpointed layer that pay off only together
     (a kernel's output and its row statistics), the bytes a device holds
-    for them a layer, and the FLOPs the backward spends making them again
-    when they are not saved."""
+    for them a layer, and the seconds the backward spends making them again
+    when they are not saved — FLOPs at the MXU's peak, or for a gathered
+    weight its bytes at the links' rate: one unit, so one sort decides.
+    ``freed``: bytes of the caller's reserve that saving them gives back (a
+    gathered weight that is kept is not gathered again)."""
     names: Tuple[str, ...]
     bytes: int
-    flops: float
+    seconds: float
+    freed: int = 0
 
 
 def select_saved(tensors: Sequence[Saveable], layers: int,
                  budget_bytes: int) -> Tuple[str, ...]:
     """The names a checkpointed layer saves under ``budget_bytes`` for all
-    ``layers``: the entries taken by FLOPs a byte (a tie keeps the order
+    ``layers``: the entries taken by seconds a byte (a tie keeps the order
     given) up to the first that no longer fits — all of them where all
     fit, ``()`` where the best does not, which is ``nothing_saveable``."""
     saved, left = [], budget_bytes
-    for t in sorted(tensors, key=lambda t: -t.flops / max(t.bytes, 1)):
-        left -= layers * t.bytes
+    for t in sorted(tensors, key=lambda t: -t.seconds / max(t.bytes, 1)):
+        left -= layers * t.bytes - t.freed
         if left < 0:
             break
         saved.extend(t.names)
@@ -132,8 +140,9 @@ def layer_policy(tensors: Sequence[Saveable], reserve_bytes: int,
     caller's shapes).  A saved value is held once a layer and once more:
     the backward pass slices the layer it differentiates out of the
     ``[layers, ...]`` stacks into a copy (the TPU compiler's own account,
-    PERF.md section 6, PR 48).  Leaves one ``train/remat_layout`` record a
-    trace."""
+    PERF.md section 6, PR 48) — less what an entry says the reserve holds
+    for it already (``Saveable.freed``).  Leaves one ``train/remat_layout``
+    record a trace."""
     limit, state = _ENGINE_MEMORY or (0, 0)
     budget = max(0, limit - state - reserve_bytes)
     saved = select_saved(tensors, layers + 1, budget)

@@ -1,7 +1,8 @@
 """What a checkpointed layer saves (PR 48): the selection from a byte
 budget, the names the model and the flash kernel's VJP rule place, and that
 saving them changes no gradient and really takes the second forward pass out
-of the backward program.
+of the backward program; and (PR 63) the experts' rows and the stacks ZeRO-3
+gathered, kept so that a step gathers them once.
 
 The budget comes from the device's ``bytes_limit`` through the engine
 (``checkpointing.engine_memory``); the CPU reports no memory, so a test that
@@ -22,7 +23,11 @@ from deepspeed_tpu.models.transformer import (CausalLM, TransformerConfig,
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ac
 from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import \
     Saveable
-from deepspeed_tpu.runtime.topology import TopologyConfig, initialize_mesh
+from deepspeed_tpu.moe.sharded_moe import ROW_NAMES, STACK_NAMES
+from deepspeed_tpu.profiling.roofline import spec_for_kind
+from deepspeed_tpu.runtime.topology import (DATA, TopologyConfig,
+                                            initialize_mesh)
+from deepspeed_tpu.runtime.zero.sharding import ZeroShardingPlan
 from deepspeed_tpu.telemetry import get_tracer
 
 pytestmark = pytest.mark.core
@@ -35,10 +40,13 @@ ALL = ("gate_proj", "up_proj", "q_proj", "k_proj", "v_proj", "attn_residual",
        "flash_out", "flash_lse")
 PLENTY = (1 << 40, 0)       # (bytes_limit, engine state): everything fits
 
-#: three entries, best FLOPs a byte first when sorted: b (8), a and c (4, the
-#: tie keeps the order given)
+#: three entries, dearest to make again a byte first when sorted: b (8), a
+#: and c (4, the tie keeps the order given)
 TENSORS = [Saveable(("a",), 100, 400.0), Saveable(("b", "b2"), 50, 400.0),
            Saveable(("c",), 10, 40.0)]
+#: and a gathered weight behind them (1 a byte), which kept gives back the 80
+#: bytes the reserve held for gathering it again
+GATHERED = TENSORS + [Saveable(("w",), 80, 80.0, freed=80)]
 
 
 @pytest.fixture(autouse=True)
@@ -58,6 +66,15 @@ def _plain_policy():
 ])
 def test_selection_is_the_prefix_that_fits(budget, want):
     assert ac.select_saved(TENSORS, layers=2, budget_bytes=budget) == want
+
+
+@pytest.mark.parametrize("budget, want", [
+    (2 * 160 + 80, ("b", "b2", "a", "c", "w")),     # 2 x 80 less the 80 freed
+    (2 * 160 + 79, ("b", "b2", "a", "c")),
+    (2 * 160 - 1, ("b", "b2", "a")),        # c does not fit: w is not tried
+])
+def test_a_kept_gather_gives_its_reserve_back(budget, want):
+    assert ac.select_saved(GATHERED, layers=2, budget_bytes=budget) == want
 
 
 def _layout_records():
@@ -286,3 +303,157 @@ def test_layout_of_the_mistral_cell():
              for n in t.names]
     assert names == ["flash_out", "flash_lse", "q_proj", "k_proj", "v_proj",
                      "attn_residual"]
+
+
+# --------------------------------------------------------------------- #
+# PR 63: the experts' rows and the stacks ZeRO-3 gathered
+# --------------------------------------------------------------------- #
+ATTENTION = ("q_proj", "k_proj", "v_proj", "attn_residual", "flash_out",
+             "flash_lse")
+
+
+def _mixtral_cell(monkeypatch, devices=4, **kw):
+    """The four-chip cell's shapes (2 x 2048 rows a chip of Mixtral-8x7B's
+    widths in bf16, depth 1) over ``devices`` data shards with the v5e's
+    peaks: ``(tensors, reserve)``."""
+    from deepspeed_tpu.profiling import roofline
+
+    monkeypatch.setattr(roofline, "device_spec",
+                        lambda device=None: spec_for_kind("TPU v5 lite"))
+    initialize_mesh(TopologyConfig(data=devices),
+                    devices=jax.devices()[:devices], force=True)
+    cfg = TransformerConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+        num_layers=1, num_heads=32, num_kv_heads=8, max_seq_len=2048,
+        remat=True, attn_impl="flash", **{"num_experts": 8, **kw})
+    return transformer._remat_layout(cfg, 2 * devices, 2048, 2)
+
+
+def test_layout_of_the_mixtral_cell(monkeypatch):
+    """PR 63's arithmetic at the four-chip cell: a chip's 8,192 pair rows
+    out of the three grouped matmuls (0.54 GB), attention's six names (0.12
+    GB), the three gathered stacks (0.94 GB each) — taken in that order,
+    the down rows first: a matmul's output is worth its contraction's FLOPs
+    at the MXU's peak (72.8 and 20.8 ms a GB), a stack three quarters of its
+    bytes over the links (3.75 ms a GB).  Activations are held twice at
+    depth 1 (``layers + 1``), a stack once: the copy its backward slices out
+    is the gathered layer the reserve holds already."""
+    tensors, reserve = _mixtral_cell(monkeypatch)
+    stack, pair_rows = 8 * 4096 * 14336 * 2, 2 * 2048 * 2
+    by_name = {t.names: t.bytes for t in tensors}
+    assert [t.names for t in tensors[:3]] == [(n,) for n in ROW_NAMES]
+    assert [t.names for t in tensors[-3:]] == [(n,) for n in STACK_NAMES]
+    gate, up, down = ((n,) for n in ROW_NAMES)
+    assert by_name[gate] == by_name[up] == pair_rows * 14336 * 2
+    assert by_name[down] == pair_rows * 4096 * 2
+    assert all(by_name[(n,)] == stack for n in STACK_NAMES)
+    rows = by_name[gate] + by_name[up] + by_name[down]
+    attention = sum(by_name.values()) - rows - 3 * stack
+    assert (rows, attention) == (536_870_912, 117_964_800)
+    # the gathered layer, the largest weight's gradient, every activation
+    # made again and a cotangent for each: above the head's 1.57 GB
+    weights = 2 * 4096 * (4096 + 2 * 1024 + 4096) + 3 * stack
+    assert reserve == weights + stack + 2 * (attention + rows) \
+        == 5_151_653_888
+    ms_a_gb = {t.names[0]: 1e12 * t.seconds / t.bytes for t in tensors}
+    assert ms_a_gb[ROW_NAMES[0]] == pytest.approx(4096 / 197)
+    assert ms_a_gb[ROW_NAMES[2]] == pytest.approx(14336 / 197)
+    assert ms_a_gb[STACK_NAMES[0]] == pytest.approx(0.75 / 0.2)
+    need = 2 * (attention + rows) + 3 * stack
+    assert need == 4_128_243_712        # PERF.md section 6, PR 63
+    everything = ROW_NAMES[2:] + ROW_NAMES[:2] + ATTENTION + STACK_NAMES
+    assert ac.select_saved(tensors, 2, need) == everything
+    assert ac.select_saved(tensors, 2, need - 1) == everything[:-1]
+
+
+def test_a_budget_without_room_for_the_stacks_keeps_the_activations(
+        monkeypatch):
+    """Where only a prefix fits, activations win: the rows, then
+    attention's names, then the stacks one by one."""
+    tensors, _ = _mixtral_cell(monkeypatch)
+    rows = ROW_NAMES[2:] + ROW_NAMES[:2]
+    activations = 2 * sum(t.bytes for t in tensors[:-3])
+    assert ac.select_saved(tensors, 2, activations) == rows + ATTENTION
+    assert ac.select_saved(tensors, 2, 10 ** 9) == rows[:2]
+    assert ac.select_saved(tensors, 2, activations + 8 * 4096 * 14336 * 2) \
+        == rows + ATTENTION + STACK_NAMES[:1]
+
+
+@pytest.mark.parametrize("case, kw, devices", [
+    ("one_device", {}, 1),
+    ("one_expert", dict(num_experts=1), 4),
+    ("dense_oracle", dict(moe_dispatch="dense"), 4),
+])
+def test_nothing_of_the_experts_is_listed_off_the_grouped_path(
+        monkeypatch, case, kw, devices):
+    """No gather on one device, no expert block with one expert, and the
+    padded einsums (one device; the dense oracle) name nothing."""
+    names = [n for t in _mixtral_cell(monkeypatch, devices, **kw)[0]
+             for n in t.names]
+    assert not set(names) & set(ROW_NAMES + STACK_NAMES)
+    assert ("gate_proj" in names) == (case == "one_expert")
+
+
+def _zero3_experts():
+    """A tiny Mixtral on four data shards, its parameters laid out as ZeRO-3
+    stores them (the expert stacks over ``data``): ``(mesh, cfg, params,
+    shardings, tokens)``."""
+    topo = initialize_mesh(TopologyConfig(data=4), devices=jax.devices()[:4],
+                           force=True)
+    cfg = _tiny(num_experts=4, moe_top_k=2, attn_impl="xla",
+                fused_rmsnorm="off")
+    model = CausalLM(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))       # float32
+    shardings = ZeroShardingPlan(
+        topo, 3, base_specs=model.partition_specs).param_shardings(params)
+    assert DATA in shardings["layers"]["gate_proj"]["kernel"].spec
+    return (topo.mesh, cfg, jax.device_put(params, shardings), shardings,
+            _tokens(batch=4))
+
+
+def _stack_gathers(hlo: str) -> int:
+    """All-gathers of a whole ``[4, 128, 256]`` / ``[4, 256, 128]`` expert
+    stack in a compiled program's text (a scan body is in it once)."""
+    return len(re.findall(
+        r"= f32\[(?:1,)?4,(?:128,256|256,128)\]\S* all-gather(?:-start)?\(",
+        hlo))
+
+
+def test_kept_stacks_are_gathered_once_a_step():
+    """Forward and backward scan each gather the three stacks under
+    ``nothing_saveable``; with the names saved the backward's are gone, and
+    so are its second grouped matmuls — and no gradient moves."""
+    mesh, cfg, params, shardings, tokens = _zero3_experts()
+    plain_cfg = dataclasses.replace(cfg, remat_policy="nothing_saveable")
+    with mesh:
+        before = len(_layout_records())
+        saved = jax.jit(_grad_fn(cfg, tokens, PLENTY), out_shardings=shardings)
+        plain = jax.jit(_grad_fn(plain_cfg, tokens), out_shardings=shardings)
+        assert _stack_gathers(saved.lower(params).compile().as_text()) == 3
+        assert _stack_gathers(plain.lower(params).compile().as_text()) == 6
+        record = _layout_records()[before].attrs
+        assert set(record["saved"]) == set(
+            ROW_NAMES + STACK_NAMES + ATTENTION[:-2])
+        matmuls = [str(jax.make_jaxpr(_grad_fn(c, tokens, m))(params)).count(
+            "ragged_dot") for c, m in ((cfg, PLENTY), (plain_cfg, None))]
+        assert matmuls[0] < matmuls[1]
+        for a, b in zip(jax.tree.leaves(saved(params)),
+                        jax.tree.leaves(plain(params))):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def test_no_budget_is_the_experts_program_of_nothing_saveable():
+    """A limit that the reserve alone uses up: the names are identities and
+    the text is ``nothing_saveable``'s."""
+    mesh, cfg, params, _, tokens = _zero3_experts()
+    reserve = transformer._remat_layout(cfg, 4, 128, 4)[1]
+
+    def text(cfg, memory):
+        return re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(
+            _grad_fn(cfg, tokens, memory))(params)))
+
+    with mesh:
+        before = len(_layout_records())
+        assert text(cfg, (reserve, 0)) == text(dataclasses.replace(
+            cfg, remat_policy="nothing_saveable"), None)
+        assert _layout_records()[before].attrs["saved"] == ()

@@ -8,9 +8,11 @@ shapes) with the saved set forced, against the rule's own choice.
 
 Sets: ``none`` (``nothing_saveable``, the program before PR 48), ``gate_up``,
 ``attention`` (q/k/v, the flash kernel's output and row statistics, the
-post-attention residual), ``all``, and ``rule`` (nothing forced: what
-``checkpointing.layer_policy`` picks from the device's ``bytes_limit``, with
-its ``train/remat_layout`` record).  One process a set — ``peak_bytes_in_use``
+post-attention residual), for the experts' cell ``rows`` (the grouped
+matmuls' rows) and ``stacks`` (the three gathered expert stacks), ``all``, and
+``rule`` (nothing forced: what ``checkpointing.layer_policy`` picks from the
+device's ``bytes_limit``, with its ``train/remat_layout`` record).  A set
+forces what the cell names of it.  One process a set — ``peak_bytes_in_use``
 is a process's high-water mark — started one after the other by a parent that
 never touches JAX.  Each prints one JSON line: ms a step (median of
 ``--steps`` after two warm ones, each ending in ``block_until_ready``), the
@@ -30,9 +32,12 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
 
 ATTENTION = ("q_proj", "k_proj", "v_proj", "flash_out", "flash_lse",
              "attn_residual")
-SETS = {"none": (), "gate_up": ("gate_proj", "up_proj"),
-        "attention": ATTENTION,
-        "all": ("gate_proj", "up_proj") + ATTENTION, "rule": None}
+GATE_UP = ("gate_proj", "up_proj")
+ROWS = ("expert_gate_rows", "expert_up_rows", "expert_down_rows")
+STACKS = ("expert_gate_whole", "expert_up_whole", "expert_down_whole")
+SETS = {"none": (), "gate_up": GATE_UP, "attention": ATTENTION, "rows": ROWS,
+        "stacks": STACKS, "all": GATE_UP + ATTENTION + ROWS + STACKS,
+        "rule": None}
 TOY = dict(seq_len=128, micro_batch_per_chip=2)
 
 
@@ -126,7 +131,7 @@ def main() -> int:
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--cpu-rehearsal", action="store_true")
-    ap.add_argument("--out", default="chiprun_out/pr48/remat_split.jsonl")
+    ap.add_argument("--out", default="chiprun_out/remat_split.jsonl")
     args = ap.parse_args()
     if args.set:
         print(json.dumps(one_set(args)), flush=True)
