@@ -28,7 +28,9 @@ mathematics on the flat token axis ``[T, ...]``.  :func:`serving_family`
 composes them into what the paged serving path asks of a model
 (``models/serving.py``): a latent row, TWO page layers a layer (the body
 takes them from one handle, ``cache.at``), the pair counts with the identity
-pairs apart; the training path is open (``loss_fn`` raises).
+pairs apart; the family is served only (``loss_fn`` raises and says what a
+training path would still lack; latent attention itself trains in
+``models/joyai_flash.py``).
 
 A CHIP'S SHARE of an expert-parallel deployment is stated by keys of its own
 and never by a width: ``n_routed_experts`` of the config file are the real
@@ -241,7 +243,7 @@ def init_params(cfg: LongCatFlashConfig, key: jax.Array, dtype=jnp.float32
 class LongCatFlashLM:
     """Model object the serving engine takes (``config`` +
     ``init_params``).  Loading a checkpoint's tensors is out of scope; the
-    training path is open."""
+    family is served only."""
 
     def __init__(self, cfg: LongCatFlashConfig):
         self.config = cfg
@@ -255,9 +257,12 @@ class LongCatFlashLM:
 
     def loss_fn(self, params, batch, rng):
         raise NotImplementedError(
-            "longcat_flash: the training path is open (ROADMAP Queue 2: "
-            "latent attention's backward); this family is served through "
-            "inference/v2 only")
+            "longcat_flash: this family is served through inference/v2 "
+            "only; latent attention's training form (expanded k/v through "
+            "flash attention, forward and backward) is "
+            "models/joyai_flash.py's, what a training path here still "
+            "lacks is the two-attention layer's block and the identity "
+            "experts' share of a dropless backward (ROADMAP R3)")
 
     def serving_family(self) -> ServingFamily:
         return serving_family(self.config)

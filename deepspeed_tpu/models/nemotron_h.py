@@ -119,7 +119,9 @@ class NemotronHConfig:
         if hf.get("num_nextn_predict_layers", 0):
             raise NotImplementedError(
                 "nemotron_h: the multi-token-prediction module is not held "
-                "(num_nextn_predict_layers must be 0; ROADMAP R8)")
+                "(num_nextn_predict_layers must be 0): a module TRAINS as a "
+                "second loss in models/joyai_flash.py; serving one as a "
+                "self-drafter is not written (ROADMAP R8)")
         if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1 \
                 or hf.get("n_shared_experts", 1) != 1:
             raise NotImplementedError(

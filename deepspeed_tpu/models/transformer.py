@@ -522,23 +522,11 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
     if cfg.remat:
         from ..runtime.activation_checkpointing import checkpointing as ac
 
-        if ac.active():
-            # DS-config activation_checkpointing (partition_activations /
-            # cpu_checkpointing) overrides the model's own remat policy —
-            # the config toggle must change execution
-            policy = ac.get_policy()
-        elif cfg.remat_policy == "auto":
-            policy = ac.layer_policy(
-                *_remat_layout(cfg, *tokens.shape, jnp.dtype(dtype).itemsize),
-                layers=cfg.num_layers)
-        else:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
-            if not callable(policy):
-                valid = ["auto"] + [n for n in dir(jax.checkpoint_policies)
-                                    if not n.startswith("_")]
-                raise ValueError(
-                    f"remat_policy={cfg.remat_policy!r} is not a "
-                    f"jax.checkpoint_policies member; valid: {valid}")
+        policy = ac.resolve_policy(
+            cfg.remat_policy,
+            lambda: _remat_layout(cfg, *tokens.shape,
+                                  jnp.dtype(dtype).itemsize),
+            layers=cfg.num_layers)
         layer_fn = jax.checkpoint(layer, policy=policy)
 
     with jax.named_scope("layers"):

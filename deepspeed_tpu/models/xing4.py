@@ -8,8 +8,14 @@ This module holds the configuration (built from the published
 ``config.json`` keys), the seeded parameter tree, and the per-token layer
 mathematics on the flat token axis ``[T, ...]``.  :func:`serving_family`
 composes them into what the paged serving path asks of a model
-(``models/serving.py``): a latent row, two layer stacks, the pair counts;
-the training path is open (``loss_fn`` raises).
+(``models/serving.py``): a latent row, two layer stacks, the pair counts.
+This family is served only (``loss_fn`` raises): latent attention's training
+form (the expanded k/v through flash attention at q/k 192, v 128), with a
+loss, partition specs and the step-balanced selection bias, is
+``models/joyai_flash.py``, which shares :func:`mla_query`,
+:func:`mla_latent` and :func:`mla_up_weights` with this file; what a
+training path here still lacks is the hyper-connected residual's backward
+at training shapes.
 
 Layers are NOT all alike, so the parameters are two stacks, each scanned on
 its own: ``dense_layers`` (the ``first_k_dense_replace`` leading ones) and
@@ -261,7 +267,7 @@ def init_params(cfg: Xing4Config, key: jax.Array, dtype=jnp.float32) -> Dict:
 class Xing4LM:
     """Model object the serving engine takes (``config`` +
     ``init_params``).  Loading a checkpoint's tensors is out of scope; the
-    training path is open."""
+    family is served only (see the module docstring)."""
 
     def __init__(self, cfg: Xing4Config):
         self.config = cfg
@@ -275,8 +281,10 @@ class Xing4LM:
 
     def loss_fn(self, params, batch, rng):
         raise NotImplementedError(
-            "xing4: the training path is open (ROADMAP R3); this family is "
-            "served through inference/v2 only")
+            "xing4: this family is served through inference/v2 only; latent "
+            "attention with sigmoid noaux_tc experts TRAINS in "
+            "models/joyai_flash.py (plain residual), the hyper-connected "
+            "residual has no training path (ROADMAP R3)")
 
     def serving_family(self) -> ServingFamily:
         return serving_family(self.config)
@@ -402,31 +410,33 @@ def hc_sublayer(X, hp: Dict, norm_scale, fn, cfg: Xing4Config, dtype):
 # --------------------------------------------------------------------- #
 # MLA projections
 # --------------------------------------------------------------------- #
-def mla_query(h, lp: Dict, cos, sin, cfg: Xing4Config):
+def mla_query(h, lp: Dict, cos, sin, cfg, rope=apply_rope):
     """Normed input [T, D] → (q_nope [T, H, dn], q_rope [T, H, rd]), RoPE
-    applied."""
+    applied (``rope``: this family's half-split pairs, or another family's:
+    ``models/joyai_flash.py`` rotates interleaved pairs).  ``cfg`` is any
+    configuration with the MLA widths."""
     T, H = h.shape[0], cfg.num_heads
     c_q = rms_norm(h @ lp["q_a_proj"]["kernel"], lp["q_a_norm"]["scale"],
                    cfg.norm_eps)
     q = (c_q @ lp["q_b_proj"]["kernel"]).reshape(T, H, cfg.qk_head_dim)
     q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], \
         q[..., cfg.qk_nope_head_dim:]
-    return q_nope, apply_rope(q_rope, cos, sin)
+    return q_nope, rope(q_rope, cos, sin)
 
 
-def mla_latent(h, lp: Dict, cos, sin, cfg: Xing4Config):
+def mla_latent(h, lp: Dict, cos, sin, cfg, rope=apply_rope):
     """Normed input [T, D] → the cached row [T, latent_row]: ``c_kv`` after
     its norm, ``k_rope`` after RoPE, zero padding."""
     T = h.shape[0]
     ckv = h @ lp["kv_a_proj"]["kernel"]
     c_kv = rms_norm(ckv[:, :cfg.kv_lora_rank], lp["kv_a_norm"]["scale"],
                     cfg.norm_eps)
-    k_rope = apply_rope(ckv[:, cfg.kv_lora_rank:], cos, sin)
+    k_rope = rope(ckv[:, cfg.kv_lora_rank:], cos, sin)
     pad = jnp.zeros((T, cfg.latent_row - cfg.latent_dim), c_kv.dtype)
     return jnp.concatenate([c_kv, k_rope, pad], axis=-1)
 
 
-def mla_up_weights(lp: Dict, cfg: Xing4Config):
+def mla_up_weights(lp: Dict, cfg):
     """(W_UK [R, H, dn], W_UV [R, H, dv]) views of ``kv_b_proj``."""
     w = lp["kv_b_proj"]["kernel"].reshape(
         cfg.kv_lora_rank, cfg.num_heads,

@@ -33,6 +33,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import get_tracer
 
@@ -213,11 +214,36 @@ def grouped_matmul(x, w, group_sizes, impl: Optional[str] = None):
     return _megablox(x, w, group_sizes)
 
 
+#: ``checkpoint_name`` tags of the three grouped matmuls' rows over the
+#: SORTED pairs (gate, up — or the plain form's one up — and down): what a
+#: checkpointed training layer may keep (``models/joyai_flash.py::
+#: _remat_layout``); anywhere else a name is the identity
+PAIR_ROW_NAMES = ("pair_gate_rows", "pair_up_rows", "pair_down_rows")
+
+
+@jax.custom_vjp
+def _rows_in_groups(x, live):
+    """``x`` as it is; differentiated, the rows that are in no group
+    (``live`` false) take a ZERO cotangent.  The grouped matmul leaves those
+    rows of its input's gradient unwritten (:func:`grouped_matmul`), and the
+    gather's transpose would add that memory into the tokens' gradient.  A
+    ``where`` on the way in would say the same at the cost of one more pass
+    over the rows in the forward."""
+    return x
+
+
+_rows_in_groups.defvjp(
+    lambda x, live: (x, live),
+    lambda live, dx: (jnp.where(live[:, None], dx, 0).astype(dx.dtype),
+                      None))
+
+
 def dropless_experts(h, idx, weights, experts: Dict,
                      valid=None, impl: Optional[str] = None, layer=None,
                      offset: Optional[int] = None,
                      identity_from: Optional[int] = None,
-                     act=jax.nn.silu) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     act=jax.nn.silu, trained: bool = False
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed experts' part of the layer: ``Σ_k g_k · E_idx_k(h)``.
 
     ``h`` [T, D]; ``idx``/``weights`` [T, k]; ``experts`` says the expert's
@@ -252,7 +278,13 @@ def dropless_experts(h, idx, weights, experts: Dict,
     elsewhere: it adds ``g·h`` where the token's residual lives (here, for
     every token: ``moe/identity``), is sorted behind the last group like a
     pair held elsewhere (no matmul row), and is counted in an entry of its
-    own, the last of the pairs."""
+    own, the last of the pairs.
+
+    A caller that DIFFERENTIATES the layer says so (``trained``): the rows of
+    the three grouped matmuls are then named for its checkpoint policy
+    (:data:`PAIR_ROW_NAMES`) and the rows in no group hand the tokens a zero
+    cotangent (:func:`_rows_in_groups`).  A serving caller traces exactly
+    what it always did."""
     T, D = h.shape
     k = idx.shape[1]
     E = experts["up"].shape[-3]
@@ -272,6 +304,8 @@ def dropless_experts(h, idx, weights, experts: Dict,
     if n > E:
         sizes = sizes[:E]
     x = jnp.take(h, token_of, axis=0)                   # [M, D]
+    if trained and n > E:
+        x = _rows_in_groups(x, jnp.take(flat, order) < E)
     # rows to a whole tile: the extra rows are zeros in the last group (where
     # pairs lie behind it, held elsewhere or identity: in no group, as they)
     M_pad = whole_tiles(M, E)
@@ -286,14 +320,18 @@ def dropless_experts(h, idx, weights, experts: Dict,
         experts = {n: w.reshape((L * E,) + w.shape[2:])
                    for n, w in experts.items()}
     with jax.named_scope("moe/experts"):
+        name = checkpoint_name if trained else (lambda x, _: x)
+        gate_rows, up_rows, down_rows = PAIR_ROW_NAMES
         if "gate" in experts:
-            g = grouped_matmul(x, experts["gate"], sizes, impl)
-            u = grouped_matmul(x, experts["up"], sizes, impl)
+            g = name(grouped_matmul(x, experts["gate"], sizes, impl),
+                     gate_rows)
+            u = name(grouped_matmul(x, experts["up"], sizes, impl), up_rows)
             u = act(g) * u
         else:
-            u = act(grouped_matmul(x, experts["up"], sizes, impl))
-        y = grouped_matmul(u.astype(h.dtype), experts["down"], sizes,
-                           impl)[:M]
+            u = act(name(grouped_matmul(x, experts["up"], sizes, impl),
+                         up_rows))
+        y = name(grouped_matmul(u.astype(h.dtype), experts["down"], sizes,
+                                impl), down_rows)[:M]
     with jax.named_scope("moe/combine"):
         inv = jnp.argsort(order)                        # back to pair order
         y = jnp.take(y, inv, axis=0).reshape(T, k, D)

@@ -10,6 +10,9 @@ recompute-based backward (dq and dkv kernels), exposed through
 Layout: inputs [B, S, H, hd] (GQA allowed: KV heads = H // group).  The kernel
 operates per (batch, head, q-block) with kv-blocks as the innermost grid dim,
 accumulating in VMEM scratch (f32).  Causal masking skips fully-masked blocks.
+``v`` may have a head width of its own (latent attention trains at q/k 192,
+v 128): the output, ``do``, ``dv`` and the forward's accumulator take ``v``'s
+width, ``dq`` and ``dk`` the queries'; nothing is padded.
 
 Precision: every dot takes its operands in the dtype the inputs arrive in
 and accumulates in float32.  The scores, the probabilities, ``ds`` and the
@@ -112,7 +115,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
     def _compute():
         q = q_ref[0, 0]                                 # [BQ, hd]
         k = k_ref[0, 0]                                 # [BK, hd]
-        v = v_ref[0, 0]                                 # [BK, hd]
+        v = v_ref[0, 0]                                 # [BK, vd]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
         q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -144,6 +147,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
 
 def _fwd(q, k, v, scale, causal, block_q, block_k):
     B, H, S, hd = q.shape
+    vd = v.shape[-1]
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
     Sq, Sk = nq * block_q, nk * block_k
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, Sq - S), (0, 0)))
@@ -163,19 +167,19 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, hd), kv_index),
-            pl.BlockSpec((1, 1, block_k, hd), kv_index),
+            pl.BlockSpec((1, 1, block_k, vd), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, vd), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, _STATS_LANES),
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, vd), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, _STATS_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, vd), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -267,6 +271,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
     q, k, v, out, lse = res
     do = g
     B, H, S, hd = q.shape
+    vd = v.shape[-1]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,S]
 
     nq, nk = _cdiv(S, block_q), _cdiv(S, block_k)
@@ -280,9 +285,15 @@ def _bwd(scale, causal, block_q, block_k, res, g):
     lsep = pad_r(lse)
     deltap = pad_r(delta)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, hd),
-                          _causal_kv_index(causal, block_q, block_k))
+    def q_rows(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, i, j: (b, h, i, 0))
+
+    def k_rows(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            _causal_kv_index(causal, block_q, block_k))
+
+    q_spec = q_rows(hd)
     r_spec = pl.BlockSpec((1, 1, block_q, _STATS_LANES),
                           lambda b, h, i, j: (b, h, i, 0))
 
@@ -290,7 +301,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=S),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        in_specs=[q_spec, k_rows(hd), k_rows(vd), q_rows(vd), r_spec, r_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
@@ -300,21 +311,27 @@ def _bwd(scale, causal, block_q, block_k, res, g):
 
     # dkv: kv-blocks outer, q-blocks inner; below-diagonal q blocks are the
     # masked ones here, so the q index map clamps UP to the first needed one
-    q_spec2 = pl.BlockSpec((1, 1, block_q, hd),
-                           _causal_q_index(causal, block_q, block_k))
-    k_spec2 = pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j, i: (b, h, j, 0))
+    def q_rows2(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            _causal_q_index(causal, block_q, block_k))
+
+    def k_rows2(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, j, i: (b, h, j, 0))
+
     r_spec2 = pl.BlockSpec((1, 1, block_q, _STATS_LANES),
                            _causal_q_index(causal, block_q, block_k))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=S),
         grid=(B, H, nk, nq),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
-        out_specs=[k_spec2, k_spec2],
+        in_specs=[q_rows2(hd), k_rows2(hd), k_rows2(vd), q_rows2(vd),
+                  r_spec2, r_spec2],
+        out_specs=[k_rows2(hd), k_rows2(vd)],
         out_shape=[jax.ShapeDtypeStruct((B, H, Sk, hd), k.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sk, hd), v.dtype)],
+                   jax.ShapeDtypeStruct((B, H, Sk, vd), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                        pltpu.VMEM((block_k, hd), jnp.float32)],
+                        pltpu.VMEM((block_k, vd), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
@@ -350,8 +367,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
     """Flash attention over [B, S, H, hd] inputs (GQA: kv may have fewer heads).
 
-    Returns [B, S, H, hd].  Falls back to padded head_dim for hd < 128 lanes
-    (Mosaic handles sub-128 minor dims; hd is kept as-is).
+    ``v`` is [B, S, KV, vd]; ``vd`` need not be ``hd``.  Returns [B, S, H, vd].
+    Mosaic handles minor dims that are no multiple of 128 lanes (64, 192):
+    each width is kept as it is.  The default scale is ``1 / sqrt(hd)``, of
+    the q/k width.
     """
     B, S, H, hd = q.shape
     KV = k.shape[2]

@@ -28,7 +28,13 @@ On TPU every reference feature maps onto a ``jax.checkpoint`` policy:
                                         (:func:`select_saved`,
                                         :func:`layer_policy`); with no memory
                                         report, or outside an engine's step:
-                                        ``nothing_saveable``, full recompute
+                                        ``nothing_saveable``, full recompute.
+                                        Each trained model lists its own
+                                        layer's values (``models/
+                                        transformer.py``; ``models/
+                                        joyai_flash.py``: the expanded k/v,
+                                        the sorted pairs' rows) and asks
+                                        :func:`resolve_policy`
   ====================================  =======================================
 """
 from __future__ import annotations
@@ -155,6 +161,31 @@ def layer_policy(tensors: Sequence[Saveable], reserve_bytes: int,
     if not saved:
         return jax.checkpoint_policies.nothing_saveable
     return jax.checkpoint_policies.save_only_these_names(*saved)
+
+
+def resolve_policy(name: str, layout: Callable[[], Tuple[Sequence[Saveable],
+                                                          int]],
+                   layers: int):
+    """The ``jax.checkpoint`` policy of a model's checkpointed layers — the
+    one rule every trained model asks (``models/transformer.py``, ``models/
+    joyai_flash.py``): a DS-config ``activation_checkpointing`` block
+    (partition_activations / cpu_checkpointing) overrides the model's own
+    choice, the config toggle must change execution; ``"auto"`` is
+    :func:`layer_policy` over the model's ``layout()`` (its layer's named
+    values and the bytes the step needs beside them); any other ``name`` is
+    a member of ``jax.checkpoint_policies``."""
+    if active():
+        return get_policy()
+    if name == "auto":
+        return layer_policy(*layout(), layers=layers)
+    policy = getattr(jax.checkpoint_policies, name, None)
+    if not callable(policy):
+        valid = ["auto"] + [n for n in dir(jax.checkpoint_policies)
+                            if not n.startswith("_")]
+        raise ValueError(
+            f"remat_policy={name!r} is not a "
+            f"jax.checkpoint_policies member; valid: {valid}")
+    return policy
 
 
 def active() -> bool:

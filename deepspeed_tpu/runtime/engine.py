@@ -66,6 +66,10 @@ class EngineState:
     grad_acc: Any                  # fp32 grad accumulation buffer (or None)
     rng: jax.Array
     comm_error: Any = None         # LoCo error feedback (explicit-comm path)
+    #: state the model declares and the optimizer does NOT train (a routing
+    #: bias the step balances, counters): replicated, updated inside the
+    #: compiled step by the model's own rule, saved with a checkpoint
+    model_state: Any = None
 
 
 def _tree_zeros_like(tree, dtype=jnp.float32):
@@ -245,6 +249,21 @@ class DeepSpeedEngine:
             or (self.overlap.enabled and self.overlap.explicit_wire))
         if zc.zero_quantized_weights and self.zero_stage < 3:
             logger.warning("zero_quantized_weights ignored below ZeRO stage 3")
+        # ---- model state the optimizer does not train ---------------- #
+        # A model may declare ``init_model_state()`` and ``next_model_state(
+        # state, counted)``: its ``loss_fn(params, batch, rng, state)`` then
+        # returns ``(loss, counted)`` and the fused step maps (state, what
+        # the step's micro-batches counted, added up) to the next state —
+        # no gradient, no weight decay, no clipping, no loss scale, no host
+        # sync.  A model that declares none compiles the program it always
+        # did (``None`` has no leaves).
+        self._model_rule = getattr(model, "next_model_state", None) \
+            if hasattr(model, "init_model_state") else None
+        if self._model_rule is not None and self._explicit_comm:
+            raise NotImplementedError(
+                "a model that declares init_model_state() trains through "
+                "the fused train_batch() step only, not the explicit-comm "
+                "path (quantized wires, sparse gradients, explicit_wire)")
         comm_error = None
         if zc.zero_quantized_gradients and getattr(zc, "zeropp_loco", False):
             from .comm.hierarchical import hop_axes, two_hop_loco_sizes
@@ -300,6 +319,8 @@ class DeepSpeedEngine:
             grad_acc=grad_acc,
             rng=on_mesh(jax.random.PRNGKey(seed)),
             comm_error=comm_error,
+            model_state=None if self._model_rule is None
+            else on_mesh(model.init_model_state()),
         )
 
         self._device_memory = self._account_device_memory()
@@ -546,7 +567,10 @@ class DeepSpeedEngine:
     def close(self):
         """Release host-side resources (watchdog thread) and flush
         observability sinks (monitor writers, telemetry exports); engine
-        state and compiled functions stay usable."""
+        state and compiled functions stay usable.  A model's state the
+        optimizer does not train is fetched here, once, and what the model
+        says of it goes on the tracer (``train/model_state``)."""
+        self.report_model_state()
         if self.watchdog is not None:
             self.watchdog.stop()
             self.watchdog = None
@@ -578,6 +602,20 @@ class DeepSpeedEngine:
             if get_telemetry() is self.telemetry:
                 set_telemetry(None)
             self.telemetry = None
+
+    def report_model_state(self) -> Optional[Dict[str, Any]]:
+        """One ``train/model_state`` record on the process-global tracer:
+        the model's own account (``model_state_report``) of the state the
+        step carries for it, fetched from the device now — the only sync it
+        ever costs, so a caller asks after its window, not inside."""
+        report = getattr(self.module, "model_state_report", None)
+        if report is None or self.state.model_state is None:
+            return None
+        t0 = time.perf_counter()
+        attrs = report(jax.device_get(self.state.model_state))
+        get_tracer().record("train/model_state", t0,
+                            time.perf_counter() - t0, **attrs)
+        return attrs
 
     # ------------------------------------------------------------------ #
     # Introspection API (reference names)
@@ -767,21 +805,48 @@ class DeepSpeedEngine:
         reduce-scatter it induces then overlaps the next micro-batch's
         compute) instead of inline.
         """
+        loss, grads, _ = self._loss_grads_counted(params, batch, rng,
+                                                  scaler_state, constrain)
+        return loss, grads
+
+    def _loss_grads_counted(self, params, batch, rng, scaler_state,
+                            constrain=True, model_state=None):
+        """:meth:`_loss_and_grads`, and what the forward counted for the
+        model's own state (``None`` for a model that declares none: its
+        ``loss_fn`` is called as it always was)."""
 
         def scaled_loss(p32):
             # the masters' cast: under ZeRO-3 what follows it is the gather
             with jax.named_scope("zero/gather_params"):
                 p = jax.tree.map(lambda x: x.astype(self.compute_dtype), p32)
             with _act_ckpt.engine_memory(*self._device_memory):
-                out = self.loss_fn(p, batch, rng)
+                if model_state is None:
+                    out = self.loss_fn(p, batch, rng)
+                else:
+                    out = self.loss_fn(p, batch, rng, model_state)
             loss = out[0] if isinstance(out, tuple) else out
-            return self.loss_scaler.scale_loss(loss.astype(jnp.float32), scaler_state), loss
+            counted = None if model_state is None else out[1]
+            return self.loss_scaler.scale_loss(loss.astype(jnp.float32), scaler_state), \
+                (loss, counted)
 
-        grads, loss = jax.grad(scaled_loss, has_aux=True)(params)
+        grads, (loss, counted) = jax.grad(scaled_loss, has_aux=True)(params)
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if constrain:
             grads = self._constrain_grads(grads)
-        return loss, grads
+        return loss, grads, counted
+
+    def _next_model_state(self, state: EngineState, new_state: EngineState,
+                          counted):
+        """The model's rule on (its state, what the step counted); a step
+        the dynamic loss scale skipped leaves it as it was."""
+        if state.model_state is None:
+            return new_state
+        nxt = self._model_rule(state.model_state, counted)
+        if self.loss_scaler.dynamic:
+            skipped = new_state.skipped_steps != state.skipped_steps
+            nxt = jax.tree.map(lambda n, o: jnp.where(skipped, o, n), nxt,
+                               state.model_state)
+        return new_state.replace(model_state=nxt)
 
     def _constrain_grads(self, grads):
         """Apply ZeRO-2/3 grad sharding (XLA lowers the psum into reduce-scatter)."""
@@ -864,8 +929,14 @@ class DeepSpeedEngine:
         def step_fn(state: EngineState, batch):
             rng, sub = jax.random.split(state.rng)
 
+            # what each micro-batch counted for the model's own state, added
+            # up over the step (None where the model declares none)
+            added = lambda stack: jax.tree.map(  # noqa: E731
+                lambda x: x.sum(axis=0), stack)
             if gas == 1:
-                loss, grads = self._loss_and_grads(state.params, batch, sub, state.scaler)
+                loss, grads, counted = self._loss_grads_counted(
+                    state.params, batch, sub, state.scaler,
+                    model_state=state.model_state)
                 mean_loss = loss
             elif use_deferred:
                 from .overlap.deferred import DeferredAccumulator
@@ -876,34 +947,39 @@ class DeepSpeedEngine:
                 def micro(carry, mb):
                     acc, pending, r = carry
                     r, r2 = jax.random.split(r)
-                    loss, grads = self._loss_and_grads(
-                        state.params, mb, r2, state.scaler, constrain=False)
+                    loss, grads, counted = self._loss_grads_counted(
+                        state.params, mb, r2, state.scaler, constrain=False,
+                        model_state=state.model_state)
                     acc, pending = reducer.step((acc, pending), grads)
-                    return (acc, pending, r), loss
+                    return (acc, pending, r), (loss, counted)
 
                 zeros = self._constrain_grads(_tree_zeros_like(state.params))
-                (acc, pending, _), losses = jax.lax.scan(
+                (acc, pending, _), (losses, counted) = jax.lax.scan(
                     micro, (zeros, _tree_zeros_like(state.params), sub),
                     batch)
                 grads = reducer.flush((acc, pending))
                 grads = jax.tree.map(lambda g: g / gas, grads)
-                mean_loss = losses.mean()
+                mean_loss, counted = losses.mean(), added(counted)
             else:
                 # batch leaves: [gas, micro_global, ...]
                 def micro(carry, mb):
                     acc, r = carry
                     r, r2 = jax.random.split(r)
-                    loss, grads = self._loss_and_grads(state.params, mb, r2, state.scaler)
+                    loss, grads, counted = self._loss_grads_counted(
+                        state.params, mb, r2, state.scaler,
+                        model_state=state.model_state)
                     acc = jax.tree.map(jnp.add, acc, grads)
-                    return (acc, r), loss
+                    return (acc, r), (loss, counted)
 
                 zeros = _tree_zeros_like(state.params)
                 zeros = self._constrain_grads(zeros)
-                (grads, _), losses = jax.lax.scan(micro, (zeros, sub), batch)
+                (grads, _), (losses, counted) = jax.lax.scan(
+                    micro, (zeros, sub), batch)
                 grads = jax.tree.map(lambda g: g / gas, grads)
-                mean_loss = losses.mean()
+                mean_loss, counted = losses.mean(), added(counted)
 
             new_state = self._apply_update(state, grads)
+            new_state = self._next_model_state(state, new_state, counted)
             new_state = new_state.replace(micro_step=state.micro_step + gas, rng=rng)
             return new_state, mean_loss
 
@@ -1264,14 +1340,16 @@ class DeepSpeedEngine:
     def forward(self, batch, rng: Optional[jax.Array] = None):
         """Loss-only forward (eval). For the training loop use backward()/step()."""
         if "forward" not in self._compiled:
-            def fwd(params, batch, rng, scaler):
+            def fwd(params, batch, rng, model_state):
                 p = jax.tree.map(lambda x: x.astype(self.compute_dtype), params)
-                out = self.loss_fn(p, batch, rng)
-                return out
+                if model_state is None:
+                    return self.loss_fn(p, batch, rng)
+                return self.loss_fn(p, batch, rng, model_state)
 
             self._compiled["forward"] = jax.jit(fwd)
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        return self._compiled["forward"](self.state.params, batch, rng, self.state.scaler)
+        return self._compiled["forward"](self.state.params, batch, rng,
+                                         self.state.model_state)
 
     __call__ = forward
 
@@ -1282,6 +1360,10 @@ class DeepSpeedEngine:
         ``forward``), JAX differentiates the loss *function*, so backward takes
         the micro-batch. Returns the micro-batch loss.
         """
+        if self._model_rule is not None:
+            raise NotImplementedError(
+                "a model that declares init_model_state() trains through "
+                "train_batch(): backward()/step() carry no model state")
         if self.state.grad_acc is None and self.gradient_accumulation_steps() > 1 \
                 and not self._explicit_comm:
             raise RuntimeError("grad accumulation buffer missing")
@@ -1440,6 +1522,13 @@ class DeepSpeedEngine:
                 payload = engine.load({"state": self.state, "client_state": None,
                                        "lr_scheduler": None, "config": None}, tag)
         restored = payload["state"]
+        # The restored tree has the target's leaves in the target's order
+        # (the graft walks the target) but an empty node comes back as a
+        # bare list (optax's zero-field states: AdamW's decayed-weights
+        # one), which is no prefix of the shardings below: take the live
+        # state's own structure.
+        restored = jax.tree.unflatten(jax.tree.structure(self.state),
+                                      jax.tree.leaves(restored))
         # Re-place on this engine's target shardings (restore may commit
         # scalar leaves to a single device, which conflicts under jit).
         target = jax.tree.map(

@@ -110,6 +110,26 @@ def _flash(grad, model_blocks=False):
     return build
 
 
+def _flash_latent(grad):
+    """Latent attention's training shape: q/k 192 wide, v 128, 32 heads,
+    2 x 4096 tokens, at the model's blocks (the JoyAI-LLM-Flash cell)."""
+    def build(dev):
+        from deepspeed_tpu.models.joyai_flash import JoyAIFlashConfig
+        from deepspeed_tpu.ops.transformer.flash_attention import \
+            flash_attention
+
+        cfg = JoyAIFlashConfig()
+        blocks = dict(block_q=cfg.flash_block_q, block_k=cfg.flash_block_k)
+        qk = _on(dev, (2, 4096, cfg.num_heads, cfg.qk_head_dim))
+        v = _on(dev, (2, 4096, cfg.num_heads, cfg.v_head_dim))
+        if not grad:
+            return partial(flash_attention, **blocks), (qk, qk, v)
+        loss = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, **blocks).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2)), (qk, qk, v)
+    return build
+
+
 def _paged(decode):
     def build(dev):
         from deepspeed_tpu.inference.v2.kernels.ragged_ops import (
@@ -472,6 +492,35 @@ def _grouped_matmul_train(dev):
     return grads, (_on(dev, (8192, D)), _on(dev, (8, D, F)),
                    _on(dev, (8, D, F)), _on(dev, (8, F, D)),
                    _on(dev, (8,), jnp.int32))
+
+
+def _joyai_expert_layer(dev):
+    """The JoyAI-LLM-Flash cell's expert layer as a chip's share, forward
+    and backward: 8,192 tokens, the top 8 of 256 router outputs, 16 experts
+    of 2048 -> 768 -> 2048 held — 65,536 pair rows of which a sixteenth lie
+    in a group, the rest behind the last one (``moe/dropless.py``,
+    ``trained``) — beside the shared expert."""
+    from deepspeed_tpu.models.joyai_flash import JoyAIFlashConfig, moe_block
+
+    cfg = JoyAIFlashConfig(experts_held=16)
+    D_, F_, E_ = cfg.hidden_size, cfg.moe_intermediate_size, cfg.held
+
+    def grads(h, router, experts, shared, bias):
+        def loss(h, router, experts, shared):
+            lp = {"router": {"kernel": router}, "experts": experts,
+                  "shared": shared}
+            return moe_block(h, lp, bias, cfg)[0].astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(h, router, experts,
+                                                    shared)
+
+    ffn = lambda *lead: {"gate": _on(dev, lead + (D_, F_)),  # noqa: E731
+                         "up": _on(dev, lead + (D_, F_)),
+                         "down": _on(dev, lead + (F_, D_))}
+    return grads, (_on(dev, (8192, D_)),
+                   _on(dev, (D_, cfg.n_routed_experts), jnp.float32),
+                   ffn(E_), ffn(), _on(dev, (cfg.n_routed_experts,),
+                                       jnp.float32))
 
 
 def _xing4_decode_window(dev):
@@ -839,11 +888,14 @@ CASES = {
     "grouped_matmul[256 pairs]": _grouped_matmul(256),
     "grouped_matmul[2048 pairs]": _grouped_matmul(2048),
     "grouped_matmul[8192 pairs, trained]": _grouped_matmul_train,
+    "expert_layer[16 of 256 held, 65536 pairs, trained]": _joyai_expert_layer,
     "xing4_decode_window": _xing4_decode_window,
     "flash_fwd": _flash(grad=False),
     "flash_bwd": _flash(grad=True),
     "flash_fwd[the model's blocks]": _flash(grad=False, model_blocks=True),
     "flash_bwd[the model's blocks]": _flash(grad=True, model_blocks=True),
+    "flash_fwd[q/k 192, v 128]": _flash_latent(grad=False),
+    "flash_bwd[q/k 192, v 128]": _flash_latent(grad=True),
     "decode_paged_attention": _paged(decode=True),
     "ragged_paged_attention": _paged(decode=False),
     "decode_paged_attention[4 rows]": _paged_decode_cell(4),

@@ -111,6 +111,41 @@ def test_backward_matches_xla(dtype, S, KV):
         _assert_close(a, b, dtype, 5e-3)
 
 
+@pytest.mark.parametrize("dtype", [F32, BF16])
+@pytest.mark.parametrize("hd, vd", [(192, 128), (96, 64), (64, 64),
+                                    (128, 128)])
+def test_v_width_of_its_own(hd, vd, dtype):
+    """Latent attention trains at q/k width 192 and v width 128: forward,
+    dq, dk and dv against XLA math, the equal-width cases beside them.  The
+    output and dv are ``vd`` wide, dq and dk ``hd``; the scale is the q/k
+    width's."""
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+
+    B, S, H = 1, 200, 2                 # 200: a padded last block
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    shapes = ((B, S, H, hd), (B, S, H, hd), (B, S, H, vd))
+    qkv = [jax.random.normal(k, s, F32).astype(dtype)
+           for k, s in zip(keys, shapes)]
+    f32 = [x.astype(F32) for x in qkv]
+
+    out = flash_attention(*qkv, causal=True, block_q=128, block_k=128)
+    assert out.shape == (B, S, H, vd) and out.dtype == dtype
+    _assert_close(out, _xla_attention(*f32, causal=True), dtype, 2e-3)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=128,
+                                       block_k=128).astype(F32) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(_xla_attention(q, k, v, causal=True) ** 2)
+
+    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(*qkv)
+    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(*f32)
+    for a, b, x in zip(g1, g2, qkv):
+        assert a.dtype == dtype and a.shape == x.shape
+        _assert_close(a, b, dtype, 5e-3)
+
+
 def _kernel_dots(fn, *args):
     """``{kernel name: [(lhs dtype, rhs dtype, result dtype), ...]}`` of
     every ``dot_general`` inside the Pallas kernels ``fn`` traces to."""
